@@ -180,7 +180,7 @@ def effective_band_width(banding: "BandingOptions", jmax: int) -> int:
     TWO guided passes -- one read unmated and the ZMW ran away on weak
     evidence (+834 bases, bucket overflow, round-5 bench draw).  But the
     measured cost of the width was real: cfg3's 15 kb band occupancy was
-    0.465 at W=128 (BENCH_r05.json), i.e. more than half the band
+    0.465 at W=128, i.e. more than half the band
     compute, VMEM, and HBM traffic polished empty lanes.  The round-6
     schedule fixes the CAUSE instead of widening around it: long buckets
     run a THIRD argmax-guided refill pass (scorer.guided_fill_passes),

@@ -242,7 +242,7 @@ def fill_alpha_beta_batch_zr(reads, rlens, win_tpl, win_trans, wlens,
         return jax.tree.map(unflat, out)
 
     from jax.sharding import PartitionSpec
-    from pbccs_tpu.parallel.mesh import READ_AXIS, ZMW_AXIS, shard_map
+    from pbccs_tpu.parallel.mesh import READ_AXIS, ZMW_AXIS
 
     def body(r, i, t, tr, j):
         # each device runs the unsharded path on its local (Z/nz, R/nr) block
@@ -252,7 +252,7 @@ def fill_alpha_beta_batch_zr(reads, rlens, win_tpl, win_trans, wlens,
     spec = PartitionSpec(ZMW_AXIS, READ_AXIS)
     # check_vma=False: pallas_call's out_shapes carry no varying-mesh-axes
     # metadata; the body is per-read elementwise so nothing varies anyway
-    return shard_map(body, mesh=mesh, in_specs=spec, out_specs=spec,
+    return jax.shard_map(body, mesh=mesh, in_specs=spec, out_specs=spec,
                      check_vma=False)(
         reads, rlens, win_tpl, win_trans, wlens)
 

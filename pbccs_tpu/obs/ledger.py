@@ -417,7 +417,7 @@ def run_record(scope: MeasurementScope, *, kind: str, source: str,
             achieved = rl_flops / 1e12 / rl_wall
             peak = _roofline.tracker().peak_tflops()
             rec["roofline_achieved_tflops"] = float(f"{achieved:.6g}")
-            if peak > 0:
+            if peak:
                 rec["roofline_efficiency"] = float(
                     f"{achieved / peak:.6g}")
     if workload is not None:

@@ -36,8 +36,10 @@ path.  PBCCS_ROOFLINE=0 disables the whole plane.
 Achieved TFLOP/s is flops-charged / refine WALL seconds -- a lower
 bound on device rate (conservative by construction); kernel_fraction
 (device-wait / wall) says how much of the gap is host overhead.
-Efficiency divides by a nominal per-platform peak
-(PLATFORM_PEAK_TFLOPS, override PBCCS_ROOFLINE_PEAK_TFLOPS).
+Efficiency divides by the published peak of the TPU `device_kind` that
+ran (TPU_PEAK_TFLOPS; a kind with no entry yields NO efficiency figure),
+a nominal ceiling off-TPU (HOST_PEAK_TFLOPS), or
+PBCCS_ROOFLINE_PEAK_TFLOPS where set.
 """
 
 from __future__ import annotations
@@ -54,12 +56,17 @@ from pbccs_tpu.obs import metrics as _metrics
 ROOFLINE_SCHEMA_VERSION = 1
 CARDS_BASENAME = "roofline_cards.json"
 
-# Nominal dense-compute ceilings (TFLOP/s) used as the efficiency
-# denominator.  These are deliberately coarse -- the defended metric is
-# the *trend*, not the absolute -- and PBCCS_ROOFLINE_PEAK_TFLOPS
-# overrides them for calibrated fleets.
-PLATFORM_PEAK_TFLOPS = {
-    "tpu": 275.0,   # v4-class MXU bf16 peak per chip
+# Efficiency denominators (TFLOP/s); PBCCS_ROOFLINE_PEAK_TFLOPS overrides
+# them for calibrated fleets.  On a TPU the peak is the published
+# per-chip bf16 figure of the `device_kind` jax reports -- a kind that is
+# not in the table gets no efficiency figure, never another chip's peak.
+TPU_PEAK_TFLOPS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16 per chip
+    "TPU v5 lite": 197.0,
+}
+# Off-TPU the ceilings are deliberately coarse (the defended metric is
+# the trend, not the absolute).
+HOST_PEAK_TFLOPS = {
     "gpu": 60.0,
     "cpu": 0.1,     # ~one AVX2 core's worth; CI runs are single-core
 }
@@ -316,6 +323,7 @@ class RooflineTracker:
         self._buckets: dict[str, _Bucket] = {}
         self._loaded_from: str | None = None
         self._peak: float | None = None
+        self._peak_resolved = False
 
     # -- cards ---------------------------------------------------------
 
@@ -471,9 +479,11 @@ class RooflineTracker:
 
     # -- derived gauges / reporting -----------------------------------
 
-    def peak_tflops(self) -> float:
+    def peak_tflops(self) -> float | None:
+        """The efficiency denominator, or None when this device has no
+        known peak (logged once): efficiency is then not reported."""
         with self._lock:
-            if self._peak is not None:
+            if self._peak_resolved:
                 return self._peak
         peak = None
         env = os.environ.get("PBCCS_ROOFLINE_PEAK_TFLOPS")
@@ -483,14 +493,25 @@ class RooflineTracker:
             except ValueError:
                 peak = None
         if peak is None:
+            import jax
+
+            from pbccs_tpu.runtime.logging import Logger
+
             try:
-                import jax
-                platform = jax.default_backend()
-            except Exception:
-                platform = "cpu"
-            peak = PLATFORM_PEAK_TFLOPS.get(platform, 1.0)
+                dev = jax.devices()[0]
+                table, key = ((TPU_PEAK_TFLOPS, dev.device_kind)
+                              if dev.platform == "tpu"
+                              else (HOST_PEAK_TFLOPS, dev.platform))
+                peak = table.get(key)
+                why = f"no published peak for device {key!r}"
+            except RuntimeError as e:
+                why = f"jax could not be asked for its device ({e})"
+            if peak is None:
+                Logger.default().warn(
+                    f"roofline: {why}; no efficiency figure is reported "
+                    "(set PBCCS_ROOFLINE_PEAK_TFLOPS to supply one)")
         with self._lock:
-            self._peak = peak
+            self._peak, self._peak_resolved = peak, True
             return self._peak
 
     def _refresh_gauges(self, label: str) -> None:
@@ -510,16 +531,17 @@ class RooflineTracker:
         gauge(ACHIEVED_TFLOPS, "Achieved TFLOP/s vs the CostCard bound "
           "(flops charged / refine wall; a lower bound on device rate)",
           bucket=label).set(_sig(achieved))
-        gauge(EFFICIENCY, "Achieved TFLOP/s over the nominal platform peak",
-          bucket=label).set(_sig(achieved / peak) if peak > 0 else 0.0)
+        if peak:
+            gauge(EFFICIENCY, "Achieved TFLOP/s over the device's peak",
+              bucket=label).set(_sig(achieved / peak))
         gauge(KERNEL_FRACTION, "Device-wait share of measured wall per "
           "bucket (roofline plane)", bucket=label).set(_sig(kfrac))
         overall = (tot_flops / 1e12 / tot_wall) if tot_wall > 0 else 0.0
         gauge(ACHIEVED_OVERALL, "Achieved TFLOP/s across all buckets "
           "(roofline plane)").set(_sig(overall))
-        gauge(EFFICIENCY_OVERALL, "Fleet-level achieved/peak efficiency "
-          "(roofline plane)").set(
-              _sig(overall / peak) if peak > 0 else 0.0)
+        if peak:
+            gauge(EFFICIENCY_OVERALL, "Fleet-level achieved/peak efficiency "
+              "(roofline plane)").set(_sig(overall / peak))
 
     def status_block(self) -> dict | None:
         """The status-verb `roofline` block (serve/protocol.py
@@ -537,7 +559,6 @@ class RooflineTracker:
                                  card_z=b.card.z)
                 achieved = (b.flops / 1e12 / b.refine_s) \
                     if b.refine_s > 0 else 0.0
-                peak = self._peak or 0.0
                 entry.update(
                     flops_charged=b.flops,
                     refine_s=round(b.refine_s, 4),
@@ -549,7 +570,7 @@ class RooflineTracker:
         peak = self.peak_tflops()
         for entry in buckets.values():
             a = entry.get("achieved_tflops", 0.0)
-            entry["efficiency"] = _sig(a / peak) if peak > 0 else 0.0
+            entry["efficiency"] = _sig(a / peak) if peak else None
         return {"schema_version": ROOFLINE_SCHEMA_VERSION,
                 "peak_tflops": peak, "buckets": buckets}
 
@@ -557,7 +578,7 @@ class RooflineTracker:
         with self._lock:
             self._buckets.clear()
             self._loaded_from = None
-            self._peak = None
+            self._peak, self._peak_resolved = None, False
 
 
 _tracker = RooflineTracker()

@@ -1,6 +1,6 @@
 """Pallas TPU kernel for dense interior mutation scoring over the slot grid.
 
-The round-3 device profile (docs/PROFILE_r03.md) showed the chunked
+The round-3 device profile showed the chunked
 mutation-scoring programs are HBM-bandwidth-bound: every elementwise step of
 the packed (Z, R, chunk, W) pipeline materializes a ~1.6 GB intermediate.
 This kernel replaced that path.  Its achieved-vs-bound gap is no longer
@@ -95,10 +95,7 @@ def dense_score_enabled(jmax: int | None = None) -> bool:
     env = os.environ.get("PBCCS_DENSE")
     if env is not None:
         return env.strip().lower() not in ("0", "false", "off", "no", "")
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _interpret() -> bool:
@@ -584,11 +581,14 @@ def build_dense_layout(reads, rlens, win_tpl, win_trans, wlens, tables,
         rw_base=rbase, rw_next=rnext)
 
 
-def layout_ptrans(layout: DenseLayout, jmax: int):
-    """(R, Jm, 9, 2, 4) patch-transition grid recovered from the baked
+def layout_ptrans72(layout: DenseLayout, jmax: int):
+    """(R, Jm, 72) patch-transition plane recovered from the baked
     72-lane plane (un-halo + un-pad is a slice/reshape XLA lowers to
     copies), so edge programs fed a DenseLayout need no second
-    dense_patch_grids pass and no duplicate unblocked plane in HBM."""
+    dense_patch_grids pass and no duplicate unblocked plane in HBM.
+    Kept 72 lanes wide: the (9, 2, 4) view of a whole plane tiles its
+    (2, 4) minor dims to (4, 128) on the TPU -- 14x the bytes, 6.6 GB at
+    a 64 x 12 x 2240 batch, which alone overflowed a v5e's HBM."""
     ptr = layout.ptr
     if ptr.ndim == 4:                       # halo'd step view
         R, nbc, rows, _ = ptr.shape
@@ -597,8 +597,12 @@ def layout_ptrans(layout: DenseLayout, jmax: int):
         # the last _OFF0 rows of the padded frame live in the final
         # step's halo section (_OFF0 <= _HALO by construction)
         ptr = jnp.concatenate([core, ptr[:, -1, step:]], axis=1)
-    return ptr[:, _OFF0: _OFF0 + jmax].reshape(
-        ptr.shape[0], jmax, 9, 2, 4)
+    return ptr[:, _OFF0: _OFF0 + jmax]
+
+
+def layout_ptrans(layout: DenseLayout, jmax: int):
+    """layout_ptrans72 viewed as the (R, Jm, 9, 2, 4) patch grid."""
+    return layout_ptrans72(layout, jmax).reshape(-1, jmax, 9, 2, 4)
 
 
 @functools.partial(jax.jit, static_argnames=("width",))
@@ -830,7 +834,7 @@ def _edge_nb_read(wins, I, tpl, trans, J, offs, bvals, boffs, bsuf, pt3,
     return jnp.log(jnp.maximum(v, _TINY)) + bsuf_b
 
 
-def _edge_ne_read(wins, I, tpl, trans, J, avals, offs, apre, ptrans,
+def _edge_ne_read(wins, I, tpl, trans, J, avals, offs, apre, pt72,
                   *, W: int):
     """Near-end scores of one read: (27,) absolute LLs for slots at
     window positions {J-2, J-1, J}.  Mirrors edge_scores_fast's near-end
@@ -838,7 +842,8 @@ def _edge_ne_read(wins, I, tpl, trans, J, avals, offs, apre, ptrans,
     corner; LL = log corner + alpha scale prefix.  Geometry is static in
     the J-relative frame, so every load is one contiguous dynamic slice.
     `wins` are this read's precomputed circular read windows
-    (_edge_read_windows rows 5-10 = columns J-3..J+2).
+    (_edge_read_windows rows 5-10 = columns J-3..J+2); `pt72` is its
+    (Jm, 72) patch-transition plane, of which only rows J-2..J are read.
     Caller guarantees J >= 8 (tiny windows bail to the host path)."""
     from pbccs_tpu.ops.mutation_score import _ext_col
 
@@ -858,7 +863,7 @@ def _edge_ne_read(wins, I, tpl, trans, J, avals, offs, apre, ptrans,
         jnp.concatenate([tplf, jnp.full(4, 4.0)]), (J - 6,), (10,))
     transS = lax.dynamic_slice(
         jnp.concatenate([trans, jnp.zeros((3, 4))]), (J - 6, 0), (9, 4))
-    ptS = lax.dynamic_slice(ptrans, (J - 2, 0, 0, 0), (3, 9, 2, 4))
+    ptS = lax.dynamic_slice(pt72, (J - 2, 0), (3, 72)).reshape(3, 9, 2, 4)
     rb6 = wins[5:11]                                         # cols J-3..J+2
 
     # t = s - (J-4) in {1..4}, static per slot (s = p - [k==del])
@@ -945,18 +950,22 @@ def edge_window_scores_batch(reads, rlens, win_tpl, win_trans, wlens,
     precomputed band_read_windows (shared with the interior kernel);
     `layout`: a pre-baked DenseLayout, whose rw_base/rw_next pair serves
     the same role (and whose baked 72-lane plane recovers `ptrans` when
-    the caller passes None for it)."""
+    the caller passes None for it).  Only six rows of each read's patch
+    plane are read, so it travels 72 lanes wide and is viewed as
+    (9, 2, 4) only after slicing (see layout_ptrans72)."""
+    Jm = win_tpl.shape[1]
     if layout is not None:
         rwin = (layout.rw_base, layout.rw_next)
         if ptrans is None:
-            ptrans = layout_ptrans(layout, win_tpl.shape[1])
+            ptrans = layout_ptrans72(layout, Jm)
+    pt72 = ptrans.reshape(-1, Jm, 72)
     rbase, rnext = rwin if rwin is not None else \
         band_read_windows(reads, alpha.offsets, width)
     wins = _edge_read_windows(rbase, rnext, wlens.astype(jnp.int32), width)
 
     def one(w11, I, tpl, trans, J, avals, aoffs, bvals, boffs, ap, bs, pt):
         nb = _edge_nb_read(w11, I, tpl, trans, J, aoffs, bvals, boffs,
-                           bs, pt[:3], W=width)
+                           bs, pt[:3].reshape(3, 9, 2, 4), W=width)
         ne = _edge_ne_read(w11, I, tpl, trans, J, avals, aoffs, ap, pt,
                            W=width)
         return jnp.concatenate([nb.reshape(3, 9), ne.reshape(3, 9)])
@@ -966,7 +975,7 @@ def edge_window_scores_batch(reads, rlens, win_tpl, win_trans, wlens,
                          wlens.astype(jnp.int32),
                          alpha.vals, alpha.offsets.astype(jnp.int32),
                          beta.vals, beta.offsets.astype(jnp.int32),
-                         apre, bsuf, ptrans)
+                         apre, bsuf, pt72)
 
 
 def splice_edge_rows(grid, e6, J):

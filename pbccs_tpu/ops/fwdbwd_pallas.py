@@ -69,17 +69,6 @@ from pbccs_tpu.ops.fwdbwd import (MAX_BAND_ADVANCE, BandedMatrix,
                                   band_offsets, circ_roll, circ_rows,
                                   in_band)
 
-def tpu_compiler_params(**kwargs):
-    """Version-compat shim for the Mosaic compiler-params dataclass: newer
-    JAX names it pltpu.CompilerParams, this pin (0.4.x) calls it
-    TPUCompilerParams.  Shared by every Pallas fill site (the Arrow
-    forward/backward scan here and the Quiver fill, which routes through
-    _fill below)."""
-    cls = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)
-
-
 _TINY = 1e-30
 # band may advance at most this many rows per column; single source of
 # truth lives in fwdbwd (guided_band_offsets clamps its slope to it)
@@ -97,10 +86,7 @@ def fills_use_pallas() -> bool:
     env = os.environ.get("PBCCS_PALLAS")
     if env is not None:
         return env.strip().lower() not in ("0", "false", "off", "no", "")
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _interpret() -> bool:
@@ -441,7 +427,11 @@ def _run_fill(cm, cd, cc, mask, seed, seedcol, rev_store: bool,
     # the Merge carry (Quiver) doubles the live column state (prev2 + its
     # scale), so merge fills run half-width read blocks for VMEM headroom
     rb = min(_RB // 2 if merge else _RB, R)
-    jb = min(_JB, nc)
+    # a grid step holds four (jb, rb, W) coefficient/output blocks, each
+    # double-buffered: at the 2x-band mating retry's W=192 that came to
+    # 20 MB, over the 16 MB of VMEM the v5e's compiler grants a kernel,
+    # so bands wider than 128 take half the columns per step
+    jb = min(_JB if W <= 128 else _JB // 2, nc)
     assert nc % jb == 0 and R % rb == 0
     njb = nc // jb
 
@@ -481,7 +471,7 @@ def _run_fill(cm, cd, cc, mask, seed, seedcol, rev_store: bool,
             jax.ShapeDtypeStruct((nc, R, 1), jnp.float32),
         ],
         scratch_shapes=scratch,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(),
     )(*operands)

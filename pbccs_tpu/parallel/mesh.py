@@ -20,23 +20,6 @@ ZMW_AXIS = "zmw"
 READ_AXIS = "read"
 
 
-def shard_map(f, **kwargs):
-    """Version-compat shim: newer JAX exports jax.shard_map at top level
-    (with a `check_vma` kwarg), this pin (0.4.x) keeps it in
-    jax.experimental.shard_map with the same kwarg named `check_rep`.
-    Single sharding entry point for the fills (models/arrow/scorer.py)
-    and the sharded device-resident loop (parallel/device_refine.py)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    if "check_vma" in kwargs:
-        import inspect
-
-        if "check_vma" not in inspect.signature(sm).parameters:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-    return sm(f, **kwargs)
-
-
 def make_zmw_mesh(n_zmw: int | None = None, n_read: int = 1,
                   devices: Sequence[jax.Device] | None = None) -> Mesh:
     """A ('zmw', 'read') mesh over the available devices.

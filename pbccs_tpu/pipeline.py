@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+import threading
 import time
 import traceback
 from typing import Sequence
@@ -39,6 +40,16 @@ ADAPTER_BEFORE = 1
 ADAPTER_AFTER = 2
 
 _reg = default_registry()
+
+# One batch on the device at a time in the single-device driver.  The
+# CLI's WorkQueue workers each draft (host) and then polish (device); the
+# worker count follows the host's cores, and N workers polishing at once
+# hold N batches' fills in HBM -- on a 13-core host with one 16 GB chip
+# that was eight 64-ZMW batches, and every one of them ran out of memory.
+# Drafts still overlap the polish in flight.  The fleet scheduler (one
+# executor thread per device) and `ccs serve` (one polish worker) already
+# dispatch this way and do not come through process_chunks.
+_polish_turn = threading.Lock()
 
 # every entry into the shared batch-polish core (offline driver, sched
 # executor, serve flush, quarantine/OOM sub-dispatches re-enter): the
@@ -966,7 +977,7 @@ def process_chunks(chunks: Sequence[Chunk],
     if not preps:
         return tally
 
-    with obs_trace.span("polish", zmws=len(preps)):
+    with _polish_turn, obs_trace.span("polish", zmws=len(preps)):
         outcomes = polish_prepared_batch(preps, settings,
                                          on_error=on_error)
     for failure, result in outcomes:

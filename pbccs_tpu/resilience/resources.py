@@ -44,6 +44,7 @@ Metrics: ``ccs_resource_oom_splits_total``,
 from __future__ import annotations
 
 import contextlib
+import functools
 import os
 import re
 import threading
@@ -161,8 +162,44 @@ def split_sizes(n: int, cap: int) -> list[int]:
     return out
 
 
+# Device memory one polish dispatch needs for each read-column (one
+# template column of one read) of its pinned bucket: the banded fills, the
+# dense kernel's pre-baked layout of them, and the refine loop's working
+# set.  Compiles of run_refine_loop for a described TPU v5e (PR 23) came
+# to 10-17 KB per read-column across the 300 bp .. 2 kb buckets, and the
+# CLI's default 64-ZMW batch at 2 kb x 12 reads did not fit its 16 GB.
+BYTES_PER_READ_COLUMN = 16 << 10
+
+
+@functools.lru_cache(maxsize=1)
+def device_bytes_limit() -> int | None:
+    """Memory of one local device as the backend reports it, or None
+    where it reports none (the CPU backend)."""
+    import jax
+
+    stats = jax.local_devices()[0].memory_stats()
+    return (stats or {}).get("bytes_limit")
+
+
+def modelled_cap(bucket: Hashable) -> int | None:
+    """The largest power-of-two Z of a ("shape", Imax, Jmax, R) bucket
+    whose dispatch BYTES_PER_READ_COLUMN says fits this device; None for
+    any other key, or where the device reports no memory limit."""
+    if not (isinstance(bucket, tuple) and len(bucket) == 4
+            and bucket[0] == "shape"):
+        return None
+    limit = device_bytes_limit()
+    if not limit:
+        return None
+    _, _imax, jmax, r = bucket
+    z = max(1, limit // (BYTES_PER_READ_COLUMN * int(r) * int(jmax)))
+    return 1 << (z.bit_length() - 1)
+
+
 class MemoryGovernor:
-    """Per-(device, shape-bucket) Z ceilings learned from OOM failures.
+    """Per-(device, shape-bucket) Z ceilings: what the device's memory
+    admits by the per-read-column model (modelled_cap), lowered by what
+    OOM failures teach.
 
     ``record_oom(bucket, z)`` after a capacity failure at batch size z
     lowers the ceiling to max(1, z // 2); ``cap(bucket)`` returns the
@@ -205,9 +242,16 @@ class MemoryGovernor:
     def cap(self, bucket: Hashable, device: str | None = None
             ) -> int | None:
         """The admission Z ceiling for bucket on device (None = no
-        limit learned).  device=None returns the fleet-wide minimum --
+        limit known).  device=None returns the fleet-wide minimum --
         the conservative bound callers that have not yet picked a
         device (the serve flush split) must respect."""
+        learned = self._learned(bucket, device)
+        modelled = modelled_cap(bucket)
+        if learned is None or modelled is None:
+            return learned if modelled is None else modelled
+        return min(learned, modelled)
+
+    def _learned(self, bucket: Hashable, device: str | None) -> int | None:
         with self._lock:
             per_dev = self._ceilings.get(bucket)
             if not per_dev:

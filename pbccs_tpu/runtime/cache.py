@@ -1,16 +1,19 @@
 """Persistent JAX compilation-cache setup shared by the CLI and bench.
 
 The polish programs take minutes to compile at batch shapes; cached
-executables make reruns start fast.  Respects a user-provided
-JAX_COMPILATION_CACHE_DIR (or an already-set config value) and falls back
-to the repo checkout's .jax_cache when writable, else a per-user cache
-directory."""
+executables make reruns start fast.  JAX_COMPILATION_CACHE_DIR, where
+set, decides the directory and nothing in code sets another; unset, the
+cache is the checkout's own .jax_cache (a fixed path: the path is part of
+the cache key, so a directory that moves never hits)."""
 
 from __future__ import annotations
 
 import contextlib
 import os
 import threading
+
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 _monitoring_installed = False
 _suppress_events = threading.local()
@@ -72,35 +75,22 @@ def _install_cache_metrics() -> None:
 
 
 def enable_compilation_cache(cache_dir: str | None = None) -> str:
-    """Point jax's persistent compilation cache at ``cache_dir`` (the
-    `--compileCache` flag), else an environment/config-provided dir,
-    else the checkout-local / per-user default.  An explicit dir is the
-    fleet-restart contract: every `ccs serve` replica and `ccs warmup`
-    pointed at the same directory shares one executable store, so a
-    rolling replica restart pays a disk load (seconds) instead of the
-    first-run XLA compile (~a minute per bucket shape)."""
+    """Point jax's persistent compilation cache at one directory and
+    return it.  JAX_COMPILATION_CACHE_DIR wins wherever it is set: the
+    machine that runs the program places the cache, and no argument
+    (`--compileCache`) overrides that.  Unset, `cache_dir` applies, else
+    `<checkout>/.jax_cache`, whether or not the checkout is a git
+    repository (the copy a chip run is made from is not).  A
+    shared directory is the fleet-restart contract: every `ccs serve`
+    replica and `ccs warmup` pointed at it shares one executable store,
+    so a rolling replica restart pays a disk load instead of the
+    first-run XLA compile."""
     import jax
 
     _install_cache_metrics()
 
-    configured = cache_dir or \
-        os.environ.get("JAX_COMPILATION_CACHE_DIR") or \
-        jax.config.jax_compilation_cache_dir
-    if configured:
-        cache_dir = configured
-    else:
-        repo = os.path.dirname(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))))
-        cache_dir = os.path.join(repo, ".jax_cache")
-        # use the checkout-local cache only when running from a source tree
-        # (a pip install would land this in site-packages, where executables
-        # are lost on upgrade) and it is actually writable
-        in_checkout = os.path.isdir(os.path.join(repo, ".git"))
-        writable = os.access(cache_dir if os.path.isdir(cache_dir) else repo,
-                             os.W_OK)
-        if not (in_checkout and writable):
-            cache_dir = os.path.join(
-                os.path.expanduser("~"), ".cache", "pbccs_tpu", "jax")
+    cache_dir = (os.environ.get("JAX_COMPILATION_CACHE_DIR") or cache_dir
+                 or os.path.join(_CHECKOUT, ".jax_cache"))
     jax.config.update("jax_compilation_cache_dir", cache_dir)
     # respect a user-provided min-compile-time; default to caching anything
     # that took >= 1 s to compile

@@ -651,8 +651,9 @@ def build_serve_parser() -> argparse.ArgumentParser:
                         "shared across replicas/restarts: a rolling "
                         "restart reloads its compiled polish programs "
                         "from disk in seconds instead of recompiling "
-                        "(default: JAX_COMPILATION_CACHE_DIR, else the "
-                        "checkout-local .jax_cache).")
+                        "(JAX_COMPILATION_CACHE_DIR, where set, wins "
+                        "over this flag; default: the checkout-local "
+                        ".jax_cache).")
     p.add_argument("--tuneProfile", default=None, metavar="PATH|auto",
                    help="ccs-tune host profile (runtime/tuning.py): "
                         "supplies defaults for --maxBatch/--maxWaitMs "

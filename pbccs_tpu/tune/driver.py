@@ -137,16 +137,15 @@ def _sha256(path: str) -> str:
 def _base_env(cfg: TuneConfig) -> dict[str, str]:
     """The candidate subprocess environment: inherit the host env minus
     any ambient knob overrides (an operator's PBCCS_BAND_W must not
-    contaminate every candidate) and minus any active profile; share
-    one persistent compilation cache across candidates so repeated
-    shapes compile once."""
+    contaminate every candidate) and minus any active profile.  The
+    candidates inherit JAX_COMPILATION_CACHE_DIR, or unset share the
+    checkout's .jax_cache (runtime/cache.py), so repeated shapes
+    compile once."""
     env = dict(os.environ)
     for k in space.BATCH_KNOBS:
         if k.apply == "env":
             env.pop(k.target, None)
     env.pop("PBCCS_TUNE_PROFILE", None)
-    env.setdefault("JAX_COMPILATION_CACHE_DIR",
-                   os.path.join(cfg.workdir, "jax_cache"))
     return env
 
 

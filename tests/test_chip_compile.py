@@ -1,0 +1,226 @@
+"""Compile the main-path device programs for a described TPU v5e, no chip.
+
+The TPU compiler is installed alongside jax and compiles for a topology
+that is described rather than attached, so these cases raise what the
+chip's compiler would raise (fast-memory limit, unaligned slice, HBM)
+at the real bucket shapes -- the things interpret-mode tests cannot see.
+Nothing runs: a pass here is not a chip run (chip_smoke.py is).
+
+This is the only file that describes the chip.  The description happens
+inside the `topo` fixture, never at import: only one process may load
+the TPU library, and under pytest-xdist every worker imports every test
+file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+HBM_BYTES = 16 * 1024 ** 3  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 -- any failure to describe = skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_persistent_cache():
+    """A compile for a described device is written to the persistent
+    cache but cannot be read back without a chip; conftest.py turns the
+    cache on, so turn it off around these compiles."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _on(tree, sharding):
+    """Shapes of `tree` placed on the described chip."""
+    return jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        tree)
+
+
+def _read_shapes(n: int, jmax: int):
+    """Flat read-batch arguments of the fill / dense kernels."""
+    from pbccs_tpu.parallel.batch import _imax_bucket
+
+    s = jax.ShapeDtypeStruct
+    imax = _imax_bucket(jmax)
+    return (s((n, imax), jnp.int8),        # reads
+            s((n,), jnp.int32),            # rlens
+            s((n, jmax), jnp.int8),        # window templates
+            s((n, jmax, 4), jnp.float32),  # window transitions
+            s((n,), jnp.int32))            # window lengths
+
+
+def _fill_case(n, jmax, width):
+    from pbccs_tpu.models.arrow.scorer import fill_alpha_beta_batch
+
+    def fn(*a):
+        return fill_alpha_beta_batch(*a, width, use_pallas=True)
+
+    return fn, _read_shapes(n, jmax)
+
+
+def _dense_case(n, jmax, width):
+    from pbccs_tpu.models.arrow.scorer import fill_alpha_beta_batch
+    from pbccs_tpu.ops.dense_score_pallas import dense_interior_scores_batch
+
+    reads = _read_shapes(n, jmax)
+    alpha, beta, _, _, apre, bsuf = jax.eval_shape(
+        lambda *a: fill_alpha_beta_batch(*a, width, use_pallas=True), *reads)
+    tables = jax.ShapeDtypeStruct((n, 8, 4), jnp.float32)
+
+    def fn(*a):
+        return dense_interior_scores_batch(*a, width)
+
+    return fn, reads + (tables, alpha, beta, apre, bsuf)
+
+
+def _bucket_shapes(z, r, jmax):
+    from pbccs_tpu.parallel.batch import _imax_bucket
+
+    s = jax.ShapeDtypeStruct
+    return (s((z, jmax), jnp.int8),                   # template tracks
+            s((z,), jnp.int32),                       # template lengths
+            s((z, 8, 4), jnp.float32),                # host transition tables
+            s((z, r, _imax_bucket(jmax)), jnp.int8),  # reads
+            s((z, r), jnp.int32),                     # rlens
+            s((z, r), jnp.int32),                     # strands
+            s((z, r), jnp.int32),                     # tstarts
+            s((z, r), jnp.int32))                     # tends
+
+
+def _bucket_statics(jmax):
+    from pbccs_tpu.models.arrow.params import (BandingOptions,
+                                               effective_band_width)
+    from pbccs_tpu.models.arrow.scorer import guided_fill_passes
+
+    return (effective_band_width(BandingOptions(), jmax),
+            guided_fill_passes(jmax))
+
+
+def _bucket_case(z, r, jmax):
+    from pbccs_tpu.parallel import batch
+
+    width, guided = _bucket_statics(jmax)
+
+    def fn(*a):
+        return batch.lowering_target()(*a, width, use_pallas=True, mesh=None,
+                                       guided_passes=guided)
+
+    return fn, _bucket_shapes(z, r, jmax)
+
+
+def _refine_loop_case(z, r, jmax):
+    """run_refine_loop as BatchPolisher.refine_device launches it on the
+    chip (dense scoring, Pallas fills), on the state _loop_state builds
+    from the bucket program's outputs."""
+    from pbccs_tpu.models.arrow.refine import RefineOptions
+    from pbccs_tpu.parallel import batch
+    from pbccs_tpu.parallel import device_refine as dr
+
+    width, guided = _bucket_statics(jmax)
+    tpl, tlens, tables, reads, rlens, strands, tstarts, tends = \
+        _bucket_shapes(z, r, jmax)
+
+    def loop_state(*a):
+        tpl, tlens, tables, reads, rlens, strands, tstarts, tends = a
+        (win_tpl, win_trans, wlens, alpha, beta, ll_a, ll_b, apre, bsuf,
+         trans_f, tpl_r, trans_r, _table, _mu, _var) = \
+            batch.lowering_target()(*a, width, use_pallas=True, mesh=None,
+                                    guided_passes=guided)
+        return dr.RefineLoopState(
+            tpl=tpl, tlens=tlens, tstarts=tstarts, tends=tends,
+            win_tpl=win_tpl, win_trans=win_trans, wlens=wlens,
+            alpha=alpha, beta=beta, a_prefix=apre, b_suffix=bsuf,
+            baselines=ll_b, trans_f=trans_f, tpl_r=tpl_r, trans_r=trans_r,
+            active=jnp.ones((z, r), bool), it=jnp.int32(0),
+            done=jnp.zeros(z, bool), converged=jnp.zeros(z, bool),
+            iterations=jnp.zeros(z, jnp.int32),
+            n_tested=jnp.zeros(z, jnp.int32),
+            n_applied=jnp.zeros(z, jnp.int32),
+            allowed=jnp.ones((z, jmax), bool),
+            history=jnp.zeros((z, 48), jnp.uint32),
+            hist_n=jnp.zeros(z, jnp.int32), overflow=jnp.asarray(False),
+            dlayout=dr.state_layout(reads, rlens, win_tpl, win_trans, wlens,
+                                    tables, alpha, beta, apre, bsuf,
+                                    width=width))
+
+    state = jax.eval_shape(loop_state, tpl, tlens, tables, reads, rlens,
+                           strands, tstarts, tends)
+    opts = RefineOptions()
+
+    def fn(*a):
+        return dr.run_refine_loop(
+            *a, width=width, use_pallas=True,
+            max_iterations=opts.max_iterations,
+            separation=opts.mutation_separation,
+            neighborhood=opts.mutation_neighborhood,
+            chunk=batch.MUT_CHUNK, min_fast_edge=batch.MIN_FAST_EDGE_WLEN,
+            dense=True, guided_passes=guided)
+
+    real_rows = jax.ShapeDtypeStruct((z, r), jnp.bool_)
+    return fn, (state, reads, rlens, strands, tables, real_rows)
+
+
+CASES = [
+    pytest.param(_fill_case, (256, 576, 64), id="fill-256x576xW64"),
+    pytest.param(_fill_case, (256, 2112, 96), id="fill-256x2112xW96"),
+    # the 2x-band mating retry of a 2 kb batch (pipeline._polish_batch_arrow)
+    pytest.param(_fill_case, (64, 2240, 192), id="fill-64x2240xW192-reband"),
+    pytest.param(_dense_case, (256, 576, 64), id="dense-256x576xW64"),
+    pytest.param(_dense_case, (256, 2112, 96), id="dense-256x2112xW96"),
+    pytest.param(_dense_case, (96, 15104, 96), id="dense-96x15104xW96"),
+    pytest.param(_bucket_case, (32, 10, 2112), id="bucket-32x10x2112"),
+    pytest.param(_bucket_case, (128, 8, 576), id="bucket-128x8x576"),
+    pytest.param(_refine_loop_case, (32, 10, 2112),
+                 id="refine_loop-32x10x2112"),
+]
+
+
+@pytest.mark.parametrize("build,shape", CASES)
+def test_compiles_for_v5e(build, shape, one_chip, no_persistent_cache,
+                          monkeypatch):
+    from pbccs_tpu.ops import dense_score_pallas, fwdbwd_pallas
+
+    # the process's backend is the CPU, where both kernels would choose
+    # interpret mode; compile the real kernels
+    monkeypatch.setattr(fwdbwd_pallas, "_interpret", lambda: False)
+    monkeypatch.setattr(dense_score_pallas, "_interpret", lambda: False)
+    jax.clear_caches()  # no trace made in interpret mode is reused
+
+    try:
+        fn, shapes = build(*shape)
+        compiled = jax.jit(fn).lower(*_on(shapes, one_chip)).compile()
+    finally:
+        jax.clear_caches()  # nor does a later test reuse these
+
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert total < HBM_BYTES, (
+        f"{total / 2**30:.2f} GiB of arguments + outputs + temps does not "
+        f"fit one v5e chip: {mem}")

@@ -122,6 +122,22 @@ def test_fill_dispatch_forced_pallas(monkeypatch, batch):
         np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=2e-3)
 
 
+def test_wide_band_half_column_blocks_match_jax_path(batch):
+    """Bands wider than 128 (the 2x mating retry) take half the columns
+    per grid step to stay inside the chip's per-kernel VMEM; the blocking
+    must not change a value."""
+    from pbccs_tpu.models.arrow.scorer import fill_alpha_beta_batch
+
+    reads, rlens, tpls, trans, tlens = batch
+    wide = 160
+    ref = fill_alpha_beta_batch(reads, rlens, tpls, trans, tlens, wide,
+                                use_pallas=False)
+    got = fill_alpha_beta_batch(reads, rlens, tpls, trans, tlens, wide,
+                                use_pallas=True)
+    for g, r in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=2e-3)
+
+
 def test_band_shift_clamp_drops_read_not_crashes():
     """A read/template length ratio beyond the kernel's max band shift must
     produce a (finite or -inf) score, never garbage; the scorer drops such

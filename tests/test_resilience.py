@@ -520,6 +520,35 @@ class TestMemoryGovernor:
         # an unrelated bucket is unaffected
         assert gov.cap(shape_bucket(64, 128, 4)) is None
 
+    @pytest.mark.parametrize("limit,bucket,want", [
+        # one v5e chip (15.75 GiB): the CLI's default 64-ZMW batch at
+        # 2 kb x 12 reads does not fit, half of it does
+        (16911433728, (2560, 2240, 12), 32),
+        (16911433728, (640, 576, 8), 128),
+        (16911433728, (15360, 15104, 4), 16),
+        # a bucket one ZMW of which outgrows the device still dispatches
+        (1 << 20, (2560, 2240, 12), 1),
+        # no reported limit (the CPU backend): no modelled ceiling
+        (None, (2560, 2240, 12), None),
+    ])
+    def test_modelled_cap_from_device_memory(self, monkeypatch, limit,
+                                             bucket, want):
+        from pbccs_tpu.resilience import resources
+
+        monkeypatch.setattr(resources, "device_bytes_limit", lambda: limit)
+        b = shape_bucket(*bucket)
+        assert resources.modelled_cap(b) == want
+        gov = MemoryGovernor()
+        assert gov.cap(b) == want
+        # keys of other shapes (the pool's opaque task keys) get none
+        assert resources.modelled_cap(("other", 1)) is None
+        if want and want > 1:
+            # a learned ceiling only ever lowers the modelled one
+            assert gov.record_oom(b, want, device="tpu:0") == want // 2
+            assert gov.cap(b, device="tpu:0") == want // 2
+            assert gov.record_oom(b, 8 * want, device="tpu:1") == 4 * want
+            assert gov.cap(b, device="tpu:1") == want
+
     def test_ceiling_reset_on_device_readmit(self):
         gov = MemoryGovernor()
         b = shape_bucket(128, 256, 8)

@@ -177,6 +177,46 @@ def test_tracker_charges_and_status_block(monkeypatch):
     assert protocol.KEY_ROOFLINE_BUCKETS in block
 
 
+class _FakeDevice:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+@pytest.mark.parametrize("platform,kind,peak", [
+    ("tpu", "TPU v5 lite", 197.0),      # the published v5e bf16 peak
+    ("tpu", "TPU v9 imaginary", None),  # unknown TPU kind: no default
+    ("cpu", "cpu", 0.1),
+    ("quantum", "qpu", None),           # unknown platform: no default
+])
+def test_peak_is_keyed_by_device_kind(monkeypatch, platform, kind, peak):
+    """On a TPU the efficiency denominator is the published peak of the
+    device_kind that ran; a kind with no entry yields no efficiency
+    figure at all (never another chip's peak, never 1.0)."""
+    import jax
+
+    monkeypatch.delenv("PBCCS_ROOFLINE", raising=False)
+    monkeypatch.delenv("PBCCS_ROOFLINE_PEAK_TFLOPS", raising=False)
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: [_FakeDevice(platform, kind)])
+    tr = _tracker_with_card(z=2)
+    tr.charge_execution(imax=64, jmax=64, r=4, z=4)
+    with tr.refine_scope(imax=64, jmax=64, r=4):
+        pass
+    assert tr.peak_tflops() == peak
+    block = tr.status_block()
+    assert block["peak_tflops"] == peak
+    entry = block["buckets"]["I64xJ64xR4"]
+    gauges = {k[0] for k in tr._registry.snapshot()}
+    if peak is None:
+        assert entry["efficiency"] is None
+        assert roofline.EFFICIENCY not in gauges
+        assert roofline.EFFICIENCY_OVERALL not in gauges
+    else:
+        assert entry["efficiency"] == pytest.approx(
+            entry["achieved_tflops"] / peak, rel=1e-5)
+        assert roofline.EFFICIENCY in gauges
+
+
 def test_tracker_charge_without_card_is_noop():
     tr = roofline.RooflineTracker(registry=MetricsRegistry())
     tr.charge_execution(imax=64, jmax=64, r=4, z=4)

@@ -210,3 +210,78 @@ class TestLogger:
         assert LogLevel.from_string("warn") == LogLevel.WARN
         with pytest.raises(ValueError):
             LogLevel.from_string("nope")
+
+
+class TestCompilationCacheDir:
+    """runtime/cache.py: the machine places the cache through
+    JAX_COMPILATION_CACHE_DIR; unset, it is the checkout's .jax_cache."""
+
+    @pytest.fixture(autouse=True)
+    def _restore_cache_dir(self):
+        import jax
+
+        prev = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+    def test_env_beats_argument(self, monkeypatch, tmp_path):
+        import jax
+
+        from pbccs_tpu.runtime.cache import enable_compilation_cache
+
+        placed = str(tmp_path / "placed")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+        got = enable_compilation_cache(str(tmp_path / "argument"))
+        assert got == placed
+        assert jax.config.jax_compilation_cache_dir == placed
+
+    def test_argument_when_env_unset(self, monkeypatch, tmp_path):
+        from pbccs_tpu.runtime.cache import enable_compilation_cache
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        arg = str(tmp_path / "argument")
+        assert enable_compilation_cache(arg) == arg
+
+    def test_checkout_local_without_git(self, monkeypatch, tmp_path):
+        """A checkout that is not a git repository (the copy a chip run
+        is made from) still caches inside itself, never under $HOME."""
+        import os
+
+        from pbccs_tpu.runtime import cache
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.setenv("HOME", str(tmp_path / "home"))
+        checkout = tmp_path / "copy"
+        checkout.mkdir()
+        assert not (checkout / ".git").exists()
+        monkeypatch.setattr(cache, "_CHECKOUT", str(checkout))
+        assert cache.enable_compilation_cache() == \
+            os.path.join(str(checkout), ".jax_cache")
+
+    def test_checkout_is_this_repo(self):
+        import os
+
+        from pbccs_tpu.runtime import cache
+
+        assert os.path.isfile(os.path.join(cache._CHECKOUT, "chip_smoke.py"))
+
+
+@pytest.mark.parametrize("gate", ["fills_use_pallas", "dense_score_enabled"])
+def test_kernel_gate_lets_a_backend_error_out(gate, monkeypatch):
+    """A backend that failed to initialise must not become a quiet choice
+    of the pure-JAX path."""
+    import jax
+
+    from pbccs_tpu.ops import dense_score_pallas, fwdbwd_pallas
+
+    fn = {"fills_use_pallas": fwdbwd_pallas.fills_use_pallas,
+          "dense_score_enabled": dense_score_pallas.dense_score_enabled}[gate]
+    monkeypatch.delenv("PBCCS_PALLAS", raising=False)
+    monkeypatch.delenv("PBCCS_DENSE", raising=False)
+
+    def broken():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(jax, "default_backend", broken)
+    with pytest.raises(RuntimeError, match="Unable to initialize backend"):
+        fn()

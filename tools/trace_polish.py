@@ -8,7 +8,7 @@ Usage:
 
 Env: BENCH_ZMWS/BENCH_TPL_LEN/BENCH_PASSES/BENCH_CORRUPTIONS as bench.py.
 Prints a category rollup and the top ops by device self-time, plus one JSON
-summary line (committed to docs/PROFILE_r03.md by hand).
+summary line.
 """
 
 from __future__ import annotations
