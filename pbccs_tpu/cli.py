@@ -481,8 +481,14 @@ def run(argv: list[str] | None = None) -> int:
     t_run0 = time_mod.monotonic()
     tally = None
     try:
-        with profiling.profile_capture(args.profile_dir):
+        with profiling.profile_capture(args.profile_dir), \
+                obs_trace.span("run", threads=_worker_threads(args),
+                               cpus=os.cpu_count(),
+                               chunk_size=args.chunkSize,
+                               devices=args.devices) as run_span:
             tally = _run_pipeline(args, files, whitelist, settings, log)
+            if run_span is not None:
+                run_span.args["zmws"] = tally.total
     except OutputWriteError as e:
         # a full disk is an OPERATIONAL failure, not a bug: report what
         # was durably written and how to resume, exit nonzero without a
@@ -527,15 +533,20 @@ def run(argv: list[str] | None = None) -> int:
     return 0
 
 
+def _worker_threads(args) -> int:
+    """WorkQueue workers of the single-device driver.  Default to at
+    least 2 even on a 1-core host: a worker blocks on the device with the
+    GIL released for most of a batch polish, so a second worker drafts
+    the NEXT batch (host POA) during that wait -- the reference's
+    reader/worker/writer overlap (ccs.cpp:388-499) re-expressed for a
+    device-bound polish stage."""
+    return args.numThreads or max(2, min(8, os.cpu_count() or 1))
+
+
 def _run_pipeline(args, files, whitelist, settings, log) -> ResultTally:
     """The reader -> WorkQueue -> batched polish -> writer body of a CLI
     run (split from run() so the observability capture scopes wrap it)."""
-    # Default to at least 2 workers even on a 1-core host: a worker
-    # blocks on the device with the GIL released for most of a batch
-    # polish, so a second worker drafts the NEXT batch (host POA) during
-    # that wait -- the reference's reader/worker/writer overlap
-    # (ccs.cpp:388-499) re-expressed for a device-bound polish stage.
-    n_threads = args.numThreads or max(2, min(8, os.cpu_count() or 1))
+    n_threads = _worker_threads(args)
     tally = ResultTally()
 
     # collect movie names for the output header
@@ -622,8 +633,10 @@ def _run_pipeline(args, files, whitelist, settings, log) -> ResultTally:
                                      gate_tally))
         idx = -1
         while True:
-            with timing.stage("read"):
+            with obs_trace.span("read") as read_span, timing.stage("read"):
                 batch = next(it, None)
+                if read_span is not None:
+                    read_span.args["zmws"] = len(batch or ())
             if batch is None:
                 return
             idx += 1
@@ -698,8 +711,9 @@ def _run_pipeline(args, files, whitelist, settings, log) -> ResultTally:
                      "work items, so the flag is ignored here")
 
         def _run_batch(idx, batch):
-            return idx, process_chunks(batch, settings,
-                                       on_error=args.batchFallback)
+            with obs_trace.span("batch", batch=idx, zmws=len(batch)):
+                return idx, process_chunks(batch, settings,
+                                           on_error=args.batchFallback)
 
         consumed = ResultTally()
         consumer_error: list[BaseException] = []

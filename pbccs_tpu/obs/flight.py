@@ -40,6 +40,14 @@ _m_rounds = _reg.counter("ccs_refine_rounds_total",
                          "Refinement rounds recorded by the flight "
                          "recorder", source="host")
 _m_rounds_dev = _reg.counter("ccs_refine_rounds_total", source="device")
+# summed over every round told of: live / capacity over a window is the
+# MEAN slot occupancy (the gauge below holds the last round only)
+_m_slots_live = _reg.counter("ccs_refine_slot_rounds_total",
+                             "Z-axis slots summed over refinement rounds: "
+                             "live (unconverged, real) slots and the "
+                             "capacity (Z) they ran in", kind="live")
+_m_slots_capacity = _reg.counter("ccs_refine_slot_rounds_total",
+                                 kind="capacity")
 _m_converged = _reg.gauge("ccs_refine_converged_fraction",
                           "Converged fraction of the most recent "
                           "refinement round's batch")
@@ -82,6 +90,8 @@ class FlightRecorder:
             rec["seq"] = self._seq
             self._ring.append(rec)
         (_m_rounds if source == "host" else _m_rounds_dev).inc()
+        _m_slots_live.inc(rec["live"])
+        _m_slots_capacity.inc(z)
         _m_converged.set(rec["converged_fraction"])
         _m_occupancy.set(rec["slot_occupancy"])
         _m_padding.set(rec["padding_waste"])

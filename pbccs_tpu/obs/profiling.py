@@ -25,7 +25,12 @@ def profile_capture(profile_dir: str | None) -> Iterator[None]:
     try:
         import jax
 
-        jax.profiler.start_trace(profile_dir)
+        # without the profiler's Python tracer: it slows the host it
+        # measures, and the program's own spans are in the capture as
+        # `ccs:` annotations (obs/trace.py)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(profile_dir, profiler_options=options)
         started = True
     except Exception as e:  # noqa: BLE001 -- observability must not kill work
         from pbccs_tpu.runtime.logging import Logger

@@ -10,8 +10,18 @@ a polish span decomposes into host marshalling vs device wait
 (docs/DESIGN.md, "The transfer-count rule").
 
 Tracing is OFF unless a tracer is installed (CLI --trace-out, serve
-`trace` verb); the disabled fast path is one global read per span() call,
-cheap enough to leave the instrumentation in the hot pipeline.
+`trace` verb); the disabled fast path is one global read per span() call
+(it hands back one shared no-op context manager: no Span, no generator,
+no profiler annotation), cheap enough to leave the instrumentation in
+the hot pipeline.
+
+While a tracer IS installed a span also records the CPU time its thread
+got (`cpu_ms`: wall minus CPU minus device wait is time the thread was
+runnable but not running -- the GIL, or a core it did not get) and, when
+jax is already imported, enters `jax.profiler.TraceAnnotation("ccs:" +
+name)`: under --profile-dir the program's spans then sit in the
+.xplane.pb host plane on the profiler's own clock, beside the device
+operations.
 
 Cross-process trace context (the fleet observability plane): a span may
 carry an inbound `ctx` dict -- ``{"trace_id": ..., "span_id": ...}``,
@@ -30,6 +40,7 @@ from __future__ import annotations
 import contextlib
 import json
 import os
+import sys
 import threading
 import time
 import uuid
@@ -46,8 +57,8 @@ class Span:
     """One finished-or-open span; nesting is per-thread."""
 
     __slots__ = ("name", "args", "tid", "t0", "t1", "device_wait_s",
-                 "parent", "index", "trace_id", "remote_parent", "sid",
-                 "open")
+                 "cpu_s", "parent", "index", "trace_id", "remote_parent",
+                 "sid", "open")
 
     def __init__(self, name: str, args: dict[str, Any], tid: int,
                  t0: float, parent: "Span | None", index: int,
@@ -60,6 +71,7 @@ class Span:
         self.t0 = t0
         self.t1 = t0
         self.device_wait_s = 0.0
+        self.cpu_s: float | None = None   # thread CPU time; None = not taken
         self.parent = parent
         self.index = index
         self.trace_id = trace_id
@@ -133,21 +145,41 @@ class Tracer:
             yield None
             return
         stack.append(sp)
+        # the profiler's clock: a no-op unless a jax.profiler capture is
+        # live.  jax is never imported from here (`ccs --help` and the
+        # pure-host tests stay off the backend).
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        note = (profiler.TraceAnnotation("ccs:" + name)
+                if profiler is not None else contextlib.nullcontext())
+        cpu0 = time.thread_time()
         try:
-            yield sp
+            with note:
+                yield sp
         finally:
+            sp.cpu_s = time.thread_time() - cpu0
             sp.t1 = time.perf_counter()
             sp.open = False
             stack.pop()
 
     def add_span(self, name: str, duration_s: float, *,
+                 start_unix: float | None = None,
                  ctx: dict | None = None, span_id: str | None = None,
                  **args) -> Span | None:
-        """Record a RETROACTIVE closed span ending now (the router's
-        per-request span: its lifetime is only known at completion).
-        `span_id` pins the exported id so the forwarding tier could name
-        this span as the remote parent BEFORE it was recorded."""
-        t1 = time.perf_counter()
+        """Record a RETROACTIVE closed span (the router's per-request
+        span: its lifetime is only known at completion).  It ends now
+        unless `start_unix` (wall clock, seconds since the epoch) says
+        where it began: then it sits where it happened, under the span
+        the calling thread has open (a compile reported by jax after the
+        fact, runtime/cache.py).  `span_id` pins the exported id so the
+        forwarding tier could name this span as the remote parent BEFORE
+        it was recorded."""
+        duration_s = max(duration_s, 0.0)
+        parent = None
+        if start_unix is None:
+            t0 = time.perf_counter() - duration_s
+        else:
+            t0 = self.t_origin + (start_unix - self.t_origin_unix)
+            parent = self.current_span()
         trace_id = remote_parent = None
         if ctx:
             trace_id = ctx.get("trace_id")
@@ -157,10 +189,10 @@ class Tracer:
                 self.dropped_spans += 1
                 return None
             sp = Span(name, args, threading.get_ident() & 0xFFFFFFFF,
-                      t1 - max(duration_s, 0.0), None, len(self._spans),
+                      t0, parent, len(self._spans),
                       trace_id=trace_id, remote_parent=remote_parent,
                       sid=span_id)
-            sp.t1 = t1
+            sp.t1 = t0 + duration_s
             sp.open = False
             self._spans.append(sp)
         return sp
@@ -216,6 +248,8 @@ class Tracer:
         for sp in self.finished_spans():
             args = dict(sp.args)
             args["device_wait_ms"] = round(sp.device_wait_s * 1e3, 3)
+            if sp.cpu_s is not None:
+                args["cpu_ms"] = round(sp.cpu_s * 1e3, 3)
             if sp.parent is not None:
                 args["parent"] = sp.parent.index
             if sp.trace_id is not None:
@@ -241,14 +275,11 @@ class Tracer:
                 "id": sp.index,
                 "args": args,
             })
-        out = {"traceEvents": events, "displayTimeUnit": "ms",
-               "meta": {"process": self.tag,
-                        "origin_unix": self.t_origin_unix,
-                        "dropped_spans": self.dropped_spans,
-                        "open_spans": open_spans}}
-        if self.dropped_spans:
-            out["droppedSpans"] = self.dropped_spans  # legacy key
-        return out
+        return {"traceEvents": events, "displayTimeUnit": "ms",
+                "meta": {"process": self.tag,
+                         "origin_unix": self.t_origin_unix,
+                         "dropped_spans": self.dropped_spans,
+                         "open_spans": open_spans}}
 
     def write_json(self, path: str) -> None:
         # atomic publish (ccs-analyze ATM001): a truncated trace JSON is
@@ -310,17 +341,30 @@ def clear_tracer(expected: Tracer) -> bool:
         return True
 
 
-@contextlib.contextmanager
-def span(name: str, ctx: dict | None = None, **args) -> Iterator[Span | None]:
-    """Record a span on the installed tracer; no-op (one global read)
-    when tracing is off.  `ctx` carries an inbound cross-process trace
-    context (see Tracer.span)."""
+class _NoSpan:
+    """What span() hands back while tracing is off: enters to None."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> None:
+        return None
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+_NO_SPAN = _NoSpan()
+
+
+def span(name: str, ctx: dict | None = None, **args):
+    """Record a span on the installed tracer (a context manager yielding
+    the Span, or None past the cap); no-op (one global read, one shared
+    object) when tracing is off.  `ctx` carries an inbound cross-process
+    trace context (see Tracer.span)."""
     t = _tracer
     if t is None:
-        yield None
-        return
-    with t.span(name, ctx=ctx, **args) as sp:
-        yield sp
+        return _NO_SPAN
+    return t.span(name, ctx=ctx, **args)
 
 
 def add_device_wait(dt: float) -> None:

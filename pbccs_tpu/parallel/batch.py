@@ -1374,29 +1374,32 @@ class BatchPolisher:
             # on the straggler count) so every draw's straggler set --
             # whatever its size -- reuses the same compiled programs
             # (_straggler_sub; pre-warmable via warm_straggler_shapes).
-            sub = self._straggler_sub(stragglers)
-            # parent gating carries over; the sub-polisher must not re-gate
-            # (it sees mid-refinement templates, not the draft).  The live
-            # read-active mask is on device (host copy is the AddRead-time
-            # snapshot by design); fetch just the straggler rows.
-            act = device_fetch(out.active)
-            sub_active = np.zeros((sub._Z, sub._R), bool)
-            for i, z in enumerate(stragglers):
-                n = min(sub._R, self._R)
-                sub_active[i, :n] = act[z, :n]
-            sub._active_dev = sub._shard(sub_active, 1)
-            sub_res = sub.refine(opts, budget=sub_budget)
-            for i, z in enumerate(stragglers):
-                self.tpls[z] = sub.tpls[i]
-                r = sub_res[i]
-                results[z] = RefineResult(
-                    converged=r.converged,
-                    n_tested=results[z].n_tested + r.n_tested,
-                    n_applied=results[z].n_applied + r.n_applied,
-                    iterations=results[z].iterations + r.iterations)
-            self._tpl_lengths_cache = None
-            self._cont.record_continuation(
-                {z: (sub, i) for i, z in enumerate(stragglers)})
+            with obs_trace.span("polish.refine.straggler",
+                                zmws=len(stragglers)):
+                sub = self._straggler_sub(stragglers)
+                # parent gating carries over; the sub-polisher must not
+                # re-gate (it sees mid-refinement templates, not the
+                # draft).  The live read-active mask is on device (host
+                # copy is the AddRead-time snapshot by design); fetch just
+                # the straggler rows.
+                act = device_fetch(out.active)
+                sub_active = np.zeros((sub._Z, sub._R), bool)
+                for i, z in enumerate(stragglers):
+                    n = min(sub._R, self._R)
+                    sub_active[i, :n] = act[z, :n]
+                sub._active_dev = sub._shard(sub_active, 1)
+                sub_res = sub.refine(opts, budget=sub_budget)
+                for i, z in enumerate(stragglers):
+                    self.tpls[z] = sub.tpls[i]
+                    r = sub_res[i]
+                    results[z] = RefineResult(
+                        converged=r.converged,
+                        n_tested=results[z].n_tested + r.n_tested,
+                        n_applied=results[z].n_applied + r.n_applied,
+                        iterations=results[z].iterations + r.iterations)
+                self._tpl_lengths_cache = None
+                self._cont.record_continuation(
+                    {z: (sub, i) for i, z in enumerate(stragglers)})
         return results
 
     def straggler_shape_min_z(self) -> int:

@@ -315,7 +315,7 @@ def prepare_chunk(chunk: Chunk, settings: ConsensusSettings
         return Failure.NO_SUBREADS, None
 
     with obs_trace.span("draft", zmw=chunk.id):
-        with timing.stage("draft.poa"):
+        with obs_trace.span("draft.poa"), timing.stage("draft.poa"):
             css, keys, summaries = poa_consensus(reads,
                                                  settings.max_poa_coverage)
         if len(css) < settings.min_length:
@@ -324,7 +324,7 @@ def prepare_chunk(chunk: Chunk, settings: ConsensusSettings
         # map reads onto the draft
         mapped: list[MappedRead] = []
         n_unmappable = 0
-        with timing.stage("draft.map"):
+        with obs_trace.span("draft.map"), timing.stage("draft.map"):
             for r, k in zip(reads, keys):
                 if r is None or k < 0:
                     continue
@@ -530,77 +530,78 @@ def _polish_batch_arrow(preps: Sequence[PreparedZmw],
         polisher = BatchPolisher(tasks, min_zscore=settings.min_zscore,
                                  buckets=buckets, min_z=min_z,
                                  prebaked=prebaked)
-    gate_info = []
-    for z, p in enumerate(preps):
-        gate_info.append(_read_gates(p, polisher.statuses[z], settings))
-    # ZMWs that shed reads to the alpha/beta mating gate retry in ONE
-    # wider-band (2x) sub-batch -- the batched analogue of the serial
-    # scorer's whole-scorer escalation (the reference rebands a
-    # mismatched pair up to 5 times before dropping,
-    # SimpleRecursor.cpp:642-691).  Keep-better-width per ZMW: a ZMW
-    # polishes at the wide band iff it MATES more reads there
-    # (status != ALPHABETAMISMATCH -- deliberately counting reads the
-    # wide band mates but the z-score gate then drops: the reference
-    # rebands to achieve alpha/beta agreement FIRST and applies the
-    # z-score gate to whatever mated, so reband-to-mate-then-gate is
-    # the parity semantics, not mates-that-survive-gating).  Otherwise
-    # it stays in the narrow batch with its drops (the serial retry's
-    # revert).  Either way the ZMW stays on the batched device path.
-    reband = sorted(z for z, p in enumerate(preps)
-                    if (polisher.statuses[z, : len(p.mapped)]
-                        == ADD_ALPHABETAMISMATCH).any())
-    wide = None
-    wide_pick: dict[int, int] = {}
-    if reband:
-        wcfg = dataclasses.replace(
-            polisher.config,
-            banding=dataclasses.replace(
-                polisher.config.banding,
-                # 2x the EFFECTIVE width (the W(L) schedule may have
-                # shrunk the narrow batch below the configured width);
-                # a non-default width passes through the schedule
-                band_width=2 * polisher._W))
-        try:  # speculative build: any failure keeps the narrow batch
-            from pbccs_tpu.utils import next_pow2
+    with obs_trace.span("polish.gates", zmws=len(preps)):
+        gate_info = []
+        for z, p in enumerate(preps):
+            gate_info.append(_read_gates(p, polisher.statuses[z], settings))
+        # ZMWs that shed reads to the alpha/beta mating gate retry in ONE
+        # wider-band (2x) sub-batch -- the batched analogue of the serial
+        # scorer's whole-scorer escalation (the reference rebands a
+        # mismatched pair up to 5 times before dropping,
+        # SimpleRecursor.cpp:642-691).  Keep-better-width per ZMW: a ZMW
+        # polishes at the wide band iff it MATES more reads there
+        # (status != ALPHABETAMISMATCH -- deliberately counting reads the
+        # wide band mates but the z-score gate then drops: the reference
+        # rebands to achieve alpha/beta agreement FIRST and applies the
+        # z-score gate to whatever mated, so reband-to-mate-then-gate is
+        # the parity semantics, not mates-that-survive-gating).  Otherwise
+        # it stays in the narrow batch with its drops (the serial retry's
+        # revert).  Either way the ZMW stays on the batched device path.
+        reband = sorted(z for z, p in enumerate(preps)
+                        if (polisher.statuses[z, : len(p.mapped)]
+                            == ADD_ALPHABETAMISMATCH).any())
+        wide = None
+        wide_pick: dict[int, int] = {}
+        if reband:
+            wcfg = dataclasses.replace(
+                polisher.config,
+                banding=dataclasses.replace(
+                    polisher.config.banding,
+                    # 2x the EFFECTIVE width (the W(L) schedule may have
+                    # shrunk the narrow batch below the configured width);
+                    # a non-default width passes through the schedule
+                    band_width=2 * polisher._W))
+            try:  # speculative build: any failure keeps the narrow batch
+                from pbccs_tpu.utils import next_pow2
 
-            # pin shapes to the narrow batch's buckets + pow2 Z so the
-            # data-dependent reband count doesn't mint fresh compiles
-            wide = BatchPolisher([tasks[z] for z in reband],
-                                 config=wcfg,
-                                 min_zscore=settings.min_zscore,
-                                 buckets=(polisher._Imax,
-                                          polisher._Jmax,
-                                          polisher._R),
-                                 min_z=next_pow2(len(reband), 4))
-        except Exception as e:  # noqa: BLE001 -- keep the narrow batch
-            record_zmw_failure("polish.wide_build", e,
-                               zmw=f"reband[{len(reband)}]")
-            wide = None
-        if wide is not None:
-            for i, z in enumerate(reband):
-                nr = len(preps[z].mapped)
-                n_narrow = int((polisher.statuses[z, :nr]
-                                != ADD_ALPHABETAMISMATCH).sum())
-                n_wide = int((wide.statuses[i, :nr]
-                              != ADD_ALPHABETAMISMATCH).sum())
-                if n_wide > n_narrow:
-                    wide_pick[z] = i
-                    gate_info[z] = _read_gates(
-                        preps[z], wide.statuses[i], settings)
-        # banding observability: retry outcomes per batch (the
-        # reference's NumFlipFlops analogue at batch granularity)
-        Logger.default().debug(
-            f"band retry: {len(reband)} ZMW(s) had mating failures at "
-            f"W={polisher._W}; "
-            f"{len(wide_pick)} adopted the 2x band, "
-            f"{len(reband) - len(wide_pick)} reverted")
-    # gate-failed ZMWs are excluded from refinement/QV (the serial path
-    # returns before polishing them); their batch slots stay idle
-    gate_failed = {z for z, g in enumerate(gate_info) if g[0] is not None}
-    skip = gate_failed | set(wide_pick)
-    # z-score statistics are reported for the draft template, before
-    # refinement (parity with the serial path)
-    global_zs = polisher.global_zscores()
+                # pin shapes to the narrow batch's buckets + pow2 Z so the
+                # data-dependent reband count doesn't mint fresh compiles
+                wide = BatchPolisher([tasks[z] for z in reband],
+                                     config=wcfg,
+                                     min_zscore=settings.min_zscore,
+                                     buckets=(polisher._Imax,
+                                              polisher._Jmax,
+                                              polisher._R),
+                                     min_z=next_pow2(len(reband), 4))
+            except Exception as e:  # noqa: BLE001 -- keep the narrow batch
+                record_zmw_failure("polish.wide_build", e,
+                                   zmw=f"reband[{len(reband)}]")
+                wide = None
+            if wide is not None:
+                for i, z in enumerate(reband):
+                    nr = len(preps[z].mapped)
+                    n_narrow = int((polisher.statuses[z, :nr]
+                                    != ADD_ALPHABETAMISMATCH).sum())
+                    n_wide = int((wide.statuses[i, :nr]
+                                  != ADD_ALPHABETAMISMATCH).sum())
+                    if n_wide > n_narrow:
+                        wide_pick[z] = i
+                        gate_info[z] = _read_gates(
+                            preps[z], wide.statuses[i], settings)
+            # banding observability: retry outcomes per batch (the
+            # reference's NumFlipFlops analogue at batch granularity)
+            Logger.default().debug(
+                f"band retry: {len(reband)} ZMW(s) had mating failures at "
+                f"W={polisher._W}; "
+                f"{len(wide_pick)} adopted the 2x band, "
+                f"{len(reband) - len(wide_pick)} reverted")
+        # gate-failed ZMWs are excluded from refinement/QV (the serial path
+        # returns before polishing them); their batch slots stay idle
+        gate_failed = {z for z, g in enumerate(gate_info) if g[0] is not None}
+        skip = gate_failed | set(wide_pick)
+        # z-score statistics are reported for the draft template, before
+        # refinement (parity with the serial path)
+        global_zs = polisher.global_zscores()
     with obs_trace.span("polish.refine", zmws=len(preps) - len(skip)):
         refine_results = polisher.refine(settings.refine, skip=skip)
     wide_refine = wide_qvs = wide_gz = None
@@ -651,25 +652,26 @@ def _polish_batch_arrow(preps: Sequence[PreparedZmw],
     # outcomes accumulate into a local list so a mid-loop fault cannot
     # double-count ZMWs when the serial fallback reruns them
     outcomes: list[tuple[Failure, ConsensusResult | None]] = []
-    for z, p in enumerate(preps):
-        failure, status_counts, n_passes = gate_info[z]
-        if failure is not None:
-            outcomes.append((failure, None))
-            continue
-        nr = len(p.mapped)
-        if z in wide_pick:
-            i = wide_pick[z]
-            failure, result = _finish_zmw(
-                p, settings, wide.tpls[i], wide_qvs[i], wide_refine[i],
-                wide.zscores[i, :nr], wide_gz[i], status_counts,
-                n_passes, p.prep_ms + polish_ms)
-        else:
-            failure, result = _finish_zmw(
-                p, settings, polisher.tpls[z], qvs[z],
-                refine_results[z], polisher.zscores[z, :nr],
-                global_zs[z], status_counts, n_passes,
-                p.prep_ms + polish_ms)
-        outcomes.append((failure, result))
+    with obs_trace.span("polish.finish", zmws=len(preps)):
+        for z, p in enumerate(preps):
+            failure, status_counts, n_passes = gate_info[z]
+            if failure is not None:
+                outcomes.append((failure, None))
+                continue
+            nr = len(p.mapped)
+            if z in wide_pick:
+                i = wide_pick[z]
+                failure, result = _finish_zmw(
+                    p, settings, wide.tpls[i], wide_qvs[i], wide_refine[i],
+                    wide.zscores[i, :nr], wide_gz[i], status_counts,
+                    n_passes, p.prep_ms + polish_ms)
+            else:
+                failure, result = _finish_zmw(
+                    p, settings, polisher.tpls[z], qvs[z],
+                    refine_results[z], polisher.zscores[z, :nr],
+                    global_zs[z], status_counts, n_passes,
+                    p.prep_ms + polish_ms)
+            outcomes.append((failure, result))
     return outcomes
 
 
@@ -913,20 +915,24 @@ def _polish_guarded(preps: Sequence[PreparedZmw],
 
 
 def prepare_batch(chunks: Sequence[Chunk],
-                  settings: ConsensusSettings | None = None
-                  ) -> tuple[ResultTally, list[PreparedZmw]]:
+                  settings: ConsensusSettings | None = None,
+                  **span_args) -> tuple[ResultTally, list[PreparedZmw]]:
     """The host half of a batch: run every chunk through the prep stages
     (filter -> POA draft -> mapping) with per-ZMW fault isolation,
     returning (tally of prep-stage outcomes, survivors ready to polish).
     Shared by process_chunks and the device-fleet scheduler's prepare
-    workers (pbccs_tpu.sched.executor), so the two drivers cannot drift."""
+    workers (pbccs_tpu.sched.executor), so the two drivers cannot drift.
+    `span_args` go on the `prepare` trace span (the fleet driver's
+    `batch=idx`: its prepare and polish run on two threads, under no
+    common `batch` span)."""
     from pbccs_tpu.resilience import faults
     from pbccs_tpu.runtime import timing
 
     settings = settings or ConsensusSettings()
     tally = ResultTally()
     preps: list[PreparedZmw] = []
-    with timing.stage("draft"):
+    with obs_trace.span("prepare", zmws=len(chunks), **span_args), \
+            timing.stage("draft"):
         for chunk in chunks:
             try:
                 faults.maybe_fail("prep.zmw", keys=[chunk.id])
@@ -977,9 +983,17 @@ def process_chunks(chunks: Sequence[Chunk],
     if not preps:
         return tally
 
-    with _polish_turn, obs_trace.span("polish", zmws=len(preps)):
-        outcomes = polish_prepared_batch(preps, settings,
-                                         on_error=on_error)
+    # the wait for the device turn is a span of its own, closed before
+    # `polish` opens: long waits say the device is the bottleneck, none
+    # (with an idle device) says the host's drafts are
+    with obs_trace.span("dispatch.turn_wait", zmws=len(preps)):
+        _polish_turn.acquire()
+    try:
+        with obs_trace.span("polish", zmws=len(preps)):
+            outcomes = polish_prepared_batch(preps, settings,
+                                             on_error=on_error)
+    finally:
+        _polish_turn.release()
     for failure, result in outcomes:
         tally.tally(failure)
         if result is not None:

@@ -12,8 +12,20 @@ import contextlib
 import os
 import threading
 
+from pbccs_tpu.obs import trace as _trace
+
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
+
+# jax's event names (jax/_src/dispatch.py, jax/_src/compiler.py): internal
+# and version-dependent, like the count events below -- a jax that renames
+# them leaves the program-load counters and spans at nothing
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_READ_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_PHASE_OF_EVENT = {_TRACE_EVENT: "trace", _LOWER_EVENT: "lower",
+                   _COMPILE_EVENT: "compile"}
 
 _monitoring_installed = False
 _suppress_events = threading.local()
@@ -36,8 +48,11 @@ def suppress_cache_metrics():
 
 def _install_cache_metrics() -> None:
     """Route jax's compilation-cache monitoring events into the metrics
-    registry: ccs_compile_cache_events_total{kind="hit"|"miss"} plus
-    ccs_compiles_total for backend compiles.  Best-effort -- event names
+    registry: ccs_compile_cache_events_total{kind="hit"|"miss"},
+    ccs_compiles_total for backend compiles, and the seconds each phase
+    of bringing a program up took (ccs_program_load_seconds_total{phase},
+    with a program.trace/lower/compile span for each while a tracer is
+    installed).  Best-effort -- event names
     are jax-internal and version-dependent, so unknown events are ignored
     and a jax without jax.monitoring leaves the counters at zero."""
     global _monitoring_installed
@@ -54,6 +69,19 @@ def _install_cache_metrics() -> None:
     compiles = reg.counter("ccs_compiles_total",
                            "Backend compile events observed via "
                            "jax.monitoring")
+    load_help = (
+        "Wall seconds this process spent bringing programs up, by phase: "
+        "trace (Python to jaxpr), lower (jaxpr to MLIR), compile (jax's "
+        "backend_compile event: in jax 0.9 it wraps compile_or_get_cached, "
+        "so it CONTAINS cache_read), cache_read (reading and loading "
+        "executables the persistent cache held; hits only, jax reports no "
+        "time for a miss).  An event nested in another on its thread (a "
+        "jnp op traced while its caller is traced or lowered) counts in "
+        "the outer one only, so trace + lower + compile never exceeds wall")
+    load_seconds = {
+        phase: reg.counter("ccs_program_load_seconds_total", load_help,
+                           phase=phase)
+        for phase in ("trace", "lower", "compile", "cache_read")}
 
     def on_event(event: str, **kw) -> None:
         if getattr(_suppress_events, "v", False):
@@ -66,10 +94,54 @@ def _install_cache_metrics() -> None:
         elif "backend_compile" in event or event.endswith("/compile"):
             compiles.inc()
 
+    # jax brackets each phase with a scalar event at its start and a
+    # time span at its end, both on the compiling thread.  Phases nest
+    # (every jnp op traced inside a jit fires a trace event of its own),
+    # so only the outermost one on a thread is booked.
+    open_phases = threading.local()
+
+    def on_phase_start(event: str, _value, **kw) -> None:
+        if event in _PHASE_OF_EVENT:
+            open_phases.n = getattr(open_phases, "n", 0) + 1
+
+    def on_phase_end(event: str, start_time: float, end_time: float,
+                     **kw) -> None:
+        phase = _PHASE_OF_EVENT.get(event)
+        if phase is None:
+            return
+        open_phases.n = n = max(getattr(open_phases, "n", 1) - 1, 0)
+        if n or getattr(_suppress_events, "v", False):
+            return
+        dur = end_time - start_time
+        load_seconds[phase].inc(dur)
+        tracer = _trace.get_tracer()
+        if tracer is None:
+            return
+        fun = str(kw.get("fun_name", ""))
+        # literal names: the REG010 span inventory reads them from here
+        if event == _TRACE_EVENT:
+            tracer.add_span("program.trace", dur, start_unix=start_time,
+                            fun=fun)
+        elif event == _LOWER_EVENT:
+            tracer.add_span("program.lower", dur, start_unix=start_time,
+                            fun=fun)
+        else:
+            tracer.add_span("program.compile", dur, start_unix=start_time,
+                            fun=fun)
+
+    def on_duration(event: str, duration_secs: float, **kw) -> None:
+        # the one phase jax reports as a duration only
+        if event == _CACHE_READ_EVENT \
+                and not getattr(_suppress_events, "v", False):
+            load_seconds["cache_read"].inc(duration_secs)
+
     try:
         import jax.monitoring
 
         jax.monitoring.register_event_listener(on_event)
+        jax.monitoring.register_scalar_listener(on_phase_start)
+        jax.monitoring.register_event_time_span_listener(on_phase_end)
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
     except Exception:  # noqa: BLE001 -- observability must not block setup
         pass
 

@@ -145,7 +145,8 @@ class ScheduledPipeline:
                 if precomputed is not None:
                     finish(seq, (idx, precomputed))
                     return
-                tally, preps = pipeline.prepare_batch(chunks, self.settings)
+                tally, preps = pipeline.prepare_batch(chunks, self.settings,
+                                                      batch=idx)
                 if not preps:
                     finish(seq, (idx, tally))
                     return
@@ -203,7 +204,8 @@ class ScheduledPipeline:
                         _m_stages["dispatch"].observe(
                             max(t_polish0 - t_submit, 0.0))
                     try:
-                        with obs_trace.span("polish", zmws=len(preps)):
+                        with obs_trace.span("polish", zmws=len(preps),
+                                            batch=idx):
                             return pipeline.polish_prepared_batch(
                                 preps, settings, on_error=on_error,
                                 raise_device_shaped=fleet

@@ -223,7 +223,59 @@ class TestTrace:
                 assert (sp is not None) == (i < 3)
         assert len(tracer.finished_spans()) == 3
         chrome = tracer.to_chrome()
-        assert chrome["droppedSpans"] == 2
+        assert chrome["meta"]["dropped_spans"] == 2
+
+    def test_span_records_cpu_time_at_most_wall(self):
+        """cpu_ms rides beside device_wait_ms: a busy span reads CPU
+        close to wall, a sleeping one close to none, neither above."""
+        import time
+
+        tracer = obs_trace.Tracer()
+        with tracer.span("busy"):
+            t_end = time.perf_counter() + 0.05
+            while time.perf_counter() < t_end:
+                pass
+        with tracer.span("asleep"):
+            time.sleep(0.05)
+        with tracer.span("open"):
+            events = {e["name"]: e for e in
+                      tracer.to_chrome()["traceEvents"]}
+        for name in ("busy", "asleep"):
+            ev = events[name]
+            # thread_time and perf_counter are two clocks: a tick of slack
+            assert 0.0 <= ev["args"]["cpu_ms"] <= ev["dur"] / 1e3 + 1.0
+        assert events["busy"]["args"]["cpu_ms"] > 25.0
+        assert events["asleep"]["args"]["cpu_ms"] < 25.0
+        # an open span's CPU time is its thread's to read, not the
+        # exporter's: left out, not reported as nothing
+        assert "cpu_ms" not in events["open"]["args"]
+
+    def test_add_span_with_a_start_lands_where_it_is_told(self):
+        """A retroactive span with `start_unix` sits at that wall-clock
+        time on the tracer's axis, under the span its thread has open;
+        without one it still ends now, with no parent."""
+        import time
+
+        tracer = obs_trace.Tracer()
+        with tracer.span("polish.setup") as outer:
+            t_started = time.time()
+            time.sleep(0.03)
+            told = tracer.add_span("program.compile", 0.01,
+                                   start_unix=t_started, fun="jit(f)")
+            now = tracer.add_span("router.request", 0.01)
+        assert told.parent is outer and now.parent is None
+        chrome = tracer.to_chrome()
+        ev = {e["name"]: e for e in chrome["traceEvents"]}
+        origin = chrome["meta"]["origin_unix"]
+        at = origin + ev["program.compile"]["ts"] / 1e6
+        assert at == pytest.approx(t_started, abs=0.005)
+        assert ev["program.compile"]["dur"] == pytest.approx(10_000, rel=0.01)
+        assert ev["program.compile"]["args"]["parent"] == \
+            ev["polish.setup"]["id"]
+        assert ev["program.compile"]["args"]["fun"] == "jit(f)"
+        # it began ~30 ms before the span that ended "now" did
+        assert ev["router.request"]["ts"] - ev["program.compile"]["ts"] \
+            > 15_000
 
     def test_install_and_clear_are_cas(self):
         """install_tracer refuses to hijack a live capture; clear_tracer

@@ -992,7 +992,7 @@ class TestBudgetedPipeline:
         from pbccs_tpu.sched.executor import ScheduledPipeline
         from pbccs_tpu.sched.pool import DevicePool
 
-        def stub_prepare(chunks, settings):
+        def stub_prepare(chunks, settings, **span_args):
             from pbccs_tpu.pipeline import ResultTally
 
             time.sleep(0.01)

@@ -393,7 +393,7 @@ def test_executor_first_attempt_device_failure_reaches_pool(monkeypatch):
     chunks = [Chunk(f"m/{i}", [Subread(f"m/{i}/0", np.zeros(8, np.int8))],
                     np.ones(4, np.float32)) for i in range(3)]
 
-    def stub_prepare(cs, settings):
+    def stub_prepare(cs, settings, **span_args):
         read = types.SimpleNamespace(seq="ACGTACGT")
         return ResultTally(), [
             PreparedZmw(c, np.zeros(12, np.int8), [read], 0, 0, 0.0)

@@ -1,0 +1,342 @@
+"""The trace sites of the batch path: the spans and counters that say why
+the chip idles and where set-up goes (obs/trace.py, runtime/cache.py,
+obs/flight.py, pipeline.py, cli.py).
+
+CPU only: the tests assert which spans exist, how they nest and which
+counters move -- never how long anything takes on a device.
+"""
+
+import json
+import threading
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from pbccs_tpu import pipeline
+from pbccs_tpu.obs import flight as obs_flight
+from pbccs_tpu.obs import trace as obs_trace
+from pbccs_tpu.obs.metrics import default_registry
+from pbccs_tpu.runtime import cache as runtime_cache
+from tests.test_cli import make_zmw_records
+
+LOAD_SECONDS = "ccs_program_load_seconds_total"
+SLOT_ROUNDS = "ccs_refine_slot_rounds_total"
+
+
+@pytest.fixture
+def tracer():
+    """A tracer installed process-wide for the test, then cleared."""
+    t = obs_trace.Tracer()
+    prev = obs_trace.set_tracer(t)
+    try:
+        yield t
+    finally:
+        obs_trace.set_tracer(prev)
+
+
+def events_by_name(chrome: dict) -> dict[str, list[dict]]:
+    out: dict[str, list[dict]] = {}
+    for ev in chrome["traceEvents"]:
+        out.setdefault(ev["name"], []).append(ev)
+    return out
+
+
+# ------------------------------------------------------------ the tracer off
+
+
+class CountingAnnotation:
+    made = 0
+
+    def __init__(self, name):
+        type(self).made += 1
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+def test_without_a_tracer_span_makes_nothing(monkeypatch):
+    """The disabled path is one global read: the same shared object every
+    time, no Span, no profiler annotation."""
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", CountingAnnotation)
+    monkeypatch.setattr(CountingAnnotation, "made", 0)
+    prev = obs_trace.set_tracer(None)
+    try:
+        a = obs_trace.span("polish.refine", zmws=3)
+        b = obs_trace.span("draft")
+        assert a is b
+        with a as sp:
+            assert sp is None
+        assert CountingAnnotation.made == 0
+        t = obs_trace.Tracer()
+        obs_trace.set_tracer(t)
+        with obs_trace.span("polish.refine", zmws=3) as sp:
+            assert sp is not None
+        assert CountingAnnotation.made == 1
+    finally:
+        obs_trace.set_tracer(prev)
+
+
+# -------------------------------------------------------- the profiler's clock
+
+
+def test_spans_sit_in_the_profilers_host_plane(tracer, tmp_path):
+    """With a tracer installed and jax.profiler capturing, a span is also
+    a `ccs:` TraceAnnotation in the .xplane.pb: one clock for the program's
+    spans and the device's operations."""
+    from pbccs_tpu.obs import profiling
+
+    with profiling.profile_capture(str(tmp_path)):
+        with obs_trace.span("polish.refine", zmws=2):
+            jnp.arange(8).sum().block_until_ready()
+    found = list(tmp_path.rglob("*.xplane.pb"))
+    assert found, "the profiler wrote no .xplane.pb"
+    data = jax.profiler.ProfileData.from_file(str(found[0]))
+    host = [p for p in data.planes if p.name.startswith("/host:")]
+    names = {e.name for p in host for line in p.lines for e in line.events}
+    assert "ccs:polish.refine" in names
+    # the capture ran without the profiler's Python tracer, whose events
+    # are named `$file:line function`
+    assert not [n for n in names if n.startswith("$")]
+
+
+# --------------------------------------------------------- program-load phases
+
+
+def phase_seconds() -> dict[str, float]:
+    reg = default_registry()
+    return {phase: reg.counter(LOAD_SECONDS, phase=phase).value
+            for phase in ("trace", "lower", "compile", "cache_read")}
+
+
+def test_a_compile_yields_program_spans_and_moves_every_phase(tracer):
+    """One fresh jit: trace, lower and compile each leave a span named for
+    the function and move their phase; a second load of the same program
+    from the persistent cache moves cache_read.  Nested events (the jnp ops
+    traced inside) are not booked twice."""
+    runtime_cache._install_cache_metrics()
+    min_secs = jax.config.jax_persistent_cache_min_compile_time_secs
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    try:
+        salt = time.time_ns() % 1_000_003      # a program no run cached
+
+        def ccs_trace_site_probe(x):
+            return jnp.sin(x) * salt + jnp.cos(x)
+
+        before = phase_seconds()
+        t0 = time.perf_counter()
+        jax.jit(ccs_trace_site_probe)(jnp.ones(7)).block_until_ready()
+        wall = time.perf_counter() - t0
+        first = phase_seconds()
+        jax.clear_caches()                      # the next call reads the disk
+        jax.jit(ccs_trace_site_probe)(jnp.ones(7)).block_until_ready()
+        after = phase_seconds()
+    finally:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          min_secs)
+    for phase in ("trace", "lower", "compile"):
+        assert first[phase] > before[phase], phase
+    assert after["cache_read"] > before["cache_read"]
+    # outermost events only: the phases of one compile fit in its wall
+    assert sum(first[p] - before[p]
+               for p in ("trace", "lower", "compile")) <= wall
+    by_name = events_by_name(tracer.to_chrome())
+    for name in ("program.trace", "program.lower", "program.compile"):
+        mine = [e for e in by_name.get(name, [])
+                if "ccs_trace_site_probe" in e["args"]["fun"]]
+        assert mine, f"no {name} span names the probe"
+    # sin, cos, multiply, add were traced inside the probe: no span each
+    assert not [e for e in by_name["program.trace"]
+                if e["args"]["fun"] in ("sin", "cos")]
+
+
+def test_suppressed_events_move_no_phase():
+    runtime_cache._install_cache_metrics()
+    before = phase_seconds()
+    with runtime_cache.suppress_cache_metrics():
+        jax.jit(lambda x: x * 3 + time.time_ns() % 7)(
+            jnp.ones(5)).block_until_ready()
+    assert phase_seconds() == before
+
+
+# ------------------------------------------------------------- slot occupancy
+
+
+def test_record_round_moves_both_slot_round_series():
+    reg = default_registry()
+    live = reg.counter(SLOT_ROUNDS, kind="live")
+    capacity = reg.counter(SLOT_ROUNDS, kind="capacity")
+    live0, cap0 = live.value, capacity.value
+    rec = obs_flight.FlightRecorder()
+    rec.record_round("t", 0, live=5, n_zmws=6, z=8)
+    rec.record_round("t", 1, live=2, n_zmws=6, z=8, source="device")
+    assert live.value - live0 == 7
+    assert capacity.value - cap0 == 16
+
+
+# ------------------------------------------------------------- the device turn
+
+
+def test_turn_wait_covers_the_time_another_batch_holds_the_turn(
+        tracer, monkeypatch):
+    """Two workers, one device turn: the second's `dispatch.turn_wait`
+    spans the first's `polish`, and its own `polish` opens after it."""
+    holding = threading.Event()
+
+    def stub_prepare(chunks, settings, **span_args):
+        return pipeline.ResultTally(), list(chunks)
+
+    def stub_polish(preps, settings, **kw):
+        holding.set()
+        time.sleep(0.2)
+        return [(pipeline.Failure.OTHER, None) for _ in preps]
+
+    monkeypatch.setattr(pipeline, "prepare_batch", stub_prepare)
+    monkeypatch.setattr(pipeline, "polish_prepared_batch", stub_polish)
+    first = threading.Thread(target=pipeline.process_chunks, args=(["a"],))
+    second = threading.Thread(target=pipeline.process_chunks,
+                              args=(["b", "c"],))
+    first.start()
+    assert holding.wait(10.0)
+    second.start()
+    first.join(10.0)
+    second.join(10.0)
+    assert not first.is_alive() and not second.is_alive()
+
+    by_name = events_by_name(tracer.to_chrome())
+    waits = {e["args"]["zmws"]: e for e in by_name["dispatch.turn_wait"]}
+    polishes = {e["args"]["zmws"]: e for e in by_name["polish"]}
+    assert waits[1]["dur"] < 50_000             # the turn was free
+    held_until = polishes[1]["ts"] + polishes[1]["dur"]
+    wait_end = waits[2]["ts"] + waits[2]["dur"]
+    assert waits[2]["dur"] > 100_000
+    assert wait_end >= held_until               # it waited the holder out
+    assert polishes[2]["ts"] >= wait_end        # and only then polished
+    assert waits[2]["args"]["cpu_ms"] < 50.0    # waiting is not work
+
+
+# --------------------------------------------------------------- the batch CLI
+
+
+def write_subread_bam(rng, path: str, holes) -> None:
+    from pbccs_tpu.io.bam import (BamHeader, BamRecord, BamWriter,
+                                  ReadGroupInfo, make_read_group_id)
+
+    movie = "m140905_042212_sidney_c100564852550000001823085912221377_s1_X0"
+    header = BamHeader(read_groups=[
+        ReadGroupInfo(movie, "SUBREAD", binding_kit="100356300",
+                      sequencing_kit="100356200",
+                      basecaller_version="2.3.0")])
+    rg_id = make_read_group_id(movie, "SUBREAD")
+    with BamWriter(path, header) as bw:
+        for hole in holes:
+            _, recs, snr = make_zmw_records(rng, movie, hole, tpl_len=60,
+                                            n_passes=4)
+            for name, seq in recs:
+                bw.write(BamRecord(name=name, seq=seq, tags={
+                    "RG": rg_id, "zm": hole, "cx": 3, "rq": 0.85,
+                    "sn": [float(s) for s in snr]}))
+
+
+def test_trace_out_of_a_batch_cli_run_has_the_spans_of_its_path(
+        rng, tmp_path):
+    """`ccs OUT IN --trace-out F` on four simulated ZMWs in two batches:
+    every span the WorkQueue driver's path reaches is in the file, one
+    batch's prepare, turn wait and polish hang under its `batch` span, and
+    polish's five parts hang under `polish`."""
+    from pbccs_tpu.cli import run
+
+    in_bam = str(tmp_path / "subreads.bam")
+    write_subread_bam(rng, in_bam, holes=(11, 12, 13, 14))
+    out_bam, trace_out = str(tmp_path / "out.bam"), str(tmp_path / "t.json")
+    rc = run([out_bam, in_bam, "--reportFile", str(tmp_path / "r.csv"),
+              "--numThreads", "2", "--chunkSize", "2", "--logLevel", "WARN",
+              "--trace-out", trace_out])
+    assert rc == 0
+    with open(trace_out) as f:
+        chrome = json.load(f)
+    assert "droppedSpans" not in chrome
+    assert chrome["meta"]["dropped_spans"] == 0
+    by_name = events_by_name(chrome)
+    by_id = {e["id"]: e for e in chrome["traceEvents"]}
+
+    reached = {"run", "read", "batch", "prepare", "filter", "draft",
+               "draft.poa", "draft.map", "dispatch.turn_wait", "polish",
+               "polish.setup", "polish.gates", "polish.refine", "polish.qv",
+               "polish.finish", "emit"}
+    assert reached <= set(by_name), reached - set(by_name)
+
+    (run_ev,) = by_name["run"]
+    assert run_ev["args"]["zmws"] == 4 and run_ev["args"]["threads"] == 2
+    assert run_ev["args"]["chunk_size"] == 2
+    assert run_ev["args"]["devices"] == 1 and run_ev["args"]["cpus"] >= 1
+    # two batches and the end of the input: three reads, on `run`'s thread
+    assert sorted(e["args"]["zmws"] for e in by_name["read"]) == [0, 2, 2]
+    assert {e["args"]["parent"] for e in by_name["read"]} == {run_ev["id"]}
+
+    def children(ev):
+        return [e for e in chrome["traceEvents"]
+                if e["args"].get("parent") == ev["id"]]
+
+    batches = by_name["batch"]
+    assert sorted(e["args"]["batch"] for e in batches) == [0, 1]
+    for batch in batches:
+        assert batch["args"]["zmws"] == 2
+        kids = [e["name"] for e in children(batch)]
+        assert kids == ["prepare", "dispatch.turn_wait", "polish"]
+        (polish,) = [e for e in children(batch) if e["name"] == "polish"]
+        assert [e["name"] for e in children(polish)] == [
+            "polish.setup", "polish.gates", "polish.refine", "polish.qv",
+            "polish.finish"]
+    for ev in by_name["filter"] + by_name["draft"]:
+        assert by_id[ev["args"]["parent"]]["name"] == "prepare"
+    for ev in by_name["draft.poa"] + by_name["draft.map"]:
+        assert by_id[ev["args"]["parent"]]["name"] == "draft"
+    for ev in chrome["traceEvents"]:
+        if not ev["name"].startswith("program."):   # told of after the fact
+            assert 0.0 <= ev["args"]["cpu_ms"] <= ev["dur"] / 1e3 + 1.0, ev
+    # any program this run brought up says where, under the span it ran in
+    for ev in by_name.get("program.compile", []):
+        assert ev["args"]["fun"] and "parent" in ev["args"]
+
+
+# ------------------------------------------------------- tools/trace_cover.py
+
+
+def test_trace_cover_reads_coverage_and_pairs_annotations_by_duration():
+    import importlib.util
+    import os
+
+    spec = importlib.util.spec_from_file_location(
+        "trace_cover", os.path.join(os.path.dirname(__file__), os.pardir,
+                                    "tools", "trace_cover.py"))
+    cover = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(cover)
+
+    def ev(i, name, ts_s, dur_s, parent=None):
+        args = {} if parent is None else {"parent": parent}
+        return {"id": i, "name": name, "ts": ts_s * 1e6, "dur": dur_s * 1e6,
+                "args": args}
+
+    events = [ev(0, "batch", 0.0, 10.0),
+              ev(1, "prepare", 0.0, 4.0, 0),
+              ev(2, "dispatch.turn_wait", 4.0, 3.0, 0),
+              ev(3, "polish", 7.0, 2.5, 0),
+              ev(4, "polish.setup", 7.0, 1.0, 3),
+              ev(5, "polish.refine", 8.0, 1.0, 3),
+              ev(6, "polish.round", 8.0, 1.0, 5),      # not polish's child
+              ev(7, "polish", 20.0, 2.0)]              # a fleet's: no parts
+    assert cover.coverage(events, "batch", cover.BATCH_PARTS) == [0.95]
+    assert cover.coverage(events, "polish", cover.POLISH_PARTS) == [0.8, 0.0]
+    # two polish annotations: each span pairs with the one of its length;
+    # `prepare` began before the capture and is not paired with the
+    # `prepare` of a later batch
+    notes = [("polish", 1000.0 + 7.00002, 2.49999),
+             ("polish", 1000.0 + 20.00004, 1.99999),
+             ("prepare", 1000.0 + 0.9, 4.0)]
+    skews = cover.annotation_skews(events, 1000.0, notes)
+    assert sorted(round(s * 1e6) for s in skews) == [20, 40]
