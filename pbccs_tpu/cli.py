@@ -3,9 +3,10 @@
     python -m pbccs_tpu.cli [OPTIONS] OUTPUT FILES...
 
 Reads subreads from BAM (PacBio conventions) or FASTA (records named
-movie/zmw[/s_e], grouped by ZMW), runs the consensus pipeline over a
-bounded ordered work pipeline, and writes a CCS BAM plus a CSV yield
-report.  Flags, defaults, CLI-level filters (whitelist, chemistry, SNR,
+movie/zmw[/s_e], grouped by ZMW), runs the consensus pipeline through the
+scheduled driver (pbccs_tpu.sched: host drafts ahead of the device, results
+in reading order), and writes a CCS BAM plus a CSV yield report.  Flags,
+defaults, CLI-level filters (whitelist, chemistry, SNR,
 read score, pass count) and output tags mirror the reference driver
 (reference src/main/ccs.cpp:284-519).
 """
@@ -38,12 +39,10 @@ from pbccs_tpu.pipeline import (
     Failure,
     ResultTally,
     Subread,
-    process_chunks,
 )
 from pbccs_tpu.runtime.chemistry import verify_chemistry
 from pbccs_tpu.runtime.logging import Logger, LogLevel, install_signal_handlers
 from pbccs_tpu.runtime.whitelist import Whitelist
-from pbccs_tpu.runtime.workqueue import WorkQueue
 
 DESCRIPTION = ("Generate circular consensus sequences (ccs) from subreads "
                "-- TPU-native implementation.")
@@ -134,21 +133,25 @@ def build_parser() -> argparse.ArgumentParser:
                         "movie:1-3,5;movie2:*. Default = %(default)s")
     add_consensus_args(p)
     p.add_argument("--numThreads", type=int, default=0,
-                   help="Number of host pipeline threads (0 = auto); with "
-                        "--devices it seeds the prepare pool unless "
-                        "--prepareWorkers is given. Default = %(default)s")
+                   help="Host threads of the prepare (POA draft) pool "
+                        "(0 = auto); the reference's spelling of "
+                        "--prepareWorkers, which wins where both are "
+                        "given. Default = %(default)s")
     p.add_argument("--chunkSize", type=int, default=64,
                    help="ZMWs per work item; each work item polishes as one "
                         "lockstep device batch. Default = %(default)s")
     p.add_argument("--devices", type=int, default=1,
-                   help="Polish across a device fleet (pbccs_tpu.sched): "
-                        "N>1 uses the first N visible devices, 0 all of "
-                        "them, 1 the legacy single-device WorkQueue "
-                        "driver. Default = %(default)s")
+                   help="Devices to polish on (pbccs_tpu.sched, one "
+                        "executor thread each): the first N visible "
+                        "devices, 0 = all of them.  The output is "
+                        "byte-identical at every count. "
+                        "Default = %(default)s")
     p.add_argument("--prepareWorkers", type=int, default=0,
-                   help="Host prepare (POA draft) threads overlapping "
-                        "in-flight device polishes in the scheduled "
-                        "driver (0 = auto; only used with --devices). "
+                   help="Host prepare (POA draft) threads: each batch "
+                        "is dealt over all of them, in reading order, "
+                        "so drafts run ahead of the device polishes "
+                        "in flight (0 = auto: --numThreads, a tuned "
+                        "profile, else 2 to 4 by core count). "
                         "Default = %(default)s")
     p.add_argument("--schedPolicy", choices=("sticky", "least", "roundrobin"),
                    default="sticky",
@@ -197,8 +200,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "tally and output are identical to an "
                         "uninterrupted run.")
     p.add_argument("--memBudget", default=None, metavar="SIZE",
-                   help="Host-memory budget for batch backlog in the "
-                        "fleet driver (--devices != 1), e.g. 8G or "
+                   help="Host-memory budget for the prepared-batch "
+                        "backlog, e.g. 8G or "
                         "512M: the prepare pool throttles (visible as "
                         "ccs_resource_throttles_total, never a crash) "
                         "while prepared-batch bytes in flight would "
@@ -443,10 +446,9 @@ def run(argv: list[str] | None = None) -> int:
         except ValueError as e:
             print(f"option --memBudget: {e}", file=sys.stderr)
             return 2
-    elif args.devices != 1:
+    else:
         # resolution ladder: no explicit --memBudget, so a tuned
-        # profile's byte budget (already stored in bytes) applies; the
-        # single-device WorkQueue driver has no prepare backlog to gate
+        # profile's byte budget (already stored in bytes) applies
         args.memBudget = tuning.knob_int("mem_budget_bytes")
 
     settings = consensus_settings_from_args(args)
@@ -482,7 +484,7 @@ def run(argv: list[str] | None = None) -> int:
     tally = None
     try:
         with profiling.profile_capture(args.profile_dir), \
-                obs_trace.span("run", threads=_worker_threads(args),
+                obs_trace.span("run", threads=_prepare_workers(args),
                                cpus=os.cpu_count(),
                                chunk_size=args.chunkSize,
                                devices=args.devices) as run_span:
@@ -533,20 +535,27 @@ def run(argv: list[str] | None = None) -> int:
     return 0
 
 
-def _worker_threads(args) -> int:
-    """WorkQueue workers of the single-device driver.  Default to at
-    least 2 even on a 1-core host: a worker blocks on the device with the
-    GIL released for most of a batch polish, so a second worker drafts
-    the NEXT batch (host POA) during that wait -- the reference's
-    reader/worker/writer overlap (ccs.cpp:388-499) re-expressed for a
-    device-bound polish stage."""
-    return args.numThreads or max(2, min(8, os.cpu_count() or 1))
+def _prepare_workers(args) -> int:
+    """Threads of the host prepare pool, at every device count: the
+    explicit flags, then a tuned profile (the runtime/tuning.py
+    resolution ladder), then 2 to 4 by core count.  Never under two,
+    even on a 1-core host: the device thread blocks on the device with
+    the GIL released for most of a polish, and drafting the NEXT batch
+    in that wait is the reference's reader/worker/writer overlap
+    (ccs.cpp:388-499) re-expressed for a device-bound polish stage.
+    Not over four: a draft is native POA under Python glue, and past
+    about four threads the glue queues on the interpreter lock, which
+    the device thread's own Python has to share."""
+    from pbccs_tpu.runtime import tuning
+
+    return (args.prepareWorkers or args.numThreads
+            or tuning.knob_int("prepare_workers")
+            or max(2, min(4, os.cpu_count() or 1)))
 
 
 def _run_pipeline(args, files, whitelist, settings, log) -> ResultTally:
-    """The reader -> WorkQueue -> batched polish -> writer body of a CLI
-    run (split from run() so the observability capture scopes wrap it)."""
-    n_threads = _worker_threads(args)
+    """The reader -> scheduled pipeline -> writer body of a CLI run (split
+    from run() so the observability capture scopes wrap it)."""
     tally = ResultTally()
 
     # collect movie names for the output header
@@ -579,12 +588,6 @@ def _run_pipeline(args, files, whitelist, settings, log) -> ResultTally:
 
     from pbccs_tpu.obs import trace as obs_trace
     from pbccs_tpu.runtime import timing
-
-    # The work queue's max_pending bounds results not yet CONSUMED, so the
-    # consumer must run concurrently with the produce loop (the reference's
-    # reader/worker/writer overlap, ccs.cpp:388-499) -- a produce-everything-
-    # then-consume loop would deadlock once the pipeline fills.
-    import threading
 
     # checkpoint journal: restore completed chunks (--resume) and record
     # each chunk as its results are consumed, in submission order, so a
@@ -625,10 +628,10 @@ def _run_pipeline(args, files, whitelist, settings, log) -> ResultTally:
         journal.start(fp, resume=args.resume and bool(restored))
 
     def _read_batches(gate_tally: ResultTally):
-        """Shared reader loop of BOTH drivers: stream (idx, batch) with
-        read-stage timing and output-header movie registration.  CLI-gate
-        skips tally into `gate_tally` (the fleet driver passes a separate
-        one because this generator runs on its feeder thread)."""
+        """The reader loop: stream (idx, batch) with read-stage timing
+        and output-header movie registration.  It runs on the pipeline's
+        feeder thread, so CLI-gate skips tally into `gate_tally`, merged
+        after the run, and never race the main thread's result merges."""
         it = iter(_chunks_from_files(files, whitelist, args, log,
                                      gate_tally))
         idx = -1
@@ -637,6 +640,8 @@ def _run_pipeline(args, files, whitelist, settings, log) -> ResultTally:
                 batch = next(it, None)
                 if read_span is not None:
                     read_span.args["zmws"] = len(batch or ())
+                    if batch is not None:
+                        read_span.args["batch"] = idx + 1
             if batch is None:
                 return
             idx += 1
@@ -645,106 +650,48 @@ def _run_pipeline(args, files, whitelist, settings, log) -> ResultTally:
                 movies.setdefault(movie, ReadGroupInfo(movie, "CCS"))
             yield idx, batch
 
-    if args.devices != 1:
-        # Device-fleet scheduler (pbccs_tpu/sched): host prepare workers
-        # overlap in-flight device polishes and batches fan out across
-        # the pool with sticky bucket routing.  Batch composition and
-        # shape derivation are IDENTICAL to the WorkQueue driver (same
-        # --chunkSize groups, same effective_shapes), so the output is
-        # byte-identical to a --devices 1 run.
-        from pbccs_tpu.sched import (DevicePool, DevicePoolConfig,
-                                     select_devices)
-        from pbccs_tpu.sched.executor import ScheduledPipeline
+    # The scheduled driver (pbccs_tpu/sched), at every device count: host
+    # prepare workers draft ahead of the device polishes in flight, ZMW by
+    # ZMW in reading order, and batches fan out across the pool with
+    # sticky bucket routing.  Batch composition and shape derivation are
+    # those of pipeline.process_chunks (same --chunkSize groups, same
+    # effective_shapes), so the output is byte-identical at any count.
+    from pbccs_tpu.sched import DevicePool, DevicePoolConfig, select_devices
+    from pbccs_tpu.sched.executor import ScheduledPipeline
 
-        devs = select_devices(args.devices)
-        # --numThreads sizes the legacy WorkQueue driver; in fleet mode
-        # it seeds the host prepare pool instead of being silently
-        # dropped (an explicit --prepareWorkers still wins).  A tuned
-        # profile slots between the explicit flags and the auto default
-        # (the runtime/tuning.py resolution ladder).
-        from pbccs_tpu.runtime import tuning
+    # --memBudget: byte-bound the prepared-batch backlog (prep pool +
+    # parked results) so a full-cell stream cannot outrun the devices
+    # into the OOM killer (resilience.resources.HostBudget)
+    budget = None
+    if args.memBudget is not None:
+        from pbccs_tpu.resilience.resources import HostBudget
 
-        prep_workers = (args.prepareWorkers or args.numThreads
-                        or tuning.knob_int("prepare_workers")
-                        or max(2, min(4, os.cpu_count() or 1)))
-        # --memBudget: byte-bound the prepared-batch backlog (prep pool
-        # + parked results) so a full-cell stream cannot outrun the
-        # devices into the OOM killer (resilience.resources.HostBudget)
-        budget = None
-        if args.memBudget is not None:
-            from pbccs_tpu.resilience.resources import HostBudget
+        budget = HostBudget(args.memBudget, logger=log)
+    pool = DevicePool(select_devices(args.devices),
+                      DevicePoolConfig(policy=args.schedPolicy), logger=log)
+    pipe = ScheduledPipeline(pool, settings,
+                             prepare_workers=_prepare_workers(args),
+                             on_error=args.batchFallback,
+                             budget=budget, logger=log)
 
-            budget = HostBudget(args.memBudget, logger=log)
-        pool = DevicePool(devs, DevicePoolConfig(policy=args.schedPolicy),
-                          logger=log)
-        pipe = ScheduledPipeline(pool, settings,
-                                 prepare_workers=prep_workers,
-                                 on_error=args.batchFallback,
-                                 budget=budget, logger=log)
-
-        # the reader runs on the pipeline's feeder thread, so its
-        # CLI-gate skips tally into their own ResultTally (merged below)
-        # instead of racing the main thread's result merges;
-        # journal-restored chunks ride through the scheduler as
-        # precomputed tallies so they merge at their index slot
-        gate_tally = ResultTally()
-        items = ((idx, batch, restored.get(idx))
-                 for idx, batch in _read_batches(gate_tally))
-        try:
-            for idx, sub_tally in pipe.run(items):
-                tally.merge(sub_tally)
-                if journal is not None and idx not in restored:
-                    journal.record_chunk(idx, sub_tally)
-        except BaseException:
-            # the run is already doomed: fail queued batches fast
-            # (PoolClosed) instead of polishing minutes of device work
-            # whose results nothing will consume
-            pool.close(wait=False)
-            raise
-        pool.close()
-        tally.merge(gate_tally)
-    else:
-        if args.memBudget is not None:
-            log.warn("--memBudget gates the fleet driver's prepare "
-                     "backlog; the single-device WorkQueue driver "
-                     "(--devices 1) is already bounded by --numThreads "
-                     "work items, so the flag is ignored here")
-
-        def _run_batch(idx, batch):
-            with obs_trace.span("batch", batch=idx, zmws=len(batch)):
-                return idx, process_chunks(batch, settings,
-                                           on_error=args.batchFallback)
-
-        consumed = ResultTally()
-        consumer_error: list[BaseException] = []
-
-        with WorkQueue(n_threads) as wq:
-            def _consume():
-                try:
-                    for idx, sub_tally in wq.results():
-                        consumed.merge(sub_tally)
-                        if journal is not None:
-                            journal.record_chunk(idx, sub_tally)
-                except BaseException as e:  # noqa: BLE001 -- re-raised below
-                    consumer_error.append(e)
-
-            consumer = threading.Thread(target=_consume,
-                                        name="pbccs-consumer")
-            consumer.start()
-            for idx, batch in _read_batches(tally):
-                if idx in restored:
-                    # journaled chunks restore in index order BEFORE any
-                    # newly computed chunk merges (journal records form a
-                    # prefix), so output order matches an uninterrupted run
-                    tally.merge(restored[idx])
-                    continue
-                with timing.stage("queue"):
-                    wq.produce(_run_batch, idx, batch)
-            wq.finalize()
-            consumer.join()
-        if consumer_error:
-            raise consumer_error[0]
-        tally.merge(consumed)
+    # journal-restored chunks ride through the scheduler as precomputed
+    # tallies so they merge at their index slot
+    gate_tally = ResultTally()
+    items = ((idx, batch, restored.get(idx))
+             for idx, batch in _read_batches(gate_tally))
+    try:
+        for idx, sub_tally in pipe.run(items):
+            tally.merge(sub_tally)
+            if journal is not None and idx not in restored:
+                journal.record_chunk(idx, sub_tally)
+    except BaseException:
+        # the run is already doomed: fail queued batches fast
+        # (PoolClosed) instead of polishing minutes of device work
+        # whose results nothing will consume
+        pool.close(wait=False)
+        raise
+    pool.close()
+    tally.merge(gate_tally)
     log.info(f"processed {tally.total} ZMWs: "
              f"{tally.counts[Failure.SUCCESS]} successes")
 

@@ -41,14 +41,12 @@ ADAPTER_AFTER = 2
 
 _reg = default_registry()
 
-# One batch on the device at a time in the single-device driver.  The
-# CLI's WorkQueue workers each draft (host) and then polish (device); the
-# worker count follows the host's cores, and N workers polishing at once
-# hold N batches' fills in HBM -- on a 13-core host with one 16 GB chip
-# that was eight 64-ZMW batches, and every one of them ran out of memory.
-# Drafts still overlap the polish in flight.  The fleet scheduler (one
-# executor thread per device) and `ccs serve` (one polish worker) already
-# dispatch this way and do not come through process_chunks.
+# One batch on the device at a time among concurrent process_chunks
+# callers: N threads polishing at once hold N batches' fills in HBM, and
+# eight 64-ZMW batches at once ran a 16 GB chip out of memory (PR 23).
+# The batch CLI's scheduled driver (one executor thread per device) and
+# `ccs serve` (one polish worker) own the device by construction and do
+# not come through process_chunks.
 _polish_turn = threading.Lock()
 
 # every entry into the shared batch-polish core (offline driver, sched
@@ -920,11 +918,12 @@ def prepare_batch(chunks: Sequence[Chunk],
     """The host half of a batch: run every chunk through the prep stages
     (filter -> POA draft -> mapping) with per-ZMW fault isolation,
     returning (tally of prep-stage outcomes, survivors ready to polish).
-    Shared by process_chunks and the device-fleet scheduler's prepare
-    workers (pbccs_tpu.sched.executor), so the two drivers cannot drift.
-    `span_args` go on the `prepare` trace span (the fleet driver's
-    `batch=idx`: its prepare and polish run on two threads, under no
-    common `batch` span)."""
+    Shared by process_chunks (a whole batch) and the scheduled driver's
+    prepare workers (pbccs_tpu.sched.executor: one contiguous slice of a
+    batch each, joined in chunk order), so the two cannot drift.
+    `span_args` go on the `prepare` trace span (the scheduled driver's
+    `batch=idx`: a batch's slices and its polish run on several threads,
+    under no common span)."""
     from pbccs_tpu.resilience import faults
     from pbccs_tpu.runtime import timing
 

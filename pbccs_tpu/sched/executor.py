@@ -1,14 +1,18 @@
 """Pipelined batch executor: host prepare overlapped with device polish.
 
-The offline driver's round-5 profile runs end to end at 42% of polish
-throughput because the serial host-side POA draft gates the device: the
-WorkQueue overlaps whole work items, but each worker still runs
-prepare -> polish sequentially, so with one device the prepare of item
-k+1 only overlaps the polish of item k when a second worker happens to
-hold it.  This executor makes the overlap structural and fleet-wide:
+The one driver of the batch CLI, at every device count.  Host drafts run
+ahead of the device in reading order, at ZMW granularity, and one thread
+owns each device:
 
-    reader ──> prepare pool (N host threads: filter -> POA -> mapping)
-                   │ prepared batches, keyed by compiled-shape bucket
+    reader ──> prepare pool (N host threads, FIFO: filter -> POA -> mapping)
+       ▲           │ (the reader reads batch k+1 when batch k's drafts
+       └───────────┤  have closed: its Python would starve them)
+                   │ each batch dealt as contiguous slices of
+                   │ ceil(len / N) ZMWs, so ONE batch spreads over all N
+                   │ workers and batch 0 is whole after one slice's time
+                   │ (a worker per whole batch finishes the first N
+                   │ batches together, with the device idle until then);
+                   │ the last slice to close assembles the batch
                    ▼
                DevicePool (one executor thread per device)
                    │ per-batch outcome tallies
@@ -18,10 +22,11 @@ hold it.  This executor makes the overlap structural and fleet-wide:
                to the single-threaded driver)
 
 Batch composition is untouched -- the same --chunkSize groups, prepared
-and polished with the same shape derivation as pipeline.process_chunks
--- so a multi-device run's output is byte-identical to the
-single-device run (same bucket shapes => same compiled programs => same
-arithmetic), merely reordered in time.
+(pipeline.prepare_batch on each slice, joined in chunk order) and
+polished with the same shape derivation as pipeline.process_chunks --
+so a run's output is byte-identical at every device and worker count
+(same bucket shapes => same compiled programs => same arithmetic),
+merely reordered in time.
 """
 
 from __future__ import annotations
@@ -56,6 +61,37 @@ _m_batches = _reg.counter(
     "ccs_sched_batches_total",
     "Prepared batches submitted to the device pool by the scheduled "
     "pipeline")
+
+
+class _BatchJob:
+    """One batch in the prepare pool: the (tally, preps) of each of its
+    slices, joined in chunk order by whichever slice closes last."""
+
+    def __init__(self, seq: int, idx: int, n_slices: int):
+        self.seq, self.idx = seq, idx
+        # fed when the batch before it has closed its drafts, so its
+        # first slice starts at once: the prepare interval starts here
+        self.t_fed = time.monotonic()
+        self._parts: list[Any] = [None] * n_slices
+        self._open = n_slices
+        self._lock = threading.Lock()
+
+    def close_slice(self, k: int, part) -> bool:
+        """Book slice k's part (or the exception it died of); True for
+        the slice that closes the batch."""
+        with self._lock:
+            self._parts[k] = part
+            self._open -= 1
+            return self._open == 0
+
+    def assemble(self) -> tuple["pipeline.ResultTally", list]:
+        tally, preps = pipeline.ResultTally(), []
+        for part in self._parts:
+            if isinstance(part, BaseException):
+                raise part
+            tally.merge(part[0])
+            preps.extend(part[1])
+        return tally, preps
 
 
 class ScheduledPipeline:
@@ -96,6 +132,14 @@ class ScheduledPipeline:
         cv = threading.Condition()
         done: dict[int, Any] = {}        # seq -> (idx, tally) | exception
         sem = threading.Semaphore(self.max_inflight)
+        # the reader and the drafts take turns: batch k+1 is read once
+        # batch k's drafts have closed.  The reader is Python and holds
+        # the GIL, and while it does, a draft's every return from native
+        # code waits a switch interval for it: beside a reader left to
+        # run through a file, a draft took 210-250 ms against 45 ms, and
+        # the first batch was whole when the reading ended, after 2.8 s
+        # with the device idle, not after 1 s (PR 26, on the chip's host)
+        ahead = threading.Semaphore(1)
         n_fed = [0]
         feeder_done = threading.Event()
         feeder_error: list[BaseException] = []
@@ -138,15 +182,25 @@ class ScheduledPipeline:
             except BaseException as e:  # noqa: BLE001 -- surfaced in run()
                 finish(seq, e)
 
-        def prep_one(seq: int, idx: int, chunks, precomputed) -> None:
-            lease = None
-            t_prep0 = time.monotonic()
+        def prep_slice(job: "_BatchJob", k: int, chunks) -> None:
+            """One contiguous slice of a batch through the host stages;
+            the slice that closes last assembles and submits the batch."""
+            if stop.is_set():
+                return   # the consumer bailed: nothing reads this batch
             try:
-                if precomputed is not None:
-                    finish(seq, (idx, precomputed))
-                    return
-                tally, preps = pipeline.prepare_batch(chunks, self.settings,
-                                                      batch=idx)
+                part = pipeline.prepare_batch(chunks, self.settings,
+                                              batch=job.idx)
+            except BaseException as e:  # noqa: BLE001 -- surfaced in run()
+                part = e
+            if job.close_slice(k, part):
+                ahead.release()   # drafted: read the next one
+                submit_batch(job)
+
+        def submit_batch(job: "_BatchJob") -> None:
+            seq, idx = job.seq, job.idx
+            lease = None
+            try:
+                tally, preps = job.assemble()
                 if not preps:
                     finish(seq, (idx, tally))
                     return
@@ -168,15 +222,22 @@ class ScheduledPipeline:
                         if lease is not None:
                             lease.release()
                         return
+                from pbccs_tpu.resilience import resources
+
+                bucket = resources.shape_bucket(imax, jmax, r)
                 # pre-bake the polish marshalling HERE, on the prepare
                 # worker: padded numpy planes + f64 SNR tables build while
                 # the device threads polish earlier batches, so
                 # BatchPolisher on the executor thread adopts arrays
                 # instead of marshalling.  Quiver polishes per ZMW and
-                # never reads a prebake; any prebake failure falls back
-                # to inline marshalling (accounted, never fatal).
+                # never reads a prebake, and a batch over the governor's
+                # ceiling is pre-split into parts that marshal their own
+                # subsets; any prebake failure falls back to inline
+                # marshalling (accounted, never fatal).
+                cap = resources.default_governor().cap(bucket)
                 prebaked = None
-                if self.settings.model != "quiver":
+                if self.settings.model != "quiver" and (
+                        cap is None or len(preps) <= cap):
                     try:
                         prebaked = pipeline.prebake_polish(preps)
                     except Exception as e:  # noqa: BLE001 -- inline fallback
@@ -186,8 +247,9 @@ class ScheduledPipeline:
                 settings, on_error = self.settings, self.on_error
                 fleet = self.pool.n_devices > 1
                 attempts = [0]
-                t_submit = time.monotonic()
-                _m_stages["prepare"].observe(max(t_submit - t_prep0, 0.0))
+                t_submit, submit_unix = time.monotonic(), time.time()
+                _m_stages["prepare"].observe(
+                    max(t_submit - job.t_fed, 0.0))
 
                 def polish(_device):
                     # first attempt on a fleet: let a device-shaped
@@ -203,6 +265,18 @@ class ScheduledPipeline:
                     if attempts[0] == 1:
                         _m_stages["dispatch"].observe(
                             max(t_polish0 - t_submit, 0.0))
+                        # the wait for the device as a span, from submit
+                        # to where `polish` opens: long waits say the
+                        # device sets the pace, none (with a starved
+                        # device) says the host's drafts do.  No thread
+                        # ran in it, so its CPU time is nil.
+                        tracer = obs_trace.get_tracer()
+                        if tracer is not None:
+                            tracer.add_span(
+                                "dispatch.turn_wait",
+                                time.time() - submit_unix,
+                                start_unix=submit_unix, zmws=len(preps),
+                                batch=idx, cpu_ms=0.0)
                     try:
                         with obs_trace.span("polish", zmws=len(preps),
                                             batch=idx):
@@ -215,12 +289,9 @@ class ScheduledPipeline:
                         _m_stages["polish"].observe(
                             max(time.monotonic() - t_polish0, 0.0))
 
-                from pbccs_tpu.resilience import resources
-
                 _m_batches.inc()
                 self.pool.submit(
-                    key, polish, zmws=len(preps),
-                    capacity_bucket=resources.shape_bucket(imax, jmax, r),
+                    key, polish, zmws=len(preps), capacity_bucket=bucket,
                     callback=lambda fut: polish_done(seq, idx, tally,
                                                      preps, fut, lease))
             except BaseException as e:  # noqa: BLE001 -- surfaced in run()
@@ -237,13 +308,35 @@ class ScheduledPipeline:
 
         def feed() -> None:
             try:
-                for idx, chunks, precomputed in items:
+                it = iter(items)
+                while True:
+                    ahead.acquire()
+                    if stop.is_set():
+                        return
+                    item = next(it, None)      # the read happens here
+                    if item is None:
+                        return
+                    idx, chunks, precomputed = item
                     sem.acquire()
                     if stop.is_set():
                         return
                     seq = n_fed[0]
                     n_fed[0] += 1
-                    prep_pool.submit(prep_one, seq, idx, chunks, precomputed)
+                    if precomputed is not None or not chunks:
+                        finish(seq, (idx, precomputed if precomputed
+                                     is not None else pipeline.ResultTally()))
+                        ahead.release()        # nothing of it to draft
+                        continue
+                    # slices sized so this ONE batch spreads over every
+                    # prepare worker; the next batch is read when these
+                    # have closed (`ahead`), so drafts stay in reading
+                    # order and run ahead of the device
+                    size = -(-len(chunks) // self.prepare_workers)
+                    slices = [chunks[i: i + size]
+                              for i in range(0, len(chunks), size)]
+                    job = _BatchJob(seq, idx, len(slices))
+                    for k, part in enumerate(slices):
+                        prep_pool.submit(prep_slice, job, k, part)
             except BaseException as e:  # noqa: BLE001 -- surfaced in run()
                 feeder_error.append(e)
             finally:
@@ -281,5 +374,6 @@ class ScheduledPipeline:
             # polish_done callback when the pool settles their futures.
             stop.set()
             sem.release()
+            ahead.release()
             feeder_done.wait(timeout=10.0)
             prep_pool.shutdown(wait=True)

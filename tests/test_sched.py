@@ -19,6 +19,7 @@ from pbccs_tpu.pipeline import (  # noqa: E402
     Chunk,
     ConsensusSettings,
     Failure,
+    ResultTally,
     Subread,
     process_chunks,
 )
@@ -425,6 +426,143 @@ def test_executor_first_attempt_device_failure_reaches_pool(monkeypatch):
     assert scope.counter_value("ccs_sched_requeues_total") == 1
 
 
+# ------------------------------------------- drafts dealt ZMW by ZMW, in order
+
+def _stub_host_and_device(monkeypatch, prep_seconds, polished=None,
+                          polish_seconds=lambda preps: 0.05):
+    """Stub the host stages below `prepare_batch` and the whole polish:
+    `prepare_chunk` sleeps `prep_seconds(chunk)` (or raises what it
+    returns), the polish sleeps `polish_seconds(preps)` and notes the ZMW
+    ids it was handed."""
+    from pbccs_tpu import pipeline as pl
+
+    def stub_prepare_chunk(chunk, settings):
+        wait = prep_seconds(chunk)
+        if isinstance(wait, BaseException):
+            raise wait
+        time.sleep(wait)
+        return _stub_prep(chunk, settings)
+
+    def stub_polish(preps, settings, **kw):
+        if polished is not None:
+            polished.append([p.chunk.id for p in preps])
+        time.sleep(polish_seconds(preps))
+        return [(Failure.SUCCESS, None) for _ in preps]
+
+    monkeypatch.setattr(pl, "prepare_chunk", stub_prepare_chunk)
+    monkeypatch.setattr(pl, "polish_prepared_batch", stub_polish)
+    monkeypatch.setattr(pl, "_pinned_batch_shapes",
+                        lambda preps, buckets, min_z: ((8, 8, 4), 4))
+    monkeypatch.setattr(pl, "prebake_polish", lambda preps: None)
+
+
+def _stub_batches(n_batches, size):
+    return [[Chunk(f"m/{b * size + i}", [], np.ones(4, np.float32))
+             for i in range(size)] for b in range(n_batches)]
+
+
+def test_first_polish_opens_after_its_own_slices_alone(monkeypatch):
+    """Three batches of four on two prepare workers: a batch is dealt as
+    two slices of two, the pool is FIFO, so batch 0 is whole after one
+    slice's time and its polish opens then -- not after every batch's
+    drafts, as when each worker takes a whole batch.  The next batch is
+    read when a batch's drafts have closed, not before."""
+    from pbccs_tpu.obs import trace as obs_trace
+
+    _stub_host_and_device(monkeypatch, lambda chunk: 0.06)
+
+    def read_batches():
+        for i, batch in enumerate(_stub_batches(3, 4)):
+            with obs_trace.span("read", batch=i):
+                pass
+            yield i, batch, None
+
+    tracer = obs_trace.Tracer()
+    prev = obs_trace.set_tracer(tracer)
+    try:
+        with make_pool(1) as pool:
+            pipe = ScheduledPipeline(pool, ConsensusSettings(),
+                                     prepare_workers=2)
+            order = [idx for idx, _t in pipe.run(read_batches())]
+    finally:
+        obs_trace.set_tracer(prev)
+    assert order == [0, 1, 2]
+    events = tracer.to_chrome()["traceEvents"]
+    prepares = [e for e in events if e["name"] == "prepare"]
+    # the reader and the drafts take turns: a batch is asked for once the
+    # drafts of the one before it have closed
+    reads = {e["args"]["batch"]: e["ts"] for e in events
+             if e["name"] == "read"}
+    for k in (1, 2):
+        assert reads[k] >= max(e["ts"] + e["dur"] for e in prepares
+                               if e["args"]["batch"] == k - 1)
+    assert sorted((e["args"]["batch"], e["args"]["zmws"])
+                  for e in prepares) == [(b, 2) for b in (0, 0, 1, 1, 2, 2)]
+    (first_polish,) = [e for e in events if e["name"] == "polish"
+                       and e["args"]["batch"] == 0]
+    closed_before = [e["args"]["batch"] for e in prepares
+                     if e["ts"] + e["dur"] <= first_polish["ts"]]
+    assert closed_before == [0, 0]              # its own slices, no other
+    last_draft_closes = max(e["ts"] + e["dur"] for e in prepares
+                            if e["args"]["batch"] == 2)
+    assert first_polish["ts"] < last_draft_closes - 100_000
+
+
+def test_slices_out_of_order_keep_chunk_and_emission_order(monkeypatch):
+    """The first slice of every batch is the slow one, so a batch's slices
+    close out of order, and on two devices batch 0 polishes longest, so
+    the polishes close out of order too: yet each polish sees its ZMWs in
+    chunk order and the outcomes come out in submission order."""
+    batches = _stub_batches(3, 6)
+    slow = {b[0].id for b in batches}
+    polished: list[list[str]] = []
+    _stub_host_and_device(
+        monkeypatch, lambda chunk: 0.1 if chunk.id in slow else 0.005,
+        polished,
+        lambda preps: 0.5 if preps[0].chunk.id == batches[0][0].id else 0.01)
+    with make_pool(2) as pool:
+        pipe = ScheduledPipeline(pool, ConsensusSettings(),
+                                 prepare_workers=3)
+        emitted = list(pipe.run(
+            (i, b, None) for i, b in enumerate(batches)))
+    assert [idx for idx, _t in emitted] == [0, 1, 2]
+    assert all(t.counts[Failure.SUCCESS] == 6 for _idx, t in emitted)
+    assert polished == [[c.id for c in b] for b in batches]
+
+
+def test_a_chunk_whose_prepare_raises_tallies_other_alone(monkeypatch):
+    batches = _stub_batches(2, 4)
+    bad = batches[1][2].id
+    polished: list[list[str]] = []
+    _stub_host_and_device(
+        monkeypatch,
+        lambda chunk: ValueError("boom") if chunk.id == bad else 0.0,
+        polished)
+    with make_pool(1) as pool:
+        pipe = ScheduledPipeline(pool, ConsensusSettings(),
+                                 prepare_workers=2)
+        emitted = dict(pipe.run(
+            (i, b, None) for i, b in enumerate(batches)))
+    assert emitted[0].counts[Failure.SUCCESS] == 4
+    assert emitted[0].counts[Failure.OTHER] == 0
+    assert emitted[1].counts[Failure.SUCCESS] == 3
+    assert emitted[1].counts[Failure.OTHER] == 1
+    assert [c.id for c in batches[1] if c.id != bad] in polished
+
+
+def test_device_starved_seconds_count_an_empty_queue_after_first_submit():
+    scope = reg.scope()
+    with make_pool(1) as pool:
+        name = worker_name(pool, 0)
+        time.sleep(0.2)                          # before the first submit
+        pool.submit("k", lambda d: time.sleep(0.2)).result(10)
+        time.sleep(0.3)                          # starved
+        pool.submit("k", lambda d: None).result(10)
+    starved = scope.counter_value("ccs_sched_device_starved_seconds_total",
+                                  device=name)
+    assert 0.25 <= starved < 0.45
+
+
 # ------------------------------------------------------------- serve engine
 
 def _stub_prep(chunk, settings):
@@ -546,6 +684,59 @@ def test_warmup_runs_tiny_bucket(capsys):
 
 
 # ---------------------------------------------------------- CLI integration
+
+@pytest.fixture(scope="module")
+def one_device_cli_run(tmp_path_factory):
+    """Six small ZMWs through `ccs --devices 1` in chunks of three."""
+    from pbccs_tpu import cli
+    from pbccs_tpu.io.fasta import write_fasta
+    from tests.test_cli import make_zmw_records
+
+    tmp = tmp_path_factory.mktemp("one_driver")
+    rng = np.random.default_rng(20260926)
+    fasta = str(tmp / "subreads.fasta")
+    write_fasta(fasta, [rec for hole in range(1, 7) for rec in
+                        make_zmw_records(rng, "m", hole)[1]])
+
+    def run(tag, *flags):
+        out = str(tmp / f"{tag}.bam")
+        argv = [out, fasta, "--skipChemistryCheck", "--chunkSize", "3",
+                "--reportFile", out + ".csv", "--logLevel", "WARN", *flags]
+        assert cli.run(argv) == 0
+        return [open(out + ext, "rb").read() for ext in ("", ".pbi", ".csv")]
+
+    return fasta, run, run("dev1", "--devices", "1")
+
+
+@pytest.mark.parametrize("other", ["process_chunks", "devices2"])
+def test_cli_one_driver_output_matches(one_device_cli_run, other, tmp_path):
+    """The one driver at `--devices 1` writes what the library's one-call
+    form computes batch by batch, and the bytes it writes at two (forced
+    host) devices."""
+    from pbccs_tpu import cli
+    from pbccs_tpu.io.bam import BamReader
+    from pbccs_tpu.runtime.logging import Logger
+    from pbccs_tpu.runtime.whitelist import Whitelist
+
+    fasta, run, dev1 = one_device_cli_run
+    if other == "devices2":
+        assert run("dev2", "--devices", "2", "--prepareWorkers", "3") == dev1
+        return
+    args = cli.build_parser().parse_args(
+        [str(tmp_path / "unused.bam"), fasta, "--skipChemistryCheck",
+         "--chunkSize", "3"])
+    settings = cli.consensus_settings_from_args(args)
+    want = []
+    for batch in cli._chunks_from_files([fasta], Whitelist("all"), args,
+                                        Logger.default(), ResultTally()):
+        want += [(f"{r.id}/ccs", r.sequence, r.qualities)
+                 for r in process_chunks(batch, settings).results]
+    bam = tmp_path / "dev1.bam"
+    bam.write_bytes(dev1[0])
+    with BamReader(str(bam)) as br:
+        got = [(rec.name, rec.seq, rec.qual) for rec in br]
+    assert len(got) == 6 and got == want
+
 
 @pytest.mark.slow
 def test_cli_multi_device_output_byte_identical(tmp_path):

@@ -219,6 +219,51 @@ def test_turn_wait_covers_the_time_another_batch_holds_the_turn(
     assert waits[2]["args"]["cpu_ms"] < 50.0    # waiting is not work
 
 
+def test_scheduled_turn_wait_runs_from_submit_to_the_polish_opening(
+        tracer, monkeypatch):
+    """The scheduled driver, one device: the second batch is submitted
+    while the first polishes, and its `dispatch.turn_wait` starts at its
+    submit (where its last `prepare` closed), ends where its `polish`
+    opens, carries `zmws` and `batch`, and costs no CPU."""
+    from pbccs_tpu.sched import DevicePool
+    from pbccs_tpu.sched.executor import ScheduledPipeline
+
+    def stub_prepare(chunks, settings, **span_args):
+        with obs_trace.span("prepare", zmws=len(chunks), **span_args):
+            return pipeline.ResultTally(), list(chunks)
+
+    def stub_polish(preps, settings, **kw):
+        time.sleep(0.3)
+        return [(pipeline.Failure.OTHER, None) for _ in preps]
+
+    monkeypatch.setattr(pipeline, "prepare_batch", stub_prepare)
+    monkeypatch.setattr(pipeline, "polish_prepared_batch", stub_polish)
+    monkeypatch.setattr(pipeline, "_pinned_batch_shapes",
+                        lambda preps, buckets, min_z: ((8, 8, 4), 4))
+    monkeypatch.setattr(pipeline, "prebake_polish", lambda preps: None)
+    with DevicePool(jax.devices()[:1]) as pool:
+        pipe = ScheduledPipeline(pool, pipeline.ConsensusSettings(),
+                                 prepare_workers=2)
+        emitted = list(pipe.run([(0, ["a"], None), (1, ["b", "c"], None)]))
+    assert [idx for idx, _tally in emitted] == [0, 1]
+
+    by_name = events_by_name(tracer.to_chrome())
+    waits = {e["args"]["batch"]: e for e in by_name["dispatch.turn_wait"]}
+    polishes = {e["args"]["batch"]: e for e in by_name["polish"]}
+    prepares = {e["args"]["batch"]: e for e in by_name["prepare"]}
+    assert {b: e["args"]["zmws"] for b, e in waits.items()} == {0: 1, 1: 2}
+    assert waits[0]["dur"] < 100_000            # the device was free
+    for b in (0, 1):
+        wait_end = waits[b]["ts"] + waits[b]["dur"]
+        prepared = prepares[b]["ts"] + prepares[b]["dur"]
+        assert 0 <= waits[b]["ts"] - prepared < 100_000   # from the submit
+        assert abs(polishes[b]["ts"] - wait_end) < 20_000  # to the polish
+        assert waits[b]["args"]["cpu_ms"] == 0.0
+    assert waits[1]["dur"] > 150_000            # it waited the holder out
+    assert (waits[1]["ts"] + waits[1]["dur"]
+            >= polishes[0]["ts"] + polishes[0]["dur"] - 20_000)
+
+
 # --------------------------------------------------------------- the batch CLI
 
 
@@ -245,9 +290,9 @@ def write_subread_bam(rng, path: str, holes) -> None:
 def test_trace_out_of_a_batch_cli_run_has_the_spans_of_its_path(
         rng, tmp_path):
     """`ccs OUT IN --trace-out F` on four simulated ZMWs in two batches:
-    every span the WorkQueue driver's path reaches is in the file, one
-    batch's prepare, turn wait and polish hang under its `batch` span, and
-    polish's five parts hang under `polish`."""
+    every span the scheduled driver's path reaches is in the file, one
+    batch's read, prepare slices, turn wait and polish carry its `batch`
+    index, and polish's five parts hang under `polish`."""
     from pbccs_tpu.cli import run
 
     in_bam = str(tmp_path / "subreads.bam")
@@ -264,7 +309,7 @@ def test_trace_out_of_a_batch_cli_run_has_the_spans_of_its_path(
     by_name = events_by_name(chrome)
     by_id = {e["id"]: e for e in chrome["traceEvents"]}
 
-    reached = {"run", "read", "batch", "prepare", "filter", "draft",
+    reached = {"run", "read", "prepare", "filter", "draft",
                "draft.poa", "draft.map", "dispatch.turn_wait", "polish",
                "polish.setup", "polish.gates", "polish.refine", "polish.qv",
                "polish.finish", "emit"}
@@ -274,21 +319,28 @@ def test_trace_out_of_a_batch_cli_run_has_the_spans_of_its_path(
     assert run_ev["args"]["zmws"] == 4 and run_ev["args"]["threads"] == 2
     assert run_ev["args"]["chunk_size"] == 2
     assert run_ev["args"]["devices"] == 1 and run_ev["args"]["cpus"] >= 1
-    # two batches and the end of the input: three reads, on `run`'s thread
-    assert sorted(e["args"]["zmws"] for e in by_name["read"]) == [0, 2, 2]
-    assert {e["args"]["parent"] for e in by_name["read"]} == {run_ev["id"]}
+    # two batches and the end of the input: three reads, on the feeder's
+    # thread (no parent), the end of the input with no batch to name
+    assert sorted((e["args"]["zmws"], e["args"].get("batch"))
+                  for e in by_name["read"]) == [(0, None), (2, 0), (2, 1)]
+    assert not any("parent" in e["args"] for e in by_name["read"])
+    assert "batch" not in by_name               # `batch=` ties, no span
 
     def children(ev):
         return [e for e in chrome["traceEvents"]
                 if e["args"].get("parent") == ev["id"]]
 
-    batches = by_name["batch"]
-    assert sorted(e["args"]["batch"] for e in batches) == [0, 1]
-    for batch in batches:
-        assert batch["args"]["zmws"] == 2
-        kids = [e["name"] for e in children(batch)]
-        assert kids == ["prepare", "dispatch.turn_wait", "polish"]
-        (polish,) = [e for e in children(batch) if e["name"] == "polish"]
+    for idx in (0, 1):
+        # two prepare workers: a batch of two is dealt as two slices
+        slices = [e for e in by_name["prepare"] if e["args"]["batch"] == idx]
+        assert [e["args"]["zmws"] for e in slices] == [1, 1]
+        (wait,) = [e for e in by_name["dispatch.turn_wait"]
+                   if e["args"]["batch"] == idx]
+        (polish,) = [e for e in by_name["polish"]
+                     if e["args"]["batch"] == idx]
+        assert wait["args"]["zmws"] == polish["args"]["zmws"] == 2
+        assert wait["ts"] >= max(e["ts"] + e["dur"] for e in slices)
+        assert abs(polish["ts"] - (wait["ts"] + wait["dur"])) < 20_000
         assert [e["name"] for e in children(polish)] == [
             "polish.setup", "polish.gates", "polish.refine", "polish.qv",
             "polish.finish"]
@@ -317,25 +369,31 @@ def test_trace_cover_reads_coverage_and_pairs_annotations_by_duration():
     cover = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(cover)
 
-    def ev(i, name, ts_s, dur_s, parent=None):
+    def ev(i, name, ts_s, dur_s, parent=None, batch=None):
         args = {} if parent is None else {"parent": parent}
+        if batch is not None:
+            args["batch"] = batch
         return {"id": i, "name": name, "ts": ts_s * 1e6, "dur": dur_s * 1e6,
                 "args": args}
 
-    events = [ev(0, "batch", 0.0, 10.0),
-              ev(1, "prepare", 0.0, 4.0, 0),
-              ev(2, "dispatch.turn_wait", 4.0, 3.0, 0),
-              ev(3, "polish", 7.0, 2.5, 0),
-              ev(4, "polish.setup", 7.0, 1.0, 3),
-              ev(5, "polish.refine", 8.0, 1.0, 3),
-              ev(6, "polish.round", 8.0, 1.0, 5),      # not polish's child
-              ev(7, "polish", 20.0, 2.0)]              # a fleet's: no parts
-    assert cover.coverage(events, "batch", cover.BATCH_PARTS) == [0.95]
+    # batch 0: two overlapping prepare slices, half a second of joining
+    # before the submit, the wait, the polish; batch 1 never polished
+    events = [ev(0, "read", 0.0, 0.1, batch=0),        # not a batch part
+              ev(1, "prepare", 0.0, 4.0, batch=0),
+              ev(8, "prepare", 1.0, 2.5, batch=0),
+              ev(2, "dispatch.turn_wait", 4.5, 3.0, batch=0),
+              ev(3, "polish", 7.5, 2.5, batch=0),
+              ev(4, "polish.setup", 7.5, 1.0, 3),
+              ev(5, "polish.refine", 8.5, 1.0, 3),
+              ev(6, "polish.round", 8.5, 1.0, 5),      # not polish's child
+              ev(9, "prepare", 3.0, 4.0, batch=1),
+              ev(7, "polish", 20.0, 2.0)]              # no batch, no parts
+    assert cover.batch_coverage(events) == [0.95]
     assert cover.coverage(events, "polish", cover.POLISH_PARTS) == [0.8, 0.0]
     # two polish annotations: each span pairs with the one of its length;
     # `prepare` began before the capture and is not paired with the
     # `prepare` of a later batch
-    notes = [("polish", 1000.0 + 7.00002, 2.49999),
+    notes = [("polish", 1000.0 + 7.50002, 2.49999),
              ("polish", 1000.0 + 20.00004, 1.99999),
              ("prepare", 1000.0 + 0.9, 4.0)]
     skews = cover.annotation_skews(events, 1000.0, notes)

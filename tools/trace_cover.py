@@ -3,13 +3,17 @@
 
     python tools/trace_cover.py SPANS.json [SPANS.json ...] [--xplane F.xplane.pb]
 
-For every `batch` span: the share of it that `prepare`, `dispatch.turn_wait`
-and `polish` cover; for every `polish` span: the share `polish.setup`,
-`polish.gates`, `polish.refine`, `polish.qv` and `polish.finish` cover (the
-children are sequential on one thread, so a share is a sum).  The least
-covered and the median of each are printed; what runs in the rest has no
-span (in `polish`: the wide-band retry between `polish.refine` and
-`polish.qv`, where a batch had mating failures).
+For every batch (the spans that carry one `batch=` index: its `prepare`
+slices on the prepare pool's threads, its `dispatch.turn_wait`, its `polish`
+on the device's thread): the share of the time from its first `prepare`
+opening to its `polish` closing that the union of those spans covers; for
+every `polish` span: the share `polish.setup`, `polish.gates`,
+`polish.refine`, `polish.qv` and `polish.finish` cover (the children are
+sequential on one thread, so a share is a sum).  The least covered and the
+median of each are printed; what runs in the rest has no span (in a batch:
+joining the slices, the pinned shapes, the budget gate and the prebake
+between the last `prepare` and the submit; in `polish`: the wide-band retry
+between `polish.refine` and `polish.qv`, where a batch had mating failures).
 
 With `--xplane` (a jax.profiler capture taken while the same run was traced:
 `--profile-dir`, or the benchmark's `--trace 1`): the skew between each
@@ -43,6 +47,32 @@ def coverage(events: list[dict], parent: str, parts: tuple) -> list[float]:
             covered[up] += e["dur"]
     return [covered[e["id"]] / e["dur"] for e in events
             if e["name"] == parent and e["dur"] > 0]
+
+
+def batch_coverage(events: list[dict]) -> list[float]:
+    """For each batch that reached `polish`: the union of its `BATCH_PARTS`
+    spans (tied by `args.batch`) over the time from the first one's start
+    to the last one's end."""
+    by_batch: dict[int, list[tuple[float, float]]] = {}
+    polished = set()
+    for e in events:
+        idx = e["args"].get("batch")
+        if idx is None or e["name"] not in BATCH_PARTS:
+            continue
+        by_batch.setdefault(idx, []).append((e["ts"], e["ts"] + e["dur"]))
+        if e["name"] == "polish":
+            polished.add(idx)
+    shares = []
+    for idx in sorted(polished):
+        spans = sorted(by_batch[idx])
+        begin = reach = spans[0][0]
+        covered = 0.0
+        for a, b in spans:
+            if b > reach:
+                covered, reach = covered + b - max(a, reach), b
+        if reach > begin:
+            shares.append(covered / (reach - begin))
+    return shares
 
 
 def annotation_skews(events: list[dict], origin_unix: float, notes: list
@@ -106,10 +136,12 @@ def main(argv=None) -> int:
         with open(path) as f:
             doc = json.load(f)
         events = doc["traceEvents"]
-        for parent, parts in (("batch", BATCH_PARTS), ("polish", POLISH_PARTS)):
-            shares = coverage(events, parent, parts)
+        for what, parts, shares in (
+                ("batches", BATCH_PARTS, batch_coverage(events)),
+                ("polish spans", POLISH_PARTS,
+                 coverage(events, "polish", POLISH_PARTS))):
             if shares:
-                print(f"{path}: {len(shares)} {parent} spans, "
+                print(f"{path}: {len(shares)} {what}, "
                       f"{' + '.join(parts)} cover {min(shares):.4f} of the "
                       f"least covered, {statistics.median(shares):.4f} of the median")
         skews += annotation_skews(events, doc["meta"]["origin_unix"], notes)
