@@ -653,9 +653,10 @@ def _run_pipeline(args, files, whitelist, settings, log) -> ResultTally:
     # The scheduled driver (pbccs_tpu/sched), at every device count: host
     # prepare workers draft ahead of the device polishes in flight, ZMW by
     # ZMW in reading order, and batches fan out across the pool with
-    # sticky bucket routing.  Batch composition and shape derivation are
-    # those of pipeline.process_chunks (same --chunkSize groups, same
-    # effective_shapes), so the output is byte-identical at any count.
+    # sticky bucket routing.  Batch composition is that of
+    # pipeline.process_chunks (same --chunkSize groups) and a batch's
+    # shapes are its own bucket's or a padded neighbour's (the shape
+    # menu), so the output is byte-identical at any count.
     from pbccs_tpu.sched import DevicePool, DevicePoolConfig, select_devices
     from pbccs_tpu.sched.executor import ScheduledPipeline
 

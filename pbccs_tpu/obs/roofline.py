@@ -12,8 +12,13 @@ a continuously measured, regression-defended quantity:
     ``memory_analysis()``): flops, bytes accessed, peak HBM, arithmetic
     intensity.  Extraction lowers the SAME canonical program the bucket
     runs (parallel/batch._batch_setup at the polisher's exact
-    shapes/statics), so with the persistent compilation cache enabled
-    the AOT compile is a disk hit, not a second compile.  Cards are
+    shapes/statics).  It is a second tracing, lowering and compile of
+    that program (the compile a disk hit where the persistent cache
+    holds it), so it runs where a card is asked for -- `ccs warmup`
+    mints one for each bucket of its menu -- and never on the polish
+    path: a server charges against the cards an earlier `ccs warmup`
+    left in the store (it loads them as it starts), a batch run against
+    none.  Cards are
     cached beside the compile cache (roofline_cards.json, or
     PBCCS_ROOFLINE_CARDS=PATH) with no timestamps, so the file is
     byte-deterministic for a given jax build -- the property

@@ -24,6 +24,7 @@ from __future__ import annotations
 import os
 import dataclasses
 import functools
+import threading
 from typing import Sequence
 
 import jax
@@ -86,6 +87,15 @@ _m_zmw_fill = _reg.histogram("ccs_batch_fill_ratio",
                              buckets=_FILL_BUCKETS, axis="zmw")
 _m_read_fill = _reg.histogram("ccs_batch_fill_ratio",
                               buckets=_FILL_BUCKETS, axis="read")
+# every shape set is a family of programs to trace, lower and load
+# (minutes at 2 kb, whatever the cache holds): the count of them is what
+# a run's set-up costs, and it moving late in a run is a stall
+_m_shape_sets = _reg.counter(
+    "ccs_polish_shape_sets_total",
+    "Polish shape sets (Imax, Jmax, R, Z at one band width) this process "
+    "built a BatchPolisher at for the first time")
+_shape_sets_seen: set[tuple] = set()
+_shape_sets_lock = threading.Lock()
 
 # mutation-axis chunk: every scoring call uses this static M so one compiled
 # program serves every refinement round and the QV sweep
@@ -103,8 +113,19 @@ def _jmax_bucket(max_len: int) -> int:
     flat +16 -- net insertions during refinement scale with template
     length, and a 15 kb polish whose templates outgrew a +16 bucket
     overflow-bailed the device-resident loop every round (straight into
-    the host loop's per-round fetches + length-scaled chunk programs)."""
-    return pad_to(max_len + max(16, max_len // 32), 64)
+    the host loop's per-round fetches + length-scaled chunk programs).
+    Past 2 kb the granularity scales with length too (about the headroom
+    itself, power-of-two steps, floor 64): the longest draft of a batch
+    moves by a per cent or two from batch to batch (2,169-2,224 over 96
+    batches of one 2 kb x 3-10 pass library), and 64-column steps put one
+    batch in ten of such a file in a bucket of its own, a second family
+    of programs."""
+    need = max_len + max(16, max_len // 32)
+    return pad_to(need, _jmax_step(need))
+
+
+def _jmax_step(n: int) -> int:
+    return max(64, 1 << max(n - 1, 1).bit_length() - 5)
 
 
 def _imax_bucket(raw_imax: int) -> int:
@@ -113,8 +134,11 @@ def _imax_bucket(raw_imax: int) -> int:
     lengths that differ by hundreds of bases run to run, and a fixed
     64-step bucket minted a fresh executable set per draw -- a ~90 s
     recompile inside every timed 15 kb repeat."""
-    step = max(64, 1 << max(raw_imax - 1, 1).bit_length() - 3)
-    return pad_to(raw_imax, step)
+    return pad_to(raw_imax, _imax_step(raw_imax))
+
+
+def _imax_step(n: int) -> int:
+    return max(64, 1 << max(n - 1, 1).bit_length() - 3)
 
 
 def length_bucket(tpl_len: int, max_read_len: int) -> tuple[int, int]:
@@ -154,6 +178,69 @@ def effective_shapes(n_zmws: int, max_reads: int, max_read_len: int,
         else:
             Jmax = max(Jmax, buckets[1])
     return Imax, Jmax, R, Z
+
+
+def _length_class_statics(jmax: int) -> tuple:
+    """What a Jmax bucket decides beyond padding: the band width and the
+    guided refill passes.  Two buckets that agree here polish a ZMW to
+    the same bytes (padding changes no arithmetic); two that differ do
+    not.  (The dense scoring route has a Jmax ceiling too, 65,536:
+    no bucket of this repo's configurations comes near it.)"""
+    return (effective_band_width(ArrowConfig().banding, jmax),
+            guided_fill_passes(jmax))
+
+
+class ShapeMenu:
+    """The (Imax, Jmax, R) a process polishes at, one pin for each length
+    class it has met, so that the programs a file needs are a closed set.
+
+    Left to itself every batch picks its own bucket, and a file's batches
+    straddle bucket edges (the longest read, the longest draft and the
+    most passes of 64 ZMWs move from batch to batch): each new bucket is a
+    family of programs, traced, lowered and loaded when it first appears,
+    minutes into a run.  The scheduled driver asks here instead: a batch
+    adopts the pin of its class (element-wise at least its own bucket;
+    the pin grows if a batch does not fit), which is what the quarantine
+    and split paths already do with a parent's buckets.  A class is a
+    neighbourhood, not the whole menu: a pin and a bucket at most one
+    step of their grids apart in Imax and in Jmax and a factor two in R,
+    with the same band width and guided passes, so the bytes are those of
+    the batch's own bucket and a 500 bp batch never pads to a 15 kb
+    neighbour.  The pins live as long as the process, as its loaded
+    programs do."""
+
+    def __init__(self):
+        self._pins: list[tuple[int, int, int]] = []
+        self._lock = threading.Lock()
+
+    @staticmethod
+    def _same_class(pin, own) -> bool:
+        return (abs(pin[0] - own[0]) <= _imax_step(max(pin[0], own[0]))
+                and abs(pin[1] - own[1]) <= _jmax_step(max(pin[1], own[1]))
+                and 2 * min(pin[2], own[2]) >= max(pin[2], own[2])
+                and _length_class_statics(pin[1])
+                == _length_class_statics(own[1]))
+
+    def shapes(self, n_zmws: int, max_reads: int, max_read_len: int,
+               max_tpl_len: int) -> tuple[int, int, int, int]:
+        """effective_shapes of these inputs under their class's pin."""
+        own = effective_shapes(n_zmws, max_reads, max_read_len, max_tpl_len)
+        with self._lock:
+            for k, pin in enumerate(self._pins):
+                if self._same_class(pin, own[:3]):
+                    got = effective_shapes(n_zmws, max_reads, max_read_len,
+                                           max_tpl_len, buckets=pin)
+                    self._pins[k] = got[:3]
+                    return got
+            self._pins.append(own[:3])
+            return own
+
+    def reset_for_tests(self) -> None:
+        with self._lock:
+            self._pins.clear()
+
+
+shape_menu = ShapeMenu()
 
 
 @dataclasses.dataclass
@@ -651,18 +738,14 @@ class BatchPolisher:
         # flight-recorder batch tag: first ZMW id + batch size names the
         # batch compactly in postmortem dumps
         self._flight_tag = f"{self.ids[0]}+{self.n_zmws}"
-        # roofline CostCard: one AOT extraction per shape bucket per
-        # process (memoized + disk-cached), BEFORE the first _setup so
-        # its execution charge finds the card -- a process whose only
-        # polisher is the bucket's first would otherwise never charge.
-        # The AOT compile warms the persistent cache for the jit call
-        # below (same program, same statics).  Mesh runs skip it -- the
-        # canonical card program is the mesh=None lowering.
-        if self.mesh is None:
-            obs_roofline.note_bucket(
-                imax=self._Imax, jmax=self._Jmax, r=self._R, z=self._Z,
-                width=self._W, use_pallas=fills_use_pallas(),
-                guided_passes=guided_fill_passes(self._Jmax))
+        # the first polisher of a shape set loads its family of programs
+        # (and then, warm_shape_set, what its later batches meet by chance)
+        key = (self._Imax, self._Jmax, self._R, self._Z, self._W)
+        with _shape_sets_lock:
+            self.first_of_shape_set = key not in _shape_sets_seen
+            _shape_sets_seen.add(key)
+        if self.first_of_shape_set:
+            _m_shape_sets.inc()
         self._setup(first=True)
 
     # --------------------------------------------------- AddRead statistics
@@ -1409,24 +1492,47 @@ class BatchPolisher:
         pow2 size so its compiled shapes are draw-independent)."""
         return next_pow2(max(self._Z // 32, 1), 4)
 
-    def _straggler_sub(self, zmws: Sequence[int]) -> "BatchPolisher":
-        """Construct the canonical straggler-continuation sub-batch for
-        the given parent rows — ONE shape recipe shared by the live
-        continuation (refine_device) and warm_straggler_shapes, so the
-        pre-warm compiles exactly the executables the continuation uses."""
-        sub_tasks = []
+    def _row_tasks(self, zmws: Sequence[int], tag: str) -> list[ZmwTask]:
+        """The given parent rows as tasks of their own: current
+        template, real reads, current windows."""
+        tasks = []
         for z in zmws:
             rows = np.nonzero(self._real_rows[z])[0]
-            sub_tasks.append(ZmwTask(
-                f"straggler/{z}", self.tpls[z].copy(), self._snrs[z],
+            tasks.append(ZmwTask(
+                f"{tag}/{z}", self.tpls[z].copy(), self._snrs[z],
                 [self._reads[z, r, : self._rlens[z, r]].copy()
                  for r in rows],
                 [int(self._strands[z, r]) for r in rows],
                 [int(self._tstarts[z, r]) for r in rows],
                 [int(self._tends[z, r]) for r in rows]))
-        return BatchPolisher(sub_tasks, config=self.config,
+        return tasks
+
+    def _straggler_sub(self, zmws: Sequence[int]) -> "BatchPolisher":
+        """Construct the canonical straggler-continuation sub-batch for
+        the given parent rows — ONE shape recipe shared by the live
+        continuation (refine_device) and warm_straggler_shapes, so the
+        pre-warm compiles exactly the executables the continuation uses."""
+        return BatchPolisher(self._row_tasks(zmws, "straggler"),
+                             config=self.config,
                              buckets=(self._Imax, self._Jmax, self._R),
                              min_z=self.straggler_shape_min_z())
+
+    def wide_band_sub(self, tasks: Sequence[ZmwTask]) -> "BatchPolisher":
+        """The 2x-band sub-batch of the pipeline's mating retry -- ONE
+        shape recipe shared by the live retry (pipeline.
+        _polish_batch_arrow) and warm_shape_set.  Shapes pin to the
+        parent's buckets + a pow2 Z so the data-dependent reband count
+        doesn't mint fresh compiles; 2x the EFFECTIVE width (the W(L)
+        schedule may have shrunk the parent below the configured width);
+        a non-default width passes through the schedule."""
+        wcfg = dataclasses.replace(
+            self.config,
+            banding=dataclasses.replace(self.config.banding,
+                                        band_width=2 * self._W))
+        return BatchPolisher(tasks, config=wcfg,
+                             min_zscore=self.min_zscore,
+                             buckets=(self._Imax, self._Jmax, self._R),
+                             min_z=next_pow2(len(tasks), 4))
 
     def warm_straggler_shapes(self, opts: RefineOptions | None = None
                               ) -> None:
@@ -1434,14 +1540,40 @@ class BatchPolisher:
 
         Whether a batch produces stragglers is data-dependent; their first
         appearance used to cold-compile a ~minute-long device loop inside
-        a timed run (the round-3 53x tail-latency outlier).  `opts` must
-        match the opts later passed to refine() -- max_iterations is part
-        of the executable cache key."""
+        a timed run (the round-3 53x tail-latency outlier), and with every
+        executable cached still traces and lowers one (85-100 s at 2 kb).
+        `opts` must match the opts later passed to refine() --
+        max_iterations is part of the executable cache key."""
         if self._Z // 32 < 1 or self.n_zmws < 1:
             return  # this Z has no straggler early exit
         sub = self._straggler_sub([0])
         sub.refine(opts)
         sub.consensus_qvs()
+
+    def warm_shape_set(self, opts: RefineOptions | None = None) -> None:
+        """Load what a shape set's first polish leaves to chance: the
+        straggler continuation's programs and the wide-band retry's
+        (which batch first leaves a ZMW behind, first fails a mating, or
+        first has a ZMW adopt the wide band, is data; each then stops
+        the run to trace, lower and load: 85-100 s, 4-14 s and about
+        90 s at 2 kb).  The one recipe of `ccs warmup`
+        (sched/warmup.py) and of the batch path, which calls it from
+        the first polish of each shape set
+        (pipeline._polish_batch_arrow).  Only for a Z with a straggler
+        exit, a batch driver's: the small flushes of `ccs serve` keep
+        their cold path.  Not covered: a wide-band sub-batch of more
+        than four ZMWs."""
+        if self._Z // 32 < 1:
+            return
+        with obs_trace.span("polish.warm", imax=self._Imax,
+                            jmax=self._Jmax, r=self._R,
+                            z=self.straggler_shape_min_z()):
+            self.warm_straggler_shapes(opts)
+            # built, gated and polished as the live retry does it
+            wide = self.wide_band_sub(self._row_tasks([0], "warm"))
+            wide.statuses
+            wide.refine(opts)
+            wide.consensus_qvs()
 
     def refine(self, opts: RefineOptions | None = None,
                skip=None, budget: int | None = None) -> list[RefineResult]:

@@ -551,26 +551,8 @@ def _polish_batch_arrow(preps: Sequence[PreparedZmw],
         wide = None
         wide_pick: dict[int, int] = {}
         if reband:
-            wcfg = dataclasses.replace(
-                polisher.config,
-                banding=dataclasses.replace(
-                    polisher.config.banding,
-                    # 2x the EFFECTIVE width (the W(L) schedule may have
-                    # shrunk the narrow batch below the configured width);
-                    # a non-default width passes through the schedule
-                    band_width=2 * polisher._W))
             try:  # speculative build: any failure keeps the narrow batch
-                from pbccs_tpu.utils import next_pow2
-
-                # pin shapes to the narrow batch's buckets + pow2 Z so the
-                # data-dependent reband count doesn't mint fresh compiles
-                wide = BatchPolisher([tasks[z] for z in reband],
-                                     config=wcfg,
-                                     min_zscore=settings.min_zscore,
-                                     buckets=(polisher._Imax,
-                                              polisher._Jmax,
-                                              polisher._R),
-                                     min_z=next_pow2(len(reband), 4))
+                wide = polisher.wide_band_sub([tasks[z] for z in reband])
             except Exception as e:  # noqa: BLE001 -- keep the narrow batch
                 record_zmw_failure("polish.wide_build", e,
                                    zmw=f"reband[{len(reband)}]")
@@ -670,7 +652,38 @@ def _polish_batch_arrow(preps: Sequence[PreparedZmw],
                     global_zs[z], status_counts, n_passes,
                     p.prep_ms + polish_ms)
             outcomes.append((failure, result))
+    if polisher.first_of_shape_set:
+        # the continuation's and the wide retry's programs belong to the
+        # batch that brings the shape set: whether THIS batch left a
+        # straggler or failed a mating is chance, and a later one that
+        # does would stop the run to trace and load them
+        try:
+            polisher.warm_shape_set(settings.refine)
+        except Exception as e:  # noqa: BLE001 -- the batch's results stand
+            record_zmw_failure("polish.warm", e, zmw=f"batch[{len(preps)}]")
     return outcomes
+
+
+def _batch_extents(preps: Sequence[PreparedZmw]) -> tuple[int, int, int, int]:
+    """(ZMWs, most reads, longest read, longest draft) of a prepared
+    batch: what its bucket is derived from."""
+    return (len(preps),
+            max(len(p.mapped) for p in preps),
+            max((len(m.seq) for p in preps for m in p.mapped), default=8),
+            max(len(p.css) for p in preps))
+
+
+def menu_batch_shapes(preps: Sequence[PreparedZmw]
+                      ) -> tuple[tuple[int, int, int], int]:
+    """The (Imax, Jmax, R)/Z a batch of the scheduled driver polishes at:
+    its length class's pin in the process's shape menu
+    (parallel.batch.ShapeMenu), so a file's batches share one family of
+    programs.  Pass the pin on as `buckets` to prebake_polish and
+    polish_prepared_batch."""
+    from pbccs_tpu.parallel.batch import shape_menu
+
+    imax, jmax, r, z = shape_menu.shapes(*_batch_extents(preps))
+    return (imax, jmax, r), z
 
 
 def _pinned_batch_shapes(preps: Sequence[PreparedZmw],
@@ -687,12 +700,8 @@ def _pinned_batch_shapes(preps: Sequence[PreparedZmw],
     axis sizes threaded through here."""
     from pbccs_tpu.parallel.batch import effective_shapes
 
-    imax, jmax, r, z = effective_shapes(
-        len(preps),
-        max(len(p.mapped) for p in preps),
-        max((len(m.seq) for p in preps for m in p.mapped), default=8),
-        max(len(p.css) for p in preps),
-        buckets=buckets, min_z=min_z)
+    imax, jmax, r, z = effective_shapes(*_batch_extents(preps),
+                                        buckets=buckets, min_z=min_z)
     return (imax, jmax, r), z
 
 

@@ -22,11 +22,14 @@ owns each device:
                to the single-threaded driver)
 
 Batch composition is untouched -- the same --chunkSize groups, prepared
-(pipeline.prepare_batch on each slice, joined in chunk order) and
-polished with the same shape derivation as pipeline.process_chunks --
-so a run's output is byte-identical at every device and worker count
-(same bucket shapes => same compiled programs => same arithmetic),
-merely reordered in time.
+(pipeline.prepare_batch on each slice, joined in chunk order) -- and a
+batch polishes at its length class's pin in the process's shape menu
+(parallel.batch.ShapeMenu: the bucket pipeline.process_chunks would pick
+for it, or a neighbour across one bucket edge that an earlier batch
+brought, with the same band width, so padding alone differs), so a
+file's batches share one family of programs and a run's output is
+byte-identical at every device and worker count, merely reordered in
+time.
 """
 
 from __future__ import annotations
@@ -204,8 +207,10 @@ class ScheduledPipeline:
                 if not preps:
                     finish(seq, (idx, tally))
                     return
-                (imax, jmax, r), z = pipeline._pinned_batch_shapes(
-                    preps, None, 1)
+                # the class's pin, not the batch's own bucket: one
+                # family of programs a file, loaded with its first batch
+                pin, z = pipeline.menu_batch_shapes(preps)
+                imax, jmax, r = pin
                 key = (jmax, imax, r, z)
                 # host-budget gate (--memBudget): charge this batch's
                 # marshalled-bytes estimate BEFORE building the prebake;
@@ -239,7 +244,8 @@ class ScheduledPipeline:
                 if self.settings.model != "quiver" and (
                         cap is None or len(preps) <= cap):
                     try:
-                        prebaked = pipeline.prebake_polish(preps)
+                        prebaked = pipeline.prebake_polish(preps,
+                                                           buckets=pin)
                     except Exception as e:  # noqa: BLE001 -- inline fallback
                         pipeline.record_zmw_failure(
                             "prepare.prebake", e,
@@ -281,7 +287,8 @@ class ScheduledPipeline:
                         with obs_trace.span("polish", zmws=len(preps),
                                             batch=idx):
                             return pipeline.polish_prepared_batch(
-                                preps, settings, on_error=on_error,
+                                preps, settings, buckets=pin,
+                                on_error=on_error,
                                 raise_device_shaped=fleet
                                 and attempts[0] == 1,
                                 prebaked=prebaked)

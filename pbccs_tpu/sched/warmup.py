@@ -7,8 +7,9 @@ compiled-shape bucket by workload geometry -- Z ZMWs per batch, PASSES
 subreads per ZMW, LEN-base templates -- and warmup drives one synthetic
 batch of exactly that geometry through the full polish surface
 (BatchPolisher setup + refine + QV sweep + the straggler-continuation
-shapes), populating the in-process executable cache and the persistent
-compilation cache (runtime/cache.py) that later processes load from.
+and wide-band-retry shapes: BatchPolisher.warm_shape_set), populating
+the in-process executable cache and the persistent compilation cache
+(runtime/cache.py) that later processes load from.
 
 By default each bucket warms on ONE device (the persistent cache serves
 the other devices' compiles as disk hits); `--allDevices` compiles on
@@ -92,13 +93,23 @@ def _warm_one(tasks) -> dict:
     """Full polish surface at this bucket's shapes; returns the effective
     compiled shapes (what a matching production batch will reuse)."""
     from pbccs_tpu.models.arrow.refine import RefineOptions
+    from pbccs_tpu.models.arrow.scorer import (fills_use_pallas,
+                                               guided_fill_passes)
+    from pbccs_tpu.obs import roofline
     from pbccs_tpu.parallel.batch import BatchPolisher
 
     opts = RefineOptions()
     polisher = BatchPolisher(tasks)
+    # the bucket's roofline CostCard is minted here, where it is asked
+    # for, before the first refine so that its charges find it: a second
+    # lowering and AOT compile of the set-up program that no batch run pays
+    roofline.note_bucket(
+        imax=polisher._Imax, jmax=polisher._Jmax, r=polisher._R,
+        z=polisher._Z, width=polisher._W, use_pallas=fills_use_pallas(),
+        guided_passes=guided_fill_passes(polisher._Jmax))
     polisher.refine(opts)
     polisher.consensus_qvs()
-    polisher.warm_straggler_shapes(opts)
+    polisher.warm_shape_set(opts)
     return {"Z": polisher._Z, "R": polisher._R,
             "Jmax": polisher._Jmax, "Imax": polisher._Imax,
             "W": polisher._W}
@@ -198,9 +209,9 @@ def run_warmup(argv: list[str] | None = None) -> int:
                      "seconds": round(dt, 2), "shapes": shapes}
             if len(sub) < len(tasks):
                 entry["governor_clamped_z"] = len(sub)
-            # the polish above minted (and persisted) this bucket's
-            # roofline CostCard; surface it so warmup output doubles as
-            # the bound report for the menu
+            # _warm_one minted (and persisted) this bucket's roofline
+            # CostCard; surface it so warmup output doubles as the bound
+            # report for the menu
             card = roofline.tracker().card(
                 roofline.bucket_label(imax, jmax, r))
             if card is not None:

@@ -1007,7 +1007,7 @@ class TestBudgetedPipeline:
         monkeypatch.setattr(pipeline, "polish_prepared_batch",
                             stub_polish)
         monkeypatch.setattr(pipeline, "prebake_polish",
-                            lambda preps: None)
+                            lambda preps, **kw: None)
         # budget fits ONE batch's estimate (the deadlock-shaped config)
         from pbccs_tpu.parallel.batch import premarshal_nbytes
 

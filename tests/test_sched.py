@@ -412,8 +412,8 @@ def test_executor_first_attempt_device_failure_reaches_pool(monkeypatch):
 
     monkeypatch.setattr(pl, "prepare_batch", stub_prepare)
     monkeypatch.setattr(pl, "polish_prepared_batch", fake_polish)
-    monkeypatch.setattr(pl, "_pinned_batch_shapes",
-                        lambda preps, buckets, min_z: ((8, 8, 4), 4))
+    monkeypatch.setattr(pl, "menu_batch_shapes",
+                        lambda preps: ((8, 8, 4), 4))
 
     scope = reg.scope()
     with make_pool(3) as pool:
@@ -451,9 +451,9 @@ def _stub_host_and_device(monkeypatch, prep_seconds, polished=None,
 
     monkeypatch.setattr(pl, "prepare_chunk", stub_prepare_chunk)
     monkeypatch.setattr(pl, "polish_prepared_batch", stub_polish)
-    monkeypatch.setattr(pl, "_pinned_batch_shapes",
-                        lambda preps, buckets, min_z: ((8, 8, 4), 4))
-    monkeypatch.setattr(pl, "prebake_polish", lambda preps: None)
+    monkeypatch.setattr(pl, "menu_batch_shapes",
+                        lambda preps: ((8, 8, 4), 4))
+    monkeypatch.setattr(pl, "prebake_polish", lambda preps, **kw: None)
 
 
 def _stub_batches(n_batches, size):

@@ -238,9 +238,9 @@ def test_scheduled_turn_wait_runs_from_submit_to_the_polish_opening(
 
     monkeypatch.setattr(pipeline, "prepare_batch", stub_prepare)
     monkeypatch.setattr(pipeline, "polish_prepared_batch", stub_polish)
-    monkeypatch.setattr(pipeline, "_pinned_batch_shapes",
-                        lambda preps, buckets, min_z: ((8, 8, 4), 4))
-    monkeypatch.setattr(pipeline, "prebake_polish", lambda preps: None)
+    monkeypatch.setattr(pipeline, "menu_batch_shapes",
+                        lambda preps: ((8, 8, 4), 4))
+    monkeypatch.setattr(pipeline, "prebake_polish", lambda preps, **kw: None)
     with DevicePool(jax.devices()[:1]) as pool:
         pipe = ScheduledPipeline(pool, pipeline.ConsensusSettings(),
                                  prepare_workers=2)

@@ -8,8 +8,9 @@ slices on the prepare pool's threads, its `dispatch.turn_wait`, its `polish`
 on the device's thread): the share of the time from its first `prepare`
 opening to its `polish` closing that the union of those spans covers; for
 every `polish` span: the share `polish.setup`, `polish.gates`,
-`polish.refine`, `polish.qv` and `polish.finish` cover (the children are
-sequential on one thread, so a share is a sum).  The least covered and the
+`polish.refine`, `polish.qv`, `polish.finish` and (in a shape set's first
+polish) `polish.warm` cover (the children are sequential on one thread, so a
+share is a sum).  The least covered and the
 median of each are printed; what runs in the rest has no span (in a batch:
 joining the slices, the pinned shapes, the budget gate and the prebake
 between the last `prepare` and the submit; in `polish`: the wide-band retry
@@ -34,7 +35,7 @@ import sys
 
 BATCH_PARTS = ("prepare", "dispatch.turn_wait", "polish")
 POLISH_PARTS = ("polish.setup", "polish.gates", "polish.refine", "polish.qv",
-                "polish.finish")
+                "polish.finish", "polish.warm")
 
 
 def coverage(events: list[dict], parent: str, parts: tuple) -> list[float]:
