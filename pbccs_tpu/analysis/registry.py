@@ -61,7 +61,7 @@ tables from the code scan, so fixing REG001/REG003 drift is mechanical.
 
 The PBCCS_* env toggles are API the same way metrics and fault sites
 are: bench recipes, A/B instructions in the PROFILE docs, and ops
-runbooks key on them -- the perf toggles in particular (PBCCS_WHOLE_ROW,
+runbooks key on them -- the perf toggles in particular (PBCCS_BAND_W,
 PBCCS_DENSE_CB, ...) exist precisely to be flipped by someone who has
 only the docs.  Their canonical inventory lives in docs/DESIGN.md
 between `<!-- ccs-analyze:env-table:{begin,end} -->` markers,

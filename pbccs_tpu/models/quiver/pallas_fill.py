@@ -215,7 +215,7 @@ def _batch(coeff_fn, feat, rlens, tpls, tlens, config, W, pin_start, pin_end,
     seed, seedcol = _pad_r([seed, seedcol], R, Rp)
     vals, ls = _run_fill(cm, cd, cc, mask, seed, seedcol,
                          rev_store=rev_store, cg=cg)
-    return vals, ls, offsets, nc
+    return vals, ls[:, :, 0], offsets, nc
 
 
 def pallas_quiver_forward_batch(feat: QuiverFeatureArrays, rlens, tpls,

@@ -64,13 +64,15 @@ from pbccs_tpu.models.arrow.params import (
     TRANS_STICK,
     transition_lookup,
 )
-from pbccs_tpu.ops.fwdbwd import (BandedMatrix, _affine_scan_circ,
-                                  circ_roll, circ_rows)
+from pbccs_tpu.ops.fwdbwd import (BAND_LEAD, BandedMatrix, _affine_scan_circ,
+                                  band_frame, band_frame_rows, circ_roll,
+                                  circ_rows, row_major)
+from pbccs_tpu.ops.fwdbwd_pallas import band_read_windows
 
 _TINY = 1e-30
 _PB = 64          # template positions per kernel sub-block
-_OFF0 = 4         # front padding of every position-indexed input
-_HALO = 16        # halo rows per block (offsets span [-3, +2] around _OFF0)
+_OFF0 = BAND_LEAD  # row of position 0 in every position-indexed input
+_HALO = 16        # halo rows per step (offsets span [-3, +2] around _OFF0)
 _CB_DEFAULT = 4   # position sub-blocks per kernel grid step (see below)
 N_SLOTS = 9
 
@@ -78,9 +80,9 @@ SUB, INS, DEL = 0, 1, 2
 
 
 # Safety cap on the kernel's template length.  VMEM residency is CONSTANT
-# in Jmax (the grid streams halo'd position blocks), so this only bounds
-# the XLA-side halo'd block views (~1.3x the fill tensors) for absurd
-# bucket sizes; every BASELINE.json config sits far below it.
+# in Jmax (the grid streams overlapping windows of the framed inputs), so
+# this only keeps absurd bucket sizes on the chunked path; every
+# BASELINE.json config sits far below it.
 DENSE_MAX_JMAX = 65536
 
 
@@ -127,34 +129,13 @@ def dense_cols_per_step(nb: int | None = None) -> int:
     return cb
 
 
-def whole_row_mode(jmax: int) -> bool:
-    """Whether the kernel runs in whole-row mode at this bucket (each ref
-    holds a read's full padded row in VMEM) vs streamed halo'd blocks.
-    One source of truth for the kernel and observability reporting.
-
-    Default OFF since the circular-lane kernels: whole-row mode slices
-    every ref at a DATA-DEPENDENT sublane offset (base_off from
-    live_ref), and with the select chains gone that per-access cost
-    outweighs the halo'd views it avoids (same-draw A/B on the chip:
-    halo 183.9 vs whole-row 175.9 ZMW/s at the headline config).
-    Env override PBCCS_WHOLE_ROW=1 re-enables for measurement."""
-    env = os.environ.get("PBCCS_WHOLE_ROW")
-    if env is not None:
-        return env.strip().lower() not in ("0", "false", "off", "no", "")
-    return False
-
-
 def cell_vmem_bytes(jmax: int, width: int) -> int:
     """Static per-grid-cell VMEM footprint estimate of the kernel's input
     refs (f32 lanes: 4 W-wide fills/reads + the packed 8-lane aux plane
     (off/apre/bsuf/wtpl/wtrans) + the 72-lane patch grid + 9 output
     lanes), at the current multi-column blocking factor."""
-    nb = -(-jmax // _PB)
-    cb = dense_cols_per_step(nb)
-    nbc = -(-nb // cb)
-    rows = (nbc + 1) * cb * _PB if whole_row_mode(jmax) \
-        else cb * _PB + _HALO
-    return rows * (4 * width + 8 + 72 + 9) * 4
+    cb = dense_cols_per_step(-(-jmax // _PB))
+    return (cb * _PB + _HALO) * (4 * width + 8 + 72 + 9) * 4
 
 
 # --------------------------------------------------------------------------
@@ -174,12 +155,15 @@ def _shift_pos(x, t: int):
     return jnp.concatenate([head, x[:t]], axis=0)
 
 
-def dense_patch_grids(win_tpl, win_trans, table, wl):
+def dense_patch_grids(win_tpl, win_trans, table, wl, lead: int = 0,
+                      rows: int | None = None):
     """Virtual-mutation patch TRANSITION planes for the full window-frame
     slot grid.
 
     win_tpl: (Jm,) int; win_trans: (Jm, 4); table: (8, 4); wl: scalar.
-    Returns trans (Jm, 9, 2, 4) f32 with the same values
+    `lead` / `rows` lay the grid out in the band frame: `rows` rows with
+    position p at row lead + p (rows outside the window are never read).
+    Returns trans (rows or Jm, 9, 2, 4) f32 with the same values
     make_patches_fast produces for (pos=j, mtype, new_base) of each slot
     -- but via static shifts and a tiny one-hot table lookup only (pos is
     an arange, so no runtime row selects are needed).  The patch BASES are
@@ -188,10 +172,14 @@ def dense_patch_grids(win_tpl, win_trans, table, wl):
     base, a constant, or tpl[p+1] for deletions).
     Slot order: subs A,C,G,T; ins A,C,G,T; del (mutations._SLOT_* tables).
     """
-    Jm = win_tpl.shape[0]
+    n = win_tpl.shape[0]
+    Jm = n if rows is None else rows
     L = jnp.asarray(wl, jnp.int32)
-    pos = jnp.arange(Jm, dtype=jnp.int32)
-    t32 = win_tpl.astype(jnp.int32)
+    pos = jnp.arange(Jm, dtype=jnp.int32) - lead
+    frame = lambda x: jnp.pad(
+        x, [(lead, Jm - lead - n)] + [(0, 0)] * (x.ndim - 1))
+    t32 = frame(win_tpl.astype(jnp.int32))
+    win_trans = frame(win_trans)
     prev_b = _shift_pos(t32, -1)
     next_b = _shift_pos(t32, 1)
     trans_p1 = _shift_pos(win_trans, 1)
@@ -233,37 +221,30 @@ _hs_scan_circ = lambda b, c, W: _affine_scan_circ(b, c)
 
 
 def _dense_kernel(alpha_ref, beta_ref, rbase_ref, rnext_ref, aux_ref,
-                  pt_ref, i_ref, live_ref, out_ref, *, W: int,
-                  whole_row: bool = False, cb: int = 1):
+                  pt_ref, i_ref, live_ref, out_ref, *, W: int, cb: int = 1):
     """Score all 9 slots of ONE (read, position-block-group) grid cell.
 
     Multi-column blocking: each grid step covers `cb` consecutive _PB-row
     position sub-blocks, so the per-step pipeline setup (block fetch,
     index maps, scan prologue) amortizes over cb * _PB template positions
-    instead of _PB -- at cb=1 the round-5 kernel ran at ~50x its VPU
-    op-count bound on per-step overhead.  Each position-indexed ref is a
-    (cb*_PB + _HALO, n) halo'd block of the padded input
-    (padded[_OFF0 + j] = original[j], grid step b starting at row
-    b*cb*_PB), so every slice below is (_PB, ...) at a static offset and
-    the whole cell is contiguous VMEM reads + vector math.  Gridding over
-    position block-groups (instead of the whole-template fori this kernel
-    used before) keeps VMEM residency CONSTANT in template length -- the
-    whole-template form OOMed the 16 MB scoped budget at a Jmax-5056
-    bucket -- and lets the pipeline stream block loads.
+    instead of _PB.  Each position-indexed ref is a (cb*_PB + _HALO, n)
+    window of the FRAMED input (row _OFF0 + j holds position j; grid step
+    b's window starts at row b*cb*_PB and overlaps the next step's by
+    _HALO rows: an element-indexed BlockSpec on the one buffer the fill
+    wrote, no blocked copy of it), so every slice below is (_PB, ...) at a
+    static offset and the whole cell is contiguous VMEM reads + vector
+    math.  Gridding over position block-groups keeps VMEM residency
+    CONSTANT in template length and lets the pipeline stream the windows.
 
     aux_ref is the 8-lane packed plane of the five narrow operands
     (lane 0 off, 1 apre, 2 bsuf, 3 wtpl, 4:8 wtrans): one sublane read
-    stream instead of five 1-to-4-lane streams (deeper sublane packing;
-    the narrow refs each paid a full fetch pipeline at <= 4/128 lane
-    utilization).
+    stream instead of five 1-to-4-lane streams.
 
     live_ref ((1, cb, 1) int32) gates each SUB-BLOCK: rounds > 0 of the
     refinement loop restrict candidates to nearby windows, so most
     (read, sub-block) cells have no valid slot and skip all compute
     (their scores are masked downstream; zeros written here are never
-    read).  Its value is the 1-based GLOBAL sub-block index (0 = dead):
-    pl.program_id has no CPU-interpret lowering, so the whole_row base
-    offset rides in through the input."""
+    read)."""
     for b2 in range(cb):
         lv = live_ref[0, b2, 0]
 
@@ -273,11 +254,10 @@ def _dense_kernel(alpha_ref, beta_ref, rbase_ref, rnext_ref, aux_ref,
                 (_PB, N_SLOTS), jnp.float32)
 
         @pl.when(lv != 0)
-        def _live(b2=b2, lv=lv):
+        def _live(b2=b2):
             out_ref[pl.dslice(b2 * _PB, _PB)] = _dense_kernel_body(
                 alpha_ref, beta_ref, rbase_ref, rnext_ref, aux_ref,
-                pt_ref, i_ref, W=W,
-                base_off=((lv - 1) * _PB if whole_row else b2 * _PB))
+                pt_ref, i_ref, W=W, base_off=b2 * _PB)
 
 
 def _dense_kernel_body(alpha_ref, beta_ref, rbase_ref, rnext_ref, aux_ref,
@@ -285,12 +265,7 @@ def _dense_kernel_body(alpha_ref, beta_ref, rbase_ref, rnext_ref, aux_ref,
     hit = 1.0 - MISMATCH_PROBABILITY
     miss = MISMATCH_PROBABILITY / 3.0
     I = i_ref[...]  # (1, 1) int32, broadcasts against (PB, W)
-    # base_off: this sub-block's row offset -- b2*_PB in halo'd-block mode
-    # (each ref is this grid step's halo'd view over cb sub-blocks);
-    # (global_block)*_PB in whole_row mode, where each ref holds the
-    # read's ENTIRE padded row (VMEM-resident; Pallas skips the re-fetch
-    # across the b axis since the index map repeats) and the halo'd
-    # per-block views never materialize in HBM.
+    # base_off: this sub-block's row offset in the step's window
     def crows(o_col):
         """(PB, W) absolute row per circular lane for (PB, 1) per-position
         offsets (fwdbwd.circ_rows over the position axis)."""
@@ -437,257 +412,140 @@ def _dense_kernel_body(alpha_ref, beta_ref, rbase_ref, rnext_ref, aux_ref,
     return jnp.stack(outs, axis=1)
 
 
-def _dense_grid_shape(jmax: int) -> tuple[int, int, int]:
-    """(cb, NBC, total_rows) of the kernel grid at this template bucket:
-    cb sub-blocks per grid step (dense_cols_per_step), NBC grid steps,
-    and the padded per-read row count every position-indexed input is
-    laid out to ((NBC + 1) * cb * _PB: one whole trailing step beyond the
-    real blocks, so the halo'd step view never reads past the end)."""
+def _dense_grid_shape(jmax: int) -> tuple[int, int]:
+    """(cb, NBC) of the kernel grid at this template bucket: cb sub-blocks
+    per grid step (dense_cols_per_step), NBC grid steps."""
     nb = -(-jmax // _PB)
     cb = dense_cols_per_step(nb)
-    nbc = -(-nb // cb)
-    return cb, nbc, (nbc + 1) * cb * _PB
+    return cb, -(-nb // cb)
 
 
 def _pad_pos(x, total: int):
-    """Pad a position-indexed per-read array so row _OFF0 + j = x[:, j],
-    to `total` rows (_dense_grid_shape)."""
+    """A narrow position-indexed per-read array in the band frame: row
+    _OFF0 + j = x[:, j], `total` rows."""
     n = x.shape[1]
     return jnp.pad(x, [(0, 0), (_OFF0, total - _OFF0 - n)]
                    + [(0, 0)] * (x.ndim - 2))
 
 
-def _halo_blocks(x, nbc: int, cb: int):
-    """(R, NBC, cb*_PB + _HALO, n) overlapped position-step view of a
-    padded (R, (NBC+1)*cb*_PB, n) input: grid step b covers padded rows
-    [b*cb*_PB, (b+1)*cb*_PB + _HALO).  Built from two reshapes + a
-    slice, so XLA lowers it to plain copies (no gather)."""
-    R = x.shape[0]
-    n = x.shape[2:]
-    step = cb * _PB
-    core = x[:, : nbc * step].reshape((R, nbc, step) + n)
-    nxt = x[:, step: (nbc + 1) * step].reshape(
-        (R, nbc, step) + n)[:, :, :_HALO]
-    return jnp.concatenate([core, nxt], axis=2)
-
-
-def band_read_windows(reads, offsets, width: int):
-    """(rbase, rnext): every column's circular-lane read window for a flat
-    read batch — rbase[r, j, L] = read_pad1 value at the band row lane L
-    of column j holds (emission operand), rnext the read_pad0 value (the
-    insertion/link operand).  ONE shared computation serves the interior
-    kernel AND the edge programs (_edge_read_windows slices it).
-
-    Only rnext rides the one-hot window matmul; rbase derives from it:
-    rbase[j][L] = read_pad0[rows_j[L] - 1], and because circular lanes
-    are column-independent (lane = row mod W), that value is
-    circ_roll(rnext[j], 1) at every lane except the band's FIRST row
-    (the cut lane o_j % W), whose operand row o_j - 1 lives in column
-    j-1's window at the same rolled lane.
-
-    Safety of the remaining garbage lanes: when o_j == o_{j-1} (flat
-    offsets are routine) the cut-lane derivation returns rf[o_j + W - 1]
-    instead of rf[o_j - 1] — but every consumer masks exactly that
-    contribution: the cut lane's row is the band's first row, whose
-    match operand is gated by in_band(rows - 1, o_prev) (ext_b /
-    mutation_score._ext_col) and whose insertion operand by
-    rows > o_col (cmask), and rows outside [1, I] are masked by in_read.
-    Any new consumer of rbase must preserve those gates.
-    This halves the (nc, N) one-hot build + MXU windowing cost."""
-    read_f = jax.vmap(lambda r: r.astype(jnp.float32))(reads)
-    from pbccs_tpu.ops.fwdbwd_pallas import window_rows_circ
-
-    rnext = jax.vmap(lambda rf, o: window_rows_circ(rf, o, width))(
-        read_f, offsets)
-    prev_col = jnp.concatenate([rnext[:, :1], rnext[:, :-1]], axis=1)
-    lane = jnp.arange(width, dtype=jnp.int32)
-    cut = (offsets.astype(jnp.int32) % width)[:, :, None] == lane
-    rbase = jnp.where(cut, circ_roll(prev_col, 1), circ_roll(rnext, 1))
-    return rbase, rnext
-
-
 class DenseLayout(typing.NamedTuple):
-    """Pre-baked kernel-layout buffers of one dense score call: every
-    transpose/pad/halo-view/window-matmul the kernel launch needs, built
-    ONCE per fill rebuild instead of inside every per-round score graph
-    (round-5 profile: data formatting 47 ms + slice/pad 58 ms per polish,
-    re-derived each round).  Produced by prepare_dense_layout (or
+    """What a dense score call reads besides the alpha and beta bands, in
+    the same frame as they (fwdbwd.BAND_LEAD: (R, rows, n), position j at
+    row _OFF0 + j), built ONCE per fill rebuild instead of inside every
+    per-round score graph and written where it is read: no pad, halo copy
+    or transpose follows.  Produced by prepare_dense_layout (or
     build_dense_layout under an enclosing trace), consumed by
     dense_interior_scores_batch + edge_window_scores_batch; carried
     across refinement rounds by device_refine.RefineLoopState so rounds
     that apply no mutation relaunch on the previous round's buffers.
 
-    alpha/beta/rbase/rnext: (R, NBC, cb*_PB+_HALO, W) halo'd step views
-    (or (R, total, W) whole rows in whole-row mode); aux: the packed
+    rbase/rnext: the band_read_windows pair (W lanes); aux: the packed
     8-lane narrow-operand plane (off|apre|bsuf|wtpl|wtrans4); ptr: the
-    72-lane patch-transition plane; rw_base/rw_next: the un-blocked
-    band_read_windows pair (R, nc, W) the edge programs slice."""
+    72-lane patch-transition plane.  The kernel takes overlapping
+    windows of each, the edge program two 16-row ones (_edge_windows)."""
 
-    alpha: jax.Array
-    beta: jax.Array
     rbase: jax.Array
     rnext: jax.Array
     aux: jax.Array
     ptr: jax.Array
-    rw_base: jax.Array
-    rw_next: jax.Array
 
 
 def build_dense_layout(reads, rlens, win_tpl, win_trans, wlens, tables,
                        alpha: BandedMatrix, beta: BandedMatrix, apre, bsuf,
-                       width: int, ptrans=None, rwin=None) -> DenseLayout:
+                       width: int) -> DenseLayout:
     """Build the DenseLayout for a flat read batch (trace-time helper;
-    prepare_dense_layout is the jitted entry).  `ptrans`/`rwin` reuse
-    precomputed patch grids / read windows when the caller already has
-    them."""
+    prepare_dense_layout is the jitted entry).  Takes the score calls'
+    operands, so one argument tuple serves all three."""
     R = reads.shape[0]
-    Jm = win_tpl.shape[1]
     W = width
-    rbase, rnext = rwin if rwin is not None else \
-        band_read_windows(reads, alpha.offsets, W)
-    if ptrans is None:
-        ptrans = jax.vmap(dense_patch_grids)(
-            win_tpl.astype(jnp.int32), win_trans, tables, wlens)
-
-    # Whole-row mode for templates that fit VMEM: every ref holds the
-    # read's full padded row and the kernel slices block b itself --
-    # Pallas skips re-fetching across the b axis (the index map repeats),
-    # so the ~1.3x halo'd per-block views never materialize in HBM.  Long
-    # templates keep the streamed halo'd steps (constant VMEM in Jmax).
-    whole_row = whole_row_mode(Jm)
-    cb, nbc, total = _dense_grid_shape(Jm)
-
-    def prep(x):
-        padded = _pad_pos(x, total)
-        return padded if whole_row else _halo_blocks(padded, nbc, cb)
-
+    rows = band_frame_rows(alpha.offsets.shape[1])
+    rbase, rnext = band_read_windows(reads, alpha.offsets, W, rows)
+    ptr = jax.vmap(
+        lambda t, tr, tb, wl: dense_patch_grids(t, tr, tb, wl, _OFF0, rows)
+    )(win_tpl.astype(jnp.int32), win_trans, tables, wlens)
     # the five narrow per-position operands pack into ONE 8-lane plane
     # (kernel lane map: 0 off, 1 apre, 2 bsuf, 3 wtpl, 4:8 wtrans) so the
-    # kernel reads one sublane stream instead of five; each pads to the
-    # common row count first (their native column counts differ: nc,
-    # nc+1, Jm)
-    aux = jnp.concatenate([
-        _pad_pos(alpha.offsets[:, :, None].astype(jnp.float32), total),
-        _pad_pos(apre[:, :, None].astype(jnp.float32), total),
-        _pad_pos(bsuf[:, :, None].astype(jnp.float32), total),
-        _pad_pos(win_tpl[:, :, None].astype(jnp.float32), total),
-        _pad_pos(win_trans.astype(jnp.float32), total),
-    ], axis=2)
-    return DenseLayout(
-        alpha=prep(alpha.vals), beta=prep(beta.vals),
-        rbase=prep(rbase), rnext=prep(rnext),
-        aux=aux if whole_row else _halo_blocks(aux, nbc, cb),
-        ptr=prep(ptrans.reshape(R, Jm, 72)),
-        rw_base=rbase, rw_next=rnext)
+    # kernel reads one sublane stream instead of five; each is framed
+    # first (their native column counts differ: nc, nc+1, Jm).  Selected
+    # lane by lane into the plane in one pass: a (R, rows, 1) piece tiles
+    # to 128 lanes, so padding and concatenating eight of them wrote the
+    # plane's bytes nine times over
+    f32 = lambda x: _pad_pos(x.astype(jnp.float32), rows)
+    lane = jnp.arange(8, dtype=jnp.int32)
+    aux = jnp.zeros((R, rows, 8), jnp.float32)
+    for k, x in enumerate([f32(alpha.offsets), f32(apre), f32(bsuf),
+                           f32(win_tpl)]
+                          + [f32(win_trans[:, :, c]) for c in range(4)]):
+        aux = jnp.where(lane == k, x[:, :, None], aux)
+    return DenseLayout(*map(row_major, (rbase, rnext, aux,
+                                        ptr.reshape(R, rows, 72))))
 
 
-def layout_ptrans72(layout: DenseLayout, jmax: int):
-    """(R, Jm, 72) patch-transition plane recovered from the baked
-    72-lane plane (un-halo + un-pad is a slice/reshape XLA lowers to
-    copies), so edge programs fed a DenseLayout need no second
-    dense_patch_grids pass and no duplicate unblocked plane in HBM.
-    Kept 72 lanes wide: the (9, 2, 4) view of a whole plane tiles its
-    (2, 4) minor dims to (4, 128) on the TPU -- 14x the bytes, 6.6 GB at
-    a 64 x 12 x 2240 batch, which alone overflowed a v5e's HBM."""
-    ptr = layout.ptr
-    if ptr.ndim == 4:                       # halo'd step view
-        R, nbc, rows, _ = ptr.shape
-        step = rows - _HALO
-        core = ptr[:, :, :step].reshape(R, nbc * step, 72)
-        # the last _OFF0 rows of the padded frame live in the final
-        # step's halo section (_OFF0 <= _HALO by construction)
-        ptr = jnp.concatenate([core, ptr[:, -1, step:]], axis=1)
-    return ptr[:, _OFF0: _OFF0 + jmax]
-
-
-def layout_ptrans(layout: DenseLayout, jmax: int):
-    """layout_ptrans72 viewed as the (R, Jm, 9, 2, 4) patch grid."""
-    return layout_ptrans72(layout, jmax).reshape(-1, jmax, 9, 2, 4)
-
-
-@functools.partial(jax.jit, static_argnames=("width",))
-def prepare_dense_layout(reads, rlens, win_tpl, win_trans, wlens, tables,
-                         alpha: BandedMatrix, beta: BandedMatrix,
-                         apre, bsuf, width: int) -> DenseLayout:
-    """Jitted DenseLayout pre-bake -- the prepare-time entry point (the
-    sched/ prepare path and BatchPolisher fill rebuilds call this once
-    per fill build; per-round score launches then consume the baked
-    buffers via dense_interior_scores_batch(layout=...))."""
-    return build_dense_layout(reads, rlens, win_tpl, win_trans, wlens,
-                              tables, alpha, beta, apre, bsuf, width)
+prepare_dense_layout = jax.jit(build_dense_layout, static_argnames=("width",))
 
 
 @functools.partial(jax.jit, static_argnames=("width",))
 def dense_interior_scores_batch(reads, rlens, win_tpl, win_trans, wlens,
                                 tables, alpha: BandedMatrix,
                                 beta: BandedMatrix, apre, bsuf, width: int,
-                                ptrans=None, live=None, rwin=None,
+                                live=None,
                                 layout: DenseLayout | None = None):
     """(R, Jm, 9) window-frame interior scores for a flat read batch.
 
     reads (R, Imax) int; rlens (R,); win_tpl (R, Jm); win_trans (R, Jm, 4);
     wlens (R,); tables (R, 8, 4); alpha/beta batched banded fills on the
-    unmutated windows; apre/bsuf (R, nc+1) scale prefixes.  Entry [r, p, k]
-    is the absolute mutated-window log-likelihood of slot (p, k) for read
-    r, valid where the caller's interior classification holds.  `rwin`:
-    precomputed band_read_windows (shared with the edge program).
-    `layout`: a pre-baked DenseLayout (prepare_dense_layout) -- the
-    kernel launches directly on its buffers and every in-graph layout
-    derivation here is skipped."""
-    R, Imax = reads.shape
+    unmutated windows, framed as the Pallas fills write them (plain XLA
+    fills are framed here, a pad); apre/bsuf (R, nc+1) scale prefixes.
+    Entry [r, p, k] is the absolute mutated-window log-likelihood of slot
+    (p, k) for read r, valid where the caller's interior classification
+    holds.  `layout`: a pre-baked DenseLayout (prepare_dense_layout) --
+    without one it is derived in-graph."""
+    R = reads.shape[0]
     Jm = win_tpl.shape[1]
     W = width
-    whole_row = whole_row_mode(Jm)
-    cb, NBC, total = _dense_grid_shape(Jm)
+    cb, NBC = _dense_grid_shape(Jm)
     NB = -(-Jm // _PB)
 
+    alpha, beta = band_frame(alpha), band_frame(beta)
     if layout is None:
         layout = build_dense_layout(reads, rlens, win_tpl, win_trans,
-                                    wlens, tables, alpha, beta, apre,
-                                    bsuf, W, ptrans=ptrans, rwin=rwin)
+                                    wlens, tables, alpha, beta, apre, bsuf,
+                                    width)
     i_in = rlens[:, None, None].astype(jnp.int32)
 
-    # live carries the 1-BASED global sub-block index (0 = dead cell):
-    # the kernel derives its whole_row base offset from it.  Sub-block
-    # liveness granularity survives multi-column blocking: the (R, NB)
-    # mask pads to (R, NBC*cb) with dead cells and reshapes per step.
-    bidx1 = jnp.arange(1, NB + 1, dtype=jnp.int32)[None, :]
-    if live is None:
-        live_nb = jnp.broadcast_to(bidx1, (R, NB))
-    else:
-        live_nb = jnp.where(live, bidx1, 0).astype(jnp.int32)
+    # sub-block liveness survives multi-column blocking: the (R, NB) mask
+    # pads to (R, NBC*cb) with dead cells and reshapes per step
+    live_nb = jnp.ones((R, NB), jnp.int32) if live is None \
+        else live.astype(jnp.int32)
     live_in = jnp.pad(live_nb, [(0, 0), (0, NBC * cb - NB)]).reshape(
         R, NBC, cb)[:, :, :, None]
-    PBH = cb * _PB + _HALO
-    kernel = functools.partial(_dense_kernel, W=W, whole_row=whole_row,
-                               cb=cb)
-    if whole_row:
-        blk = lambda n: pl.BlockSpec((None, total, n),
-                                     lambda r, b: (r, 0, 0))
-    else:
-        blk = lambda n: pl.BlockSpec((None, None, PBH, n),
-                                     lambda r, b: (r, b, 0, 0))
-    out = pl.pallas_call(
-        kernel,
+    # step b reads rows [b*step, b*step + PBH) of each framed operand;
+    # sub-blocks past the template's last are dead, so where the last
+    # window passes the frame's end it is the window's padding they see
+    step, PBH = cb * _PB, cb * _PB + _HALO
+    over = max(0, (NBC - 1) * step + PBH - alpha.vals.shape[1])
+    win = lambda n: pl.BlockSpec(
+        (None, pl.Element(PBH, (0, over)), pl.Element(n)),
+        lambda r, b: (r, b * step, 0))
+    return pl.pallas_call(
+        functools.partial(_dense_kernel, W=W, cb=cb),
         grid=(R, NBC),
         in_specs=[
-            blk(W), blk(W), blk(W), blk(W),              # alpha/beta/rb/rn
-            blk(8),                                      # packed aux
-            blk(72),                                     # patch trans
+            win(W), win(W), win(W), win(W),              # alpha/beta/rb/rn
+            win(8),                                      # packed aux
+            win(72),                                     # patch trans
             pl.BlockSpec((None, 1, 1), lambda r, b: (r, 0, 0)),  # rlen
             pl.BlockSpec((None, 1, cb, 1),
                          lambda r, b: (r, b, 0, 0)),     # live
         ],
-        out_specs=pl.BlockSpec((None, cb * _PB, N_SLOTS),
+        out_specs=pl.BlockSpec((None, step, N_SLOTS),
                                lambda r, b: (r, b, 0)),
-        out_shape=jax.ShapeDtypeStruct((R, NBC * cb * _PB, N_SLOTS),
-                                       jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((R, Jm, N_SLOTS), jnp.float32),
         interpret=_interpret(),
     )(
-        layout.alpha, layout.beta, layout.rbase, layout.rnext,
+        alpha.vals, beta.vals, layout.rbase, layout.rnext,
         layout.aux, layout.ptr, i_in, live_in,
     )
-    return out[:, :Jm]
 
 
 # --------------------------------------------------------------------------
@@ -723,30 +581,71 @@ _NE_MASK9 = np.array([[True] * 4 + [False] * 4 + [True],
                       [True] * 9])
 
 
-def _edge_read_windows(rbase, rnext, J, W: int):
-    """(R, 11, W) circular-lane read windows for the edge programs,
-    SLICED from the interior kernel's per-column window tensors (rbase =
-    read_pad1 windows at every column's band offset, rnext = read_pad0
-    windows; dense_interior_scores_batch builds both once per score
-    call on the MXU via window_rows_circ).
+_EW = 16   # rows of an edge window: 8-aligned, and holds any 7 rows in a row
+
+
+def _edge_windows(alpha_v, beta_v, layout: DenseLayout, J, jmax: int):
+    """Every row of the framed buffers the edge programs read, as seven
+    (R, _EW, n) windows fetched by one copy-only kernel: rows [0, _EW) of
+    rbase, rnext, beta and the patch plane (near-begin: columns 1..4,
+    beta columns 4..6, positions 0..2) and the _EW rows from the 8-row
+    tile that holds column J - 4 of alpha, rbase and the patch plane
+    (near-end: columns J-4..J+2).  Returns (windows, rem), rem = the row
+    of column J - 4 in the near-end windows.
+
+    A kernel, because XLA relayouts a WHOLE band for each of these reads
+    (a gather wants its sliced axis major-most, and even a one-row static
+    slice pulled the band into the layout of its small result): five
+    band copies a round, from buffers the kernels need row-major."""
+    R = alpha_v.shape[0]
+    col = _OFF0 + J.astype(jnp.int32) - 4
+    tile = col // 8          # prefetched; * 8 in the index map is provably aligned
+    over = max(0, jmax // 8 * 8 + _EW - alpha_v.shape[1])  # J <= jmax
+
+    def win(x, at_j: bool):
+        idx = (lambda r, t: (r, t[r] * 8, 0)) if at_j else \
+            (lambda r, b: (r, 0, 0))
+        return pl.BlockSpec((None, pl.Element(_EW, (0, over if at_j else 0)),
+                             pl.Element(x.shape[2])), idx)
+
+    ops = [(layout.rbase, False), (layout.rnext, False), (beta_v, False),
+           (layout.ptr, False), (alpha_v, True), (layout.rbase, True),
+           (layout.ptr, True)]
+
+    def kernel(tile_ref, *refs):
+        for src, dst in zip(refs[:len(ops)], refs[len(ops):]):
+            dst[...] = src[...]
+
+    outs = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(R,),
+            in_specs=[win(x, at_j) for x, at_j in ops],
+            out_specs=[pl.BlockSpec((None, _EW, x.shape[2]),
+                                    lambda r, b: (r, 0, 0)) for x, _ in ops]),
+        out_shape=[jax.ShapeDtypeStruct((R, _EW, x.shape[2]), jnp.float32)
+                   for x, _ in ops],
+        interpret=_interpret(),
+    )(tile, *(x for x, _ in ops))
+    return outs, col - tile * 8
+
+
+def _edge_read_windows(rb_begin, rn_begin, rb_end, rem, W: int):
+    """(R, 11, W) circular-lane read windows for the edge programs, from
+    the edge windows of DenseLayout.rbase / .rnext (read_pad1 / read_pad0
+    windows at every column's band offset).
 
     Rows 0-3: columns 1..4 (the near-begin refill columns); row 4: the
     read_pad0 window at column 4's offset (the near-begin link row);
-    rows 5-10: columns J-3..J+2 (the near-end extension columns, offsets
-    clipped to the last column like the edge oracle's offs_pad).
-
-    The per-read dynamic slices these replace lowered to scalar-core
-    gathers under vmap — ~13% of all device time on the round-5 headline
-    profile; here the near-begin rows are STATIC slices and the near-end
-    rows one whole-row contiguous dynamic slice per read."""
-    wins_nb = rbase[:, 1:5]                                      # (R, 4, W)
-    rn4 = rnext[:, 4:5]                                          # (R, 1, W)
-    rbase_pad = jnp.concatenate(
-        [rbase, jnp.repeat(rbase[:, -1:], 2, axis=1)], axis=1)
+    rows 5-10: columns J-3..J+2 (the near-end extension columns; the
+    frame's rows past the last column hold its offset's window, like the
+    edge oracle's offs_pad)."""
     wins_ne = jax.vmap(
-        lambda rb, j: lax.dynamic_slice(rb, (j - 3, 0), (6, W))
-    )(rbase_pad, J)                                              # (R, 6, W)
-    return jnp.concatenate([wins_nb, rn4, wins_ne], axis=1)
+        lambda rb, k: lax.dynamic_slice(rb, (k + 1, 0), (6, W))
+    )(rb_end, rem)                                               # (R, 6, W)
+    return jnp.concatenate([rb_begin[:, _OFF0 + 1: _OFF0 + 5],
+                            rn_begin[:, _OFF0 + 4: _OFF0 + 5],
+                            wins_ne], axis=1)
 
 
 def _edge_nb_read(wins, I, tpl, trans, J, offs, bvals, boffs, bsuf, pt3,
@@ -756,7 +655,8 @@ def _edge_nb_read(wins, I, tpl, trans, J, offs, bvals, boffs, bsuf, pt3,
     near-begin branch: refill virtual DP columns 1..4 from the pinned
     start, LinkAlphaBeta at virtual column 4 against saved beta column
     5 - ld.  `wins` are this read's precomputed circular read windows
-    (_edge_read_windows rows: 0-3 = columns 1..4, 4 = the link row)."""
+    (_edge_read_windows rows: 0-3 = columns 1..4, 4 = the link row);
+    `bvals` holds the framed beta band's first rows."""
     from pbccs_tpu.ops.mutation_score import (_circ_rows_batch, _ext_col,
                                               _in_band)
 
@@ -815,7 +715,7 @@ def _edge_nb_read(wins, I, tpl, trans, J, offs, bvals, boffs, bsuf, pt3,
         o_prev = o_j
 
     blc = 5 + _SHIFT27                                   # 5 - ld, static
-    B_col = bvals[blc]                                   # (27, W)
+    B_col = bvals[_OFF0 + blc]                           # (27, W)
     o_b = boffs[blc]
     bsuf_b = bsuf[blc]
     rows4 = _circ_rows_batch(jnp.broadcast_to(offs[4], (M,)), W)
@@ -834,7 +734,7 @@ def _edge_nb_read(wins, I, tpl, trans, J, offs, bvals, boffs, bsuf, pt3,
     return jnp.log(jnp.maximum(v, _TINY)) + bsuf_b
 
 
-def _edge_ne_read(wins, I, tpl, trans, J, avals, offs, apre, pt72,
+def _edge_ne_read(wins, I, tpl, trans, J, A5, offs, apre, ptS,
                   *, W: int):
     """Near-end scores of one read: (27,) absolute LLs for slots at
     window positions {J-2, J-1, J}.  Mirrors edge_scores_fast's near-end
@@ -842,20 +742,20 @@ def _edge_ne_read(wins, I, tpl, trans, J, avals, offs, apre, pt72,
     corner; LL = log corner + alpha scale prefix.  Geometry is static in
     the J-relative frame, so every load is one contiguous dynamic slice.
     `wins` are this read's precomputed circular read windows
-    (_edge_read_windows rows 5-10 = columns J-3..J+2); `pt72` is its
-    (Jm, 72) patch-transition plane, of which only rows J-2..J are read.
+    (_edge_read_windows rows 5-10 = columns J-3..J+2); `A5` is alpha
+    columns J-4..J and `ptS` the (3, 9, 2, 4) patch transitions of
+    positions J-2..J.
     Caller guarantees J >= 8 (tiny windows bail to the host path)."""
     from pbccs_tpu.ops.mutation_score import _ext_col
 
     eps = MISMATCH_PROBABILITY
     hit, em_miss = 1.0 - eps, eps / 3.0
     M = 27
-    nc = avals.shape[0]
+    nc = offs.shape[0]
     tplf = tpl.astype(jnp.float32)
     maxl = J + jnp.asarray(_LD27, jnp.int32)
 
     # J-relative contiguous slices (padded so no dynamic_slice clamping)
-    A5 = lax.dynamic_slice(avals, (J - 4, 0), (5, W))        # cols J-4..J
     offs_pad = jnp.concatenate([offs, jnp.broadcast_to(offs[nc - 1:], (2,))])
     offs7 = lax.dynamic_slice(offs_pad, (J - 4,), (7,))      # J-4..J+2
     apre4 = lax.dynamic_slice(apre, (J - 3,), (4,))          # cols J-3..J
@@ -863,7 +763,6 @@ def _edge_ne_read(wins, I, tpl, trans, J, avals, offs, apre, pt72,
         jnp.concatenate([tplf, jnp.full(4, 4.0)]), (J - 6,), (10,))
     transS = lax.dynamic_slice(
         jnp.concatenate([trans, jnp.zeros((3, 4))]), (J - 6, 0), (9, 4))
-    ptS = lax.dynamic_slice(pt72, (J - 2, 0), (3, 72)).reshape(3, 9, 2, 4)
     rb6 = wins[5:11]                                         # cols J-3..J+2
 
     # t = s - (J-4) in {1..4}, static per slot (s = p - [k==del])
@@ -940,42 +839,44 @@ def _edge_ne_read(wins, I, tpl, trans, J, avals, offs, apre, pt72,
 
 @functools.partial(jax.jit, static_argnames=("width",))
 def edge_window_scores_batch(reads, rlens, win_tpl, win_trans, wlens,
-                             alpha: BandedMatrix, beta: BandedMatrix,
-                             apre, bsuf, ptrans, width: int, rwin=None,
+                             tables, alpha: BandedMatrix,
+                             beta: BandedMatrix, apre, bsuf, width: int,
                              layout: DenseLayout | None = None):
     """(R, 6, 9) window-frame edge-slot scores: rows 0..2 = window
     positions {0, 1, 2} (near-begin), rows 3..5 = {J-2, J-1, J}
     (near-end).  Entries whose slot is actually interior (ins at J-2) or
-    invalid are garbage the caller masks/splices around.  `rwin`:
-    precomputed band_read_windows (shared with the interior kernel);
-    `layout`: a pre-baked DenseLayout, whose rw_base/rw_next pair serves
-    the same role (and whose baked 72-lane plane recovers `ptrans` when
-    the caller passes None for it).  Only six rows of each read's patch
-    plane are read, so it travels 72 lanes wide and is viewed as
-    (9, 2, 4) only after slicing (see layout_ptrans72)."""
-    Jm = win_tpl.shape[1]
-    if layout is not None:
-        rwin = (layout.rw_base, layout.rw_next)
-        if ptrans is None:
-            ptrans = layout_ptrans72(layout, Jm)
-    pt72 = ptrans.reshape(-1, Jm, 72)
-    rbase, rnext = rwin if rwin is not None else \
-        band_read_windows(reads, alpha.offsets, width)
-    wins = _edge_read_windows(rbase, rnext, wlens.astype(jnp.int32), width)
+    invalid are garbage the caller masks/splices around.  Same operands
+    as dense_interior_scores_batch: the framed bands and a DenseLayout
+    (derived in-graph without one), read in place through _edge_windows
+    -- a few rows of a read's bands, windows and 72-lane patch plane,
+    which is viewed as (9, 2, 4) only after slicing (a whole plane in
+    that view tiles its (2, 4) minor dims to (4, 128): 14x the bytes)."""
+    alpha, beta = band_frame(alpha), band_frame(beta)
+    if layout is None:
+        layout = build_dense_layout(reads, rlens, win_tpl, win_trans,
+                                    wlens, tables, alpha, beta, apre, bsuf,
+                                    width)
+    J = wlens.astype(jnp.int32)
+    (rb_b, rn_b, beta_b, pt_b, alpha_e, rb_e, pt_e), rem = _edge_windows(
+        alpha.vals, beta.vals, layout, J, win_tpl.shape[1])
+    wins = _edge_read_windows(rb_b, rn_b, rb_e, rem, width)
 
-    def one(w11, I, tpl, trans, J, avals, aoffs, bvals, boffs, ap, bs, pt):
-        nb = _edge_nb_read(w11, I, tpl, trans, J, aoffs, bvals, boffs,
-                           bs, pt[:3].reshape(3, 9, 2, 4), W=width)
-        ne = _edge_ne_read(w11, I, tpl, trans, J, avals, aoffs, ap, pt,
-                           W=width)
+    def one(w11, I, tpl, trans, J, aoffs, bvals, boffs, ap, bs, pt3, a16,
+            pt16, k):
+        nb = _edge_nb_read(w11, I, tpl, trans, J, aoffs, bvals, boffs, bs,
+                           pt3.reshape(3, 9, 2, 4), W=width)
+        ne = _edge_ne_read(
+            w11, I, tpl, trans, J, lax.dynamic_slice(a16, (k, 0), (5, width)),
+            aoffs, ap, lax.dynamic_slice(pt16, (k + 2, 0),
+                                         (3, 72)).reshape(3, 9, 2, 4),
+            W=width)
         return jnp.concatenate([nb.reshape(3, 9), ne.reshape(3, 9)])
 
     return jax.vmap(one)(wins, rlens.astype(jnp.int32),
-                         win_tpl.astype(jnp.int32), win_trans,
-                         wlens.astype(jnp.int32),
-                         alpha.vals, alpha.offsets.astype(jnp.int32),
-                         beta.vals, beta.offsets.astype(jnp.int32),
-                         apre, bsuf, pt72)
+                         win_tpl.astype(jnp.int32), win_trans, J,
+                         alpha.offsets.astype(jnp.int32), beta_b,
+                         beta.offsets.astype(jnp.int32), apre, bsuf,
+                         pt_b[:, _OFF0: _OFF0 + 3], alpha_e, pt_e, rem)
 
 
 def splice_edge_rows(grid, e6, J):

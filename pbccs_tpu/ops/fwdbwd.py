@@ -83,6 +83,61 @@ class BandedMatrix(NamedTuple):
         return self.vals.shape[-1]
 
 
+# The FRAMED band: what the Pallas fills write and the dense kernel, the
+# edge program and the QV sweep read in place (docs/DESIGN.md "One band
+# layout").  vals is (band_frame_rows(Jmax + 1), W) with column j at row
+# BAND_LEAD + j; offsets and log_scales stay (Jmax + 1,).  The lead rows
+# let the dense kernel reach columns p-3.. of its first positions without
+# a pad, the tail rows its last positions' p+2 and the edge program's
+# J+2; the row count is a multiple of both kernels' 64-row steps.  A plain
+# (Jmax + 1, W) band (the XLA fills below) is the frame with no lead.
+BAND_LEAD = 4
+
+
+def row_major(x):
+    """Pin a band-sized array to the row-major layout the Pallas kernels
+    read and write.  Left to itself the TPU compiler carries a band whose
+    W is not a multiple of 128 lanes with the column axis minor-most (no
+    lane padding) and copies it to row-major in front of every kernel
+    call and back behind it."""
+    from jax.experimental.layout import Layout, with_layout_constraint
+
+    return with_layout_constraint(
+        x, Layout(major_to_minor=tuple(range(x.ndim))))
+
+
+def band_frame_rows(n_cols: int) -> int:
+    """Rows of the framed band of n_cols columns: the lead, the columns,
+    two more for the edge program's J + 2, up to a multiple of 64."""
+    return -(-(BAND_LEAD + n_cols + 2) // 64) * 64
+
+
+def band_lead(bm: BandedMatrix) -> int:
+    """Rows in front of column 0 (static, from the shapes)."""
+    return BAND_LEAD if bm.vals.shape[-2] != bm.offsets.shape[-1] else 0
+
+
+def band_columns(bm: BandedMatrix) -> BandedMatrix:
+    """The plain (..., Jmax + 1, W) band of a framed one (a slice: for the
+    paths off the refine loop, which index vals by column)."""
+    lead = band_lead(bm)
+    if not lead:
+        return bm
+    n = bm.offsets.shape[-1]
+    return bm._replace(vals=bm.vals[..., lead: lead + n, :])
+
+
+def band_frame(bm: BandedMatrix) -> BandedMatrix:
+    """The framed band of a plain one (zero rows around the columns): what
+    the Pallas fills make unnecessary, for fills that came from XLA."""
+    if band_lead(bm):
+        return bm
+    n = bm.offsets.shape[-1]
+    pad = [(0, 0)] * (bm.vals.ndim - 2) + [
+        (BAND_LEAD, band_frame_rows(n) - BAND_LEAD - n), (0, 0)]
+    return bm._replace(vals=jnp.pad(bm.vals, pad))
+
+
 def band_offsets(read_len, tpl_len, n_cols: int, width: int):
     """Static-shape band layout: column j covers rows
     [o(j), o(j)+W) with o(j) centered on the diagonal i = j * I/J.
@@ -168,11 +223,11 @@ def guided_band_offsets(alpha_vals, alpha_offsets, read_len, tpl_len,
     path ~sqrt(L) rows off the straight diagonal, past W/2; one or two
     guided refills recover it (the reference's flip-flop count analogue).
 
-    alpha_vals (ncA, W), alpha_offsets (ncA,): a prior fill's band.
+    alpha_vals (ncA, W) or framed, alpha_offsets (ncA,): a prior fill's band.
     Returns (n_cols,) int32 offsets (n_cols defaults to ncA; extra columns
     repeat the last value so kernel shift/overflow math sees slope 0).
     """
-    ncA = alpha_vals.shape[0]
+    ncA = alpha_offsets.shape[0]
     n_cols = ncA if n_cols is None else n_cols
     W = width
     S = MAX_BAND_ADVANCE
@@ -181,6 +236,8 @@ def guided_band_offsets(alpha_vals, alpha_offsets, read_len, tpl_len,
     j = jnp.arange(ncA, dtype=jnp.float32)
 
     lane = jnp.argmax(alpha_vals, axis=-1).astype(jnp.int32)
+    if lane.shape[0] != ncA:               # a framed band: its column rows
+        lane = lane[BAND_LEAD: BAND_LEAD + ncA]
     q = alpha_offsets % W                  # circular layout: lane -> row
     c = (alpha_offsets - q + lane
          + jnp.where(lane < q, W, 0)).astype(jnp.float32)

@@ -28,18 +28,25 @@ SimpleRecursor.cpp:62-296), evaluated as
 
 The backward (beta) fill reuses the *same* kernel in backward mode (rolls
 and scan run the other circular direction), iterating kernel columns as the
-*static* map j = Jmax - cc so every index is computable with static slices.
+*static* map j = top - cc so every index is computable with static slices.
 The per-read seed column (j = J) is injected by the kernel via a
 seed-column select, and the output index map statically reverses columns so
 no per-read re-assembly is needed.
 
+The band leaves the kernel FRAMED and read-major (fwdbwd.BAND_LEAD): vals
+(R, rows, W) with template column j at row BAND_LEAD + j, which is the
+buffer the dense scoring kernel windows, the edge program reads and the
+refine loop carries -- nothing transposes, slices, pads or copies it on the
+way (docs/DESIGN.md "One band layout").  The frame costs the kernel its
+lead rows as dead columns (zero coefficients, zero values).
+
 TPU lowering notes (all load-bearing, each worth ~10-100x on v5e):
-  * every precompute lookup is a static pad/slice or a vmapped
-    lax.dynamic_slice (gather-of-contiguous-slices); per-element jnp.take
-    and scatter (.at[].set) forms of the same lower to scalar-core loops.
-  * all arrays keep the natural (R, columns, W) layout end to end; the
-    kernel indexes the column axis dynamically on the sublane dimension
-    rather than transposing 28MB matrices around the call.
+  * every precompute lookup is a static pad/slice or a one-hot matmul;
+    per-element jnp.take and scatter (.at[].set) forms of the same lower
+    to scalar-core loops.
+  * the coefficients enter columns-leading (the scan loads a column by
+    address arithmetic) and the band leaves read-major (the scan stores a
+    column into one row of every read's tile).
   * log-likelihoods are masked reductions, not per-read gathers.
 
 Numerics: the Hillis-Steele scan associates the affine recurrence in a
@@ -65,9 +72,9 @@ from pbccs_tpu.models.arrow.params import (
     TRANS_STICK,
     MISMATCH_PROBABILITY,
 )
-from pbccs_tpu.ops.fwdbwd import (MAX_BAND_ADVANCE, BandedMatrix,
-                                  band_offsets, circ_roll, circ_rows,
-                                  in_band)
+from pbccs_tpu.ops.fwdbwd import (BAND_LEAD, MAX_BAND_ADVANCE, BandedMatrix,
+                                  band_frame_rows, band_lead, band_offsets,
+                                  circ_roll, circ_rows, in_band, row_major)
 
 _TINY = 1e-30
 # band may advance at most this many rows per column; single source of
@@ -160,18 +167,71 @@ def window_rows_circ(x, starts, W: int, exact: bool = False):
     return jnp.where(L[None, :] >= q[:, None], win1, win2)
 
 
+def band_read_windows(reads, offsets, width: int, rows: int | None = None):
+    """(rbase, rnext): every column's circular-lane read window for a flat
+    read batch — rbase[r, j, L] = read_pad1 value at the band row lane L
+    of column j holds (emission operand), rnext the read_pad0 value (the
+    insertion/link operand).  ONE shared computation serves the interior
+    kernel, the edge programs (dense_score_pallas._edge_read_windows
+    slices it) AND the alpha fill's coefficient precompute below: the
+    same call on the same reads and offsets, which the compiler merges
+    within a program, so a fill rebuild runs these window matmuls once
+    (the beta fill keeps its own, in its reversed column order).  With
+    `rows` the pair comes out in the band frame ((R, rows, W), column j
+    at row BAND_LEAD + j, the rows around the columns holding the first and
+    the last column's windows): the windows are computed where they are
+    read, so nothing pads or copies them afterwards.
+
+    Only rnext rides the one-hot window matmul; rbase derives from it:
+    rbase[j][L] = read_pad0[rows_j[L] - 1], and because circular lanes
+    are column-independent (lane = row mod W), that value is
+    circ_roll(rnext[j], 1) at every lane except the band's FIRST row
+    (the cut lane o_j % W), whose operand row o_j - 1 lives in column
+    j-1's window at the same rolled lane.
+
+    Safety of the remaining garbage lanes: when o_j == o_{j-1} (flat
+    offsets are routine, and the frame's rows past the last column repeat
+    its offset) the cut-lane derivation returns rf[o_j + W - 1]
+    instead of rf[o_j - 1] — but every consumer masks exactly that
+    contribution: the cut lane's row is the band's first row, whose
+    match operand is gated by in_band(rows - 1, o_prev) (ext_b /
+    mutation_score._ext_col / _forward_coeffs' cm) and whose insertion
+    operand by rows > o_col (cmask / _forward_coeffs' cc), and rows
+    outside [1, I] are masked by in_read / valid.
+    Any new consumer of rbase must preserve those gates.
+    This halves the (nc, N) one-hot build + MXU windowing cost."""
+    read_f = reads.astype(jnp.float32)
+    offsets = offsets.astype(jnp.int32)
+    if rows is not None:
+        offsets = jax.vmap(lambda o: _edge_clip_rows(o, BAND_LEAD, rows))(offsets)
+    rnext = jax.vmap(lambda rf, o: window_rows_circ(rf, o, width))(
+        read_f, offsets)
+    prev_col = jnp.concatenate([rnext[:, :1], rnext[:, :-1]], axis=1)
+    lane = jnp.arange(width, dtype=jnp.int32)
+    cut = (offsets % width)[:, :, None] == lane
+    rbase = jnp.where(cut, circ_roll(prev_col, 1), circ_roll(rnext, 1))
+    return rbase, rnext
+
+
+
 # shared circular-layout helpers (single source of truth in ops.fwdbwd)
 _circ_rows_cols = circ_rows
 _in_band2 = in_band
 
 
-def _forward_coeffs(read, I, tpl, trans, J, offsets, W: int, eps: float):
+def _forward_coeffs(read, I, tpl, trans, J, offsets, rbase, W: int,
+                    eps: float, lead: int = 0):
     """Per-column circular-lane band coefficients of the alpha recurrence
     for one read.
 
     read: (Imax,) int32; tpl: (Jmax,) int32; trans: (Jmax, 4) f32;
-    offsets: (nc,) int32 band offsets.  Returns (cm, cd, cc) each (nc, W),
-    rescale mask (nc,) f32, seed (W,) f32, seedcol int32.
+    offsets: (nc,) int32 band offsets of the kernel's columns; rbase:
+    (nc, W) their read windows (band_read_windows: read base i-1 at the
+    lane of row i).  Kernel column t holds template column j = t - lead
+    (the frame's lead rows are dead columns: zero coefficients, zero
+    values).  Returns
+    (cm, cd, cc) each (nc, W), rescale mask (nc,) f32, seed (W,) f32,
+    seedcol int32.
 
     Circular layout: lane L of column j holds row circ_rows(o(j))[L], so
     the kernel reads the previous column with ONE static lane roll; the
@@ -185,17 +245,15 @@ def _forward_coeffs(read, I, tpl, trans, J, offsets, W: int, eps: float):
     nc = offsets.shape[0]
     hit, miss = 1.0 - eps, eps / 3.0
 
-    j = jnp.arange(nc, dtype=jnp.int32)[:, None]            # (nc, 1)
+    j = jnp.arange(nc, dtype=jnp.int32)[:, None] - lead     # (nc, 1)
     o = offsets[:, None]
     om1 = _edge_clip_rows(offsets, 1, nc)[:, None]          # offset of col j-1
 
     rows = _circ_rows_cols(offsets, W)                      # (nc, W)
-    read_pad = jnp.concatenate([read[0:1], read])           # [row] = read[row-1]
-    rbase = window_rows_circ(read_pad, offsets, W)
-    t_cur = _edge_clip_rows(tpl, 1, nc)[:, None]
-    t_next = _edge_clip_rows(tpl, 0, nc)[:, None]
-    tr_prev = _edge_clip_rows(trans, 2, nc)                 # (nc, 4)
-    tr_cur = _edge_clip_rows(trans, 1, nc)
+    t_cur = _edge_clip_rows(tpl, 1 + lead, nc)[:, None]
+    t_next = _edge_clip_rows(tpl, lead, nc)[:, None]
+    tr_prev = _edge_clip_rows(trans, 2 + lead, nc)          # (nc, 4)
+    tr_cur = _edge_clip_rows(trans, 1 + lead, nc)
 
     valid = (rows >= 1) & (rows <= I - 1)
     em = jnp.where(rbase == t_cur, hit, miss)
@@ -224,40 +282,45 @@ def _forward_coeffs(read, I, tpl, trans, J, offsets, W: int, eps: float):
     cd = jnp.where(pinned, 0.0, cd)
     cc = jnp.where(pinned, 0.0, cc)
 
-    dead = (j == 0) | (j > J)
+    dead = (j <= 0) | (j > J)
     cm = jnp.where(dead, 0.0, cm)
     cd = jnp.where(dead, 0.0, cd)
     cc = jnp.where(dead, 0.0, cc)
 
     mask = ((j[:, 0] >= 1) & (j[:, 0] < J)).astype(jnp.float32)
     seed = (jnp.arange(W) == 0).astype(jnp.float32)
-    return cm, cd, cc, mask, seed, jnp.int32(0)
+    return cm, cd, cc, mask, seed, jnp.int32(lead)
 
 
-def _backward_coeffs(read, I, tpl, trans, J, offsets, W: int, eps: float):
-    """Beta coefficients: kernel column cc holds beta column j = Jmax - cc
+def _backward_coeffs(read, I, tpl, trans, J, offsets, W: int, eps: float,
+                     nc: int, top: int):
+    """Beta coefficients: kernel column cc holds beta column j = top - cc
     in the SAME circular lane layout as alpha (lane L = row r === L mod W;
     no lane reversal -- the kernel's backward mode rolls the other way).
-    The kernel's output index map reverses columns, so beta column j sits
-    at output column j + (nc-1-Jmax).
+    The kernel stores its columns reversed, so beta column j sits at output
+    row nc - 1 - cc = j + (nc - 1 - top): the frame's lead, which the
+    caller folds into `top`.  offsets: (>= Jmax + 1,) band offsets by
+    template column (columns past the last repeat it).  The read windows
+    are its own matmul, in the kernel's reversed column order: taking
+    them from band_read_windows' rnext through a reverse read wrong from
+    the kernel's 320th column on at 1,024 reads x 640 rows x W 64 on the
+    chip (PR 28; right at 256 reads, and on the CPU).
 
     Mirrors the JAX step in fwdbwd.banded_backward column for column."""
     Imax = read.shape[0]
-    Jmax = tpl.shape[0]
-    nc = offsets.shape[0]
     hit, miss = 1.0 - eps, eps / 3.0
 
     cc_idx = jnp.arange(nc, dtype=jnp.int32)[:, None]
-    j = Jmax - cc_idx                                       # beta column (static)
-    o_j = _rev_clip_rows(offsets, Jmax, nc)
-    o_j1 = _rev_clip_rows(offsets, Jmax + 1, nc)[:, None]   # offset of col j+1
+    j = top - cc_idx                                        # beta column (static)
+    o_j = _rev_clip_rows(offsets, top, nc)
+    o_j1 = _rev_clip_rows(offsets, top + 1, nc)[:, None]    # offset of col j+1
 
     rows = _circ_rows_cols(o_j, W)                          # (nc, W)
     o_j = o_j[:, None]
-    read_pad = jnp.concatenate([read, read[Imax - 1:]])
+    read_pad = jnp.concatenate([read, read[Imax - 1:]]).astype(jnp.float32)
     rnext = window_rows_circ(read_pad, o_j[:, 0], W)        # read base i+1
-    t_next = _rev_clip_rows(tpl, Jmax, nc)[:, None]         # base of col j+1
-    tr_cur = _rev_clip_rows(trans, Jmax - 1, nc)            # moves leaving j-1
+    t_next = _rev_clip_rows(tpl, top, nc)[:, None]          # base of col j+1
+    tr_cur = _rev_clip_rows(trans, top - 1, nc)             # moves leaving j-1
 
     valid = (rows >= 1) & (rows <= I - 1)
     nxt_match = rnext == t_next
@@ -292,7 +355,7 @@ def _backward_coeffs(read, I, tpl, trans, J, offsets, W: int, eps: float):
 
     mask = ((j[:, 0] >= 1) & (j[:, 0] <= J - 1)).astype(jnp.float32)
     seed = (jnp.arange(W) == I % W).astype(jnp.float32)
-    return cm, cd, cc, mask, seed, (Jmax - J).astype(jnp.int32)
+    return cm, cd, cc, mask, seed, (top - J).astype(jnp.int32)
 
 
 # --------------------------------------------------------------------------
@@ -305,11 +368,13 @@ _roll_lanes = circ_roll    # Mosaic-friendly: two static slices + concat
 
 def _fill_kernel(*refs, jb_size: int, rev_store: bool, merge: bool,
                  backward: bool):
-    """Column scan over circular-lane bands.  Arrays are in kernel layout
-    (columns, R, W): the column axis is the *leading* (untiled) dimension,
-    so the per-column dynamic index is plain VMEM address arithmetic.
-    (Dynamic indexing on the sublane axis of an (R, columns, W) layout
-    measured ~20x slower on v5e.)
+    """Column scan over circular-lane bands.  The coefficient inputs are in
+    kernel layout (columns, R, W): the column axis is the *leading*
+    (untiled) dimension, so loading a column is plain VMEM address
+    arithmetic.  (Dynamic LOADS on the sublane axis of an (R, columns, W)
+    layout measured ~20x slower on v5e.)  The outputs are read-major
+    (R, columns, W) blocks: storing a column to one row of each read's
+    tile costs a few percent of the scan (_run_fill has the numbers).
 
     Circular lanes (fwdbwd.BandedMatrix): cell (i, j) lives at lane
     i mod W whatever the column offset, so the cross-column operand is ONE
@@ -393,13 +458,12 @@ def _fill_kernel(*refs, jb_size: int, rev_store: bool, merge: bool,
                 prev2, sprev = prev, scale
             prev = col
 
-        if rev_store:
-            out_base = jb_size - base - u
-            vals_ref[pl.dslice(out_base, u)] = jnp.stack(cols[::-1])
-            ls_ref[pl.dslice(out_base, u)] = jnp.stack(lss[::-1])
-        else:
-            vals_ref[pl.dslice(base, u)] = jnp.stack(cols)
-            ls_ref[pl.dslice(base, u)] = jnp.stack(lss)
+        # the outputs are READ-major blocks (rb, jb, W): a column goes to
+        # one row of every read's tile
+        for k in range(u):
+            row = (jb_size - 1 - base - k) if rev_store else base + k
+            vals_ref[:, pl.dslice(row, 1), :] = cols[k][:, None, :]
+            ls_ref[:, pl.dslice(row, 1), :] = lss[k][:, None, :]
         prev_ref[...] = prev
         if merge:
             prev2_ref[...] = prev2
@@ -413,12 +477,19 @@ def _run_fill(cm, cd, cc, mask, seed, seedcol, rev_store: bool,
               cg=None, backward: bool | None = None):
     """Invoke the column-scan kernel.
 
-    cm/cd/cc: (nc, R, W) KERNEL layout (columns leading -- produced
-    directly by the coefficient vmaps with out_axes=1, so no transpose of
-    the multi-MB coefficient tensors sits between precompute and kernel);
-    mask: (nc, R); seed: (R, W); seedcol: (R,).
-    Returns vals (R, nc, W) and log-scales (R, nc).  With rev_store, output
-    column t holds kernel column nc-1-t.  Passing cg engages the Merge
+    cm/cd/cc: (nc, R, W) KERNEL layout, columns leading (the scan indexes
+    a column with plain VMEM address arithmetic); mask: (nc, R);
+    seed: (R, W); seedcol: (R,).
+    Returns vals (R, nc, W) and log-scales (R, nc, 1), READ-major: the
+    scan stores each column into a row of the step's (rb, jb, W) output
+    block, so the band leaves the kernel in the layout every reader of
+    it takes (the dense kernel's BlockSpecs, the edge program's windows,
+    the log-likelihood reductions) and no XLA transpose follows.  (On one
+    TPU v5e, PR 28: alpha + beta fills with their precompute at 384 x
+    2,304 x W96 took 90.1 ms storing so, 90.6 ms transposing a collected
+    (jb, rb, W) block once a step, 92.1 ms reading it back a read at a
+    time; 99.7 ms with the columns-leading output and XLA's transpose.)
+    With rev_store, output row t holds kernel column nc-1-t.  Passing cg engages the Merge
     carry (Quiver recurrence).  backward sets the kernel's roll/scan
     direction (defaults to rev_store)."""
     nc, R, W = cm.shape
@@ -435,17 +506,14 @@ def _run_fill(cm, cd, cc, mask, seed, seedcol, rev_store: bool,
     assert nc % jb == 0 and R % rb == 0
     njb = nc // jb
 
-    cm_k, cd_k, cc_k = cm, cd, cc
-    mk_k = mask[:, :, None]
-
     kernel = functools.partial(_fill_kernel, jb_size=jb, rev_store=rev_store,
                                merge=merge, backward=backward)
     if rev_store:
-        col_spec = pl.BlockSpec((jb, rb, W), lambda r, j: (njb - 1 - j, r, 0))
-        vec_ospec = pl.BlockSpec((jb, rb, 1), lambda r, j: (njb - 1 - j, r, 0))
+        col_ospec = pl.BlockSpec((rb, jb, W), lambda r, j: (r, njb - 1 - j, 0))
+        vec_ospec = pl.BlockSpec((rb, jb, 1), lambda r, j: (r, njb - 1 - j, 0))
     else:
-        col_spec = pl.BlockSpec((jb, rb, W), lambda r, j: (j, r, 0))
-        vec_ospec = pl.BlockSpec((jb, rb, 1), lambda r, j: (j, r, 0))
+        col_ospec = pl.BlockSpec((rb, jb, W), lambda r, j: (r, j, 0))
+        vec_ospec = pl.BlockSpec((rb, jb, 1), lambda r, j: (r, j, 0))
     in_col = pl.BlockSpec((jb, rb, W), lambda r, j: (j, r, 0))
     in_vec = pl.BlockSpec((jb, rb, 1), lambda r, j: (j, r, 0))
     in_specs = [
@@ -454,28 +522,27 @@ def _run_fill(cm, cd, cc, mask, seed, seedcol, rev_store: bool,
         in_vec,                                          # mask
         in_col, in_col, in_col,                          # cm, cd, cc
     ]
-    operands = [seed, seedcol[:, None], mk_k, cm_k, cd_k, cc_k]
-    scratch = [pltpu.VMEM((rb, W), jnp.float32)]
+    operands = [seed, seedcol[:, None], mask[:, :, None], cm, cd, cc]
+    scratch = [pltpu.VMEM((rb, W), jnp.float32)]         # running column
     if merge:
         in_specs += [in_col]                             # cg
         operands += [cg]
         scratch += [pltpu.VMEM((rb, W), jnp.float32),    # prev2
                     pltpu.VMEM((rb, 1), jnp.float32)]    # its scale
-    vals, ls = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid=(R // rb, njb),
         in_specs=in_specs,
-        out_specs=[col_spec, vec_ospec],
+        out_specs=[col_ospec, vec_ospec],
         out_shape=[
-            jax.ShapeDtypeStruct((nc, R, W), jnp.float32),
-            jax.ShapeDtypeStruct((nc, R, 1), jnp.float32),
+            jax.ShapeDtypeStruct((R, nc, W), jnp.float32),
+            jax.ShapeDtypeStruct((R, nc, 1), jnp.float32),
         ],
         scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(),
     )(*operands)
-    return jnp.transpose(vals, (1, 0, 2)), jnp.transpose(ls[:, :, 0])
 
 
 def _pad_cols(n: int) -> int:
@@ -483,17 +550,11 @@ def _pad_cols(n: int) -> int:
 
 
 def _resolve_offsets(offsets, I, J, nc: int, width: int):
-    """Diagonal offsets unless precomputed ones are supplied; pads supplied
-    offsets to nc columns by repeating the last value (slope 0 padding)."""
+    """(R, nc) band offsets by template column: diagonal unless
+    precomputed ones are supplied."""
     if offsets is None:
         return jax.vmap(lambda i, jl: band_offsets(i, jl, nc, width))(I, J)
-    offsets = jnp.asarray(offsets, jnp.int32)
-    if offsets.shape[1] < nc:
-        offsets = jnp.concatenate(
-            [offsets, jnp.broadcast_to(offsets[:, -1:],
-                                       (offsets.shape[0],
-                                        nc - offsets.shape[1]))], axis=1)
-    return offsets[:, :nc]
+    return jnp.asarray(offsets, jnp.int32)[:, :nc]
 
 
 def _pad_reads(r: int) -> int:
@@ -518,12 +579,24 @@ def _pad_r(arrs, R, Rp, axis: int = 0):
 # --------------------------------------------------------------------------
 
 
+def _framed(vals, ls, offsets, R: int) -> BandedMatrix:
+    """The fill's outputs as a framed BandedMatrix (fwdbwd.BAND_LEAD): the
+    values as the kernel wrote them, the log-scales by template column."""
+    n = offsets.shape[1]
+    if vals.shape[0] != R:                  # read lanes padded to a block
+        vals, ls = vals[:R], ls[:R]
+    return BandedMatrix(row_major(vals), offsets,
+                        ls[:, BAND_LEAD: BAND_LEAD + n, 0])
+
+
 def pallas_forward_batch(reads, rlens, tpls, trans, tlens, width: int,
                          pr_miscall: float = MISMATCH_PROBABILITY,
                          offsets=None) -> BandedMatrix:
     """Batched banded forward fills: reads (R, Imax) int8/int32, rlens (R,),
-    tpls (R, Jmax), trans (R, Jmax, 4), tlens (R,).  Returns a BandedMatrix
-    with batched leaves (R, Jmax+1, W) / (R, Jmax+1).
+    tpls (R, Jmax), trans (R, Jmax, 4), tlens (R,).  Returns a FRAMED
+    BandedMatrix (fwdbwd.BAND_LEAD): vals (R, band_frame_rows(Jmax + 1), W)
+    with column j at row BAND_LEAD + j, written once by the kernel;
+    offsets / log_scales (R, Jmax + 1).
 
     offsets: optional (R, >= Jmax+1) precomputed band offsets (guided
     rebanding, fwdbwd.guided_band_offsets); default diagonal layout.
@@ -532,24 +605,27 @@ def pallas_forward_batch(reads, rlens, tpls, trans, tlens, width: int,
     carry no mass)."""
     R, Imax = reads.shape
     Jmax = tpls.shape[1]
-    nc = _pad_cols(Jmax + 1)
+    nc = band_frame_rows(Jmax + 1)
     Rp = _pad_reads(R)
 
     I = rlens.astype(jnp.int32)
     J = tlens.astype(jnp.int32)
-    offsets = _resolve_offsets(offsets, I, J, nc, width)
+    offsets = _resolve_offsets(offsets, I, J, Jmax + 1, width)
+    rbase, _ = band_read_windows(reads, offsets, width, nc)
+    # turned columns-leading once, here, and not once a coefficient tensor
+    rbase = row_major(jnp.swapaxes(rbase, 0, 1))
     cm, cd, cc, mask, seed, seedcol = jax.vmap(
-        lambda r, i, t, tr, jl, o: _forward_coeffs(
-            r.astype(jnp.int32), i, t.astype(jnp.int32), tr, jl, o,
-            width, pr_miscall),
-        out_axes=(1, 1, 1, 1, 0, 0),
-    )(reads, I, tpls, trans, J, offsets)
+        lambda r, i, t, tr, jl, o, rb: _forward_coeffs(
+            r.astype(jnp.int32), i, t.astype(jnp.int32), tr, jl,
+            _edge_clip_rows(o, BAND_LEAD, nc), rb, width, pr_miscall,
+            lead=BAND_LEAD),
+        in_axes=(0, 0, 0, 0, 0, 0, 1), out_axes=(1, 1, 1, 1, 0, 0),
+    )(reads, I, tpls, trans, J, offsets, rbase)
 
     cm, cd, cc, mask = _pad_r([cm, cd, cc, mask], R, Rp, axis=1)
     seed, seedcol = _pad_r([seed, seedcol], R, Rp)
     vals, ls = _run_fill(cm, cd, cc, mask, seed, seedcol, rev_store=False)
-    return BandedMatrix(vals[:R, : Jmax + 1], offsets[:, : Jmax + 1],
-                        ls[:R, : Jmax + 1])
+    return _framed(vals, ls, offsets, R)
 
 
 def pallas_backward_batch(reads, rlens, tpls, trans, tlens, width: int,
@@ -559,29 +635,25 @@ def pallas_backward_batch(reads, rlens, tpls, trans, tlens, width: int,
     pallas_forward_batch."""
     R, Imax = reads.shape
     Jmax = tpls.shape[1]
-    nc = _pad_cols(Jmax + 1)
+    nc = band_frame_rows(Jmax + 1)
     Rp = _pad_reads(R)
 
     I = rlens.astype(jnp.int32)
     J = tlens.astype(jnp.int32)
-    offsets = _resolve_offsets(offsets, I, J, nc, width)
+    offsets = _resolve_offsets(offsets, I, J, Jmax + 1, width)
+    # the kernel stores column cc at row nc-1-cc: with beta column
+    # j = top - cc that is row j + BAND_LEAD
     cm, cd, cc, mask, seed, seedcol = jax.vmap(
         lambda r, i, t, tr, jl, o: _backward_coeffs(
             r.astype(jnp.int32), i, t.astype(jnp.int32), tr, jl, o,
-            width, pr_miscall),
+            width, pr_miscall, nc, top=nc - 1 - BAND_LEAD),
         out_axes=(1, 1, 1, 1, 0, 0),
     )(reads, I, tpls, trans, J, offsets)
 
     cm, cd, cc, mask = _pad_r([cm, cd, cc, mask], R, Rp, axis=1)
     seed, seedcol = _pad_r([seed, seedcol], R, Rp)
     vals, ls = _run_fill(cm, cd, cc, mask, seed, seedcol, rev_store=True)
-    # with rev_store, output column t = kernel col nc-1-t = beta col
-    # Jmax - (nc-1-t) => beta col j sits at t = j + (nc-1-Jmax); lanes are
-    # already in the shared circular layout (no kernel-frame flip).
-    lo = nc - 1 - Jmax
-    vals = vals[:R, lo: lo + Jmax + 1]
-    ls = ls[:R, lo: lo + Jmax + 1]
-    return BandedMatrix(vals, offsets[:, : Jmax + 1], ls)
+    return _framed(vals, ls, offsets, R)
 
 
 # --------------------------------------------------------------------------
@@ -592,20 +664,19 @@ def pallas_backward_batch(reads, rlens, tpls, trans, tlens, width: int,
 def forward_loglik_batch(alpha: BandedMatrix, rlens, tlens):
     """LL[r] = log alpha(I, J) + sum of column log-scales.  Column J is
     one-hot (only the pinned final cell is non-zero), so the final value is a
-    masked sum over the whole band."""
+    masked sum over the whole band (framed or plain)."""
     J = tlens.astype(jnp.int32)[:, None]
-    ncols = alpha.vals.shape[1]
-    jcols = jnp.arange(ncols, dtype=jnp.int32)[None, :]
-    final = jnp.sum(jnp.where((jcols == J)[:, :, None], alpha.vals, 0.0),
-                    axis=(1, 2))
+    rows = jnp.arange(alpha.vals.shape[1], dtype=jnp.int32)[None, :]
+    final = jnp.sum(jnp.where((rows == J + band_lead(alpha))[:, :, None],
+                              alpha.vals, 0.0), axis=(1, 2))
+    jcols = jnp.arange(alpha.log_scales.shape[1], dtype=jnp.int32)[None, :]
     ls = jnp.sum(jnp.where(jcols <= J, alpha.log_scales, 0.0), axis=1)
     return jnp.log(jnp.maximum(final, _TINY)) + ls
 
 
 def backward_loglik_batch(beta: BandedMatrix, tlens):
     J = tlens.astype(jnp.int32)[:, None]
-    ncols = beta.vals.shape[1]
-    jcols = jnp.arange(ncols, dtype=jnp.int32)[None, :]
-    b00 = beta.vals[:, 0, 0]
+    jcols = jnp.arange(beta.log_scales.shape[1], dtype=jnp.int32)[None, :]
+    b00 = beta.vals[:, band_lead(beta), 0]
     ls = jnp.sum(jnp.where(jcols <= J, beta.log_scales, 0.0), axis=1)
     return jnp.log(jnp.maximum(b00, _TINY)) + ls

@@ -38,8 +38,9 @@ from pbccs_tpu.models.arrow.params import (
     context_index,
 )
 from pbccs_tpu.ops.fwdbwd import (BandedMatrix, _affine_scan_circ,
-                                  _gather_band, banded_forward, circ_roll,
-                                  circ_rows, forward_loglik, in_band)
+                                  _gather_band, band_columns,
+                                  banded_forward, circ_roll, circ_rows,
+                                  forward_loglik, in_band)
 from pbccs_tpu.ops.fwdbwd_pallas import window_rows_circ
 
 SUB, INS, DEL = 0, 1, 2
@@ -142,6 +143,7 @@ def extend_link_score(read, read_len, win_tpl, win_trans, win_len,
     Parity: MutationScorer::ScoreMutation mid-template branch
     (MutationScorer.cpp:191-206) = ExtendAlpha(2 cols) + LinkAlphaBeta.
     """
+    alpha, beta = band_columns(alpha), band_columns(beta)
     W = alpha.width
     Imax = read.shape[0]
     eps = pr_miscall
@@ -415,6 +417,7 @@ def interior_scores_fast(read, read_len, win_tpl, win_trans, win_len,
     read: (Imax,) int32; p/mtype: (M,) oriented window-frame mutations;
     patch_*: (M, 2), (M, 2, 4), (M,) oriented virtual-mutation patches.
     """
+    alpha, beta = band_columns(alpha), band_columns(beta)
     W = alpha.width
     nc = alpha.vals.shape[0]
     eps = pr_miscall
@@ -525,6 +528,7 @@ def edge_scores_fast(read, read_len, win_tpl, win_trans, win_len,
     Caller guarantees win_len >= 8, so the two regimes cannot overlap; tiny
     windows stay on the full-refill path.
     """
+    alpha, beta = band_columns(alpha), band_columns(beta)
     W = alpha.width
     nc = alpha.vals.shape[0]
     eps = pr_miscall

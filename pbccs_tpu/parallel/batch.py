@@ -1771,17 +1771,17 @@ class BatchPolisher:
         and the static VMEM footprint of the dense kernel's grid cell.
         One device fetch; intended for logs and the bench artifact, and
         for justifying W-per-length-bucket schedules."""
-        from pbccs_tpu.ops.dense_score_pallas import (cell_vmem_bytes,
-                                                      whole_row_mode)
+        from pbccs_tpu.ops.dense_score_pallas import cell_vmem_bytes
+        from pbccs_tpu.ops.fwdbwd import band_columns
 
         W = self._W
-        nc = int(self.alpha.vals.shape[2])
+        vals = band_columns(self.alpha).vals
+        nc = int(vals.shape[2])
         # occupancy: fraction of band lanes holding live probability mass
         # per in-window column, averaged over real active reads
         live_col = (jnp.arange(nc)[None, None, :]
                     <= self.wlens[:, :, None])
-        nz = jnp.sum((self.alpha.vals > 0) & live_col[:, :, :, None],
-                     axis=(2, 3))
+        nz = jnp.sum((vals > 0) & live_col[:, :, :, None], axis=(2, 3))
         denom = jnp.maximum(jnp.sum(live_col, axis=2) * W, 1)
         occ = nz / denom
         act = self._active_dev
@@ -1793,7 +1793,6 @@ class BatchPolisher:
         statuses = self._stats_host["statuses"]
         real = self._real_rows
         jm = int(self.win_tpl.shape[2])   # the kernel's actual bucket
-        whole_row = whole_row_mode(jm)
         vmem_cell = cell_vmem_bytes(jm, W)
         return {
             "band_width": W,
@@ -1806,7 +1805,7 @@ class BatchPolisher:
                                     & real).sum()),
             "zscore_drops": int(((statuses == ADD_POOR_ZSCORE)
                                  & real).sum()),
-            "dense_kernel_mode": "whole_row" if whole_row else "halo",
+            "dense_kernel_mode": "halo",
             "dense_kernel_vmem_per_cell_bytes": int(vmem_cell),
             "guided_fill_passes": guided_fill_passes(self._Jmax),
         }

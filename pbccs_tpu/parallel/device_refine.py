@@ -45,7 +45,7 @@ from pbccs_tpu.models.arrow.mutations import (_LN10 as _MUT_LN10,
                                               _SLOT_TYPES, DELETION,
                                               INSERTION, QV_SATURATED,
                                               SUBSTITUTION)
-from pbccs_tpu.ops.fwdbwd import BandedMatrix
+from pbccs_tpu.ops.fwdbwd import BandedMatrix, row_major
 
 N_SLOTS = 9
 EDGE_BUDGET = 64  # packed edge-mutation slab width per scoring chunk
@@ -378,14 +378,10 @@ def _state_layout(reads, rlens, win_tpl, win_trans, wlens, table,
     flat = lambda a: a.reshape((Z * R,) + a.shape[2:])
     tables = flat(jnp.broadcast_to(table[:, None],
                                    (Z, R) + table.shape[1:]))
-    alpha_f = BandedMatrix(flat(alpha.vals), flat(alpha.offsets),
-                           flat(alpha.log_scales))
-    beta_f = BandedMatrix(flat(beta.vals), flat(beta.offsets),
-                          flat(beta.log_scales))
     lay = build_dense_layout(flat(reads), flat(rlens), flat(win_tpl),
                              flat(win_trans), flat(wlens), tables,
-                             alpha_f, beta_f, flat(a_prefix),
-                             flat(b_suffix), width)
+                             *jax.tree.map(flat, (alpha, beta)),
+                             flat(a_prefix), flat(b_suffix), width)
     return jax.tree.map(lambda a: a.reshape((Z, R) + a.shape[1:]), lay)
 
 
@@ -407,16 +403,10 @@ def _score_slot_grid_dense(st: "RefineLoopState", reads, rlens, strands,
     whole grid then maps and reduces in one pass, with no packed edge
     slab, no edge budget, and no template-frame edge machinery."""
     from pbccs_tpu.ops.dense_score_pallas import (
-        band_read_windows, dense_interior_scores_batch, dense_patch_grids,
+        build_dense_layout, dense_interior_scores_batch,
         edge_window_scores_batch, splice_edge_rows, window_grid_to_template)
 
     Z, R = reads.shape[:2]
-    # pre-baked kernel layout carried in the loop state: flatten its
-    # (Z, R)-leading leaves to the call's (Z*R)-flat read batch
-    lay = st.dlayout
-    if lay is not None:
-        lay = jax.tree.map(
-            lambda a: a.reshape((Z * R,) + a.shape[2:]), lay)
     jmax = st.tpl.shape[1]
     M = jmax * N_SLOTS
 
@@ -435,13 +425,14 @@ def _score_slot_grid_dense(st: "RefineLoopState", reads, rlens, strands,
     W = st.alpha.vals.shape[-1]
     f_reads, f_rlens = flat(reads), flat(rlens)
     f_wt, f_wtr, f_wl = flat(st.win_tpl), flat(st.win_trans), flat(st.wlens)
-    alpha_f = BandedMatrix(flat(st.alpha.vals), flat(st.alpha.offsets),
-                           flat(st.alpha.log_scales))
-    beta_f = BandedMatrix(flat(st.beta.vals), flat(st.beta.offsets),
-                          flat(st.beta.log_scales))
+    alpha_f, beta_f = jax.tree.map(flat, (st.alpha, st.beta))
     f_apre, f_bsuf = flat(st.a_prefix), flat(st.b_suffix)
-    ptrans = None if lay is not None else jax.vmap(dense_patch_grids)(
-        f_wt.astype(jnp.int32), f_wtr, tables, f_wl)
+    # the kernel layout carried in the loop state, its (Z, R)-leading
+    # leaves flattened to the call's (Z*R)-flat read batch; a state
+    # without one derives it here, once for the kernel and the edge program
+    lay = jax.tree.map(flat, st.dlayout) if st.dlayout is not None else \
+        build_dense_layout(f_reads, f_rlens, f_wt, f_wtr, f_wl, tables,
+                           alpha_f, beta_f, f_apre, f_bsuf, W)
     # (read, position-block) live mask: rounds > 0 restrict candidates to
     # nearby windows, so most kernel grid cells have no valid slot and
     # can skip all compute.  A block is live iff any valid candidate
@@ -479,24 +470,17 @@ def _score_slot_grid_dense(st: "RefineLoopState", reads, rlens, strands,
             pref, idx.reshape(Z, -1), axis=1).reshape(Z, R, NB)
         live = (take(hi) - take(lo)) > 0
     live = live & real_rows[:, :, None] & st.active[:, :, None]
-    # one shared per-column read-window computation serves the interior
-    # kernel and the edge program (the edge program's former per-read
-    # dynamic slices were ~13% of device time on the round-5 profile);
-    # with a pre-baked layout even that is already done
-    rwin = None if lay is not None else \
-        band_read_windows(f_reads, alpha_f.offsets, W)
     grid_w = dense_interior_scores_batch(
         f_reads, f_rlens, f_wt, f_wtr, f_wl, tables, alpha_f, beta_f,
-        f_apre, f_bsuf, W, ptrans, live.reshape(Z * R, NB), rwin,
-        layout=lay)
+        f_apre, f_bsuf, W, live.reshape(Z * R, NB), layout=lay)
 
     # edge slots always compute (not gated behind a cond): the edge
     # program has no data dependence on the kernel output, so XLA
     # overlaps the two -- a measured win over skipping edges in the
     # rounds that don't need them
     e6 = edge_window_scores_batch(f_reads, f_rlens, f_wt, f_wtr, f_wl,
-                                  alpha_f, beta_f, f_apre, f_bsuf,
-                                  ptrans, W, rwin, layout=lay)
+                                  tables, alpha_f, beta_f, f_apre, f_bsuf,
+                                  W, layout=lay)
     grid_w = jax.vmap(splice_edge_rows)(grid_w, e6, f_wl.astype(jnp.int32))
     mapped = jax.vmap(
         lambda g, s, a, b: window_grid_to_template(g, s, a, b, jmax)
@@ -914,6 +898,10 @@ def run_refine_loop(state: "RefineLoopState", reads, rlens, strands, table,
                             jax.vmap(allowed_z)(favorable),
                             st.allowed)
 
+        # the loop carries the bands and the layout as the kernels take them
+        alpha = alpha._replace(vals=row_major(alpha.vals))
+        beta = beta._replace(vals=row_major(beta.vals))
+        dlayout = jax.tree.map(row_major, dlayout)
         return RefineLoopState(
             tpl=tpl, tlens=tlens, tstarts=tstarts, tends=tends,
             win_tpl=win_tpl, win_trans=win_trans, wlens=wlens,
@@ -964,7 +952,7 @@ def _state_specs(zmw: str, read: str,
         baselines=zr, trans_f=z, tpl_r=z, trans_r=z, active=zr,
         it=rep, done=z, converged=z, iterations=z, n_tested=z,
         n_applied=z, allowed=z, history=z, hist_n=z, overflow=rep,
-        dlayout=DenseLayout(*([zr] * 8)) if with_layout else None)
+        dlayout=DenseLayout(*([zr] * 4)) if with_layout else None)
 
 
 @functools.lru_cache(maxsize=64)

@@ -16,6 +16,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -224,3 +225,95 @@ def test_compiles_for_v5e(build, shape, one_chip, no_persistent_cache,
     assert total < HBM_BYTES, (
         f"{total / 2**30:.2f} GiB of arguments + outputs + temps does not "
         f"fit one v5e chip: {mem}")
+
+
+# --------------------------------------------------------------------------
+# the loop program moves no band only to change its order, origin or padding
+# --------------------------------------------------------------------------
+
+# Band-sized layout-only instructions the optimised loop program may hold:
+# (opcode, where it comes from (op_name under run_refine_loop), how many, why).
+# Anything else of a band tensor's element count or more fails the guard.
+ALLOWED_BAND_LAYOUT_OPS = [
+    ("copy", r"^$", 10,
+     "the program's boundary, once a dispatch: arrays cross jitted programs "
+     "in the device's default layout, column-minor where W < 128 lanes, and "
+     "the kernels take row-major (4 bands in, 4 out; and, in and out, the "
+     "72-lane patch plane where W = 64 makes it band-sized)"),
+    ("copy", r"^while/body/cond$", 1,
+     "W = 64 only: the 72-lane patch plane leaves the rebuild's branch"),
+    ("copy", r"^while/body/cond/branch_1_fun/transpose$", 1,
+     "the alpha fill's read windows, computed read-major with the layout's "
+     "and turned columns-leading for the coefficients (the kernel loads a "
+     "column by address arithmetic on its untiled leading axis)"),
+    ("copy", r"^while/body/cond/branch_1_fun/vmap\(\)/dot_general$", 2,
+     "the beta fill: the two halves of its own window matmul, turned before "
+     "its coefficients are computed (sharing the alpha fill's windows through "
+     "a reverse read wrong on the chip: fwdbwd_pallas._backward_coeffs)"),
+    ("pad", r"^while/body/cond/branch_1_fun/concatenate$", 7,
+     "inside fusions, arithmetic: band_read_windows' lane rotation and "
+     "previous-column shift (a roll is a concatenate of two slices)"),
+    ("concatenate", r"^while/body/cond/branch_1_fun/vmap\(\)/concatenate$", 2,
+     "the bf16 im2col of the reads, the window matmuls' operand (one for "
+     "read base i, one for base i-1)"),
+]
+_LAYOUT_OPCODES = ("copy", "transpose", "pad", "concatenate", "slice")
+
+
+def band_layout_ops(hlo_text: str, floor: int):
+    """(opcode, op_name under the loop program) of every layout-only HLO
+    instruction, fused or not, whose output has `floor` elements or more.
+    The Pallas kernels are opaque custom calls: nothing inside them counts."""
+    import re
+
+    shape = re.compile(r"\b(?:pred|[suf]\d+|bf16)\[([\d,]*)\]")
+    found = []
+    for line in hlo_text.splitlines():
+        m = re.match(r"^\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([a-z\-]+)\(", line)
+        if not m or m.group(2) not in _LAYOUT_OPCODES:
+            continue
+        sizes = [int(np.prod([int(d) for d in dims.split(",") if d]))
+                 for dims in shape.findall(m.group(1))]
+        if max(sizes, default=0) < floor:
+            continue
+        name = re.search(r'op_name="([^"]*)"', line)
+        where = re.sub(r"^jit\(fn\)/jit\(run_refine_loop\)/?", "",
+                       name.group(1) if name else "")
+        found.append((m.group(2), where))
+    return found
+
+
+@pytest.mark.parametrize("z,r,jmax", [(32, 32, 576), (32, 12, 2304)],
+                         ids=["500bp-32x32x576", "2kb-32x12x2304"])
+def test_loop_program_rewrites_no_band(z, r, jmax, one_chip,
+                                       no_persistent_cache, monkeypatch):
+    """In the optimised HLO of run_refine_loop at the cells' shape sets no
+    copy, transpose, pad, concatenate or slice outside the Pallas calls
+    has an output of a band tensor's element count or more, but those on
+    ALLOWED_BAND_LAYOUT_OPS.  This is what keeps the transposes, halo
+    copies, pads and per-round relayouts PR 28 took out from coming back."""
+    import collections
+    import re
+
+    from pbccs_tpu.ops import dense_score_pallas, fwdbwd, fwdbwd_pallas
+
+    monkeypatch.setattr(fwdbwd_pallas, "_interpret", lambda: False)
+    monkeypatch.setattr(dense_score_pallas, "_interpret", lambda: False)
+    jax.clear_caches()
+    try:
+        fn, shapes = _refine_loop_case(z, r, jmax)
+        text = jax.jit(fn).lower(*_on(shapes, one_chip)).compile().as_text()
+    finally:
+        jax.clear_caches()
+
+    width, _ = _bucket_statics(jmax)
+    band = z * r * fwdbwd.band_frame_rows(jmax + 1) * width
+    seen = collections.Counter(band_layout_ops(text, band))
+    assert text.count("tpu_custom_call") >= 4      # two fills, dense, edge rows
+    for (opcode, where), n in sorted(seen.items()):
+        allowed = [cap for op, rx, cap, _ in ALLOWED_BAND_LAYOUT_OPS
+                   if op == opcode and re.search(rx, where)]
+        assert allowed and n <= max(allowed), (
+            f"{n} band-sized `{opcode}` from {where or 'the program boundary'!r} "
+            f"in the loop program at {z}x{r}x{jmax}: not on "
+            "ALLOWED_BAND_LAYOUT_OPS (or more than it allows)")

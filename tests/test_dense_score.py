@@ -23,6 +23,7 @@ from pbccs_tpu.models.arrow.scorer import (  # noqa: E402
     oriented_window,
 )
 from pbccs_tpu.ops import dense_score_pallas as dsp  # noqa: E402
+from pbccs_tpu.ops import fwdbwd as fb  # noqa: E402
 from pbccs_tpu.ops.fwdbwd import BandedMatrix  # noqa: E402
 from pbccs_tpu.ops.mutation_score import (  # noqa: E402
     interior_read_scores_fast,
@@ -75,6 +76,16 @@ def _setup_case(rng, L, n_reads, windows):
                 te=jnp.asarray(te_a), win_tpl=win_tpl,
                 win_trans=win_trans, wlens=wlens, alpha=alpha, beta=beta,
                 apre=apre, bsuf=bsuf, Jmax=Jmax)
+
+
+def _framed(x, layout):
+    """A per-column (R, nc, ...) array in the band frame of `layout`."""
+    return dsp._pad_pos(x, layout.rbase.shape[1])
+
+
+def _columns(x, n):
+    """The n template columns of a framed (R, rows, ...) array."""
+    return np.asarray(x)[:, dsp._OFF0: dsp._OFF0 + n]
 
 
 def _expected_grid(case, r):
@@ -192,13 +203,10 @@ def test_edge_window_scores_match_oracle(rng, windows):
     case = _setup_case(rng, 60, 2, windows)
     R = case["reads"].shape[0]
     tables = jnp.broadcast_to(case["table"][None], (R, 8, 4))
-    ptrans = jax.vmap(dsp.dense_patch_grids)(
-        case["win_tpl"].astype(jnp.int32), case["win_trans"], tables,
-        case["wlens"])
     e6 = np.asarray(dsp.edge_window_scores_batch(
         case["reads"], case["rlens"], case["win_tpl"], case["win_trans"],
-        case["wlens"], case["alpha"], case["beta"], case["apre"],
-        case["bsuf"], ptrans, W))
+        case["wlens"], tables, case["alpha"], case["beta"], case["apre"],
+        case["bsuf"], W))
 
     for r in range(R):
         J = int(case["wlens"][r])
@@ -305,23 +313,22 @@ def test_band_read_windows_flat_offset_garbage_lane(rng):
     assert not np.array_equal(poison, rbase)
 
     tables = jnp.broadcast_to(case["table"][None], (R, 8, 4))
-    ptrans = jax.vmap(dsp.dense_patch_grids)(
-        case["win_tpl"].astype(jnp.int32), case["win_trans"], tables,
-        case["wlens"])
+    args = (case["reads"], case["rlens"], case["win_tpl"],
+            case["win_trans"], case["wlens"], tables, case["alpha"],
+            case["beta"], case["apre"], case["bsuf"], W)
+    baked = dsp.prepare_dense_layout(*args)
+
+    def probe(rb):
+        """The baked layout with its read-base windows replaced."""
+        return baked._replace(rbase=_framed(jnp.asarray(rb), baked))
 
     def interior(rb):
         return np.asarray(dsp.dense_interior_scores_batch(
-            case["reads"], case["rlens"], case["win_tpl"],
-            case["win_trans"], case["wlens"], tables, case["alpha"],
-            case["beta"], case["apre"], case["bsuf"], W,
-            rwin=(jnp.asarray(rb), jnp.asarray(rnext))))
+            *args, layout=probe(rb)))
 
     def edges(rb):
         return np.asarray(dsp.edge_window_scores_batch(
-            case["reads"], case["rlens"], case["win_tpl"],
-            case["win_trans"], case["wlens"], case["alpha"], case["beta"],
-            case["apre"], case["bsuf"], ptrans, W,
-            rwin=(jnp.asarray(rb), jnp.asarray(rnext))))
+            *args, layout=probe(rb)))
 
     int_ref, edge_ref = interior(rbase), edges(rbase)
     checked = 0
@@ -386,18 +393,13 @@ def test_prepared_layout_matches_ingraph(rng):
     ptrans = jax.vmap(dsp.dense_patch_grids)(
         case["win_tpl"].astype(jnp.int32), case["win_trans"], tables,
         case["wlens"])
-    edge_args = (case["reads"], case["rlens"], case["win_tpl"],
-                 case["win_trans"], case["wlens"], case["alpha"],
-                 case["beta"], case["apre"], case["bsuf"])
-    got_e = np.asarray(dsp.edge_window_scores_batch(
-        *edge_args, None, W, layout=layout))
-    want_e = np.asarray(dsp.edge_window_scores_batch(
-        *edge_args, ptrans, W))
+    got_e = np.asarray(dsp.edge_window_scores_batch(*args, layout=layout))
+    want_e = np.asarray(dsp.edge_window_scores_batch(*args))
     np.testing.assert_array_equal(got_e, want_e)
-    # the recovered patch plane is the one that was baked
+    # the baked patch plane holds the per-position patch grids in place
+    Jm = int(case["win_tpl"].shape[1])
     np.testing.assert_array_equal(
-        np.asarray(dsp.layout_ptrans(layout, int(case["win_tpl"].shape[1]))),
-        np.asarray(ptrans))
+        _columns(layout.ptr, Jm), np.asarray(ptrans).reshape(R, Jm, 72))
 
 
 def test_dense_scores_match_dense_oracle_prebaked(rng):
@@ -522,8 +524,8 @@ def test_band_read_windows_prebake_equivalence(rng):
             case["win_trans"], case["wlens"], tables, alpha,
             case["beta"], case["apre"], case["bsuf"], W)
     layout = dsp.prepare_dense_layout(*args)
-    np.testing.assert_array_equal(np.asarray(layout.rw_base), rbase)
-    np.testing.assert_array_equal(np.asarray(layout.rw_next), rnext)
+    np.testing.assert_array_equal(_columns(layout.rbase, nc), rbase)
+    np.testing.assert_array_equal(_columns(layout.rnext, nc), rnext)
     np.testing.assert_array_equal(
         np.asarray(dsp.dense_interior_scores_batch(*args, layout=layout)),
         np.asarray(dsp.dense_interior_scores_batch(*args)))
@@ -566,16 +568,6 @@ def test_multi_column_blocking_parity(rng, monkeypatch):
     full, masked = outs[1]
     assert np.array_equal(masked[1, : dsp._PB], full[1, : dsp._PB])
     assert not masked[1, dsp._PB: 2 * dsp._PB].any()
-
-    # whole-row mode composes with multi-column blocking (the kernel's
-    # base offset comes from the live value, not the sub-block index)
-    monkeypatch.setenv("PBCCS_WHOLE_ROW", "1")
-    monkeypatch.setenv("PBCCS_DENSE_CB", "2")
-    dsp.dense_interior_scores_batch.clear_cache()
-    dsp.prepare_dense_layout.clear_cache()
-    wr = np.asarray(dsp.dense_interior_scores_batch(
-        *args, live=jnp.asarray(live)))
-    np.testing.assert_array_equal(wr, outs[1][1])
 
 
 @pytest.mark.slow
@@ -636,3 +628,167 @@ def test_dense_patch_grids_match_make_patches(rng):
     got_t = np.asarray(ptrans).reshape(L * 9, 2, 4)
     want_t = np.asarray(ref.trans)
     np.testing.assert_allclose(got_t, want_t, rtol=1e-6, atol=1e-7)
+
+
+# --------------------------------------------------------------------------
+# PR 28: the kernel reads overlapping windows of the one framed buffer
+# --------------------------------------------------------------------------
+
+
+def _halo_blocks(x, nbc, cb):
+    """The (R, NBC, cb*_PB + _HALO, n) overlapped copy of a padded
+    (R, (NBC+1)*cb*_PB, n) input the kernel used to be handed."""
+    R, n = x.shape[0], x.shape[2:]
+    step = cb * dsp._PB
+    core = x[:, : nbc * step].reshape((R, nbc, step) + n)
+    nxt = x[:, step: (nbc + 1) * step].reshape(
+        (R, nbc, step) + n)[:, :, :dsp._HALO]
+    return jnp.concatenate([core, nxt], axis=2)
+
+
+def _scores_on_halo_copies(args, live):
+    """dense_interior_scores_batch as it launched before PR 28: every
+    position-indexed operand zero-padded, copied into halo'd step blocks
+    and handed to the same kernel body through blocked BlockSpecs."""
+    import functools
+
+    from jax.experimental import pallas as pl
+
+    reads, rlens, win_tpl, win_trans, wlens, tables, alpha, beta, apre, \
+        bsuf, width = args
+    R, Jm = win_tpl.shape
+    cb, nbc = dsp._dense_grid_shape(Jm)
+    NB = -(-Jm // dsp._PB)
+    total = (nbc + 1) * cb * dsp._PB
+    rbase, rnext = dsp.band_read_windows(reads, alpha.offsets, width)
+    ptr = jax.vmap(dsp.dense_patch_grids)(
+        win_tpl.astype(jnp.int32), win_trans, tables, wlens)
+    f32 = lambda x: x.astype(jnp.float32)
+    aux = jnp.concatenate([
+        dsp._pad_pos(f32(alpha.offsets)[:, :, None], total),
+        dsp._pad_pos(apre[:, :, None], total),
+        dsp._pad_pos(bsuf[:, :, None], total),
+        dsp._pad_pos(f32(win_tpl)[:, :, None], total),
+        dsp._pad_pos(win_trans, total)], axis=2)
+    ops = [_halo_blocks(dsp._pad_pos(x, total), nbc, cb) for x in
+           (alpha.vals, beta.vals, rbase, rnext)]
+    ops += [_halo_blocks(aux, nbc, cb),
+            _halo_blocks(dsp._pad_pos(ptr.reshape(R, Jm, 72), total), nbc, cb)]
+    live_in = jnp.pad(live.astype(jnp.int32),
+                      [(0, 0), (0, nbc * cb - NB)]).reshape(R, nbc, cb, 1)
+    PBH = cb * dsp._PB + dsp._HALO
+    blk = lambda n: pl.BlockSpec((None, None, PBH, n),
+                                 lambda r, b: (r, b, 0, 0))
+    out = pl.pallas_call(
+        functools.partial(dsp._dense_kernel, W=width, cb=cb),
+        grid=(R, nbc),
+        in_specs=[blk(width)] * 4 + [blk(8), blk(72),
+                  pl.BlockSpec((None, 1, 1), lambda r, b: (r, 0, 0)),
+                  pl.BlockSpec((None, 1, cb, 1), lambda r, b: (r, b, 0, 0))],
+        out_specs=pl.BlockSpec((None, cb * dsp._PB, dsp.N_SLOTS),
+                               lambda r, b: (r, b, 0)),
+        out_shape=jax.ShapeDtypeStruct((R, nbc * cb * dsp._PB, dsp.N_SLOTS),
+                                       jnp.float32),
+        interpret=True,
+    )(*ops, rlens[:, None, None].astype(jnp.int32), live_in)
+    return np.asarray(out[:, :Jm])
+
+
+@pytest.fixture(scope="module")
+def three_step_case():
+    """Three grid steps (cb 1, three sub-blocks), fills from the Pallas
+    kernel (a framed band) and from XLA (a plain one)."""
+    rng = np.random.default_rng(2828)
+    case = _setup_case(rng, 150, 2, [(0, 0, 150), (1, 4, 146)])
+    R = case["reads"].shape[0]
+    tables = jnp.broadcast_to(case["table"][None], (R, 8, 4))
+    plain = (case["reads"], case["rlens"], case["win_tpl"],
+             case["win_trans"], case["wlens"], tables, case["alpha"],
+             case["beta"], case["apre"], case["bsuf"], W)
+    pallas_fills = fill_alpha_beta_batch(*plain[:5], W, use_pallas=True)
+    framed = plain[:6] + (pallas_fills[0], pallas_fills[1],
+                          pallas_fills[4], pallas_fills[5], W)
+    return plain, framed
+
+
+@pytest.mark.parametrize("step", [0, 1, 2], ids=["first", "middle", "last"])
+def test_inplace_windows_match_halo_copies(three_step_case, step, monkeypatch):
+    """Each grid step's scores from element-indexed windows of the framed
+    buffers (the Pallas fill's band as written, and an XLA fill's framed
+    by band_frame) equal, bit for bit, the scores of the same kernel body
+    on the materialised halo'd copies it used to read -- with every
+    sub-block live and with only this step's live."""
+    monkeypatch.setenv("PBCCS_DENSE_CB", "1")
+    dsp.dense_interior_scores_batch.clear_cache()
+    plain, framed = three_step_case
+    Jm = int(plain[2].shape[1])
+    assert dsp._dense_grid_shape(Jm) == (1, 3)
+    rows = slice(step * dsp._PB, min((step + 1) * dsp._PB, Jm))
+    only = np.zeros((plain[0].shape[0], 3), bool)
+    only[:, step] = True
+    try:
+        for args in (plain, framed):
+            unframed = args[:6] + (fb.band_columns(args[6]),
+                                   fb.band_columns(args[7])) + args[8:]
+            for live in (np.ones_like(only), only):
+                got = np.asarray(dsp.dense_interior_scores_batch(
+                    *args, live=jnp.asarray(live)))
+                want = _scores_on_halo_copies(unframed, jnp.asarray(live))
+                assert got.shape == want.shape
+                np.testing.assert_array_equal(got[:, rows], want[:, rows])
+                assert np.abs(want[:, rows]).sum() > 0
+    finally:
+        dsp.dense_interior_scores_batch.clear_cache()
+
+
+@pytest.mark.parametrize("strand", [0, 1], ids=["forward", "reverse"])
+def test_edge_program_on_framed_layout_matches_oracle(strand):
+    """The edge program fed the Pallas fills' framed bands and the baked
+    layout (its rows fetched by the window kernel) equals
+    edge_scores_fast on a near-begin and a near-end slot of every kind."""
+    from pbccs_tpu.ops.mutation_score import edge_scores_fast
+
+    rng = np.random.default_rng(28 + strand)
+    case = _setup_case(rng, 60, 2, [(strand, 0, 60), (strand, 3, 57)])
+    R = case["reads"].shape[0]
+    tables = jnp.broadcast_to(case["table"][None], (R, 8, 4))
+    five = (case["reads"], case["rlens"], case["win_tpl"],
+            case["win_trans"], case["wlens"])
+    alpha, beta, _, _, apre, bsuf = fill_alpha_beta_batch(
+        *five, W, use_pallas=True)
+    assert fb.band_lead(alpha) == fb.BAND_LEAD
+    args = five + (tables, alpha, beta, apre, bsuf, W)
+    layout = dsp.prepare_dense_layout(*args)
+    e6 = np.asarray(dsp.edge_window_scores_batch(*args, layout=layout))
+
+    checked = 0
+    for r in range(R):
+        J = int(case["wlens"][r])
+        wt = case["win_tpl"][r].astype(jnp.int32)
+        for row, p in ((0, 0), (1, 1), (4, J - 1), (5, J)):
+            for k in (1, 6, 8):                    # sub C, ins G, del
+                mtype = [0, 0, 0, 0, 1, 1, 1, 1, 2][k]
+                nbase = [0, 1, 2, 3, 0, 1, 2, 3, 0][k]
+                if (mtype != 1 and p >= J) or \
+                        (mtype == 0 and int(wt[p]) == nbase):
+                    continue
+                patch = make_patches_fast(
+                    wt, case["win_trans"][r], case["table"], jnp.int32(J),
+                    jnp.asarray([p], jnp.int32),
+                    jnp.asarray([mtype], jnp.int32),
+                    jnp.asarray([nbase], jnp.int32))
+                want = float(edge_scores_fast(
+                    case["reads"][r].astype(jnp.int32), case["rlens"][r],
+                    wt, case["win_trans"][r], case["wlens"][r],
+                    BandedMatrix(alpha.vals[r], alpha.offsets[r],
+                                 alpha.log_scales[r]),
+                    BandedMatrix(beta.vals[r], beta.offsets[r],
+                                 beta.log_scales[r]),
+                    apre[r], bsuf[r], jnp.asarray([p], jnp.int32),
+                    jnp.asarray([mtype], jnp.int32), patch.bases,
+                    patch.trans, patch.shift)[0])
+                np.testing.assert_allclose(
+                    e6[r, row, k], want, rtol=2e-5, atol=2e-3,
+                    err_msg=f"read {r} row {row} slot {k}")
+                checked += 1
+    assert checked >= 16

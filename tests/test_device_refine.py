@@ -375,3 +375,59 @@ def test_template_hash_distinguishes(rng):
     p3 = pad.copy()
     p3[50] = 0  # beyond tlen: must not affect the hash
     assert int(dr.template_hash(jnp.asarray(p3), jnp.int32(40))) == h0
+
+
+@pytest.mark.parametrize("what", ["templates", "qvs", "converged",
+                                  "iterations", "n_tested", "n_applied"])
+def test_loop_and_qv_sweep_give_the_parents_outputs(what, pr28_loop_run):
+    """run_refine_loop and run_qv_ints end to end at one small bucket, the
+    dense kernel and the Pallas fills interpreted: the templates, QVs and
+    counters PR 27's tree gave for the same three ZMWs
+    (tests/fixtures/pr28/refine_loop_parent.json), and a loop state that
+    carries the framed bands and the four-leaf layout."""
+    got, want = pr28_loop_run
+    assert got[what] == want[what]
+
+
+@pytest.fixture(scope="module")
+def pr28_loop_run():
+    import json
+    import os
+
+    from pbccs_tpu.models.arrow.refine import RefineOptions
+    from pbccs_tpu.ops import fwdbwd as fb
+    from pbccs_tpu.parallel.batch import BatchPolisher, ZmwTask
+    from pbccs_tpu.simulate import simulate_zmw
+
+    mp = pytest.MonkeyPatch()
+    for k in ("PBCCS_DENSE", "PBCCS_PALLAS", "PBCCS_DEVICE_REFINE"):
+        mp.setenv(k, "1")
+    try:
+        rng = np.random.default_rng(2828)
+        tasks = []
+        for z in range(3):
+            tpl, reads, strands, snr = simulate_zmw(rng, 70, 5)
+            draft = tpl.copy()
+            draft[15 + 9 * z] = (draft[15 + 9 * z] + 1) % 4
+            draft = np.delete(draft, 40 + z)
+            tasks.append(ZmwTask(f"pr28/{z}", draft, snr, reads, strands,
+                                 [0] * 5, [len(draft)] * 5))
+        p = BatchPolisher(tasks)
+        st = p._loop_state(set())
+        assert fb.band_lead(st.alpha) == fb.band_lead(st.beta) == fb.BAND_LEAD
+        assert len(st.dlayout) == 4
+        assert {a.shape[2] for a in st.dlayout} == {st.alpha.vals.shape[2]}
+        res = p.refine_device(RefineOptions(max_iterations=10))
+        qvs = p.consensus_qvs()
+    finally:
+        mp.undo()
+    got = {"templates": [np.asarray(t).tolist() for t in p.tpls],
+           "qvs": [np.asarray(q).tolist() for q in qvs],
+           "converged": [bool(r.converged) for r in res],
+           "iterations": [int(r.iterations) for r in res],
+           "n_tested": [int(r.n_tested) for r in res],
+           "n_applied": [int(r.n_applied) for r in res]}
+    path = os.path.join(os.path.dirname(__file__), "fixtures", "pr28",
+                        "refine_loop_parent.json")
+    with open(path) as f:
+        return got, json.load(f)

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import pytest
 
 from pbccs_tpu.models.arrow.params import (
+    MISMATCH_PROBABILITY,
     snr_to_transition_table_host,
     template_transition_params,
 )
@@ -62,6 +63,12 @@ def _batch(rng, specs, Imax, Jmax, snr=8.0):
 WIDTH = 48
 
 
+def _plain(fills):
+    """fill_alpha_beta_batch's outputs with the bands by template column."""
+    alpha, beta, *rest = fills
+    return (fb.band_columns(alpha), fb.band_columns(beta), *rest)
+
+
 @pytest.fixture(scope="module")
 def batch():
     rng = np.random.default_rng(20260730)
@@ -71,7 +78,8 @@ def batch():
 
 def test_forward_matches_jax_path(batch):
     reads, rlens, tpls, trans, tlens = batch
-    pa = fp.pallas_forward_batch(reads, rlens, tpls, trans, tlens, WIDTH)
+    pa = fb.band_columns(
+        fp.pallas_forward_batch(reads, rlens, tpls, trans, tlens, WIDTH))
     for r in range(reads.shape[0]):
         a = fb.banded_forward(reads[r], rlens[r], tpls[r], trans[r], tlens[r], WIDTH)
         np.testing.assert_allclose(np.asarray(pa.vals[r]), np.asarray(a.vals),
@@ -84,7 +92,8 @@ def test_forward_matches_jax_path(batch):
 
 def test_backward_matches_jax_path(batch):
     reads, rlens, tpls, trans, tlens = batch
-    pb = fp.pallas_backward_batch(reads, rlens, tpls, trans, tlens, WIDTH)
+    pb = fb.band_columns(
+        fp.pallas_backward_batch(reads, rlens, tpls, trans, tlens, WIDTH))
     for r in range(reads.shape[0]):
         b = fb.banded_backward(reads[r], rlens[r], tpls[r], trans[r], tlens[r], WIDTH)
         np.testing.assert_allclose(np.asarray(pb.vals[r]), np.asarray(b.vals),
@@ -117,7 +126,8 @@ def test_fill_dispatch_forced_pallas(monkeypatch, batch):
     monkeypatch.delenv("PBCCS_PALLAS", raising=False)
     ref = fill_alpha_beta_batch(reads, rlens, tpls, trans, tlens, WIDTH)
     monkeypatch.setenv("PBCCS_PALLAS", "1")
-    got = fill_alpha_beta_batch(reads, rlens, tpls, trans, tlens, WIDTH)
+    got = _plain(fill_alpha_beta_batch(reads, rlens, tpls, trans, tlens,
+                                       WIDTH))
     for g, r in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
         np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=2e-3)
 
@@ -132,8 +142,8 @@ def test_wide_band_half_column_blocks_match_jax_path(batch):
     wide = 160
     ref = fill_alpha_beta_batch(reads, rlens, tpls, trans, tlens, wide,
                                 use_pallas=False)
-    got = fill_alpha_beta_batch(reads, rlens, tpls, trans, tlens, wide,
-                                use_pallas=True)
+    got = _plain(fill_alpha_beta_batch(reads, rlens, tpls, trans, tlens,
+                                       wide, use_pallas=True))
     for g, r in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
         np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=2e-3)
 
@@ -164,3 +174,106 @@ def test_band_shift_clamp_drops_read_not_crashes():
     # the clamped band cannot represent this read: it must be deterministically
     # droppable (LL at the log-tiny floor), not silently mis-scored
     assert ll[0] < -60.0
+
+
+# --------------------------------------------------------------------------
+# PR 28: the fill writes the framed, read-major band itself
+# --------------------------------------------------------------------------
+
+
+def _unframed_fill(reads, rlens, tpls, trans, tlens, W, backward):
+    """The fill as it ran before the frame: the kernel's columns are the
+    template's (padded to a step), each coefficient builder makes its own
+    read windows, and the band is cut out of the kernel's output by XLA."""
+    R, Imax = reads.shape
+    Jmax = tpls.shape[1]
+    nc = fp._pad_cols(Jmax + 1)
+    I, J = rlens.astype(jnp.int32), tlens.astype(jnp.int32)
+    offs = jax.vmap(lambda i, j: fb.band_offsets(i, j, nc, W))(I, J)
+
+    def one(r, i, t, tr, j, o):
+        r, t = r.astype(jnp.int32), t.astype(jnp.int32)
+        if backward:
+            return fp._backward_coeffs(r, i, t, tr, j, o, W,
+                                       MISMATCH_PROBABILITY, nc, top=Jmax)
+        rb = fp.window_rows_circ(jnp.concatenate([r[0:1], r]), o, W)
+        return fp._forward_coeffs(r, i, t, tr, j, o, rb, W,
+                                  MISMATCH_PROBABILITY)
+
+    cm, cd, cc, mask, seed, seedcol = jax.vmap(
+        one, out_axes=(1, 1, 1, 1, 0, 0))(reads, I, tpls, trans, J, offs)
+    Rp = fp._pad_reads(R)
+    cm, cd, cc, mask = fp._pad_r([cm, cd, cc, mask], R, Rp, axis=1)
+    seed, seedcol = fp._pad_r([seed, seedcol], R, Rp)
+    vals, ls = fp._run_fill(cm, cd, cc, mask, seed, seedcol,
+                            rev_store=backward)
+    lo = nc - 1 - Jmax if backward else 0
+    return (np.asarray(vals[:R, lo: lo + Jmax + 1]),
+            np.asarray(ls[:R, lo: lo + Jmax + 1, 0]),
+            np.asarray(offs[:, : Jmax + 1]))
+
+
+FRAME_CASES = {
+    "500bp-W64-R32": dict(R=32, Jmax=576, W=64, Imax=640),
+    "2kb-W96-R12": dict(R=12, Jmax=2304, W=96, Imax=2560),
+    "retry-W192-R8": dict(R=8, Jmax=1152, W=192, Imax=1280),
+    "R40-not-a-block": dict(R=40, Jmax=128, W=48, Imax=192),
+}
+
+
+@pytest.fixture(scope="module", params=list(FRAME_CASES))
+def frame_case(request):
+    c = FRAME_CASES[request.param]
+    rng = np.random.default_rng(28 + c["R"])
+    specs = [(0, int(rng.integers(c["Jmax"] // 2, c["Jmax"] - 3)))
+             for _ in range(c["R"])]
+    specs[0] = (0, c["Jmax"] - 1)            # one window fills the bucket
+    return c, _batch(rng, specs, Imax=c["Imax"], Jmax=c["Jmax"])
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["alpha", "beta"])
+def test_framed_fill_is_the_unframed_fill(frame_case, backward):
+    """The band the kernel writes read-major in the dense kernel's row
+    frame holds, column for column and bit for bit, what the kernel wrote
+    columns-leading in the template's own frame; the rows around the
+    columns are zero; and it is ops/fwdbwd's band to float32 rounding
+    (the two scans associate differently, as they always have)."""
+    c, (reads, rlens, tpls, trans, tlens) = frame_case
+    fill = fp.pallas_backward_batch if backward else fp.pallas_forward_batch
+    got = fill(reads, rlens, tpls, trans, tlens, c["W"])
+    n = c["Jmax"] + 1
+    assert got.vals.shape == (c["R"], fb.band_frame_rows(n), c["W"])
+    assert fb.band_lead(got) == fb.BAND_LEAD
+    vals, ls, offs = _unframed_fill(reads, rlens, tpls, trans, tlens,
+                                    c["W"], backward)
+    plain = fb.band_columns(got)
+    np.testing.assert_array_equal(np.asarray(plain.vals), vals)
+    np.testing.assert_array_equal(np.asarray(plain.log_scales), ls)
+    np.testing.assert_array_equal(np.asarray(plain.offsets), offs)
+    framed = np.asarray(got.vals)
+    assert not framed[:, : fb.BAND_LEAD].any()
+    assert not framed[:, fb.BAND_LEAD + n:].any()
+    xla = fb.banded_backward if backward else fb.banded_forward
+    for r in (0, c["R"] - 1):
+        ref = xla(reads[r], rlens[r], tpls[r], trans[r], tlens[r], c["W"])
+        np.testing.assert_allclose(vals[r], np.asarray(ref.vals), atol=1e-5)
+        np.testing.assert_allclose(ls[r], np.asarray(ref.log_scales),
+                                   atol=1e-5)
+
+
+def test_band_frame_of_a_plain_band_round_trips(batch):
+    """band_frame (what the Pallas fill makes unnecessary) puts an XLA
+    fill's band where the Pallas fill writes its own, and band_columns
+    takes either back."""
+    reads, rlens, tpls, trans, tlens = batch
+    plain = jax.vmap(lambda r, i, t, tr, j: fb.banded_forward(
+        r, i, t, tr, j, WIDTH))(reads, rlens, tpls, trans, tlens)
+    framed = fb.band_frame(plain)
+    assert fb.band_lead(plain) == 0 and fb.band_lead(framed) == fb.BAND_LEAD
+    pallas = fp.pallas_forward_batch(reads, rlens, tpls, trans, tlens, WIDTH)
+    assert framed.vals.shape == pallas.vals.shape
+    np.testing.assert_allclose(np.asarray(framed.vals),
+                               np.asarray(pallas.vals), atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(fb.band_columns(framed).vals),
+                                  np.asarray(plain.vals))
+    assert fb.band_frame(framed) is framed

@@ -69,6 +69,8 @@ def two_batches_of_32(tmp_path_factory):
         return out
 
     pbatch.shape_menu.reset_for_tests()
+    with pbatch._shape_sets_lock:       # sets an earlier test file of this
+        pbatch._shape_sets_seen.clear()  # worker's process built count anew
     sets_before = counter_total(SHAPE_SETS)
     pbatch.BatchPolisher.refine = refine_then_leave_one_behind
     try:

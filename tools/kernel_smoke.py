@@ -85,10 +85,7 @@ def main() -> int:
     # and blow the budget.)
     layout = dsp.prepare_dense_layout(*args)
     grid = np.asarray(dsp.dense_interior_scores_batch(*args, layout=layout))
-    edge_args = (jnp.asarray(reads_p), jnp.asarray(rlens), win_tpl,
-                 win_trans, wlens, alpha, beta, apre, bsuf)
-    e6 = np.asarray(dsp.edge_window_scores_batch(
-        *edge_args, None, W, layout=layout))
+    e6 = np.asarray(dsp.edge_window_scores_batch(*args, layout=layout))
 
     # f64 dense oracle over every served slot of every read
     slot_mt = [0, 0, 0, 0, 1, 1, 1, 1, 2]
