@@ -1,16 +1,14 @@
 #!/usr/bin/env python
-"""Dump the bench.py workload in the text format refbench.cpp consumes.
+"""Dump a polish workload in the text format refbench.cpp consumes.
 
-Reproduces bench.build_tasks with the same seed, so the reference C++
-baseline measures the identical 128 ZMWs the TPU bench polishes (first
-draw; bench.py's timed repeats draw fresh but statistically identical
-workloads from the same stream).
+Draws pbccs_tpu.simulate.build_tasks from seed 20260729, so the
+reference C++ measures the ZMWs a same-seed run of this framework
+polishes.
 
 Usage: python native/refbench/dump_workload.py [OUT.txt]
-Env knobs mirror bench.py: BENCH_ZMWS/BENCH_TPL_LEN/BENCH_PASSES/
-BENCH_CORRUPTIONS, plus REFBENCH_ITERS (default 10, = bench.py's
-RefineOptions.max_iterations) and REFBENCH_MIN_ZSCORE (default -5, the
-reference CLI default).
+The workload is 128 ZMWs x 300 bp x 8 passes with 2 corruptions a draft.
+Env knobs: REFBENCH_ITERS (default 10), REFBENCH_MIN_ZSCORE (default -5,
+the reference CLI default) and REFBENCH_DRAW.
 """
 
 from __future__ import annotations
@@ -25,15 +23,10 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 def main() -> None:
     import numpy as np
 
-    from bench import build_tasks
     from pbccs_tpu.models.arrow.params import decode_bases
+    from pbccs_tpu.simulate import build_tasks, parse_passes
 
-    from bench import parse_passes
-
-    n_zmws = int(os.environ.get("BENCH_ZMWS", 128))
-    tpl_len = int(os.environ.get("BENCH_TPL_LEN", 300))
-    n_passes = os.environ.get("BENCH_PASSES", "8")   # "8" or "3-10" range
-    n_corr = int(os.environ.get("BENCH_CORRUPTIONS", 2))
+    n_zmws, tpl_len, n_passes, n_corr = 128, 300, "8", 2
     iters = int(os.environ.get("REFBENCH_ITERS", 10))
     min_z = float(os.environ.get("REFBENCH_MIN_ZSCORE", -5.0))
 
@@ -41,11 +34,8 @@ def main() -> None:
 
     rng = np.random.default_rng(20260729)
     tasks, _truths = build_tasks(rng, n_zmws, tpl_len, n_passes, n_corr)
-    # REFBENCH_DRAW=k dumps the k-th draw of the stream (default 1).
-    # bench.py scores ACCURACY on draw #2 (warmup consumes draw #1, the
-    # first timed repeat is draw #2), so converged/mean_qv comparisons
-    # against the framework artifact must dump draw 2 -- throughput is
-    # draw-invariant, accuracy is not (docs/ACCURACY.md).
+    # REFBENCH_DRAW=k dumps the k-th draw of the stream (default 1):
+    # throughput is draw-invariant, accuracy is not (docs/ACCURACY.md)
     for _ in range(int(os.environ.get("REFBENCH_DRAW", 1)) - 1):
         tasks, _truths = build_tasks(rng, n_zmws, tpl_len, n_passes, n_corr)
 

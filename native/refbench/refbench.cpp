@@ -1,6 +1,7 @@
 // Honest CPU baseline harness: drives the REFERENCE ConsensusCore Arrow
 // implementation (compiled unmodified from /root/reference, -O3 -msse3)
-// on the exact workload bench.py measures, and reports ZMWs/sec.
+// on the workload pbccs_tpu.simulate.build_tasks draws, and reports
+// ZMWs/sec.
 //
 // This is the "faithful reimplementation" clause of BASELINE.md satisfied
 // with the original implementation itself: AddRead (FillAlphaBeta), the
@@ -13,7 +14,8 @@
 // Quiver header chain, which needs much more of Boost than the shim set
 // under stubs/ provides.
 //
-// Workload file (produced by dump_workload.py, identical ZMWs to bench.py):
+// Workload file (produced by dump_workload.py: the ZMWs a same-seed
+// build_tasks call gives):
 //   CONFIG <n_zmws> <tpl_len> <n_passes> <max_iterations> <min_zscore>
 //   ZMW <id> <snrA> <snrC> <snrG> <snrT> <n_reads>
 //   DRAFT <acgt-string>
@@ -255,8 +257,7 @@ int main(int argc, char** argv)
         repSecs.push_back(std::chrono::duration<double>(t1 - t0).count());
     }
 
-    // median run time: same statistic bench.py reports for the device,
-    // so the vs_reference_cpp ratio compares like with like
+    // median run time over the repetitions
     std::sort(repSecs.begin(), repSecs.end());
     double medSec = repSecs[repSecs.size() / 2];
     double zps = w.zmws.size() / medSec;

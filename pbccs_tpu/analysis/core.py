@@ -12,7 +12,7 @@ every pass shares:
   * SourceFile -- a parsed source with its inline-suppression map
     (`# ccs-analyze: ignore[RULE,...]` on the flagged line);
   * repo scanning -- which files each pass sees (code passes scan
-    pbccs_tpu/, tools/, bench.py, chip_smoke.py; tests and fixtures are
+    pbccs_tpu/, tools/, chip_smoke.py; tests and fixtures are
     never scanned);
   * small AST helpers (dotted-name resolution, module string constants)
     used by more than one pass.
@@ -163,8 +163,7 @@ def load_source(path: pathlib.Path, root: pathlib.Path
 
 
 # what the code passes scan, relative to the repo root
-SCAN_ROOTS = ("pbccs_tpu", "tools", "bench.py", "chip_smoke.py",
-              "__graft_entry__.py")
+SCAN_ROOTS = ("pbccs_tpu", "tools", "chip_smoke.py", "__graft_entry__.py")
 SKIP_DIRS = {"__pycache__", ".git", "tests", "native", "fixtures"}
 
 

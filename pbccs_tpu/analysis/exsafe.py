@@ -32,7 +32,7 @@ publish: the receiver owns the handle, and the lint only checks the
 structural forms it can reason about (with-item, simple assignment,
 bare statement).  Read-mode opens and unresolvable modes never flag.
 
-Scope: package sources only (`pbccs_tpu/`); tools/ and bench.py are
+Scope: package sources only (`pbccs_tpu/`); tools/ holds
 operator scripts whose scratch artifacts are not product outputs.
 Path-scoped runs (fixtures, `ccs analyze file.py`) check every given
 file.

@@ -399,11 +399,6 @@ def run(argv: list[str] | None = None) -> int:
         from pbccs_tpu.obs.console import run_top
 
         return run_top(argv[1:])
-    if argv and argv[0] == "roofline":
-        # `ccs roofline`: per-bucket CostCard bound vs measured report
-        from pbccs_tpu.obs.roofline import run_roofline
-
-        return run_roofline(argv[1:])
     args = build_parser().parse_args(argv)
     apply_resilience_args(args)
 

@@ -32,7 +32,7 @@ import at module load, so the CLI's argument errors stay fast):
 
 `runtime/timing.py` keeps its historical module-level API as a
 back-compat shim over the default registry, so existing callers
-(bench.py, engine status) see identical semantics.
+(engine status) see identical semantics.
 """
 
 from pbccs_tpu.obs.metrics import (  # noqa: F401

@@ -158,12 +158,6 @@ def _replica_row(name: str | None, metrics: dict, prev: dict | None,
             "padding_waste": _metric(
                 metrics, "ccs_refine_padding_waste", name),
         },
-        roofline={
-            "efficiency": _metric(
-                metrics, "ccs_roofline_efficiency_overall", name),
-            "achieved_tflops": _metric(
-                metrics, "ccs_roofline_achieved_tflops_overall", name),
-        },
     )
     # window figures need a previous sample of the same replica
     throughput = None
@@ -270,7 +264,7 @@ def render_text(view: dict[str, Any]) -> str:
         + ("" if view["fleet"].get("accepting", True) else "[DRAINING] "),
         f"{'REPLICA':<22} {'UP':>3} {'ZMW/S':>8} {'QDEPTH':>6} "
         f"{'INFLT':>6} {'SLO-BURN':>9} {'CONV':>6} {'OCC':>6} "
-        f"{'PADW':>6} {'EFF':>9}",
+        f"{'PADW':>6}",
     ]
     for r in view["replicas"]:
         if r.get("absent"):
@@ -290,7 +284,6 @@ def render_text(view: dict[str, Any]) -> str:
         burn = slo.get("window_burn_rate",
                        slo.get("violation_rate"))
         ref = r.get("refine", {})
-        rl = r.get("roofline", {})
         lines.append(
             f"{r['replica']:<22} {'y':>3} "
             f"{_fmt(r.get('throughput_zmws_per_sec'), 8, 2)} "
@@ -299,8 +292,7 @@ def render_text(view: dict[str, Any]) -> str:
             f"{_fmt(burn, 9, 4)} "
             f"{_fmt(ref.get('converged_fraction'), 6, 3)} "
             f"{_fmt(ref.get('slot_occupancy'), 6, 3)} "
-            f"{_fmt(ref.get('padding_waste'), 6, 3)} "
-            f"{_fmt(rl.get('efficiency'), 9, 6)}")
+            f"{_fmt(ref.get('padding_waste'), 6, 3)}")
     tenancy = view["fleet"].get("tenancy")
     if tenancy:
         shedding = " [SHEDDING]" if tenancy.get("shedding") else ""

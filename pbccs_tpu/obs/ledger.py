@@ -1,16 +1,15 @@
 """Performance ledger: schema-versioned NDJSON per-run perf records.
 
-The bench/trace/metrics planes are write-only: bench rows, span rollups,
-and the federated exposition are produced and never *watched*, so a
-kernel_fraction slide or a compile-count blowup survives until a human
+The trace/metrics planes are write-only: span rollups and the
+federated exposition are produced and never *watched*, so a
+padding-waste slide or a compile-count blowup survives until a human
 re-reads JSON.  The ledger is the machine-readable record the
 regression sentinel (tools/perf_gate.py) defends baselines against and
 the substrate ROADMAP's continuous-batching and autopilot items key on:
 
-  * one NDJSON record per run/row/snapshot, appended to ``--perfLedger
-    PATH`` by the batch CLI, per bench row by bench.py, and
-    periodically by the serve engine (plus per-replica records merged
-    fleet-wide by `ccs router --perfLedger`);
+  * one NDJSON record per run/snapshot, appended to ``--perfLedger
+    PATH`` by the batch CLI and periodically by the serve engine (plus
+    per-replica records merged fleet-wide by `ccs router --perfLedger`);
   * every field carries a TOLERANCE CLASS (``LEDGER_FIELDS``) the gate
     keys enforcement on -- wall-clock metrics are noisy and
     accelerator-only, CPU-deterministic counters are exact everywhere
@@ -39,7 +38,7 @@ from typing import Any
 
 from pbccs_tpu.obs.metrics import MeasurementScope, default_registry
 
-LEDGER_SCHEMA_VERSION = 1
+LEDGER_SCHEMA_VERSION = 2
 
 # Tolerance classes (what tools/perf_gate.py enforces per class):
 #   meta     identity/environment fields -- recorded, never gated
@@ -48,8 +47,8 @@ LEDGER_SCHEMA_VERSION = 1
 #            enforced only on accelerator hosts (CPU wall time is noise)
 #   resource host-memory figures: relative band, accelerator hosts only
 #   counter  CPU-deterministic counts: exact match, enforced everywhere
-#   ratio    CPU-deterministic ratios/shares (fill, padding, region
-#            shares): absolute band, enforced everywhere
+#   ratio    CPU-deterministic ratios (fill, padding): absolute band,
+#            enforced everywhere
 #   compile  compile/cache counts: exact match everywhere, but only
 #            when the ledger's jax_version matches the baseline's (a
 #            jax upgrade legitimately changes compile behavior)
@@ -63,7 +62,7 @@ LEDGER_CLASSES = ("meta", "live", "wall", "resource", "counter", "ratio",
 LEDGER_FIELDS = {
     # ---- identity / environment (meta) ----
     "schema_version": "meta",
-    "kind": "meta",            # batch_run | bench_row | serve_snapshot |
+    "kind": "meta",            # batch_run | serve_snapshot |
     #                            router_snapshot | replica_snapshot |
     #                            fleet_event | tenant_snapshot
     "t_unix": "meta",
@@ -79,10 +78,6 @@ LEDGER_FIELDS = {
     "device_wait_s": "wall",
     "device_step_ms": "wall",  # mean device fetch-to-fetch step
     "compile_s": "wall",       # warmup/compile seconds where measured
-    # roofline rates: flops-charged / refine wall (a timing, so wall
-    # class -- but also floor-gated via PERF_BASELINE.json "floors")
-    "roofline_achieved_tflops": "wall",
-    "roofline_efficiency": "wall",
     # ---- host memory (resource) ----
     "peak_rss_bytes": "resource",
     # ---- CPU-deterministic counters (exact everywhere) ----
@@ -105,19 +100,12 @@ LEDGER_FIELDS = {
     "oom_ceilings": "counter",
     "admission_presplits": "counter",
     "budget_throttles": "counter",
-    # roofline plane (obs/roofline.py): CostCard-bound work charged for
-    # executed canonical programs -- integer-scaled from the card, so
-    # deterministic wherever the card is (same jax build)
-    "roofline_flops": "counter",
-    "roofline_bytes": "counter",
-    # ---- CPU-deterministic ratios/shares (absolute band everywhere) ----
+    # ---- CPU-deterministic ratios (absolute band everywhere) ----
     "fill_ratio_zmw": "ratio",
     "fill_ratio_read": "ratio",
     "padding_waste": "ratio",
     "slot_occupancy": "ratio",
     "converged_fraction": "ratio",
-    "kernel_fraction": "ratio",
-    "region_shares": "ratio",  # {region: share of device self-time}
     # ---- compile/cache counts (exact iff jax_version matches) ----
     "compiles": "compile",
     "compile_cache_hits": "compile",
@@ -142,7 +130,7 @@ LEDGER_FIELDS = {
     "queue_depth": "live",
     "replica": "live",
     # ---- multi-tenant edge (kind == "tenant_snapshot" accounting rows
-    # from the router's fair queue, plus bench noisy-neighbor figures) ----
+    # from the router's fair queue) ----
     "tenant": "meta",            # tenant name the record is about
     "tenant_priority": "meta",   # shed class (0 = never shed)
     "tenant_inflight": "live",
@@ -150,8 +138,6 @@ LEDGER_FIELDS = {
     "tenant_completed": "live",
     "tenant_sheds": "live",
     "tenant_rejects": "live",
-    "tenant_p99_ms": "wall",     # per-tenant p99 under contention
-    "tenant_b_p99_gain": "wall",  # victim p99 fairness-off / fairness-on
 }
 
 _reg = default_registry()
@@ -338,14 +324,12 @@ def run_record(scope: MeasurementScope, *, kind: str, source: str,
                wall_s: float | None = None,
                zmws: int | None = None,
                results: int | None = None,
-               kernel_fraction: float | None = None,
-               region_shares: dict | None = None,
                extra: dict | None = None) -> dict[str, Any]:
     """Build one ledger record from a MeasurementScope's registry deltas
     plus caller-known figures.  The scope supplies every counter the
     registry already tracks (compiles, refine rounds, slot fills,
     governor interventions); the caller supplies what only it knows
-    (wall time, workload identity, region attribution)."""
+    (wall time, workload identity)."""
     from pbccs_tpu.resilience.resources import peak_rss_bytes
 
     # ONE registry snapshot for the whole record (scope.counter_value
@@ -400,26 +384,6 @@ def run_record(scope: MeasurementScope, *, kind: str, source: str,
         rec["fill_ratio_read"] = round(rused / rslots, 4)
     if fetches and wait_s:
         rec["device_step_ms"] = round(wait_s * 1e3 / fetches, 4)
-    # roofline plane (obs/roofline.py): CostCard-bound work charged over
-    # this window.  Absent when no card was available (degraded path) --
-    # the gate only compares fields both sides carry.
-    rl_flops = _counter_sum(delta, "ccs_roofline_flops_total")
-    if rl_flops > 0:
-        rec["roofline_flops"] = rl_flops
-        rec["roofline_bytes"] = _counter_sum(
-            delta, "ccs_roofline_bytes_total")
-        rl_wall = float(sum(
-            v for (n, _), v in delta.items()
-            if n == "ccs_roofline_refine_seconds_total"
-            and isinstance(v, (int, float))))
-        if rl_wall > 0:
-            from pbccs_tpu.obs import roofline as _roofline
-            achieved = rl_flops / 1e12 / rl_wall
-            peak = _roofline.tracker().peak_tflops()
-            rec["roofline_achieved_tflops"] = float(f"{achieved:.6g}")
-            if peak:
-                rec["roofline_efficiency"] = float(
-                    f"{achieved / peak:.6g}")
     if workload is not None:
         rec["workload"] = workload
     if wall_s is not None:
@@ -430,14 +394,6 @@ def run_record(scope: MeasurementScope, *, kind: str, source: str,
         rec["zmws"] = int(zmws)
     if results is not None:
         rec["results"] = int(results)
-    if kernel_fraction is not None:
-        rec["kernel_fraction"] = round(float(kernel_fraction), 4)
-    if region_shares:
-        total = sum(region_shares.values())
-        if total > 0:
-            rec["region_shares"] = {
-                k: round(v / total, 4)
-                for k, v in sorted(region_shares.items())}
     if extra:
         rec.update(extra)
     return rec

@@ -8,7 +8,7 @@ to runtime/timing.py's module globals).  Design constraints, in order:
     on (name, labels), so hot paths hold a direct reference);
   * concurrent measurement windows: values are MONOTONE (counters and
     histogram buckets only grow); a MeasurementScope snapshots the
-    registry and reports deltas, so bench.py and a live serving engine
+    registry and reports deltas, so a batch run and a live serving engine
     can window the same registry without clobbering each other (the old
     timing.reset() zeroed shared globals under everyone);
   * standard exposition: render_prometheus() emits the Prometheus text

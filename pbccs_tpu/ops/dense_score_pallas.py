@@ -3,14 +3,9 @@
 The round-3 device profile showed the chunked
 mutation-scoring programs are HBM-bandwidth-bound: every elementwise step of
 the packed (Z, R, chunk, W) pipeline materializes a ~1.6 GB intermediate.
-This kernel replaced that path.  Its achieved-vs-bound gap is no longer
-quoted here as hard-coded milliseconds (the round-5 snapshot figures
-rotted as the kernel evolved): the live bound is the per-bucket XLA
-CostCard and the measured side is the roofline plane's per-dispatch
-timing -- run `ccs roofline` (or read the ccs_roofline_* gauges /
-docs/PROFILE_r06.md for the attribution method).  The round-6 gap was
-attacked by this file's multi-column blocking, 8-lane aux packing, and
-prepare-time layout pre-bake (DenseLayout).  The kernel evaluates the
+This kernel replaced that path.  Its achieved-vs-bound gap is not
+quoted here: the benchmark's `dense_roofline` (benchmark/kernels/dense.py
+over the device trace) measures it on the chip.  The kernel evaluates the
 Extend(2 cols)+Link algebra
 (reference ConsensusCore/src/C++/Arrow/SimpleRecursor.cpp:373-487, :306-357)
 for EVERY slot of the position-major mutation grid (9 slots per template
@@ -107,12 +102,10 @@ def _interpret() -> bool:
 def dense_cols_per_step(nb: int | None = None) -> int:
     """Multi-column blocking: how many _PB-row position sub-blocks one
     kernel grid step processes (amortizing the per-step scan/setup and
-    pipeline-fetch overhead that dominated the round-5 kernel interior,
-    where the dense kernel ran far above its op-count bound with one _PB
-    block per step; today's measured multiple is the roofline plane's
-    achieved-vs-CostCard figure, `ccs roofline`).  Liveness granularity
-    stays one _PB sub-block: dead sub-blocks inside a live grid step
-    still skip their compute.
+    pipeline-fetch overhead of one _PB block per step; the measured
+    multiple of the bound is the benchmark's `dense_roofline`).  Liveness
+    granularity stays one _PB sub-block: dead sub-blocks inside a live
+    grid step still skip their compute.
 
     Env override PBCCS_DENSE_CB (>= 1), then an applied `ccs tune`
     host profile (runtime/tuning.py resolution ladder), then

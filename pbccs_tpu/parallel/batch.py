@@ -62,7 +62,6 @@ from pbccs_tpu.ops.mutation_score import (
     make_patches_fast,
 )
 from pbccs_tpu.obs import flight as obs_flight
-from pbccs_tpu.obs import roofline as obs_roofline
 from pbccs_tpu.obs import trace as obs_trace
 from pbccs_tpu.obs.metrics import default_registry, log_buckets
 from pbccs_tpu.parallel.mesh import READ_AXIS, ZMW_AXIS, pad_to
@@ -394,10 +393,9 @@ def _batch_setup(tpls, tlens, tables, reads, rlens, strands, tstarts, tends,
 
 
 def lowering_target():
-    """The canonical per-bucket program the roofline plane lowers for
-    CostCard extraction (obs/roofline.py): the jitted _batch_setup.
-    Exposed as a function so roofline never imports batch at module
-    scope (batch imports roofline; this breaks the cycle)."""
+    """The canonical per-bucket program, the jitted _batch_setup, for
+    callers that lower it without building a polisher
+    (tests/test_chip_compile.py)."""
     return _batch_setup
 
 
@@ -874,10 +872,6 @@ class BatchPolisher:
             mesh=self.mesh,
             guided_passes=guided_fill_passes(self._Jmax))
         self.alpha, self.beta = alpha, beta
-        # charge this execution of the canonical program against the
-        # bucket's CostCard bound (no-op until a card exists)
-        obs_roofline.charge_execution(imax=self._Imax, jmax=self._Jmax,
-                                      r=self._R, z=self._Z)
         self._tpl_dev = self._shard(tl)
         self._tpl32_dev = self._tpl_dev.astype(jnp.int32)
         self._tpl32_r_dev = self.tpl_r.astype(jnp.int32)
@@ -1594,11 +1588,6 @@ class BatchPolisher:
         (defaults to opts.max_iterations); a straggler continuation passes
         its remaining rounds so parent + continuation together never exceed
         the reference's single max_iterations bound."""
-        with obs_roofline.refine_scope(imax=self._Imax, jmax=self._Jmax,
-                                       r=self._R):
-            return self._refine_impl(opts, skip, budget)
-
-    def _refine_impl(self, opts, skip, budget) -> list[RefineResult]:
         opts = opts or RefineOptions()
         if budget is None:
             budget = opts.max_iterations

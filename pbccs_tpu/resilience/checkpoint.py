@@ -8,11 +8,11 @@ journal is an append-only NDJSON file beside the output:
     {"type":"chunk","index":1,...}
 
 One line per COMPLETED work item (a --chunkSize batch of ZMWs), written
-in consumption order (= submission order, the WorkQueue contract) and
-fsynced, so a `kill -9` loses at most the in-flight chunks.  On
-`--resume` the CLI re-reads its inputs (recomputing the CLI-level gate
-tallies, which are deterministic), restores completed chunks from the
-journal, and produces only the rest -- the final tally and output are
+in consumption order (= submission order, the scheduled driver's
+contract) and fsynced, so a `kill -9` loses at most the in-flight
+chunks.  On `--resume` the CLI re-reads its inputs (recomputing the
+CLI-level gate tallies, which are deterministic), restores completed
+chunks from the journal, and produces only the rest -- the final tally and output are
 byte-identical to an uninterrupted run.
 
 Robustness of the journal itself:

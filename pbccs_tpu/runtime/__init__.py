@@ -1,1 +1,1 @@
-"""Host runtime: ZMW selection, ordered work pipeline, logging, chemistry."""
+"""Host runtime: ZMW selection, logging, chemistry."""

@@ -8,7 +8,6 @@ the cache key, so a directory that moves never hits)."""
 
 from __future__ import annotations
 
-import contextlib
 import os
 import threading
 
@@ -28,22 +27,6 @@ _PHASE_OF_EVENT = {_TRACE_EVENT: "trace", _LOWER_EVENT: "lower",
                    _COMPILE_EVENT: "compile"}
 
 _monitoring_installed = False
-_suppress_events = threading.local()
-
-
-@contextlib.contextmanager
-def suppress_cache_metrics():
-    """Hide compile/cache-event counts from the ledger counters for the
-    duration.  Used by the roofline CostCard extraction: its AOT compile
-    of the canonical bucket program races the workload's own jit on the
-    shared persistent cache, so counting its hit/miss would make the
-    deterministic compile-class ledger counters timing-dependent."""
-    prev = getattr(_suppress_events, "v", False)
-    _suppress_events.v = True
-    try:
-        yield
-    finally:
-        _suppress_events.v = prev
 
 
 def _install_cache_metrics() -> None:
@@ -84,8 +67,6 @@ def _install_cache_metrics() -> None:
         for phase in ("trace", "lower", "compile", "cache_read")}
 
     def on_event(event: str, **kw) -> None:
-        if getattr(_suppress_events, "v", False):
-            return
         if "compilation_cache" in event:
             if "hit" in event:
                 hits.inc()
@@ -110,7 +91,7 @@ def _install_cache_metrics() -> None:
         if phase is None:
             return
         open_phases.n = n = max(getattr(open_phases, "n", 1) - 1, 0)
-        if n or getattr(_suppress_events, "v", False):
+        if n:
             return
         dur = end_time - start_time
         load_seconds[phase].inc(dur)
@@ -131,8 +112,7 @@ def _install_cache_metrics() -> None:
 
     def on_duration(event: str, duration_secs: float, **kw) -> None:
         # the one phase jax reports as a duration only
-        if event == _CACHE_READ_EVENT \
-                and not getattr(_suppress_events, "v", False):
+        if event == _CACHE_READ_EVENT:
             load_seconds["cache_read"].inc(duration_secs)
 
     try:

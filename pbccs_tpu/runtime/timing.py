@@ -13,8 +13,8 @@ MeasurementScope over the default registry) reports deltas.  reset()
 keeps its historical meaning -- start a new window -- but now only
 replaces the MODULE-DEFAULT window that the module-level getters read
 from: a live serving engine holds its own window (engine status), so a
-bench.py reset in the same process can no longer clobber the engine's
-counters (and vice versa).
+reset elsewhere in the process cannot clobber the engine's counters
+(and vice versa).
 
 device_fetch() additionally attributes its blocking time to the
 innermost open trace span (obs/trace.py) so exported span trees carry

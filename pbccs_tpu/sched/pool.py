@@ -373,14 +373,8 @@ class DevicePool:
     def _run_task(self, w: _Worker, task: _Task) -> None:
         import jax
 
-        from pbccs_tpu.obs import roofline
         from pbccs_tpu.resilience import faults, resources
 
-        # per-dispatch roofline scope: wall + device-wait for THIS task,
-        # keyed by its shape bucket when it declared one (serve flushes
-        # do; ad-hoc closures fall back to the task key)
-        rl_label = (roofline.label_from_capacity_bucket(task.capacity_bucket)
-                    or str(task.key))
         try:
             # the device-level chaos site: keyed by WORKER name so a spec
             # can sicken one device (ZMW-poison specs live inside the
@@ -391,8 +385,7 @@ class DevicePool:
             with resources.device_scope(w.name):
                 faults.maybe_fail("sched.dispatch",
                                   keys=[w.name, str(task.key)])
-                with jax.default_device(w.device), \
-                        roofline.dispatch_scope(rl_label, zmws=task.zmws):
+                with jax.default_device(w.device):
                     result = task.fn(w.device)
         except BaseException as e:  # noqa: BLE001 -- classified below
             self._on_task_error(w, task, e)

@@ -93,20 +93,10 @@ def _warm_one(tasks) -> dict:
     """Full polish surface at this bucket's shapes; returns the effective
     compiled shapes (what a matching production batch will reuse)."""
     from pbccs_tpu.models.arrow.refine import RefineOptions
-    from pbccs_tpu.models.arrow.scorer import (fills_use_pallas,
-                                               guided_fill_passes)
-    from pbccs_tpu.obs import roofline
     from pbccs_tpu.parallel.batch import BatchPolisher
 
     opts = RefineOptions()
     polisher = BatchPolisher(tasks)
-    # the bucket's roofline CostCard is minted here, where it is asked
-    # for, before the first refine so that its charges find it: a second
-    # lowering and AOT compile of the set-up program that no batch run pays
-    roofline.note_bucket(
-        imax=polisher._Imax, jmax=polisher._Jmax, r=polisher._R,
-        z=polisher._Z, width=polisher._W, use_pallas=fills_use_pallas(),
-        guided_passes=guided_fill_passes(polisher._Jmax))
     polisher.refine(opts)
     polisher.consensus_qvs()
     polisher.warm_shape_set(opts)
@@ -161,7 +151,6 @@ def run_warmup(argv: list[str] | None = None) -> int:
     targets = devices if args.allDevices else devices[:1]
     entries = [parse_bucket(b) for b in args.bucket]
 
-    from pbccs_tpu.obs import roofline
     from pbccs_tpu.parallel.batch import effective_shapes
     from pbccs_tpu.resilience import resources
 
@@ -209,25 +198,10 @@ def run_warmup(argv: list[str] | None = None) -> int:
                      "seconds": round(dt, 2), "shapes": shapes}
             if len(sub) < len(tasks):
                 entry["governor_clamped_z"] = len(sub)
-            # _warm_one minted (and persisted) this bucket's roofline
-            # CostCard; surface it so warmup output doubles as the bound
-            # report for the menu
-            card = roofline.tracker().card(
-                roofline.bucket_label(imax, jmax, r))
-            if card is not None:
-                entry["cost_card"] = {
-                    "label": card.label, "flops": card.flops,
-                    "bytes_accessed": card.bytes_accessed,
-                    "peak_hbm_bytes": card.peak_hbm_bytes,
-                    "intensity": card.intensity, "card_z": card.z}
             report.append(entry)
             log.info(f"warmup: {entry['bucket']} on {name}: "
                      f"{dt:.1f}s, shapes {shapes}")
-    out: dict = {"warmed": report}
-    cards_file = roofline.cards_path()
-    if cards_file:
-        out["roofline_cards"] = cards_file
-    print(json.dumps(out))
+    print(json.dumps({"warmed": report}))
     log.flush()
     return 0
 

@@ -27,8 +27,8 @@ workload up front; the engine does not, so it:
 
 The device itself is single-owner: polish batches run on a dedicated
 executor (default 1 worker -- one lockstep batch on device at a time,
-matching the offline driver; the WorkQueue overlap trick applies to host
-stages, which here live on the prep workers)."""
+matching the offline driver; overlap applies to host stages, which
+here live on the prep workers)."""
 
 from __future__ import annotations
 
@@ -42,7 +42,6 @@ from pbccs_tpu.obs import flight as _obs_flight  # noqa: F401 -- import
 # registers the refine-loop gauges, so an idle replica's exposition
 # still carries ccs_refine_* series (zeroes) and `ccs top` renders a
 # uniform per-replica surface instead of nulls until first traffic
-from pbccs_tpu.obs import roofline as obs_roofline
 from pbccs_tpu.obs import trace as obs_trace
 from pbccs_tpu.obs.metrics import default_registry, log_buckets
 from pbccs_tpu.pipeline import (
@@ -281,11 +280,8 @@ class CcsEngine:
             self._stop_flush = False
         self._start_t = time.monotonic()
         # the engine's OWN measurement window: a timing.reset() elsewhere
-        # in the process (bench.py) no longer clobbers engine counters
+        # in the process does not clobber engine counters
         self._window = timing.window()
-        # pick up CostCards minted by an earlier warmup process so the
-        # roofline block/gauges have bounds before the first polish
-        obs_roofline.tracker().load_persisted()
         n_polish = self.config.polish_workers
         if self.config.devices != 1:
             # device-fleet mode: the DevicePool's per-device executor
@@ -675,19 +671,12 @@ class CcsEngine:
         for req in reqs:
             req.t_polish0 = t_polish0
         try:
-            # per-dispatch roofline scope keyed by the flush's shape
-            # bucket; reentrancy-guarded, so in fleet mode (this method
-            # runs inside a pool task that opened its own scope) only the
-            # pool's outer scope counts
-            rl_label = obs_roofline.bucket_label(*_flush_shapes(preps))
             with obs_trace.span("serve.polish", ctx=ctx,
                                 bucket=str(batch.key),
                                 zmws=len(batch.items),
                                 reason=batch.reason,
                                 trace_ids=trace_ids), \
-                    timing.stage("serve.polish"), \
-                    obs_roofline.dispatch_scope(rl_label,
-                                                zmws=len(batch.items)):
+                    timing.stage("serve.polish"):
                 outcomes = self._run_polish_inner(preps, raise_dev,
                                                   first_attempt)
         finally:
@@ -913,16 +902,10 @@ class CcsEngine:
         # only when this process writes a ledger, federated fleet-wide
         # by `ccs router --perfLedger`
         perf = {"perf": ledger.perf_block()} if ledger is not None else {}
-        # the status verb's roofline block (protocol.FIELD_ROOFLINE):
-        # per-bucket CostCard bound + measured achieved/efficiency;
-        # absent until the plane has a card or a charge
-        rl_block = obs_roofline.tracker().status_block()
-        rl = {"roofline": rl_block} if rl_block else {}
         return {
             "engine": "ccs-serve",
             **sched,
             **perf,
-            **rl,
             "slo": self._slo_block(),
             "uptime_s": round(time.monotonic() - self._start_t, 3),
             "queue_depth": max(0, snap["pending"] - snap["in_flight_zmws"]),
@@ -978,7 +961,7 @@ class CcsEngine:
                      "ccs_retries_", "ccs_quarantine", "ccs_degraded_",
                      "ccs_watchdog_", "ccs_faults_", "ccs_sched_",
                      "ccs_slo_", "ccs_refine_", "ccs_flight_",
-                     "ccs_metrics_", "ccs_roofline_", "ccs_tenant_")):
+                     "ccs_metrics_", "ccs_tenant_")):
                 continue
             suffix = "{%s}" % ",".join(
                 f"{k}={v}" for k, v in labels) if labels else ""

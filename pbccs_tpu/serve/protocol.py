@@ -157,13 +157,6 @@ KEY_PERF_SCHEMA = "schema_version"
 KEY_PERF_RECORDS = "records"
 KEY_PERF_LAST = "last_record"
 
-# status-verb roofline block (obs/roofline.py status_block): per-bucket
-# CostCard bound + measured achieved/efficiency.
-FIELD_ROOFLINE = "roofline"
-KEY_ROOFLINE_SCHEMA = "schema_version"
-KEY_ROOFLINE_PEAK = "peak_tflops"
-KEY_ROOFLINE_BUCKETS = "buckets"
-
 # status-verb supervisor block (serve/supervisor.py status_block): the
 # fleet autopilot's slot table (state machine per managed replica
 # process), its recent fleet events, and rolling-restart progress.
@@ -254,12 +247,6 @@ WIRE_FIELDS = {
     FIELD_PERF: {"keys": (KEY_PERF_SCHEMA, KEY_PERF_RECORDS,
                           KEY_PERF_LAST),
                  "verbs": (VERB_STATUS,)},
-    # rides the STATUS exchange: present once the roofline plane holds a
-    # CostCard or a charge for any bucket; absent on cold replicas or
-    # under PBCCS_ROOFLINE=0.
-    FIELD_ROOFLINE: {"keys": (KEY_ROOFLINE_SCHEMA, KEY_ROOFLINE_PEAK,
-                              KEY_ROOFLINE_BUCKETS),
-                     "verbs": (VERB_STATUS,)},
     # rides the STATUS exchange: present when a fleet supervisor
     # (serve/supervisor.py) controls the answering router -- the slot
     # table `ccs top` renders restarting/dead/draining states from,

@@ -364,6 +364,12 @@ def server_ssl_context(certfile: str, keyfile: str) -> ssl.SSLContext:
     raises on unreadable/mismatched PEMs at startup, never mid-accept."""
     ctx = ssl.SSLContext(ssl.PROTOCOL_TLS_SERVER)
     ctx.minimum_version = ssl.TLSVersion.TLSv1_2
+    # no TLS 1.3 session tickets: nothing here resumes a session, and a
+    # ticket is a post-handshake message the peer's reader thread works
+    # through inside the SSL object while its writer sends the first
+    # frame on it -- a frame sent in that window was lost and the
+    # request hung (seen under load: test_tls_round_trip)
+    ctx.num_tickets = 0
     ctx.load_cert_chain(certfile, keyfile)
     return ctx
 

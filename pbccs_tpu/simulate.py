@@ -103,3 +103,35 @@ def simulate_zmw(rng: np.random.Generator, tpl_len: int, n_passes: int,
             reads.append(sample_read(rng, rc, trans_rev))
             strands.append(1)
     return tpl, reads, strands, snr
+
+
+def parse_passes(s) -> tuple[int, int]:
+    """A pass count is a fixed count ('8') or an inclusive range
+    ('3-10', per-ZMW uniform draw -- BASELINE.json config 2)."""
+    s = str(s)
+    if "-" in s:
+        lo, hi = s.split("-", 1)
+        return int(lo), int(hi)
+    return int(s), int(s)
+
+
+def build_tasks(rng, n_zmws: int, tpl_len: int, n_passes, n_corruptions: int):
+    """n_zmws polish tasks with their true templates: drafts corrupted at
+    n_corruptions positions so the refinement loop does real mutation
+    work."""
+    from pbccs_tpu.parallel.batch import ZmwTask
+
+    lo, hi = n_passes if isinstance(n_passes, tuple) else \
+        parse_passes(n_passes)
+    tasks, truths = [], []
+    for z in range(n_zmws):
+        np_z = int(rng.integers(lo, hi + 1)) if hi > lo else lo
+        tpl, reads, strands, snr = simulate_zmw(rng, tpl_len, np_z)
+        draft = tpl.copy()
+        for _ in range(n_corruptions):
+            pos = int(rng.integers(5, tpl_len - 5))
+            draft[pos] = (draft[pos] + 1 + int(rng.integers(0, 3))) % 4
+        tasks.append(ZmwTask(f"bench/{z}", draft, snr, reads, strands,
+                             [0] * np_z, [len(draft)] * np_z))
+        truths.append(tpl)
+    return tasks, truths

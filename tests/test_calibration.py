@@ -24,12 +24,14 @@ from pbccs_tpu.models.arrow.params import decode_bases
 
 
 def _polish_workload(n_zmws, tpl_len, n_passes, seed):
-    from bench import build_tasks, run_workload
+    from pbccs_tpu.parallel.batch import BatchPolisher
+    from pbccs_tpu.simulate import build_tasks
 
     rng = np.random.default_rng(seed)
     tasks, truths = build_tasks(rng, n_zmws, tpl_len, n_passes, 2)
-    p, results, qvs = run_workload(tasks)
-    return p, truths, qvs
+    p = BatchPolisher(tasks)
+    p.refine()
+    return p, truths, p.consensus_qvs()
 
 
 @pytest.mark.slow

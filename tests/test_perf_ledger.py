@@ -32,8 +32,7 @@ def make_record(**over):
            "jax_version": "1.2.3", "platform": "cpu",
            "polish_dispatches": 3, "refine_rounds_host": 40,
            "padding_waste": 0.25, "compiles": 7, "wall_s": 2.0,
-           "zmws": 8, "results": 8, "peak_rss_bytes": 1000,
-           "region_shares": {"kernels": 0.6, "other": 0.4}}
+           "zmws": 8, "results": 8, "peak_rss_bytes": 1000}
     rec.update(over)
     return rec
 
@@ -109,10 +108,17 @@ class TestRunRecord:
         # every produced field is schema-declared (the append contract)
         assert set(rec) <= set(LEDGER_FIELDS)
 
-    def test_region_shares_normalized(self):
-        rec = run_record(_REG.scope(), kind="bench_row", source="b",
-                         region_shares={"kernels": 30.0, "other": 10.0})
-        assert rec["region_shares"] == {"kernels": 0.75, "other": 0.25}
+    def test_what_the_caller_did_not_supply_is_absent(self):
+        """The gate compares only fields both sides carry: a record
+        holds no rate without a wall time and no ratio without slots."""
+        rec = run_record(_REG.scope(), kind="serve_snapshot", source="t",
+                         extra={"pending": 3})
+        for field in ("wall_s", "zmws_per_sec", "zmws", "results",
+                      "workload", "fill_ratio_zmw", "fill_ratio_read",
+                      "padding_waste", "device_step_ms"):
+            assert field not in rec, field
+        assert rec["pending"] == 3 and rec["polish_dispatches"] == 0
+        assert set(rec) <= set(LEDGER_FIELDS)
 
     def test_environment_fields_never_initialize_a_backend(self,
                                                            monkeypatch):
@@ -171,14 +177,6 @@ class TestPerfGate:
             counters_only=True)
         assert [v["metric"] for v in bad] == ["padding_waste"]
 
-    def test_kernel_share_drop_fails(self):
-        bad, _ = perf_gate.compare(
-            self._baseline(),
-            [make_record(region_shares={"kernels": 0.4, "other": 0.6})],
-            counters_only=True)
-        assert {v["metric"] for v in bad} == {"region_shares.kernels",
-                                              "region_shares.other"}
-
     def test_compile_class_skipped_on_jax_mismatch(self):
         violations, notes = perf_gate.compare(
             self._baseline(),
@@ -216,40 +214,24 @@ class TestPerfGate:
         assert any(v["metric"] == "refine_rounds_host"
                    and v["observed"] is None for v in bad)
 
-    def test_floor_reads_specialized_record_kinds(self):
-        # tenant_b_p99_gain rides tenant_snapshot rows, not the
-        # batch_run rows the selector matches: the floor falls back to
-        # the latest record of any kind in the whole ledger
-        base = self._baseline(platform="tpu",
-                              floors={"tenant_b_p99_gain": 1.0})
-        batch = make_record(platform="tpu")
-        snap = {"kind": "tenant_snapshot", "platform": "tpu",
-                "jax_version": "1.2.3", "tenant": "tenantB",
-                "tenant_b_p99_gain": 2.7}
-        ok, _ = perf_gate.compare(base, [batch],
-                                  all_records=[batch, snap])
-        assert ok == []
-        bad, _ = perf_gate.compare(
-            base, [batch],
-            all_records=[batch, dict(snap, tenant_b_p99_gain=0.4)])
-        assert [(v["metric"], v["class"]) for v in bad] == [
-            ("tenant_b_p99_gain", "floor")]
+    def test_ignored_metric_is_exempt_and_noted(self):
+        rec = make_record(compiles=99, refine_rounds_host=47)
+        bad, notes = perf_gate.compare(self._baseline(), [rec],
+                                       counters_only=True,
+                                       ignore={"compiles"})
+        assert [v["metric"] for v in bad] == ["refine_rounds_host"]
+        assert any("exempted" in n and "compiles" in n for n in notes)
 
-    def test_floor_absent_everywhere_is_violation(self):
-        base = self._baseline(platform="tpu",
-                              floors={"tenant_b_p99_gain": 1.0})
-        batch = make_record(platform="tpu")
-        bad, _ = perf_gate.compare(base, [batch], all_records=[batch])
-        assert any(v["metric"] == "tenant_b_p99_gain"
-                   and v["observed"] is None for v in bad)
-
-    def test_floor_skipped_on_cpu_platform(self):
-        # wall-class floor gating mirrors the wall band: recorded-only
-        # on CPU CI, enforced on matching accelerator hosts
-        base = self._baseline(floors={"tenant_b_p99_gain": 1.0})
-        violations, notes = perf_gate.compare(base, [make_record()])
-        assert violations == []
-        assert any("tenant_b_p99_gain" in n for n in notes)
+    @pytest.mark.parametrize("section,value,reason", [
+        ("metrics", [1, 2], "metrics must be an object"),
+        ("metrics", {"zmws": {"a": 1}}, "metrics.zmws must be a number"),
+        ("tolerances", {"wall": "wide"}, "tolerances.wall must be a number"),
+        ("select", ["batch_run"], "select must be an object"),
+    ])
+    def test_bad_baseline_names_its_fault(self, section, value, reason):
+        assert perf_gate.bad_baseline_reason(self._baseline()) is None
+        doc = self._baseline(**{section: value})
+        assert reason in perf_gate.bad_baseline_reason(doc)
 
     def test_update_baseline_prints_accepted_deltas(self, tmp_path,
                                                     capsys):
@@ -299,7 +281,7 @@ class TestPerfGate:
 
     def test_no_matching_records_is_usage_error(self, tmp_path):
         ledger = tmp_path / "l.ndjson"
-        ledger.write_text(json.dumps(make_record(kind="bench_row"))
+        ledger.write_text(json.dumps(make_record(kind="serve_snapshot"))
                           + "\n")
         base = tmp_path / "b.json"
         base.write_text(json.dumps(self._baseline()))

@@ -14,6 +14,7 @@ import json
 import socket
 import threading
 import time
+import types
 
 import pytest
 
@@ -73,6 +74,7 @@ class FakeReplica:
         self.held: list[tuple] = []
         self._lock = threading.Lock()
         self._conns: list[socket.socket] = []
+        self.accepted = 0               # connections taken, ever
         self._srv = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
         self._srv.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
         self._srv.bind(("127.0.0.1", 0))
@@ -93,6 +95,7 @@ class FakeReplica:
                 return
             with self._lock:
                 self._conns.append(conn)
+                self.accepted += 1
             threading.Thread(target=self._serve, args=(conn,),
                              daemon=True).start()
 
@@ -170,6 +173,10 @@ class FakeReplica:
             c.close()
 
     def notify_draining(self):
+        """The drain notice of a real replica: from here on its status
+        says it accepts nothing (a probe reply that still said
+        `accepting` would clear the router's drain flag again)."""
+        self.accepting = False
         with self._lock:
             conns = list(self._conns)
         for c in conns:
@@ -397,9 +404,16 @@ class TestRouting:
             fake.close()
 
     def test_no_replica_reachable_is_overloaded(self):
-        fake = FakeReplica()
-        fake.close()  # nothing listening
-        router, server = make_router([fake])
+        # a port that is bound and never listened on refuses every
+        # connect, and stays this test's while it runs.  A FakeReplica
+        # that was closed keeps accepting into its backlog until its
+        # accept thread wakes, and its port is anybody's after that:
+        # the router's link then came up, dropped with the request on
+        # it, and the client read `internal`
+        held = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
+        held.bind(("127.0.0.1", 0))
+        router, server = make_router(
+            [types.SimpleNamespace(port=held.getsockname()[1])])
         try:
             with CcsClient(server.host, server.port) as cli:
                 with pytest.raises(ServeError) as ei:
@@ -408,6 +422,7 @@ class TestRouting:
         finally:
             server.shutdown()
             router.close()
+            held.close()
 
     def test_submit_after_close_is_closed_error(self, fakes_pair):
         router, _server = make_router(fakes_pair)
@@ -584,12 +599,14 @@ class TestFailover:
                     return next(r for r in router.status()["replicas"]
                                 if r["replica"] == a.name)["connected"]
 
+                before = a.accepted
                 a.drop()   # idle connection loss (no in-flight)
                 # the loss registers first, then the health loop
-                # reconnects; one strike != benched, so the bucket's
-                # home assignment survives the round trip
-                assert wait_until(lambda: not connected(), timeout=15.0)
-                assert wait_until(connected, timeout=15.0)
+                # reconnects (50 ms later: a poll may never see the gap,
+                # so count the fake's accepts); one strike != benched,
+                # so the bucket's home assignment survives the round trip
+                assert wait_until(lambda: a.accepted > before
+                                  and connected(), timeout=15.0)
                 assert cli.submit_wire(
                     dict(ZMW, id="m/2")).reply(10.0)["status"] == "Success"
             assert len(a.received) == 2 and not b.received

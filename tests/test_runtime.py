@@ -1,17 +1,15 @@
-"""Intervals, whitelist, work queue, logging: host runtime components.
+"""Intervals, whitelist, logging: host runtime components.
 
 Patterns: reference tests/TestInterval.cpp, TestWhitelist.cpp (incl.
-invalid-spec throws) and the WorkQueue ordering contract (WorkQueue.h).
+invalid-spec throws).
 """
 
 import io
-import time
 
 import pytest
 
 from pbccs_tpu.runtime.logging import Logger, LogLevel
 from pbccs_tpu.runtime.whitelist import Whitelist
-from pbccs_tpu.runtime.workqueue import WorkQueue
 from pbccs_tpu.utils.intervals import Interval, IntervalTree
 
 
@@ -82,117 +80,6 @@ class TestWhitelist:
     def test_invalid_specs(self, bad):
         with pytest.raises(ValueError):
             Whitelist(bad)
-
-
-class TestWorkQueue:
-    def test_preserves_order(self):
-        def work(i):
-            time.sleep(0.01 * ((7 * i) % 5))  # jittered finish order
-            return i * i
-
-        # max_pending bounds UNCONSUMED results, so a produce-all-then-
-        # consume loop needs the pipeline sized for the whole workload
-        # (concurrent consumers are exercised below and in cli.py)
-        with WorkQueue(4, max_pending=20) as wq:
-            for i in range(20):
-                wq.produce(work, i)
-            wq.finalize()
-            assert list(wq.results()) == [i * i for i in range(20)]
-
-    def test_exception_propagates_to_consumer(self):
-        def work(i):
-            if i == 3:
-                raise RuntimeError("boom")
-            return i
-
-        with WorkQueue(2) as wq:
-            for i in range(6):
-                wq.produce(work, i)
-            wq.finalize()
-            with pytest.raises(RuntimeError, match="boom"):
-                list(wq.results())
-
-    def test_ordered_consumption_out_of_order_completion(self):
-        """Earlier tasks finishing LAST must not reorder consumption."""
-        import threading
-
-        gate = threading.Event()
-
-        def work(i):
-            if i == 0:
-                gate.wait(timeout=5.0)  # task 0 completes after the rest
-            return i
-
-        with WorkQueue(4) as wq:
-            for i in range(8):
-                wq.produce(work, i)
-            wq.finalize()
-            it = wq.results()
-            gate_setter = threading.Timer(0.05, gate.set)
-            gate_setter.start()
-            try:
-                assert list(it) == list(range(8))
-            finally:
-                gate_setter.cancel()
-
-    def test_producer_backpressure_at_max_pending(self):
-        """produce() blocks once max_pending results are unconsumed --
-        including COMPLETED ones -- and unblocks as results are consumed."""
-        import threading
-
-        max_pending = 3
-        wq = WorkQueue(2, max_pending=max_pending)
-        produced = []
-        done = threading.Event()
-
-        def producer():
-            for i in range(max_pending + 2):
-                wq.produce(lambda i=i: i, i)
-                produced.append(i)
-            done.set()
-
-        t = threading.Thread(target=producer)
-        t.start()
-        # tasks are trivial and complete immediately; the producer must
-        # still stall at max_pending because nothing has been consumed
-        done.wait(timeout=0.5)
-        assert not done.is_set()
-        assert len(produced) == max_pending
-        # consuming results frees slots and unblocks the producer
-        it = wq.results()
-        assert next(it) == 0
-        assert next(it) == 1
-        assert done.wait(timeout=5.0)
-        wq.finalize()
-        assert list(it) == [2, 3, 4]
-        t.join()
-        wq.shutdown()
-
-    def test_exception_propagates_to_blocked_producer(self):
-        """A producer stalled on a full pipeline wakes and raises when a
-        worker fails while it waits."""
-        import threading
-
-        release = threading.Event()
-
-        def work(i):
-            if i == 0:
-                release.wait(timeout=5.0)
-                raise RuntimeError("boom")
-            return i
-
-        wq = WorkQueue(1, max_pending=2)
-        wq.produce(work, 0)
-        wq.produce(work, 1)  # fills the pipeline (nothing consumed)
-        threading.Timer(0.05, release.set).start()
-        with pytest.raises(RuntimeError, match="no new tasks accepted"):
-            # blocks on the full pipeline, then task 0 fails
-            for i in range(2, 50):
-                wq.produce(work, i)
-        wq.finalize()
-        with pytest.raises(RuntimeError, match="boom"):
-            list(wq.results())
-        wq.shutdown()
 
 
 class TestLogger:

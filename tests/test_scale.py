@@ -1,4 +1,4 @@
-"""Host-marshalling scale behavior (VERDICT round-1 item 10).
+"""Host-marshalling scale behavior.
 
 At the 150k-ZMW streamed config the host must not serialize on Python
 per-(chunk, ZMW) loops while marshalling mutation batches.  These tests
@@ -6,9 +6,9 @@ drive BatchPolisher.score_mutation_arrays' marshalling at Z=1024 with the
 device dispatch stubbed out, asserting (a) routing correctness of the
 vectorized ragged->dense packing/unpacking against a hand-computed
 expectation and (b) that marshalling cost stays in linear, sub-second
-territory.  Device compute at scale is exercised separately by bench.py
-(the real chip) -- compiling Z=1024 CPU programs in CI is minutes of
-XLA time and tests nothing about marshalling.
+territory.  Device compute at scale is the benchmark's, on the chip --
+compiling Z=1024 CPU programs in CI is minutes of XLA time and tests
+nothing about marshalling.
 """
 
 import time

@@ -550,6 +550,40 @@ def test_a_chunk_whose_prepare_raises_tallies_other_alone(monkeypatch):
     assert [c.id for c in batches[1] if c.id != bad] in polished
 
 
+@pytest.mark.parametrize("max_inflight", [1, 3])
+def test_reader_stalls_at_max_inflight_until_results_are_taken(
+        monkeypatch, max_inflight):
+    """No more than `max_inflight` batches are past the reader (drafting,
+    queued, polished and not yet taken) however far behind the consumer
+    falls: the reader holds one more in its hand and waits."""
+    polished: list[list[str]] = []
+    _stub_host_and_device(monkeypatch, lambda chunk: 0.0, polished,
+                          lambda preps: 0.0)
+    read: list[int] = []
+
+    def read_batches():
+        for i, batch in enumerate(_stub_batches(8, 2)):
+            read.append(i)
+            yield i, batch, None
+
+    with make_pool(1) as pool:
+        pipe = ScheduledPipeline(pool, ConsensusSettings(),
+                                 prepare_workers=2,
+                                 max_inflight=max_inflight)
+        out = pipe.run(read_batches())
+        order = [next(out)[0]]
+        deadline = time.monotonic() + 20.0   # the consumer falls behind
+        while (len(polished) < max_inflight
+               or len(read) < max_inflight + 1) \
+                and time.monotonic() < deadline:
+            time.sleep(0.02)
+        time.sleep(0.3)                      # and nothing more moves
+        assert len(polished) == max_inflight
+        assert read == list(range(max_inflight + 1))
+        order += [idx for idx, _t in out]
+    assert order == list(range(8)) and len(polished) == 8
+
+
 def test_device_starved_seconds_count_an_empty_queue_after_first_submit():
     scope = reg.scope()
     with make_pool(1) as pool:

@@ -508,11 +508,26 @@ class TestReconnectBackoff:
             RouterConfig(health_interval_s=0.02, health_timeout_s=0.5,
                          reconnect_backoff_base_s=0.2,
                          reconnect_backoff_cap_s=1.0)).start()
+        held = None
         try:
             assert wait_until(lambda: router.status()
                               ["replicas"][0]["connected"])
             scope = _REG.scope()
             fake.close()   # hard down: reconnect attempts now fail
+            # hold the port, never listening, so every connect is
+            # refused: left free it is any xdist worker's next fake
+            # (the fake's listener lives until its accept() wakes)
+            held = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
+            held.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
+
+            def bound():
+                try:
+                    held.bind(("127.0.0.1", fake.port))
+                except OSError:
+                    return False
+                return True
+
+            assert wait_until(bound, timeout=5.0)
             # with a 0.02s probe tick and a >=0.2s backoff window, most
             # ticks must be SKIPPED (counted) rather than attempted
             assert wait_until(lambda: scope.counter_value(
@@ -520,6 +535,8 @@ class TestReconnectBackoff:
                 replica=name) >= 3, timeout=15.0)
         finally:
             router.close(drain=False)
+            if held is not None:
+                held.close()
 
 
 # ------------------------------------------------------- ledger schema

@@ -154,15 +154,6 @@ def test_a_compile_yields_program_spans_and_moves_every_phase(tracer):
                 if e["args"]["fun"] in ("sin", "cos")]
 
 
-def test_suppressed_events_move_no_phase():
-    runtime_cache._install_cache_metrics()
-    before = phase_seconds()
-    with runtime_cache.suppress_cache_metrics():
-        jax.jit(lambda x: x * 3 + time.time_ns() % 7)(
-            jnp.ones(5)).block_until_ready()
-    assert phase_seconds() == before
-
-
 # ------------------------------------------------------------- slot occupancy
 
 

@@ -14,18 +14,15 @@ skips:
      must produce byte-identical output + yield report vs an
      uninterrupted run, restoring (not recomputing) the journaled
      chunks.
-  3. CRASH / RESUME -- a workqueue-task fault (--faults
-     workqueue.task:error@2*1) makes the run die with a nonzero exit;
-     --resume completes it to the identical output.
-  4. SERVE WATCHDOG -- a live engine with a short polish deadline fed a
+  3. SERVE WATCHDOG -- a live engine with a short polish deadline fed a
      hung dispatch: the affected requests fail with a structured
      timeout, the engine keeps serving, and a follow-up request
      succeeds.
-  5. OOM MATRIX (--ooms) -- injected device OOMs at the dispatch site:
+  4. OOM MATRIX (--ooms) -- injected device OOMs at the dispatch site:
      full output parity every round (never a quarantined healthy
      batch), governor ceilings recorded, later rounds pre-split at
      admission.
-  6. INPUT FUZZ -- the randomized long leg of tools/fuzz_inputs.py:
+  5. INPUT FUZZ -- the randomized long leg of tools/fuzz_inputs.py:
      --fuzzRounds seeded structured corruptions over the BAM decode
      classes (bit flips, truncation, length-field lies, tag mutations),
      asserting the hardening invariant at bench scale (process
@@ -221,28 +218,7 @@ def leg_kill9_resume(args, tmp, fasta, report: dict) -> None:
           not os.path.exists(ckpt))
 
 
-def leg_crash_resume(args, tmp, fasta, report: dict) -> None:
-    print("== leg 3: worker-task crash, then --resume ==")
-    ref = os.path.join(tmp, "ref.fasta")   # from leg 2
-    out = os.path.join(tmp, "crashed.fasta")
-    ckpt = os.path.join(tmp, "crashed.ckpt")
-    r = _run_cli(_cli_cmd(out, fasta, args,
-                          ("--checkpoint", ckpt,
-                           "--faults", "workqueue.task:error@2*1")))
-    check(report, "crash_exit_nonzero", r.returncode != 0,
-          f"exit {r.returncode}")
-    check(report, "crash_left_journal", os.path.exists(ckpt))
-    r = _run_cli(_cli_cmd(out, fasta, args,
-                          ("--checkpoint", ckpt, "--resume")))
-    check(report, "crash_resume_ok", r.returncode == 0,
-          r.stderr[-300:] if r.returncode else "")
-    check(report, "crash_resume_output_identical",
-          open(ref).read() == open(out).read())
-    check(report, "crash_resume_report_identical",
-          open(ref + ".csv").read() == open(out + ".csv").read())
-
-
-# --------------------------------------------------------- 4. serve watchdog
+# --------------------------------------------------------- 3. serve watchdog
 
 def leg_serve_watchdog(chunks, report: dict) -> None:
     """Engine-level watchdog semantics (stubbed pipeline: the engine's
@@ -250,7 +226,7 @@ def leg_serve_watchdog(chunks, report: dict) -> None:
     leg 1's watchdog_recovery_parity).  A polish deadline short enough
     to catch the injected 30 s hang would also catch a legitimate
     cold-compile CPU polish, so the stub keeps the leg deterministic."""
-    print("== leg 4: serve engine watchdog ==")
+    print("== leg 3: serve engine watchdog ==")
     from pbccs_tpu.pipeline import PreparedZmw
     from pbccs_tpu.serve.engine import CcsEngine, ServeConfig
 
@@ -286,7 +262,7 @@ def leg_serve_watchdog(chunks, report: dict) -> None:
                   eng.status()["engine"] == "ccs-serve")
 
 
-# ------------------------------------------------- 5. OOM-adaptive dispatch
+# ------------------------------------------------- 4. OOM-adaptive dispatch
 
 def leg_oom_matrix(chunks, args, report: dict) -> None:
     """--ooms rounds of injected device OOMs at the dispatch site: every
@@ -295,7 +271,7 @@ def leg_oom_matrix(chunks, args, report: dict) -> None:
     batch), the memory governor must record a shape ceiling, and later
     rounds must pre-split at admission instead of re-discovering the
     OOM."""
-    print(f"== leg 5: OOM-adaptive dispatch ({args.ooms} rounds) ==")
+    print(f"== leg 4: OOM-adaptive dispatch ({args.ooms} rounds) ==")
     from pbccs_tpu.obs.metrics import default_registry
     from pbccs_tpu.resilience import resources
 
@@ -322,13 +298,13 @@ def leg_oom_matrix(chunks, args, report: dict) -> None:
           bool(resources.default_governor().snapshot()))
 
 
-# ---------------------------------------------------------- 6. input fuzz
+# ---------------------------------------------------------- 5. input fuzz
 
 def leg_input_fuzz(args, report: dict) -> None:
     """The randomized long leg of the structured input fuzzer: every
     decode corruption class re-rolled --fuzzRounds times (fuzz_inputs
     --smoke is the deterministic tier-1 subset of this)."""
-    print(f"== leg 6: randomized input fuzz ({args.fuzzRounds} rounds) ==")
+    print(f"== leg 5: randomized input fuzz ({args.fuzzRounds} rounds) ==")
     sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
     import fuzz_inputs
 
@@ -355,7 +331,6 @@ def main(argv=None) -> int:
         leg_fault_matrix(chunks, report)
         if not args.skip_subprocess:
             leg_kill9_resume(args, tmp, fasta, report)
-            leg_crash_resume(args, tmp, fasta, report)
         leg_serve_watchdog(chunks, report)
         if args.ooms:
             leg_oom_matrix(chunks, args, report)

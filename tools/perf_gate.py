@@ -9,9 +9,8 @@ the gate is strict exactly where determinism makes strictness honest:
             slot totals, governor interventions): exact match,
             enforced EVERYWHERE -- a drifted counter is a behavior
             change, not noise;
-  ratio     CPU-deterministic ratios/shares (fill ratio, padding waste,
-            kernel_fraction, span-rollup region shares): absolute band
-            (default 0.02), enforced everywhere;
+  ratio     CPU-deterministic ratios (fill ratio, padding waste):
+            absolute band (default 0.02), enforced everywhere;
   compile   compile/cache counts: exact, but only when the ledger's
             jax_version matches the baseline's (a jax upgrade
             legitimately changes compile behavior -- the mismatch is
@@ -24,18 +23,6 @@ the gate is strict exactly where determinism makes strictness honest:
             product;
   resource  peak RSS: median vs a wide relative band (default 50%),
             same platform rule as wall.
-
-An optional baseline ``floors`` section maps ledger fields to hard
-MINIMUMS (violation class "floor"): the roofline efficiency floor for
-the headline config lives here, so a kernel-share slide is caught by
-the sentinel even when the relative wall band would tolerate it.
-Floors follow their field's class gating (wall-class floors only on a
-matching accelerator platform; none in --counters-only mode) and are
-carried through --update-baseline verbatim -- they are policy, not
-measurement.  A floor field absent from the selector-matched records
-is read from the latest record of any kind in the ledger (fields like
-``tenant_b_p99_gain`` ride ``tenant_snapshot`` rows, not the
-``batch_run`` rows the class bands select).
 
 Exit 0 clean; exit 1 with ONE structured JSON diff line per violation
 (metric, class, baseline, observed, tolerance); exit 2 on usage errors
@@ -102,15 +89,12 @@ def observed_metrics(records: list[dict]) -> dict[str, Any]:
     """Collapse matching records into one observed-metric map: the LAST
     record for deterministic classes, the MEDIAN across records for the
     noisy wall/resource classes (median-of-N is the committed
-    statistic, mirroring bench.py's repeat handling)."""
+    statistic)."""
     out: dict[str, Any] = {}
     last = records[-1]
     for field, cls in LEDGER_FIELDS.items():
         if cls in ("counter", "ratio", "compile"):
-            if field == "region_shares":
-                if isinstance(last.get(field), dict):
-                    out[field] = last[field]
-            elif _numeric(last.get(field)):
+            if _numeric(last.get(field)):
                 out[field] = last[field]
         elif cls in ("wall", "resource"):
             vals = [r[field] for r in records if _numeric(r.get(field))]
@@ -127,11 +111,7 @@ def bad_baseline_reason(baseline: dict) -> str | None:
     if not isinstance(metrics, dict):
         return "metrics must be an object"
     for name, val in metrics.items():
-        if name == "region_shares":
-            if not (isinstance(val, dict)
-                    and all(_numeric(v) for v in val.values())):
-                return "metrics.region_shares must be an object of numbers"
-        elif not _numeric(val):
+        if not _numeric(val):
             return (f"metrics.{name} must be a number, got "
                     f"{type(val).__name__}")
     tolerances = baseline.get("tolerances")
@@ -145,16 +125,6 @@ def bad_baseline_reason(baseline: dict) -> str | None:
     select = baseline.get("select")
     if select is not None and not isinstance(select, dict):
         return "select must be an object"
-    floors = baseline.get("floors")
-    if floors is not None:
-        if not isinstance(floors, dict):
-            return "floors must be an object"
-        for name, val in floors.items():
-            if not _numeric(val):
-                return (f"floors.{name} must be a number, got "
-                        f"{type(val).__name__}")
-            if LEDGER_FIELDS.get(name) not in _GATED:
-                return (f"floors.{name}: not a gated ledger field")
     return None
 
 
@@ -165,18 +135,15 @@ def _violation(metric: str, cls: str, base, obs, tol) -> dict:
 
 def compare(baseline: dict, records: list[dict], *,
             counters_only: bool = False,
-            all_records: list[dict] | None = None,
             ignore: set[str] | frozenset[str] | None = None
             ) -> tuple[list[dict], list[str]]:
     """(violations, notes) of the observed ledger records vs baseline.
 
     `records` are the selector-matched records the class bands run
-    over; `all_records` (default: same) is the whole ledger, which
-    floors may fall back to for fields only specialized record kinds
-    carry (e.g. tenant_snapshot's tenant_b_p99_gain).  `ignore` names
-    metrics exempt from enforcement (noted, not silently dropped) --
-    the ccs-tune referee uses it for fields a candidate knob
-    legitimately perturbs (e.g. band_w changes compile counts)."""
+    over.  `ignore` names metrics exempt from enforcement (noted, not
+    silently dropped) -- the ccs-tune referee uses it for fields a
+    candidate knob legitimately perturbs (e.g. band_w changes compile
+    counts)."""
     tol = {**DEFAULT_TOLERANCES, **(baseline.get("tolerances") or {})}
     base_metrics = baseline.get("metrics") or {}
     obs = observed_metrics(records)
@@ -218,17 +185,6 @@ def compare(baseline: dict, records: list[dict], *,
         if cls in ("wall", "resource") and not wall_enforced:
             continue
         obs_val = obs.get(metric)
-        if metric == "region_shares":
-            base_shares = base_val if isinstance(base_val, dict) else {}
-            obs_shares = obs_val if isinstance(obs_val, dict) else {}
-            for region in sorted(set(base_shares) | set(obs_shares)):
-                b = float(base_shares.get(region, 0.0))
-                o = float(obs_shares.get(region, 0.0))
-                if abs(o - b) > tol["ratio"]:
-                    violations.append(_violation(
-                        f"region_shares.{region}", "ratio", b, o,
-                        tol["ratio"]))
-            continue
         if not _numeric(base_val):
             # defense in depth for library callers that skipped the
             # bad_baseline_reason gate; main() exits 2 before this
@@ -254,39 +210,6 @@ def compare(baseline: dict, records: list[dict], *,
                 violations.append(_violation(metric, cls, base_val,
                                              round(obs_val, 4),
                                              tol[cls]))
-
-    # floors: hard minimums (e.g. roofline_efficiency for the headline
-    # config) -- a kernel-share slide fails here even when the relative
-    # band above would tolerate it.  Enforcement gating mirrors the
-    # floor field's class: wall/resource floors only on a matching
-    # accelerator platform, compile floors only on a matching jax, and
-    # none of them in --counters-only mode.
-    floors = baseline.get("floors") or {}
-    for metric, floor in sorted(floors.items()):
-        cls = LEDGER_FIELDS.get(metric)
-        if cls not in _GATED or not _numeric(floor):
-            continue
-        if counters_only:
-            notes.append(f"floor {metric!r} skipped in counters-only "
-                         "mode")
-            continue
-        if cls == "compile" and not jax_match:
-            continue
-        if cls in ("wall", "resource") and not wall_enforced:
-            notes.append(f"floor {metric!r} skipped on platform "
-                         f"{platform!r}")
-            continue
-        obs_val = obs.get(metric)
-        if obs_val is None:
-            # a floor may target a field only a specialized record kind
-            # carries (tenant_snapshot's tenant_b_p99_gain): fall back
-            # to the latest record of ANY kind in the ledger with it
-            obs_val = next(
-                (r[metric] for r in reversed(all_records or records)
-                 if _numeric(r.get(metric))), None)
-        if not _numeric(obs_val) or obs_val < floor:
-            violations.append(_violation(metric, "floor", floor,
-                                         obs_val, 0.0))
     return violations, notes
 
 
@@ -318,12 +241,6 @@ def update_baseline(path: str, baseline: dict | None,
                            old_tol if isinstance(old_tol, dict)
                            and all(_numeric(v) for v in old_tol.values())
                            else None)
-    # floors are policy, not measurement: carry them through verbatim
-    # (a refresh must not silently drop the efficiency floor)
-    old_floors = (baseline or {}).get("floors")
-    if isinstance(old_floors, dict) and old_floors \
-            and all(_numeric(v) for v in old_floors.values()):
-        fresh["floors"] = old_floors
     for metric in sorted(set(old_metrics) | set(fresh["metrics"])):
         old, new = old_metrics.get(metric), fresh["metrics"].get(metric)
         if old != new:
@@ -421,7 +338,6 @@ def main(argv: list[str] | None = None) -> int:
 
     violations, notes = compare(baseline, matching,
                                 counters_only=args.counters_only,
-                                all_records=records,
                                 ignore=set(args.ignore) or None)
     for note in notes:
         print(f"perf_gate: note: {note}", file=sys.stderr)

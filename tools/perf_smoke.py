@@ -59,8 +59,8 @@ def _child_env(cache_dir: str) -> dict:
 def write_workload(path: str) -> None:
     import numpy as np
 
-    from bench import build_tasks
     from pbccs_tpu.models.arrow.params import decode_bases
+    from pbccs_tpu.simulate import build_tasks
 
     tasks, _ = build_tasks(np.random.default_rng(SEED), N_ZMWS, TPL_LEN,
                            str(N_PASSES), 1)

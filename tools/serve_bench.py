@@ -1,8 +1,7 @@
 #!/usr/bin/env python
 """Load generator for `ccs serve`: latency/throughput vs the offline driver.
 
-Generates a simulated multi-ZMW workload (simulate.simulate_zmw, the same
-generator bench.py uses), then:
+Generates a simulated multi-ZMW workload (simulate.simulate_zmw), then:
 
   1. OFFLINE BASELINE -- times pipeline.process_chunks over the whole
      workload in chunkSize batches (the batch CLI's execution shape) and

@@ -61,12 +61,6 @@ echo "== perf smoke (ledger schema + counter determinism + perf_gate vs PERF_BAS
 # counters-only mode and reject a perturbed one with a structured diff
 timeout -k 10 600 env JAX_PLATFORMS=cpu python tools/perf_smoke.py || exit 1
 
-echo "== roofline smoke (CostCard determinism + ccs roofline + efficiency floor gate) =="
-# two fresh-process warmups of a 2-bucket menu (shared compile cache,
-# separate card stores): cards must be byte-identical, the report must
-# parse, and perf_gate must enforce the new roofline fields + floor
-timeout -k 10 600 env JAX_PLATFORMS=cpu python tools/roofline_smoke.py || exit 1
-
 echo "== tune smoke (ccs tune: output-change rejection, profile ship, loader ladder, attribution) =="
 # one real search over a loaded band-width grid: the output-changing
 # candidate must be rejected, the profile must ship + apply + stamp
