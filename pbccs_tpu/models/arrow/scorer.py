@@ -21,6 +21,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from pbccs_tpu.models.arrow import mutations as mutlib
 from pbccs_tpu.models.arrow.expectations import per_base_mean_and_variance
@@ -136,7 +137,7 @@ def guided_fill_passes(jmax: int) -> int:
 
 def fill_alpha_beta_batch(reads, rlens, win_tpl, win_trans, wlens, width: int,
                           use_pallas: bool | None = None, offsets=None,
-                          guided_passes: int = 0):
+                          guided_passes: int = 0, need=None):
     """Batched alpha/beta fills + log-likelihoods + scale prefixes.
 
     Dispatches to the Pallas TPU kernel (ops.fwdbwd_pallas) when available,
@@ -151,17 +152,143 @@ def fill_alpha_beta_batch(reads, rlens, win_tpl, win_trans, wlens, width: int,
     `offsets` (R, nc) pins the band layout (e.g. carried from a previous
     round's guided fill); `guided_passes` > 0 additionally re-centers the
     band on the alpha argmax path and refills that many times (static
-    trace-time count -- see guided_fill_passes)."""
+    trace-time count -- see guided_fill_passes).
+
+    `need` ((R,) bool) names the reads to fill: they are filled in passes
+    (for_needed_reads, fill_pass), and a read it leaves out costs no
+    precompute and no scan and comes back with zero bands and zero
+    likelihoods."""
+    if use_pallas is None:
+        use_pallas = fills_use_pallas()
+    if need is None:
+        alpha, beta, ll_a, ll_b = _fill_pair(
+            reads, rlens, win_tpl, win_trans, wlens, width, use_pallas,
+            offsets, guided_passes)
+        return alpha, beta, ll_a, ll_b, *_scale_sums(alpha, beta)
+
+    N = need.shape[0]
+    inputs = (reads, rlens, win_tpl, win_trans, wlens)
+
+    def one_pass(idx, live, carry):
+        take = lambda a: None if a is None else jnp.take(a, idx, axis=0)
+        return fill_pass(tuple(map(take, inputs)), idx, live, *carry, width,
+                         use_pallas, take(offsets), guided_passes)[:2]
+
+    ll0 = jnp.zeros(N, jnp.float32)
+    (alpha, beta), (ll_a, ll_b) = for_needed_reads(
+        need, one_pass,
+        (_zero_bands(N, win_tpl.shape[1], width, framed=use_pallas),
+         (ll0, ll0)))
+    return alpha, beta, ll_a, ll_b, *_scale_sums(alpha, beta)
+
+
+def _scale_sums(alpha, beta):
+    return (jax.vmap(scale_prefix)(alpha.log_scales),
+            jax.vmap(scale_suffix)(beta.log_scales))
+
+
+# Reads a pass over the needed reads takes: two blocks of the fill kernel.
+# Not measured against 32 or 128 on the chip (PERF.md section 7).
+_FILL_CHUNK = 64
+
+
+def for_needed_reads(need, one_pass, carry):
+    """`carry` after one_pass(idx, live, carry) has run over the reads the
+    (R,) bool `need` names, _FILL_CHUNK at a pass: idx (C,) int32 are a
+    pass's reads, its first `live` the needed ones (the last pass is part
+    empty).  The needed reads come in their own order; the loop makes
+    ceil(needed / C) passes, so what a pass does costs nothing for a read
+    that needs none of it."""
+    N = need.shape[0]
+    C = min(_FILL_CHUNK, N)
+    n = jnp.sum(need, dtype=jnp.int32)
+    order = jnp.argsort(~need, stable=True).astype(jnp.int32)
+    order = jnp.pad(order, (0, -N % C), constant_values=N - 1)
+
+    def body(k, carry):
+        idx = lax.dynamic_slice(order, (k * C,), (C,))
+        return one_pass(idx, jnp.minimum(n - k * C, C), carry)
+
+    return lax.fori_loop(0, (n + C - 1) // C, body, carry)
+
+
+def put_rows(old, idx, live, new):
+    """`old` with old[idx[i]] = new[i] for i < live: a plain scatter, for
+    the small per-read arrays (on the chip a band goes by
+    fwdbwd_pallas.place_reads)."""
+    rows = jnp.where(jnp.arange(idx.shape[0]) < live, idx, old.shape[0])
+    return old.at[rows].set(new.astype(old.dtype), mode="drop")
+
+
+def band_placer(use_pallas: bool):
+    """place(new, idx, live, old) for band-sized arrays: put_rows's result
+    by the copy-only kernel where the fills are the kernel's (no row that
+    is not placed is touched), else by put_rows itself."""
+    from pbccs_tpu.ops.fwdbwd_pallas import place_reads
+
+    return place_reads if use_pallas else (
+        lambda new, idx, live, old: put_rows(old, idx, live, new))
+
+
+def fill_pass(inputs, idx, live, bands, lls, width: int, use_pallas: bool,
+              offsets=None, guided_passes: int = 0):
+    """One pass of for_needed_reads over the fills: `inputs` are the
+    pass's (reads, rlens, win_tpl, win_trans, wlens), its first `live`
+    needed.  The precompute and the fill run on them, and each finished
+    read goes to its own row idx[i] of what the caller carries: `bands`
+    ((alpha, beta) of the whole batch) and `lls` ((ll_a, ll_b) of the
+    batch).  No other row is touched.  With the kernel, its `live` skips
+    the dead block (fwdbwd_pallas._run_fill) and place_reads copies each
+    band, so a read that is not filled moves no band byte; offsets,
+    log-scales and likelihoods go back by scatter, as everything does on
+    the pure-JAX path.  A read's likelihoods are the same bits from a
+    pass as from a fill of every read (fwdbwd_pallas._scale_total).
+    Returns (bands, lls, the pass's own (alpha, beta))."""
+    from pbccs_tpu.ops.fwdbwd import BandedMatrix, band_frame, band_lead
+
+    *filled, ll_a, ll_b = _fill_pair(*inputs, width, use_pallas, offsets,
+                                     guided_passes,
+                                     live=live if use_pallas else None)
+    if band_lead(bands[0]):     # the pure-JAX fills' bands come plain
+        filled = [band_frame(new) for new in filled]
+    place = band_placer(use_pallas)
+    bands = tuple(
+        BandedMatrix(place(new.vals, idx, live, old.vals),
+                     put_rows(old.offsets, idx, live, new.offsets),
+                     put_rows(old.log_scales, idx, live, new.log_scales))
+        for old, new in zip(bands, filled))
+    lls = (put_rows(lls[0], idx, live, ll_a),
+           put_rows(lls[1], idx, live, ll_b))
+    return bands, lls, filled
+
+
+def _zero_bands(n: int, jmax: int, width: int, framed: bool):
+    """(alpha, beta) of n reads that no fill has written yet, framed as
+    the kernel's fills are or plain as the pure-JAX fills'."""
+    from pbccs_tpu.ops.fwdbwd import BandedMatrix, band_frame_rows
+
+    cols = jmax + 1
+    zeros = BandedMatrix(
+        jnp.zeros((n, band_frame_rows(cols) if framed else cols, width),
+                  jnp.float32),
+        jnp.zeros((n, cols), jnp.int32), jnp.zeros((n, cols), jnp.float32))
+    return zeros, zeros
+
+
+def _fill_pair(reads, rlens, win_tpl, win_trans, wlens, width: int,
+               use_pallas: bool, offsets, guided_passes: int, live=None):
+    """(alpha, beta, ll_a, ll_b) of a read batch, guided passes included.
+    With `live`, rows [0, live) only (the rest holds no meaning)."""
     from pbccs_tpu.ops.fwdbwd import BandedMatrix, guided_band_offsets
 
     alpha, ll_a = _fill_alpha(reads, rlens, win_tpl, win_trans, wlens,
-                              width, use_pallas, offsets)
+                              width, use_pallas, offsets, live)
     for _ in range(guided_passes):
         g_off = jax.vmap(
             lambda av, ao, i, jl: guided_band_offsets(av, ao, i, jl, width)
         )(alpha.vals, alpha.offsets, rlens, wlens)
         alpha_g, ll_g = _fill_alpha(reads, rlens, win_tpl, win_trans, wlens,
-                                    width, use_pallas, g_off)
+                                    width, use_pallas, g_off, live)
         # keep-better per read: a re-centered band normally recovers the
         # probability mass the diagonal band clipped, but when the first
         # fill locked onto a wrong ridge the guided band can LOSE mass --
@@ -175,21 +302,18 @@ def fill_alpha_beta_batch(reads, rlens, win_tpl, win_trans, wlens, width: int,
         ll_a = jnp.where(keep, ll_g, ll_a)
     beta, ll_b = _fill_beta(reads, rlens, win_tpl, win_trans, wlens,
                             width, use_pallas,
-                            alpha.offsets if guided_passes else offsets)
-    apre = jax.vmap(scale_prefix)(alpha.log_scales)
-    bsuf = jax.vmap(scale_suffix)(beta.log_scales)
-    return alpha, beta, ll_a, ll_b, apre, bsuf
+                            alpha.offsets if guided_passes else offsets, live)
+    return alpha, beta, ll_a, ll_b
 
 
 def _fill_alpha(reads, rlens, win_tpl, win_trans, wlens, width: int,
-                use_pallas: bool | None, offsets):
+                use_pallas: bool, offsets, live=None):
     from pbccs_tpu.ops import fwdbwd_pallas as fpal
 
-    if use_pallas is None:
-        use_pallas = fpal.fills_use_pallas()
     if use_pallas:
         alpha = fpal.pallas_forward_batch(reads, rlens, win_tpl, win_trans,
-                                          wlens, width, offsets=offsets)
+                                          wlens, width, offsets=offsets,
+                                          live=live)
         return alpha, fpal.forward_loglik_batch(alpha, rlens, wlens)
     alpha = jax.vmap(
         lambda r, i, t, tr, j, o: banded_forward(r, i, t, tr, j, width,
@@ -200,14 +324,13 @@ def _fill_alpha(reads, rlens, win_tpl, win_trans, wlens, width: int,
 
 
 def _fill_beta(reads, rlens, win_tpl, win_trans, wlens, width: int,
-               use_pallas: bool | None, offsets):
+               use_pallas: bool, offsets, live=None):
     from pbccs_tpu.ops import fwdbwd_pallas as fpal
 
-    if use_pallas is None:
-        use_pallas = fpal.fills_use_pallas()
     if use_pallas:
         beta = fpal.pallas_backward_batch(reads, rlens, win_tpl, win_trans,
-                                          wlens, width, offsets=offsets)
+                                          wlens, width, offsets=offsets,
+                                          live=live)
         return beta, fpal.backward_loglik_batch(beta, wlens)
     beta = jax.vmap(
         lambda r, i, t, tr, j, o: banded_backward(r, i, t, tr, j, width,
@@ -219,7 +342,7 @@ def _fill_beta(reads, rlens, win_tpl, win_trans, wlens, width: int,
 
 def fill_alpha_beta_batch_zr(reads, rlens, win_tpl, win_trans, wlens,
                              width: int, use_pallas: bool, mesh=None,
-                             guided_passes: int = 0):
+                             guided_passes: int = 0, need=None):
     """(Z, R)-leading alpha/beta fills + log-likelihoods + scale prefixes.
 
     Unsharded (mesh=None) this flattens to the (Z*R,) read batch and
@@ -230,31 +353,37 @@ def fill_alpha_beta_batch_zr(reads, rlens, win_tpl, win_trans, wlens,
     wrapper mesh runs had to fall back to the pure-JAX fill path and
     forfeit the kernel's measured ~69x single-chip advantage.  Reads are
     independent, so no collectives are needed in the body; boundary
-    shardings match the batch arrays' native P('zmw','read') layout."""
+    shardings match the batch arrays' native P('zmw','read') layout.
+
+    `need` ((Z, R) bool) as in fill_alpha_beta_batch; each device of a
+    mesh packs the needed reads of its own block."""
     Z, R = reads.shape[:2]
     flat = lambda a: a.reshape((Z * R,) + a.shape[2:])
     unflat = lambda a: a.reshape((Z, R) + a.shape[1:])
 
     if mesh is None or not use_pallas:
-        out = fill_alpha_beta_batch(flat(reads), flat(rlens), flat(win_tpl),
-                                    flat(win_trans), flat(wlens), width,
-                                    use_pallas, guided_passes=guided_passes)
+        out = fill_alpha_beta_batch(
+            flat(reads), flat(rlens), flat(win_tpl), flat(win_trans),
+            flat(wlens), width, use_pallas, guided_passes=guided_passes,
+            need=None if need is None else flat(need))
         return jax.tree.map(unflat, out)
 
     from jax.sharding import PartitionSpec
     from pbccs_tpu.parallel.mesh import READ_AXIS, ZMW_AXIS
 
-    def body(r, i, t, tr, j):
+    def body(r, i, t, tr, j, *need):
         # each device runs the unsharded path on its local (Z/nz, R/nr) block
         return fill_alpha_beta_batch_zr(r, i, t, tr, j, width, True, None,
-                                        guided_passes=guided_passes)
+                                        guided_passes=guided_passes,
+                                        need=need[0] if need else None)
 
     spec = PartitionSpec(ZMW_AXIS, READ_AXIS)
     # check_vma=False: pallas_call's out_shapes carry no varying-mesh-axes
     # metadata; the body is per-read elementwise so nothing varies anyway
     return jax.shard_map(body, mesh=mesh, in_specs=spec, out_specs=spec,
                      check_vma=False)(
-        reads, rlens, win_tpl, win_trans, wlens)
+        reads, rlens, win_tpl, win_trans, wlens,
+        *(() if need is None else (need,)))
 
 
 @functools.partial(jax.jit, static_argnames=("width", "use_pallas",

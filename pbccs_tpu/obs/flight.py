@@ -48,6 +48,16 @@ _m_slots_live = _reg.counter("ccs_refine_slot_rounds_total",
                              "capacity (Z) they ran in", kind="live")
 _m_slots_capacity = _reg.counter("ccs_refine_slot_rounds_total",
                                  kind="capacity")
+# reads the device loop's rebuilds refilled (the reads of the ZMWs that
+# applied a mutation that round) against what refilling the whole batch at
+# every rebuild would have taken: filled / capacity over a window is how
+# often the fills' gate engages
+_m_fills = _reg.counter("ccs_refine_fill_reads_total",
+                        "Reads the refine loop's rebuilds refilled, and "
+                        "the reads a refill of every lane at each rebuild "
+                        "would have taken (capacity)", kind="filled")
+_m_fills_capacity = _reg.counter("ccs_refine_fill_reads_total",
+                                 kind="capacity")
 _m_converged = _reg.gauge("ccs_refine_converged_fraction",
                           "Converged fraction of the most recent "
                           "refinement round's batch")
@@ -132,6 +142,13 @@ def default_recorder() -> FlightRecorder:
 def record_round(batch: str, round_idx: int, live: int, n_zmws: int,
                  z: int, source: str = "host") -> None:
     _default.record_round(batch, round_idx, live, n_zmws, z, source)
+
+
+def record_fill_reads(filled: int, capacity: int) -> None:
+    """One dispatch of the device loop: reads its rebuilds refilled, and
+    reads they would have refilled at Z * R a rebuild."""
+    _m_fills.inc(int(filled))
+    _m_fills_capacity.inc(int(capacity))
 
 
 def dump(reason: str, logger=None) -> list:

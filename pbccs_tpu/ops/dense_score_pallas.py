@@ -445,14 +445,16 @@ class DenseLayout(typing.NamedTuple):
 
 def build_dense_layout(reads, rlens, win_tpl, win_trans, wlens, tables,
                        alpha: BandedMatrix, beta: BandedMatrix, apre, bsuf,
-                       width: int) -> DenseLayout:
+                       width: int, windows=None) -> DenseLayout:
     """Build the DenseLayout for a flat read batch (trace-time helper;
     prepare_dense_layout is the jitted entry).  Takes the score calls'
-    operands, so one argument tuple serves all three."""
+    operands, so one argument tuple serves all three.  `windows`: the
+    (rbase, rnext) planes where the caller holds them already (the refine
+    loop's rebuild makes them for the reads it refills)."""
     R = reads.shape[0]
     W = width
     rows = band_frame_rows(alpha.offsets.shape[1])
-    rbase, rnext = band_read_windows(reads, alpha.offsets, W, rows)
+    rbase, rnext = windows or band_read_windows(reads, alpha.offsets, W, rows)
     ptr = jax.vmap(
         lambda t, tr, tb, wl: dense_patch_grids(t, tr, tb, wl, _OFF0, rows)
     )(win_tpl.astype(jnp.int32), win_trans, tables, wlens)

@@ -40,6 +40,11 @@ refine loop carries -- nothing transposes, slices, pads or copies it on the
 way (docs/DESIGN.md "One band layout").  The frame costs the kernel its
 lead rows as dead columns (zero coefficients, zero values).
 
+A caller that needs only some reads filled packs them first and says how
+many (`live`): the kernel skips the read blocks that hold none, and
+place_reads, a copy-only kernel, puts each finished band in its own
+read's rows of the buffer the caller carries.
+
 TPU lowering notes (all load-bearing, each worth ~10-100x on v5e):
   * every precompute lookup is a static pad/slice or a one-hot matmul;
     per-element jnp.take and scatter (.at[].set) forms of the same lower
@@ -367,7 +372,7 @@ _roll_lanes = circ_roll    # Mosaic-friendly: two static slices + concat
 
 
 def _fill_kernel(*refs, jb_size: int, rev_store: bool, merge: bool,
-                 backward: bool):
+                 backward: bool, gated: bool = False):
     """Column scan over circular-lane bands.  The coefficient inputs are in
     kernel layout (columns, R, W): the column axis is the *leading*
     (untiled) dimension, so loading a column is plain VMEM address
@@ -393,7 +398,17 @@ def _fill_kernel(*refs, jb_size: int, rev_store: bool, merge: bool,
     With merge=True (the Quiver recurrence) one extra input (cg) and two
     extra scratch slots (prev2, its scale) carry the j-2 Merge operand:
     b += cg[L] * roll(prev2)[L] / scale_prev
-    (Quiver/SimpleRecursor.cpp merge move; models/quiver/recursor.py)."""
+    (Quiver/SimpleRecursor.cpp merge move; models/quiver/recursor.py).
+
+    With gated=True the first ref is the scalar-prefetched count of LIVE
+    read blocks: the scan runs for read blocks [0, count) and a dead
+    block's steps do nothing.  _run_fill's index maps hand a dead step the
+    blocks of the last live step, so the pipeline fetches no coefficient
+    and stores no band for it either: a dead block costs its grid steps'
+    overhead (about 0.35 us each) and its rows of the outputs are never
+    written."""
+    if gated:
+        live_ref, *refs = refs
     if merge:
         (seed_ref, seedcol_ref, mask_ref, cm_ref, cd_ref,
          cc_ref, cg_ref, vals_ref, ls_ref, prev_ref, prev2_ref,
@@ -470,11 +485,27 @@ def _fill_kernel(*refs, jb_size: int, rev_store: bool, merge: bool,
             sprev_ref[...] = sprev
         return 0
 
-    lax.fori_loop(0, jb_size // u, body, 0)
+    def scan():
+        lax.fori_loop(0, jb_size // u, body, 0)
+
+    if gated:
+        pl.when(pl.program_id(0) < live_ref[0])(scan)
+    else:
+        scan()
+
+
+def _held_step(i, c, n, last_c):
+    """Grid step (i, c) of a kernel whose leading axis has n live rows:
+    itself, or for a dead row (i >= n) the last live step, (n - 1,
+    last_c).  Index maps built on it leave a dead step's blocks unchanged
+    from the step before, so the pipeline fetches and stores nothing."""
+    dead = i >= n
+    return (jnp.where(dead, jnp.maximum(n - 1, 0), i),
+            jnp.where(dead, last_c, c))
 
 
 def _run_fill(cm, cd, cc, mask, seed, seedcol, rev_store: bool,
-              cg=None, backward: bool | None = None):
+              cg=None, backward: bool | None = None, live=None):
     """Invoke the column-scan kernel.
 
     cm/cd/cc: (nc, R, W) KERNEL layout, columns leading (the scan indexes
@@ -491,7 +522,17 @@ def _run_fill(cm, cd, cc, mask, seed, seedcol, rev_store: bool,
     time; 99.7 ms with the columns-leading output and XLA's transpose.)
     With rev_store, output row t holds kernel column nc-1-t.  Passing cg engages the Merge
     carry (Quiver recurrence).  backward sets the kernel's roll/scan
-    direction (defaults to rev_store)."""
+    direction (defaults to rev_store).
+
+    `live` (optional int32 scalar, traced): only reads [0, live) need a
+    fill.  The kernel then scans the ceil(live / rb) read blocks that
+    hold one and skips the rest (their count is scalar-prefetched; see
+    _fill_kernel for what a dead block costs), and the rows of the dead
+    blocks come back UNWRITTEN: whatever the buffers held.  A caller
+    packs the reads it needs first and takes rows [0, live) of the
+    result (models/arrow/scorer.fill_pass places them with
+    place_reads).  Without `live` every block is scanned by the program
+    this was before the gate."""
     nc, R, W = cm.shape
     merge = cg is not None
     backward = rev_store if backward is None else backward
@@ -506,19 +547,27 @@ def _run_fill(cm, cd, cc, mask, seed, seedcol, rev_store: bool,
     assert nc % jb == 0 and R % rb == 0
     njb = nc // jb
 
+    gated = live is not None
     kernel = functools.partial(_fill_kernel, jb_size=jb, rev_store=rev_store,
-                               merge=merge, backward=backward)
-    if rev_store:
-        col_ospec = pl.BlockSpec((rb, jb, W), lambda r, j: (r, njb - 1 - j, 0))
-        vec_ospec = pl.BlockSpec((rb, jb, 1), lambda r, j: (r, njb - 1 - j, 0))
-    else:
-        col_ospec = pl.BlockSpec((rb, jb, W), lambda r, j: (r, j, 0))
-        vec_ospec = pl.BlockSpec((rb, jb, 1), lambda r, j: (r, j, 0))
-    in_col = pl.BlockSpec((jb, rb, W), lambda r, j: (j, r, 0))
-    in_vec = pl.BlockSpec((jb, rb, 1), lambda r, j: (j, r, 0))
+                               merge=merge, backward=backward, gated=gated)
+
+    def step(r, j, *count):
+        """The (read block, column step) whose blocks grid step (r, j)
+        holds: its own, or for a dead read block the last live step's."""
+        return _held_step(r, j, count[0][0], njb - 1) if gated else (r, j)
+
+    def spec(shape, index):
+        return pl.BlockSpec(shape, lambda r, j, *n: index(*step(r, j, *n)))
+
+    out_col = (lambda r, j: (r, njb - 1 - j, 0)) if rev_store else \
+        (lambda r, j: (r, j, 0))
+    col_ospec = spec((rb, jb, W), out_col)
+    vec_ospec = spec((rb, jb, 1), out_col)
+    in_col = spec((jb, rb, W), lambda r, j: (j, r, 0))
+    in_vec = spec((jb, rb, 1), lambda r, j: (j, r, 0))
     in_specs = [
-        pl.BlockSpec((rb, W), lambda r, j: (r, 0)),     # seed
-        pl.BlockSpec((rb, 1), lambda r, j: (r, 0)),     # seedcol
+        spec((rb, W), lambda r, j: (r, 0)),             # seed
+        spec((rb, 1), lambda r, j: (r, 0)),             # seedcol
         in_vec,                                          # mask
         in_col, in_col, in_col,                          # cm, cd, cc
     ]
@@ -529,20 +578,90 @@ def _run_fill(cm, cd, cc, mask, seed, seedcol, rev_store: bool,
         operands += [cg]
         scratch += [pltpu.VMEM((rb, W), jnp.float32),    # prev2
                     pltpu.VMEM((rb, 1), jnp.float32)]    # its scale
+    if gated:
+        blocks = (jnp.asarray(live, jnp.int32) + rb - 1) // rb
+        operands = [jnp.minimum(blocks, R // rb).reshape(1)] + operands
     return pl.pallas_call(
         kernel,
-        grid=(R // rb, njb),
-        in_specs=in_specs,
-        out_specs=[col_ospec, vec_ospec],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=int(gated), grid=(R // rb, njb),
+            in_specs=in_specs, out_specs=[col_ospec, vec_ospec],
+            scratch_shapes=scratch),
         out_shape=[
             jax.ShapeDtypeStruct((R, nc, W), jnp.float32),
             jax.ShapeDtypeStruct((R, nc, 1), jnp.float32),
         ],
-        scratch_shapes=scratch,
+        # dead read blocks revisit the last live block's outputs, so the
+        # read-block axis of a gated call is no longer independent steps
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary" if gated else "parallel",
+                                 "arbitrary")),
         interpret=_interpret(),
     )(*operands)
+
+
+# Band rows a step of place_reads moves (1 MB at W = 96 with the double
+# buffers' four copies well inside VMEM); other slab sizes were not
+# measured on the chip (PERF.md section 7).
+_PLACE_ROWS = 2048
+
+
+def place_reads(packed, dest, n, into):
+    """`into` ((R, rows, W)) with into[dest[i]] = packed[i] for i < n;
+    packed (P, rows, W) with P >= n; dest (P,) int32, rows of `into`, its
+    first n distinct; n a traced int32 scalar.  `into` is aliased to the
+    result.
+
+    A copy-only kernel, one grid step a packed read (and a slab of at most
+    _PLACE_ROWS rows): a placed read is read once and written once, and a
+    read that is not placed is not touched -- step i >= n holds the blocks
+    step n - 1 held, so the pipeline moves nothing for it.  An XLA scatter
+    or select of a band moves every read's rows instead (and, PR 28, first
+    turns the whole band into a layout of its liking).  This is how a
+    packed, gated fill (_run_fill's `live`) gets its bands back to their
+    own reads.  (With n == 0 the one block the pipeline must still store
+    is written with what it held: the aliased input is fetched there.)"""
+    P, rows, W = packed.shape
+    slab = max(d for d in range(8, min(rows, _PLACE_ROWS) + 1, 8)
+               if rows % d == 0)
+    C = rows // slab
+
+    def src(i, c, dest_ref, n_ref):
+        i, c = _held_step(i, c, n_ref[0], C - 1)
+        return i, c, 0
+
+    def dst(i, c, dest_ref, n_ref):
+        i, c = _held_step(i, c, n_ref[0], C - 1)
+        return dest_ref[i], c, 0
+
+    def held(i, c, dest_ref, n_ref):
+        return dest_ref[0], C - 1, 0
+
+    def kernel(dest_ref, n_ref, src_ref, held_ref, out_ref):
+        n = n_ref[0]
+
+        @pl.when(pl.program_id(0) < n)
+        def _():
+            out_ref[...] = src_ref[...]
+
+        @pl.when(n == 0)
+        def _():
+            out_ref[...] = held_ref[...]
+
+    block = (None, slab, W)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(P, C),
+            in_specs=[pl.BlockSpec(block, src), pl.BlockSpec(block, held)],
+            out_specs=pl.BlockSpec(block, dst)),
+        out_shape=jax.ShapeDtypeStruct(into.shape, into.dtype),
+        input_output_aliases={3: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=_interpret(),
+    )(dest.astype(jnp.int32), jnp.asarray(n, jnp.int32).reshape(1),
+      packed, into)
 
 
 def _pad_cols(n: int) -> int:
@@ -591,7 +710,7 @@ def _framed(vals, ls, offsets, R: int) -> BandedMatrix:
 
 def pallas_forward_batch(reads, rlens, tpls, trans, tlens, width: int,
                          pr_miscall: float = MISMATCH_PROBABILITY,
-                         offsets=None) -> BandedMatrix:
+                         offsets=None, live=None) -> BandedMatrix:
     """Batched banded forward fills: reads (R, Imax) int8/int32, rlens (R,),
     tpls (R, Jmax), trans (R, Jmax, 4), tlens (R,).  Returns a FRAMED
     BandedMatrix (fwdbwd.BAND_LEAD): vals (R, band_frame_rows(Jmax + 1), W)
@@ -602,7 +721,11 @@ def pallas_forward_batch(reads, rlens, tpls, trans, tlens, width: int,
     rebanding, fwdbwd.guided_band_offsets); default diagonal layout.
     Must be monotone (any per-column advance is representable in the
     circular lane layout; columns whose bands do not overlap simply
-    carry no mass)."""
+    carry no mass).
+
+    live: optional traced count: only reads [0, live) are filled, in whole
+    kernel blocks; the vals and log-scales of the rest are unwritten
+    memory (_run_fill)."""
     R, Imax = reads.shape
     Jmax = tpls.shape[1]
     nc = band_frame_rows(Jmax + 1)
@@ -624,13 +747,14 @@ def pallas_forward_batch(reads, rlens, tpls, trans, tlens, width: int,
 
     cm, cd, cc, mask = _pad_r([cm, cd, cc, mask], R, Rp, axis=1)
     seed, seedcol = _pad_r([seed, seedcol], R, Rp)
-    vals, ls = _run_fill(cm, cd, cc, mask, seed, seedcol, rev_store=False)
+    vals, ls = _run_fill(cm, cd, cc, mask, seed, seedcol, rev_store=False,
+                         live=live)
     return _framed(vals, ls, offsets, R)
 
 
 def pallas_backward_batch(reads, rlens, tpls, trans, tlens, width: int,
                           pr_miscall: float = MISMATCH_PROBABILITY,
-                          offsets=None) -> BandedMatrix:
+                          offsets=None, live=None) -> BandedMatrix:
     """Batched banded backward fills; same conventions as
     pallas_forward_batch."""
     R, Imax = reads.shape
@@ -652,13 +776,34 @@ def pallas_backward_batch(reads, rlens, tpls, trans, tlens, width: int,
 
     cm, cd, cc, mask = _pad_r([cm, cd, cc, mask], R, Rp, axis=1)
     seed, seedcol = _pad_r([seed, seedcol], R, Rp)
-    vals, ls = _run_fill(cm, cd, cc, mask, seed, seedcol, rev_store=True)
+    vals, ls = _run_fill(cm, cd, cc, mask, seed, seedcol, rev_store=True,
+                         live=live)
     return _framed(vals, ls, offsets, R)
 
 
 # --------------------------------------------------------------------------
 # batched log-likelihoods (masked reductions; no per-read gathers)
 # --------------------------------------------------------------------------
+
+
+def _scale_total(log_scales, J):
+    """(R,) sum of each read's column log-scales over columns [0, J], in
+    an order that is the code's own: the halves of the row are added
+    elementwise until one column is left.  A jnp.sum is summed in an
+    order XLA picks from the operand's shape and layout: on the chip a
+    pass of 64 reads and a batch of 384 summed the same 2,305 log-scales
+    of a read one or two ulp apart (PR 30), which is enough to move a QV
+    character.  Elementwise float adds are not reassociated, so a read's
+    likelihood is the same bits in whatever batch, pass or layout it is
+    summed."""
+    cols = jnp.arange(log_scales.shape[1], dtype=jnp.int32)[None, :]
+    x = jnp.where(cols <= J, log_scales, 0.0)
+    n = 1 << (x.shape[1] - 1).bit_length()
+    x = jnp.pad(x, ((0, 0), (0, n - x.shape[1])))
+    while n > 1:
+        n //= 2
+        x = x[:, :n] + x[:, n:]
+    return x[:, 0]
 
 
 def forward_loglik_batch(alpha: BandedMatrix, rlens, tlens):
@@ -669,14 +814,12 @@ def forward_loglik_batch(alpha: BandedMatrix, rlens, tlens):
     rows = jnp.arange(alpha.vals.shape[1], dtype=jnp.int32)[None, :]
     final = jnp.sum(jnp.where((rows == J + band_lead(alpha))[:, :, None],
                               alpha.vals, 0.0), axis=(1, 2))
-    jcols = jnp.arange(alpha.log_scales.shape[1], dtype=jnp.int32)[None, :]
-    ls = jnp.sum(jnp.where(jcols <= J, alpha.log_scales, 0.0), axis=1)
-    return jnp.log(jnp.maximum(final, _TINY)) + ls
+    return jnp.log(jnp.maximum(final, _TINY)) + _scale_total(
+        alpha.log_scales, J)
 
 
 def backward_loglik_batch(beta: BandedMatrix, tlens):
     J = tlens.astype(jnp.int32)[:, None]
-    jcols = jnp.arange(beta.log_scales.shape[1], dtype=jnp.int32)[None, :]
     b00 = beta.vals[:, band_lead(beta), 0]
-    ls = jnp.sum(jnp.where(jcols <= J, beta.log_scales, 0.0), axis=1)
-    return jnp.log(jnp.maximum(b00, _TINY)) + ls
+    return jnp.log(jnp.maximum(b00, _TINY)) + _scale_total(
+        beta.log_scales, J)

@@ -354,7 +354,7 @@ def premarshal(tasks: Sequence[ZmwTask], *,
                                              "guided_passes"))
 def _batch_setup(tpls, tlens, tables, reads, rlens, strands, tstarts, tends,
                  width: int, use_pallas: bool, mesh: Mesh | None = None,
-                 guided_passes: int = 0):
+                 guided_passes: int = 0, real_rows=None):
     """Per-ZMW template tracks + per-read window fills + moments.
 
     All leading axes are (Z, ...) with reads (Z, R, Imax).  `tables` are the
@@ -362,7 +362,9 @@ def _batch_setup(tpls, tlens, tables, reads, rlens, strands, tstarts, tends,
     (snr_to_transition_table_host) so batched and per-ZMW scorers agree.
     Window building vmaps over (ZMW, read); the alpha/beta fills run on the
     flattened (Z*R) read batch so the Pallas kernel path serves every read
-    in one launch."""
+    in one launch.  With `real_rows` ((Z, R) bool) the fills leave the
+    lanes that hold no read unfilled (zero bands, zero likelihoods:
+    scorer.fill_alpha_beta_batch's `need`)."""
 
     def one_zmw(tpl, L, table, st1, ts1, te1):
         trans_f = template_transition_params(tpl, table, L)
@@ -386,7 +388,7 @@ def _batch_setup(tpls, tlens, tables, reads, rlens, strands, tstarts, tends,
 
     alpha, beta, ll_a, ll_b, apre, bsuf = fill_alpha_beta_batch_zr(
         reads, rlens, win_tpl, win_trans, wlens, width, use_pallas, mesh,
-        guided_passes=guided_passes)
+        guided_passes=guided_passes, need=real_rows)
     return (win_tpl, win_trans, wlens, alpha, beta,
             ll_a, ll_b, apre, bsuf,
             trans_f, tpl_r, trans_r, table, mu, var)
@@ -870,7 +872,8 @@ class BatchPolisher:
             # has no GSPMD partitioning rule
             use_pallas=fills_use_pallas(),
             mesh=self.mesh,
-            guided_passes=guided_fill_passes(self._Jmax))
+            guided_passes=guided_fill_passes(self._Jmax),
+            real_rows=self._shard(self._real_rows, 1))
         self.alpha, self.beta = alpha, beta
         self._tpl_dev = self._shard(tl)
         self._tpl32_dev = self._tpl_dev.astype(jnp.int32)
@@ -1291,7 +1294,7 @@ class BatchPolisher:
             history=jnp.zeros((Z, H), jnp.uint32),
             hist_n=jnp.zeros(Z, jnp.int32),
             overflow=jnp.asarray(False),
-            dlayout=dlayout)
+            dlayout=dlayout, fill_reads=jnp.zeros(2, jnp.int32))
 
     def refine_device(self, opts: RefineOptions | None = None,
                       skip=None, budget: int | None = None
@@ -1365,7 +1368,9 @@ class BatchPolisher:
                        out.iterations, out.n_tested, out.n_applied,
                        jnp.broadcast_to(out.overflow.astype(jnp.int32),
                                         (Z,)),
-                       jnp.broadcast_to(qv_fb.astype(jnp.int32), (Z,))],
+                       jnp.broadcast_to(qv_fb.astype(jnp.int32), (Z,)),
+                       jnp.broadcast_to(out.fill_reads[0], (Z,)),
+                       jnp.broadcast_to(out.fill_reads[1], (Z,))],
                       axis=1),
             out.tpl.astype(jnp.int32),
             out.tstarts.astype(jnp.int32),
@@ -1375,17 +1380,19 @@ class BatchPolisher:
         h = device_fetch(packed, np.int64)
         tlens_h, conv_h, iters_h = h[:, 0], h[:, 1], h[:, 2]
         tested_h, applied_h, overflow_h = h[:, 3], h[:, 4], h[:, 5]
+        obs_flight.record_fill_reads(h[0, 7], h[0, 8])
         if overflow_h[0]:
             return None  # host loop re-runs from the polisher's last state
+        planes = h[:, 9:]   # template, window starts, window ends, QVs
         if not h[0, 6]:  # no tiny-window fallback in the QV sweep
             self._cont.qv_cache = (frozenset(skip or ()),
-                                   h[:, 7 + Jmax + 2 * R:].astype(np.int32))
+                                   planes[:, Jmax + 2 * R:].astype(np.int32))
 
-        tpl_h = h[:, 7: 7 + Jmax].astype(np.int8)
+        tpl_h = planes[:, :Jmax].astype(np.int8)
         for z in range(self.n_zmws):
             self.tpls[z] = tpl_h[z, : tlens_h[z]].copy()
-        self._tstarts = h[:, 7 + Jmax: 7 + Jmax + R].astype(np.int32)
-        self._tends = h[:, 7 + Jmax + R: 7 + Jmax + 2 * R].astype(np.int32)
+        self._tstarts = planes[:, Jmax: Jmax + R].astype(np.int32)
+        self._tends = planes[:, Jmax + R: Jmax + 2 * R].astype(np.int32)
         self._tpl_lengths_cache = None
 
         # adopt the loop's final device state so the QV sweep reuses it

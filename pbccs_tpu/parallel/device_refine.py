@@ -344,6 +344,18 @@ class RefineLoopState(NamedTuple):
     # previous rebuild's baked buffers instead of re-deriving the
     # layout in-graph every round.
     dlayout: typing.Any = None
+    # (2,) int32: reads the loop's rebuilds filled, and reads they would
+    # have filled had each refilled the whole batch (Z * R a rebuild);
+    # fetched with the outcome, ccs_refine_fill_reads_total.  A state
+    # built without one starts at zero.
+    fill_reads: typing.Any = None
+
+
+def reads_to_refill(applied, real_rows):
+    """(Z, R) bool: the reads a rebuild of run_refine_loop refills, those
+    of the ZMWs that applied a mutation this round ((Z,) bool) whose lane
+    holds a read."""
+    return applied[:, None] & real_rows
 
 
 def _chunk_count(jmax: int, chunk: int) -> int:
@@ -366,12 +378,13 @@ def slot_geometry(ts, te, strand, ms, me, is_ins):
 
 def _state_layout(reads, rlens, win_tpl, win_trans, wlens, table,
                   alpha: BandedMatrix, beta: BandedMatrix, a_prefix,
-                  b_suffix, width: int):
+                  b_suffix, width: int, windows=None):
     """(Z, R)-leading DenseLayout for RefineLoopState.dlayout: flatten
     the batch to the kernel's (Z*R)-flat read frame, bake the layout
     (ops.dense_score_pallas.build_dense_layout), reshape leaves back.
-    Plain function for enclosing traces (the loop's rebuild);
-    state_layout below is the jitted prepare-time entry."""
+    Plain function for enclosing traces (the loop's rebuild, which hands
+    in the read `windows` it holds); state_layout below is the jitted
+    prepare-time entry."""
     from pbccs_tpu.ops.dense_score_pallas import build_dense_layout
 
     Z, R = reads.shape[:2]
@@ -381,7 +394,8 @@ def _state_layout(reads, rlens, win_tpl, win_trans, wlens, table,
     lay = build_dense_layout(flat(reads), flat(rlens), flat(win_tpl),
                              flat(win_trans), flat(wlens), tables,
                              *jax.tree.map(flat, (alpha, beta)),
-                             flat(a_prefix), flat(b_suffix), width)
+                             flat(a_prefix), flat(b_suffix), width,
+                             jax.tree.map(flat, windows))
     return jax.tree.map(lambda a: a.reshape((Z, R) + a.shape[1:]), lay)
 
 
@@ -745,8 +759,7 @@ def run_refine_loop(state: "RefineLoopState", reads, rlens, strands, table,
     sub-batch is a host-side construct that would break the sharding."""
     from pbccs_tpu.models.arrow.params import (revcomp_padded,
                                                template_transition_params)
-    from pbccs_tpu.models.arrow.scorer import (fill_alpha_beta_batch_zr,
-                                               oriented_window)
+    from pbccs_tpu.models.arrow.scorer import oriented_window
     from pbccs_tpu.parallel import batch as batchmod
 
     Z, R = reads.shape[:2]
@@ -756,28 +769,78 @@ def run_refine_loop(state: "RefineLoopState", reads, rlens, strands, table,
     # it when present and rebuilds it whenever the fills rebuild)
     with_layout = state.dlayout is not None
 
-    def rebuild(tpl, tlens, tstarts, tends, active):
-        def one_zmw(t, L, tb, st1, ts1, te1):
-            trans_f = template_transition_params(t, tb, L)
-            t_r = revcomp_padded(t, L)
-            trans_r = template_transition_params(t_r, tb, L)
-            win = jax.vmap(
-                lambda s, a, b: oriented_window(s, a, b, t, t_r, L, tb)
-            )(st1, ts1, te1)
-            return win + (trans_f, t_r, trans_r)
+    flat = lambda a: a.reshape((Z * R,) + a.shape[2:])
+    unflat = lambda a: a.reshape((Z, R) + a.shape[1:])
 
-        (win_tpl, win_trans, wlens, trans_f, tpl_r, trans_r) = jax.vmap(
-            one_zmw)(tpl, tlens, table, strands, tstarts, tends)
-        alpha, beta, ll_a, ll_b, apre, bsuf = fill_alpha_beta_batch_zr(
-            reads, rlens, win_tpl, win_trans, wlens, width, use_pallas,
-            guided_passes=guided_passes)
-        active = batchmod._update_active.__wrapped__(
-            active, ll_a, ll_b, rlens, tstarts, tends)
+    def zmw_tracks(t, L, tb):
+        t_r = revcomp_padded(t, L)
+        return (template_transition_params(t, tb, L), t_r,
+                template_transition_params(t_r, tb, L))
+
+    def rebuild(tpl, tlens, tstarts, tends, applied, st: RefineLoopState):
+        """Windows, fills and layout against the templates of this round.
+        A read is refilled only if its ZMW applied a mutation this round
+        and its lane holds a read (reads_to_refill): every other read has
+        the template, window and offsets it had, so its bands are what a
+        refill would write, and it keeps them, with its likelihoods and
+        its gate.  One pass a chunk of the reads that need it
+        (scorer.for_needed_reads): the read's window of its ZMW's new
+        template, its fills (scorer.fill_pass) and, for the dense layout,
+        its band read windows (the alpha fill's own: one computation,
+        placed like a band).  Under a mesh each device packs the needed
+        reads of its own block."""
+        from pbccs_tpu.ops.fwdbwd_pallas import band_read_windows
+        from pbccs_tpu.models.arrow.scorer import (_scale_sums, band_placer,
+                                                   fill_pass,
+                                                   for_needed_reads,
+                                                   put_rows)
+
+        need = reads_to_refill(applied, real_rows)
+        trans_f, tpl_r, trans_r = jax.vmap(zmw_tracks)(tpl, tlens, table)
+        per_read = tuple(map(flat, (reads, rlens, strands, tstarts, tends)))
+        per_zmw = (tpl, tpl_r, tlens, table)
+        place = band_placer(use_pallas)
+
+        def one_pass(idx, live, carry):
+            wins, bands, lls, lay = carry
+            f_reads, f_rlens, st1, ts1, te1 = (
+                jnp.take(a, idx, axis=0) for a in per_read)
+            win = jax.vmap(oriented_window)(
+                st1, ts1, te1, *(jnp.take(a, idx // R, axis=0)
+                                 for a in per_zmw))
+            wins = tuple(put_rows(old, idx, live, new)
+                         for old, new in zip(wins, win))
+            bands, lls, (alpha_c, _) = fill_pass(
+                (f_reads, f_rlens, *win), idx, live, bands, lls, width,
+                use_pallas, guided_passes=guided_passes)
+            if lay is not None:
+                lay = tuple(
+                    place(new, idx, live, old) for old, new in zip(
+                        lay, band_read_windows(f_reads, alpha_c.offsets,
+                                               width, lay[0].shape[1])))
+            return wins, bands, lls, lay
+
+        wins, (alpha, beta), lls, lay = for_needed_reads(
+            flat(need), one_pass,
+            (tuple(map(flat, (st.win_tpl, st.win_trans, st.wlens))),
+             jax.tree.map(flat, (st.alpha, st.beta)),
+             # (ll_a, ll_b): a read that is not refilled keeps its
+             # baseline (ll_b); its ll_a is not looked at
+             (flat(st.baselines),) * 2,
+             tuple(map(flat, st.dlayout[:2])) if with_layout else None))
+        apre, bsuf = jax.tree.map(unflat, _scale_sums(alpha, beta))
+        win_tpl, win_trans, wlens = map(unflat, wins)
+        alpha, beta, (ll_a, ll_b), lay = jax.tree.map(
+            unflat, (alpha, beta, lls, lay))
+        active = jnp.where(need, batchmod._update_active.__wrapped__(
+            st.active, ll_a, ll_b, rlens, tstarts, tends), st.active)
         dlay = _state_layout(reads, rlens, win_tpl, win_trans, wlens,
-                             table, alpha, beta, apre, bsuf,
-                             width) if with_layout else None
+                             table, alpha, beta, apre, bsuf, width,
+                             lay) if with_layout else None
+        counts = jnp.stack([need.sum(dtype=jnp.int32), jnp.int32(need.size)])
         return (win_tpl, win_trans, wlens, alpha, beta, apre, bsuf,
-                ll_b, trans_f, tpl_r, trans_r, active, dlay)
+                ll_b, trans_f, tpl_r, trans_r, active, dlay,
+                st.fill_reads + counts)
 
     def score_all(st: RefineLoopState, start, end, mtype, base, valid):
         return score_slot_grid(st, reads, rlens, strands, table, real_rows,
@@ -883,11 +946,11 @@ def run_refine_loop(state: "RefineLoopState", reads, rlens, strands, table,
         # converging batch)
         same = (st.win_tpl, st.win_trans, st.wlens, st.alpha, st.beta,
                 st.a_prefix, st.b_suffix, st.baselines, st.trans_f,
-                st.tpl_r, st.trans_r, st.active, st.dlayout)
+                st.tpl_r, st.trans_r, st.active, st.dlayout, st.fill_reads)
         (win_tpl, win_trans, wlens, alpha, beta, apre, bsuf, baselines,
-         trans_f, tpl_r, trans_r, active, dlayout) = lax.cond(
+         trans_f, tpl_r, trans_r, active, dlayout, fill_reads) = lax.cond(
             apply_mask.any(),
-            lambda: rebuild(tpl, tlens, tstarts, tends, st.active),
+            lambda: rebuild(tpl, tlens, tstarts, tends, apply_mask, st),
             lambda: same)
 
         # 7. next round's nearby filter from this round's favorables
@@ -911,7 +974,7 @@ def run_refine_loop(state: "RefineLoopState", reads, rlens, strands, table,
             it=st.it + 1, done=done_now, converged=converged,
             iterations=iterations, n_tested=n_tested, n_applied=n_applied,
             allowed=allowed, history=history, hist_n=hist_n,
-            overflow=overflow, dlayout=dlayout)
+            overflow=overflow, dlayout=dlayout, fill_reads=fill_reads)
 
     # Straggler early exit: each lockstep round costs full (Z, ...) compute
     # whatever the active count, so once only a handful of ZMWs remain
@@ -930,7 +993,14 @@ def run_refine_loop(state: "RefineLoopState", reads, rlens, strands, table,
                 & (live > straggler_exit)
                 & ~st.overflow)
 
-    return lax.while_loop(cond, body, state)
+    if state.fill_reads is None:
+        state = state._replace(fill_reads=jnp.zeros(2, jnp.int32))
+    out = lax.while_loop(cond, body, state)
+    if axis is not None:
+        # each device counted its own reads (a rebuild is a local branch:
+        # no collective may sit in it); the outcome carries the mesh's sum
+        out = out._replace(fill_reads=lax.psum(out.fill_reads, axis))
+    return out
 
 
 def _state_specs(zmw: str, read: str,
@@ -952,7 +1022,8 @@ def _state_specs(zmw: str, read: str,
         baselines=zr, trans_f=z, tpl_r=z, trans_r=z, active=zr,
         it=rep, done=z, converged=z, iterations=z, n_tested=z,
         n_applied=z, allowed=z, history=z, hist_n=z, overflow=rep,
-        dlayout=DenseLayout(*([zr] * 4)) if with_layout else None)
+        dlayout=DenseLayout(*([zr] * 4)) if with_layout else None,
+        fill_reads=rep)
 
 
 @functools.lru_cache(maxsize=64)

@@ -111,12 +111,15 @@ def test_batch_sharded_pallas_fills(rng, monkeypatch):
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("tpl_len", [60, 300])
+@pytest.mark.parametrize("tpl_len,pallas", [(60, False), (300, False),
+                                            (60, True)])
 def test_batch_sharded_device_refine_matches_unsharded(rng, monkeypatch,
-                                                       tpl_len):
+                                                       tpl_len, pallas):
     """The sharded device-resident refinement loop (shard_map over the
     ('zmw', 'read') mesh with read-axis psum) produces the same templates,
-    refine stats, and QVs as the single-device device loop.
+    refine stats, and QVs as the single-device device loop.  With
+    `pallas` the fills are the kernel's (interpreted): each device's
+    rebuild packs the needed reads of its own block (PR 30).
 
     tpl_len=300 runs a multi-block (NB=6) bucket so the mesh path covers
     the halo-block streaming, the W(L) schedule, and the live-mask einsum
@@ -134,6 +137,8 @@ def test_batch_sharded_device_refine_matches_unsharded(rng, monkeypatch,
 
     monkeypatch.setenv("PBCCS_DEVICE_REFINE", "1")
     monkeypatch.setenv("PBCCS_DENSE", "1")
+    if pallas:
+        monkeypatch.setenv("PBCCS_PALLAS", "1")
     plain = BatchPolisher(tasks)
     rp = plain.refine(opts)
     qp = plain.consensus_qvs()

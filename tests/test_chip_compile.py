@@ -128,10 +128,13 @@ def _bucket_case(z, r, jmax):
     width, guided = _bucket_statics(jmax)
 
     def fn(*a):
-        return batch.lowering_target()(*a, width, use_pallas=True, mesh=None,
-                                       guided_passes=guided)
+        # as BatchPolisher launches it: the lanes that hold a read named
+        return batch.lowering_target()(*a[:-1], width, use_pallas=True,
+                                       mesh=None, guided_passes=guided,
+                                       real_rows=a[-1])
 
-    return fn, _bucket_shapes(z, r, jmax)
+    return fn, _bucket_shapes(z, r, jmax) + (
+        jax.ShapeDtypeStruct((z, r), jnp.bool_),)
 
 
 def _refine_loop_case(z, r, jmax):
@@ -234,6 +237,9 @@ def test_compiles_for_v5e(build, shape, one_chip, no_persistent_cache,
 # Band-sized layout-only instructions the optimised loop program may hold:
 # (opcode, where it comes from (op_name under run_refine_loop), how many, why).
 # Anything else of a band tensor's element count or more fails the guard.
+# Inside the rebuild's passes (_REBUILD_PASS: scorer.for_needed_reads' loop
+# in the rebuild's branch) a band tensor is a pass's, _FILL_CHUNK reads'.
+_REBUILD_PASS = r"^while/body/cond/branch_1_fun/while/body/"
 ALLOWED_BAND_LAYOUT_OPS = [
     ("copy", r"^$", 10,
      "the program's boundary, once a dispatch: arrays cross jitted programs "
@@ -242,28 +248,29 @@ ALLOWED_BAND_LAYOUT_OPS = [
      "72-lane patch plane where W = 64 makes it band-sized)"),
     ("copy", r"^while/body/cond$", 1,
      "W = 64 only: the 72-lane patch plane leaves the rebuild's branch"),
-    ("copy", r"^while/body/cond/branch_1_fun/transpose$", 1,
+    ("copy", _REBUILD_PASS + r"transpose$", 1,
      "the alpha fill's read windows, computed read-major with the layout's "
      "and turned columns-leading for the coefficients (the kernel loads a "
      "column by address arithmetic on its untiled leading axis)"),
-    ("copy", r"^while/body/cond/branch_1_fun/vmap\(\)/dot_general$", 2,
+    ("copy", _REBUILD_PASS + r"vmap\(\)/dot_general$", 2,
      "the beta fill: the two halves of its own window matmul, turned before "
      "its coefficients are computed (sharing the alpha fill's windows through "
      "a reverse read wrong on the chip: fwdbwd_pallas._backward_coeffs)"),
-    ("pad", r"^while/body/cond/branch_1_fun/concatenate$", 7,
+    ("pad", _REBUILD_PASS + r"concatenate$", 7,
      "inside fusions, arithmetic: band_read_windows' lane rotation and "
      "previous-column shift (a roll is a concatenate of two slices)"),
-    ("concatenate", r"^while/body/cond/branch_1_fun/vmap\(\)/concatenate$", 2,
+    ("concatenate", _REBUILD_PASS + r"vmap\(\)/concatenate$", 2,
      "the bf16 im2col of the reads, the window matmuls' operand (one for "
      "read base i, one for base i-1)"),
 ]
 _LAYOUT_OPCODES = ("copy", "transpose", "pad", "concatenate", "slice")
 
 
-def band_layout_ops(hlo_text: str, floor: int):
+def band_layout_ops(hlo_text: str, floor: int, pass_floor: int | None = None):
     """(opcode, op_name under the loop program) of every layout-only HLO
-    instruction, fused or not, whose output has `floor` elements or more.
-    The Pallas kernels are opaque custom calls: nothing inside them counts."""
+    instruction, fused or not, whose output has `floor` elements or more
+    (`pass_floor` or more inside the rebuild's passes).  The Pallas
+    kernels are opaque custom calls: nothing inside them counts."""
     import re
 
     shape = re.compile(r"\b(?:pred|[suf]\d+|bf16)\[([\d,]*)\]")
@@ -274,11 +281,12 @@ def band_layout_ops(hlo_text: str, floor: int):
             continue
         sizes = [int(np.prod([int(d) for d in dims.split(",") if d]))
                  for dims in shape.findall(m.group(1))]
-        if max(sizes, default=0) < floor:
-            continue
         name = re.search(r'op_name="([^"]*)"', line)
         where = re.sub(r"^jit\(fn\)/jit\(run_refine_loop\)/?", "",
                        name.group(1) if name else "")
+        in_pass = pass_floor is not None and re.match(_REBUILD_PASS, where)
+        if max(sizes, default=0) < (pass_floor if in_pass else floor):
+            continue
         found.append((m.group(2), where))
     return found
 
@@ -295,6 +303,7 @@ def test_loop_program_rewrites_no_band(z, r, jmax, one_chip,
     import collections
     import re
 
+    from pbccs_tpu.models.arrow import scorer
     from pbccs_tpu.ops import dense_score_pallas, fwdbwd, fwdbwd_pallas
 
     monkeypatch.setattr(fwdbwd_pallas, "_interpret", lambda: False)
@@ -307,9 +316,13 @@ def test_loop_program_rewrites_no_band(z, r, jmax, one_chip,
         jax.clear_caches()
 
     width, _ = _bucket_statics(jmax)
-    band = z * r * fwdbwd.band_frame_rows(jmax + 1) * width
-    seen = collections.Counter(band_layout_ops(text, band))
-    assert text.count("tpu_custom_call") >= 4      # two fills, dense, edge rows
+    a_read = fwdbwd.band_frame_rows(jmax + 1) * width
+    seen = collections.Counter(band_layout_ops(
+        text, z * r * a_read, min(z * r, scorer._FILL_CHUNK) * a_read))
+    # the rebuild's passes are in sight: their im2col is found
+    assert any(re.match(_REBUILD_PASS, where) for _, where in seen)
+    # two fills, dense, edge rows; four place_reads a pass
+    assert text.count("tpu_custom_call") >= 8
     for (opcode, where), n in sorted(seen.items()):
         allowed = [cap for op, rx, cap, _ in ALLOWED_BAND_LAYOUT_OPS
                    if op == opcode and re.search(rx, where)]

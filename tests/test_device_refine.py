@@ -431,3 +431,106 @@ def pr28_loop_run():
                         "refine_loop_parent.json")
     with open(path) as f:
         return got, json.load(f)
+
+
+# --------------------------------------------------------------------------
+# PR 30: a rebuild refills the reads of the ZMWs that applied, no others
+# --------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("what", ["templates", "qvs", "converged",
+                                  "iterations", "n_tested", "n_applied",
+                                  "fill_reads"])
+def test_refilling_the_needed_reads_gives_the_full_refills_outputs(
+        what, ragged_loop_runs):
+    """Six ZMWs of 3-10 reads in 12 read lanes whose drafts are 0-3 edits
+    from the truth, so they leave the loop in different rounds: the loop
+    that refills the applying ZMWs' real reads gives the templates, QVs
+    and counters of the loop that refills every lane at every rebuild
+    (as the loop did before PR 30), and counts the reads it filled."""
+    needed, every = ragged_loop_runs
+    applies = [i - c for i, c in zip(needed["iterations"],
+                                     needed["converged"])]
+    capacity = max(applies) * needed["lanes"]
+    # the reference did refill every lane: an inert patch fails here
+    assert every["fill_reads"] == (capacity, capacity)
+    if what != "fill_reads":
+        assert needed[what] == every[what]
+        return
+    assert len(set(applies)) > 1        # they leave in different rounds
+    filled = sum(a * n for a, n in zip(applies, needed["n_reads"]))
+    assert needed["fill_reads"] == (filled, capacity)
+    assert 0 < filled < capacity
+
+
+@pytest.mark.parametrize("what", ["templates", "qvs", "converged",
+                                  "iterations", "n_tested", "n_applied"])
+def test_ragged_loop_gives_the_parents_outputs(what, ragged_loop_runs):
+    """The same six ZMWs against what PR 29's tree, whose every rebuild
+    refilled every lane in one call, gave for them
+    (tests/fixtures/pr30/ragged_loop_parent.json)."""
+    import json
+    import os
+
+    needed, _ = ragged_loop_runs
+    path = os.path.join(os.path.dirname(__file__), "fixtures", "pr30",
+                        "ragged_loop_parent.json")
+    with open(path) as f:
+        assert needed[what] == json.load(f)[what]
+
+
+@pytest.fixture(scope="module")
+def ragged_loop_runs():
+    import jax
+    import jax.numpy as jnp
+
+    from pbccs_tpu.models.arrow.refine import RefineOptions
+    from pbccs_tpu.obs.metrics import default_registry
+    from pbccs_tpu.parallel.batch import BatchPolisher, ZmwTask
+    from pbccs_tpu.simulate import simulate_zmw
+
+    def run():
+        rng = np.random.default_rng(3030)
+        tasks = []
+        for z, (passes, edits) in enumerate([(3, 0), (10, 3), (5, 1),
+                                             (8, 2), (4, 3), (7, 0)]):
+            tpl, reads, strands, snr = simulate_zmw(rng, 70, passes)
+            draft = tpl.copy()
+            for e in range(edits):
+                at = 12 + 17 * e + z
+                draft[at] = (draft[at] + 1) % 4
+            tasks.append(ZmwTask(f"pr30/{z}", draft, snr, reads, strands,
+                                 [0] * passes, [len(draft)] * passes))
+        counter = lambda kind: int(default_registry().counter(
+            "ccs_refine_fill_reads_total", kind=kind).value)
+        before = counter("filled"), counter("capacity")
+        p = BatchPolisher(tasks, buckets=(128, 96, 12))
+        assert p._R == 12
+        res = p.refine_device(RefineOptions(max_iterations=10))
+        qvs = p.consensus_qvs()
+        return {"templates": [np.asarray(t).tolist() for t in p.tpls],
+                "qvs": [np.asarray(q).tolist() for q in qvs],
+                "converged": [bool(r.converged) for r in res],
+                "iterations": [int(r.iterations) for r in res],
+                "n_tested": [int(r.n_tested) for r in res],
+                "n_applied": [int(r.n_applied) for r in res],
+                "n_reads": [len(t.reads) for t in tasks],
+                "lanes": p._Z * p._R,
+                "fill_reads": (counter("filled") - before[0],
+                               counter("capacity") - before[1])}
+
+    mp = pytest.MonkeyPatch()
+    for k in ("PBCCS_DENSE", "PBCCS_PALLAS", "PBCCS_DEVICE_REFINE"):
+        mp.setenv(k, "1")
+    try:
+        needed = run()
+        # the loop as it was: every rebuild refills every lane, the lanes
+        # of the ZMWs that applied nothing and the lanes with no read too
+        mp.setattr(dr, "reads_to_refill",
+                   lambda applied, real_rows: jnp.ones_like(real_rows))
+        jax.clear_caches()
+        every = run()
+    finally:
+        mp.undo()
+        jax.clear_caches()
+    return needed, every

@@ -277,3 +277,138 @@ def test_band_frame_of_a_plain_band_round_trips(batch):
     np.testing.assert_array_equal(np.asarray(fb.band_columns(framed).vals),
                                   np.asarray(plain.vals))
     assert fb.band_frame(framed) is framed
+
+
+# --------------------------------------------------------------------------
+# PR 30: the fill skips read blocks that hold no live read
+# --------------------------------------------------------------------------
+
+
+def _live_counts(R):
+    """Live-read counts that end inside a block, on a block's edge and,
+    where the batch has one, inside its second block."""
+    return sorted({1, min(R, 31), min(R, 32), min(R, 33), R})
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["alpha", "beta"])
+def test_gated_fill_is_the_ungated_fill_on_its_live_reads(frame_case,
+                                                           backward):
+    """A fill told that reads [0, live) alone need one writes them what
+    the ungated fill writes, bit for bit, block by whole block; NaN in
+    the inputs of every other read changes nothing in them (no read's
+    scan looks at another's, and a dead block's never ran)."""
+    c, (reads, rlens, tpls, trans, tlens) = frame_case
+    fill = fp.pallas_backward_batch if backward else fp.pallas_forward_batch
+    want = fill(reads, rlens, tpls, trans, tlens, c["W"])
+    for live in _live_counts(c["R"]):
+        dead = jnp.arange(c["R"]) >= live
+        poisoned = jnp.where(dead[:, None, None], jnp.nan, trans)
+        got = fill(reads, rlens, tpls, poisoned, tlens, c["W"],
+                   live=jnp.int32(live))
+        for g, w in ((got.vals, want.vals), (got.log_scales,
+                                             want.log_scales)):
+            np.testing.assert_array_equal(np.asarray(g)[:live],
+                                          np.asarray(w)[:live])
+
+
+def test_fill_given_no_liveness_is_the_program_it_was(frame_case):
+    """With no `live` the call prefetches no scalar and keeps its read
+    blocks independent, as before the gate (its output is pinned above,
+    against the unframed fill); with one, it prefetches the block count."""
+    c, batch = frame_case
+
+    def call_params(**kw):
+        jaxpr = jax.make_jaxpr(
+            lambda *a: fp.pallas_forward_batch(*a, c["W"], **kw))(*batch)
+        (eqn,) = [e for e in jaxpr.jaxpr.eqns
+                  if e.primitive.name == "pallas_call"]
+        return (eqn.params["grid_mapping"].num_index_operands,
+                eqn.params["compiler_params"]["mosaic_tpu"]
+                .dimension_semantics[0], len(eqn.outvars))
+
+    assert call_params() == (0, "parallel", 2)
+    assert call_params(live=jnp.int32(1)) == (1, "arbitrary", 2)
+
+
+@pytest.mark.parametrize("n", [0, 1, 5, 40])
+def test_place_reads_moves_the_placed_rows_alone(n):
+    rng = np.random.default_rng(30)
+    packed = jnp.asarray(rng.normal(size=(64, 4160, 48)).astype(np.float32))
+    into = jnp.asarray(rng.normal(size=(40, 4160, 48)).astype(np.float32))
+    dest = np.concatenate([rng.permutation(40), np.zeros(24)]).astype(np.int32)
+    got = fp.place_reads(packed, jnp.asarray(dest), jnp.int32(n), into)
+    want = np.asarray(into).copy()
+    want[dest[:n]] = np.asarray(packed)[:n]
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def test_needed_reads_are_refilled_and_the_rest_kept(frame_case):
+    """fill_alpha_beta_batch with `need`: the needed reads get the bands,
+    log-scales and likelihoods a fill of every read gives them (to float32
+    rounding here: the passes run inside a loop, which the CPU compiles
+    with other fused multiply-adds than the eager full fill; on the chip
+    the kernel is one Mosaic program either way) and every other read
+    zero bands; passes over bands a caller carries (fill_pass under
+    for_needed_reads, as the refine loop's rebuild runs them) leave every
+    other read the bands and likelihoods it went in with, bit for bit."""
+    from pbccs_tpu.models.arrow.scorer import (fill_alpha_beta_batch,
+                                               fill_pass, for_needed_reads)
+
+    c, batch = frame_case
+    rng = np.random.default_rng(30 + c["R"])
+    full = fill_alpha_beta_batch(*batch, c["W"], use_pallas=True)
+    prev = jax.tree.map(lambda x: x + 1, (full[:2], full[2:4]))
+
+    def one_pass(idx, live, carry):
+        take = lambda a: jnp.take(a, idx, axis=0)
+        return fill_pass(tuple(map(take, batch)), idx, live, *carry,
+                         c["W"], True)[:2]
+
+    for share in (0.0, 0.3, 1.0):
+        need = rng.random(c["R"]) < share
+        got = for_needed_reads(jnp.asarray(need), one_pass, prev)
+        for g, f in zip(jax.tree.leaves(got), jax.tree.leaves(full[:4])):
+            np.testing.assert_allclose(np.asarray(g)[need],
+                                       np.asarray(f)[need], rtol=2e-5,
+                                       atol=1e-6)
+        for g, p in zip(jax.tree.leaves(got), jax.tree.leaves(prev)):
+            np.testing.assert_array_equal(np.asarray(g)[~need],
+                                          np.asarray(p)[~need])
+    half = np.arange(c["R"]) < c["R"] // 2
+    fresh = fill_alpha_beta_batch(*batch, c["W"], use_pallas=True,
+                                  need=jnp.asarray(half))
+    for g, f in zip(jax.tree.leaves(fresh), jax.tree.leaves(full)):
+        np.testing.assert_allclose(np.asarray(g)[half], np.asarray(f)[half],
+                                   rtol=2e-5, atol=1e-6)
+    for band in fresh[:2]:
+        assert not np.asarray(band.vals)[~half].any()
+        assert np.asarray(band.vals)[half].any()
+
+
+@pytest.mark.parametrize("cols", [1, 97, 577, 2305])
+def test_a_reads_scale_total_is_the_same_bits_in_any_batch(cols):
+    """The likelihoods' sum of log-scales is a pairwise sum written out in
+    elementwise adds: a read's total is what the same tree gives in numpy
+    float32, whether it is summed alone, in a pass of 64 reads or in a
+    batch of 384, so a refill in passes gives the baselines of a refill
+    in one call (on the chip a jnp.sum did not: PERF.md, PR 30)."""
+    rng = np.random.default_rng(cols)
+    x = rng.normal(-1.0, 0.5, size=(384, cols)).astype(np.float32)
+    J = rng.integers(0, cols, size=(384, 1)).astype(np.int32)
+
+    want = np.where(np.arange(cols)[None, :] <= J, x, np.float32(0))
+    n = 1 << (cols - 1).bit_length()
+    want = np.pad(want, ((0, 0), (0, n - cols)))
+    while n > 1:
+        n //= 2
+        want = want[:, :n] + want[:, n:]
+    total = jax.jit(fp._scale_total)
+    for rows in (1, 64, 384):
+        got = np.concatenate([
+            np.asarray(total(jnp.asarray(x[k: k + rows]),
+                             jnp.asarray(J[k: k + rows])))
+            for k in range(0, 384, rows)])
+        np.testing.assert_array_equal(got, want[:, 0])
+    exact = np.where(np.arange(cols)[None, :] <= J, x, 0).astype(
+        np.float64).sum(axis=1)
+    np.testing.assert_allclose(want[:, 0], exact, rtol=1e-6, atol=1e-5)
