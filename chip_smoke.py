@@ -43,8 +43,9 @@ CHILD_TIMEOUT_S = 1000
 # the CLI's default batch of 64 ZMWs; the kernel check is one fill bucket
 # of that configuration (256 reads, Jmax 2112, W 96).
 REAL = dict(n_zmws=256, tpl_len=2000, passes=(3, 10), serve_zmws=32,
-            kernel=(256, 2112, 96))
+            serve_args=("--bucket", "16x10x2000"), kernel=(256, 2112, 96))
 TINY = dict(n_zmws=8, tpl_len=120, passes=(3, 4), serve_zmws=4,
+            serve_args=("--bucket", "4x4x120", "--maxBatch", "4"),
             kernel=(8, 192, 64))
 SERVE_SESSIONS = 4
 SERVE_LEDGER_INTERVAL_S = 5.0
@@ -463,12 +464,15 @@ def phase_fleet(args) -> dict:
 
 def serve_phase(args, zmws: list, truth: dict, dev: dict) -> None:
     """`ccs serve` as its own process (the only one on the chip), this
-    parent its client."""
+    parent its client.  The server declares its deployment (`--bucket`),
+    so its ready line comes once the programs are loaded and names them;
+    the first wave then loads nothing."""
     from pbccs_tpu.obs.metrics import parse_exposition
     from pbccs_tpu.pipeline import Chunk, Subread
     from pbccs_tpu.serve.client import CcsClient
 
-    n = sizes_for(args.rehearse)["serve_zmws"]
+    sizes = sizes_for(args.rehearse)
+    n = sizes["serve_zmws"]
     chunks = [Chunk(f"{MOVIE}/{z}",
                     [Subread(f"{MOVIE}/{z}/{i}", r)
                      for i, r in enumerate(reads)], snr)
@@ -481,7 +485,8 @@ def serve_phase(args, zmws: list, truth: dict, dev: dict) -> None:
         proc = subprocess.Popen(
             [sys.executable, "-m", "pbccs_tpu.cli", "serve", "--port", "0",
              "--perfLedger", os.path.join(args.workdir, "serve_perf.ndjson"),
-             "--perfLedgerInterval", str(SERVE_LEDGER_INTERVAL_S)],
+             "--perfLedgerInterval", str(SERVE_LEDGER_INTERVAL_S),
+             *sizes["serve_args"]],
             stdout=subprocess.PIPE, stderr=log, text=True,
             env=child_env(args.rehearse), cwd=HERE)
     timer = threading.Timer(CHILD_TIMEOUT_S, proc.kill)
@@ -489,11 +494,13 @@ def serve_phase(args, zmws: list, truth: dict, dev: dict) -> None:
     try:
         port = None
         for line in proc.stdout:
-            m = re.search(r"CCS-SERVE-READY \S+ (\d+)", line)
+            m = re.search(r"CCS-SERVE-READY \S+ (\d+)(.*)", line)
             if m:
-                port = int(m.group(1))
+                port, warmed = int(m.group(1)), m.group(2).strip()
                 break
         check(port is not None, "ccs serve never printed its ready line")
+        check(re.search(r"shape_sets=[1-9]", warmed) is not None,
+              f"the ready line names no warmed shape set: {warmed!r}")
         # keep the pipe drained so the server never blocks on its stdout
         tail: list[str] = []
         drain = threading.Thread(
@@ -575,9 +582,9 @@ def serve_phase(args, zmws: list, truth: dict, dev: dict) -> None:
             proc.kill()
             proc.wait()
     say(f"timing: serve phase on {serve_platform} (the batch child saw "
-        f"{dev['kind']}): ready after {ready_s:.3f} s; first wave of {n} "
-        f"ZMWs {cold:.3f} s (compile and cache load included); steady wave "
-        f"{warm:.3f} s, "
+        f"{dev['kind']}): ready after {ready_s:.3f} s, its programs loaded "
+        f"({warmed}); first wave of {n} ZMWs {cold:.3f} s (nothing left to "
+        f"compile or load); steady wave {warm:.3f} s, "
         f"{n / warm:.3f} ZMW/s from {SERVE_SESSIONS} sessions; "
         "SIGTERM drained, exit 0")
 

@@ -96,6 +96,13 @@ _m_shape_sets = _reg.counter(
 _shape_sets_seen: set[tuple] = set()
 _shape_sets_lock = threading.Lock()
 
+
+def shape_sets_seen() -> set[tuple]:
+    """The (Imax, Jmax, R, Z, W) this process has built a polisher at."""
+    with _shape_sets_lock:
+        return set(_shape_sets_seen)
+
+
 # mutation-axis chunk: every scoring call uses this static M so one compiled
 # program serves every refinement round and the QV sweep
 MUT_CHUNK = 512
@@ -142,10 +149,10 @@ def _imax_step(n: int) -> int:
 
 def length_bucket(tpl_len: int, max_read_len: int) -> tuple[int, int]:
     """The (Jmax, Imax) compiled-shape bucket a ZMW of this geometry
-    polishes in -- the grouping key of the serving engine's dynamic
-    batcher (pbccs_tpu.serve.batcher): ZMWs that share a bucket share
-    every compiled polish program, so batching within a bucket never
-    mints new executables."""
+    would polish in alone -- the router's sticky-routing key
+    (pbccs_tpu.serve.router), read off raw read lengths before any
+    draft exists.  (The serving engine's batcher groups by the pin of
+    the ZMW's length class instead: ShapeMenu, below.)"""
     return _jmax_bucket(tpl_len), _imax_bucket(max_read_len + 8)
 
 
@@ -221,18 +228,33 @@ class ShapeMenu:
                 == _length_class_statics(own[1]))
 
     def shapes(self, n_zmws: int, max_reads: int, max_read_len: int,
-               max_tpl_len: int) -> tuple[int, int, int, int]:
-        """effective_shapes of these inputs under their class's pin."""
+               max_tpl_len: int, *, fit_lanes: bool = False
+               ) -> tuple[int, int, int, int]:
+        """effective_shapes of these inputs under their class's pin.
+
+        `fit_lanes` is `ccs serve`'s, whose flushes hold one ZMW or
+        sixteen: the batch also joins a pin of its lengths whose lanes
+        hold its reads however few of them it fills (a lone 3-pass ZMW
+        polishes in the 12 lanes its class was warmed at: the fills skip
+        a lane without a read), and a pin it fits as it stands comes
+        before one it would grow.  A batch driver's chunks do not ask for
+        it: a chunk of few passes keeps lanes of its own."""
         own = effective_shapes(n_zmws, max_reads, max_read_len, max_tpl_len)
         with self._lock:
-            for k, pin in enumerate(self._pins):
-                if self._same_class(pin, own[:3]):
-                    got = effective_shapes(n_zmws, max_reads, max_read_len,
-                                           max_tpl_len, buckets=pin)
-                    self._pins[k] = got[:3]
-                    return got
-            self._pins.append(own[:3])
-            return own
+            ks = [k for k, pin in enumerate(self._pins)
+                  if self._same_class(pin, own[:3])
+                  or (fit_lanes and own[2] <= pin[2]
+                      and self._same_class(pin, (*own[:2], pin[2])))]
+            if fit_lanes:
+                ks.sort(key=lambda k: any(
+                    o > p for o, p in zip(own[:3], self._pins[k])))
+            if not ks:
+                self._pins.append(own[:3])
+                return own
+            got = effective_shapes(n_zmws, max_reads, max_read_len,
+                                   max_tpl_len, buckets=self._pins[ks[0]])
+            self._pins[ks[0]] = got[:3]
+            return got
 
     def reset_for_tests(self) -> None:
         with self._lock:
@@ -667,7 +689,7 @@ class BatchPolisher:
                  min_zscore: float = float("nan"),
                  mesh: Mesh | None = None, *,
                  buckets: tuple[int, int, int] | None = None,
-                 min_z: int = 1,
+                 min_z: int = 1, fixed_z: bool = False,
                  prebaked: PrebakedBatch | None = None):
         """`buckets` = (Imax, Jmax, R) lower bounds and `min_z` a ZMW-axis
         lower bound: sub-batches carved out of a parent batch (straggler
@@ -676,6 +698,12 @@ class BatchPolisher:
         letting each draw's straggler count pick its own shapes compiled a
         fresh ~minute-long device loop mid-bench (the round-3 53x
         tail-latency outlier).
+
+        `fixed_z`: the caller polishes every batch of this bucket at this
+        one Z however many ZMWs it holds (`ccs serve`: `min_z` is its
+        --maxBatch), so the wide-band retry keeps that Z too and the
+        shape set's first polish loads it whatever Z is
+        (wide_band_sub, warm_shape_set).
 
         `prebaked`: a PrebakedBatch marshalled ahead of time on a prepare
         worker (pipeline.prebake_polish); adopted when its shapes match
@@ -686,6 +714,7 @@ class BatchPolisher:
         self.config = config or ArrowConfig()
         self.min_zscore = min_zscore
         self.mesh = mesh
+        self.fixed_z = fixed_z
         self.n_zmws = len(tasks)
         self.ids = [t.id for t in tasks]
         self.tpls: list[np.ndarray] = [np.asarray(t.tpl, np.int8) for t in tasks]
@@ -1522,8 +1551,10 @@ class BatchPolisher:
         """The 2x-band sub-batch of the pipeline's mating retry -- ONE
         shape recipe shared by the live retry (pipeline.
         _polish_batch_arrow) and warm_shape_set.  Shapes pin to the
-        parent's buckets + a pow2 Z so the data-dependent reband count
-        doesn't mint fresh compiles; 2x the EFFECTIVE width (the W(L)
+        parent's buckets + a pow2 Z (the parent's own under `fixed_z`:
+        one program however many ZMWs reband) so the data-dependent
+        reband count doesn't mint fresh compiles; 2x the EFFECTIVE width
+        (the W(L)
         schedule may have shrunk the parent below the configured width);
         a non-default width passes through the schedule."""
         wcfg = dataclasses.replace(
@@ -1533,7 +1564,9 @@ class BatchPolisher:
         return BatchPolisher(tasks, config=wcfg,
                              min_zscore=self.min_zscore,
                              buckets=(self._Imax, self._Jmax, self._R),
-                             min_z=next_pow2(len(tasks), 4))
+                             min_z=self._Z if self.fixed_z
+                             else next_pow2(len(tasks), 4),
+                             fixed_z=self.fixed_z)
 
     def warm_straggler_shapes(self, opts: RefineOptions | None = None
                               ) -> None:
@@ -1560,15 +1593,18 @@ class BatchPolisher:
         90 s at 2 kb).  The one recipe of `ccs warmup`
         (sched/warmup.py) and of the batch path, which calls it from
         the first polish of each shape set
-        (pipeline._polish_batch_arrow).  Only for a Z with a straggler
-        exit, a batch driver's: the small flushes of `ccs serve` keep
-        their cold path.  Not covered: a wide-band sub-batch of more
-        than four ZMWs."""
-        if self._Z // 32 < 1:
+        (pipeline._polish_batch_arrow), a flush of `ccs serve` among
+        them.  A batch driver's Z has a straggler exit or, below 32,
+        nothing to load here; a `fixed_z` polisher (every flush of
+        `ccs serve` at its --maxBatch) has no continuation and loads its
+        one wide-band program, at its own Z.  Not covered: a batch
+        driver's wide-band sub-batch of more than four ZMWs."""
+        if self._Z // 32 < 1 and not self.fixed_z:
             return
         with obs_trace.span("polish.warm", imax=self._Imax,
                             jmax=self._Jmax, r=self._R,
-                            z=self.straggler_shape_min_z()):
+                            z=self._Z if self.fixed_z
+                            else self.straggler_shape_min_z()):
             self.warm_straggler_shapes(opts)
             # built, gated and polished as the live retry does it
             wide = self.wide_band_sub(self._row_tasks([0], "warm"))

@@ -512,7 +512,8 @@ def prebake_polish(preps: Sequence[PreparedZmw], *,
 def _polish_batch_arrow(preps: Sequence[PreparedZmw],
                         settings: ConsensusSettings, *,
                         buckets: tuple[int, int, int] | None = None,
-                        min_z: int = 1, prebaked=None
+                        min_z: int = 1, fixed_z: bool = False,
+                        prebaked=None
                         ) -> list[tuple[Failure, ConsensusResult | None]]:
     """One lockstep BatchPolisher dispatch over `preps`: the raw Arrow
     device path, outcomes ALIGNED with `preps`.  Raises on any batch-path
@@ -527,7 +528,7 @@ def _polish_batch_arrow(preps: Sequence[PreparedZmw],
     with obs_trace.span("polish.setup", zmws=len(preps)):
         polisher = BatchPolisher(tasks, min_zscore=settings.min_zscore,
                                  buckets=buckets, min_z=min_z,
-                                 prebaked=prebaked)
+                                 fixed_z=fixed_z, prebaked=prebaked)
     with obs_trace.span("polish.gates", zmws=len(preps)):
         gate_info = []
         for z, p in enumerate(preps):
@@ -673,16 +674,18 @@ def _batch_extents(preps: Sequence[PreparedZmw]) -> tuple[int, int, int, int]:
             max(len(p.css) for p in preps))
 
 
-def menu_batch_shapes(preps: Sequence[PreparedZmw]
+def menu_batch_shapes(preps: Sequence[PreparedZmw], *,
+                      fit_lanes: bool = False
                       ) -> tuple[tuple[int, int, int], int]:
-    """The (Imax, Jmax, R)/Z a batch of the scheduled driver polishes at:
-    its length class's pin in the process's shape menu
-    (parallel.batch.ShapeMenu), so a file's batches share one family of
-    programs.  Pass the pin on as `buckets` to prebake_polish and
-    polish_prepared_batch."""
+    """The (Imax, Jmax, R)/Z a batch of the scheduled driver (or, with
+    `fit_lanes`, a flush of `ccs serve`) polishes at: its length class's
+    pin in the process's shape menu (parallel.batch.ShapeMenu), so a
+    file's batches share one family of programs.  Pass the pin on as
+    `buckets` to prebake_polish and polish_prepared_batch."""
     from pbccs_tpu.parallel.batch import shape_menu
 
-    imax, jmax, r, z = shape_menu.shapes(*_batch_extents(preps))
+    imax, jmax, r, z = shape_menu.shapes(*_batch_extents(preps),
+                                         fit_lanes=fit_lanes)
     return (imax, jmax, r), z
 
 
@@ -708,7 +711,7 @@ def _pinned_batch_shapes(preps: Sequence[PreparedZmw],
 def _guarded_dispatch(preps: Sequence[PreparedZmw],
                       settings: ConsensusSettings, *,
                       buckets: tuple[int, int, int] | None,
-                      min_z: int, prebaked=None
+                      min_z: int, fixed_z: bool = False, prebaked=None
                       ) -> list[tuple[Failure, ConsensusResult | None]]:
     """One fault-domain batch dispatch: the chaos fault site
     ("polish.dispatch", keyed by ZMW ids so poison specs can target one
@@ -725,7 +728,8 @@ def _guarded_dispatch(preps: Sequence[PreparedZmw],
         # delay exercises exactly the hung-dispatch recovery path
         faults.maybe_fail("polish.dispatch", keys=ids)
         return _polish_batch_arrow(preps, settings, buckets=buckets,
-                                   min_z=min_z, prebaked=prebaked)
+                                   min_z=min_z, fixed_z=fixed_z,
+                                   prebaked=prebaked)
 
     def attempt():
         return watchdog.run_with_deadline(dispatch, site="polish.dispatch")
@@ -740,7 +744,7 @@ def _guarded_dispatch(preps: Sequence[PreparedZmw],
 def polish_prepared_batch(preps: Sequence[PreparedZmw],
                           settings: ConsensusSettings | None = None, *,
                           buckets: tuple[int, int, int] | None = None,
-                          min_z: int = 1,
+                          min_z: int = 1, fixed_z: bool = False,
                           on_error: str = "bisect",
                           raise_device_shaped: bool = False,
                           prebaked=None
@@ -753,9 +757,11 @@ def polish_prepared_batch(preps: Sequence[PreparedZmw],
 
     `buckets`/`min_z` pin the BatchPolisher's (Imax, Jmax, R)/Z shapes to
     caller-chosen lower bounds: the serving engine pins them to its length
-    bucket + pow2 sizes so variable-size online flushes reuse one bounded
-    compiled-program menu instead of minting a fresh device loop per
-    (batch size, read count) draw.
+    class's pin in the shape menu and to its --maxBatch (`fixed_z`: every
+    flush at that one Z, its wide-band retry too, and the family loaded
+    with the shape set's first polish), so variable-size online flushes
+    are one program family instead of a fresh device loop per (batch
+    size, read count) draw.
 
     A batch-path error no longer re-runs everything serially with the
     exception discarded: the dispatch is guarded (hang watchdog,
@@ -827,6 +833,7 @@ def polish_prepared_batch(preps: Sequence[PreparedZmw],
             start += size
         return out
     return _polish_guarded(preps, settings, buckets=buckets, min_z=min_z,
+                           fixed_z=fixed_z,
                            pin=pin, z_pin=z_pin, on_error=on_error,
                            raise_device_shaped=raise_device_shaped,
                            prebaked=prebaked)
@@ -881,6 +888,7 @@ def _capacity_split(preps: Sequence[PreparedZmw],
 def _polish_guarded(preps: Sequence[PreparedZmw],
                     settings: ConsensusSettings, *,
                     buckets: tuple[int, int, int] | None, min_z: int,
+                    fixed_z: bool = False,
                     pin, z_pin: int, on_error: str,
                     raise_device_shaped: bool, prebaked
                     ) -> list[tuple[Failure, ConsensusResult | None]]:
@@ -891,7 +899,8 @@ def _polish_guarded(preps: Sequence[PreparedZmw],
     task-shaped -> quarantine bisection / legacy serial fallback."""
     try:
         return _guarded_dispatch(preps, settings, buckets=buckets,
-                                 min_z=min_z, prebaked=prebaked)
+                                 min_z=min_z, fixed_z=fixed_z,
+                                 prebaked=prebaked)
     except Exception as e:  # noqa: BLE001 -- classified below
         from pbccs_tpu.resilience import quarantine, resources, retry, \
             watchdog
@@ -917,7 +926,7 @@ def _polish_guarded(preps: Sequence[PreparedZmw],
         return quarantine.isolate(
             preps,
             lambda sub: _guarded_dispatch(sub, settings, buckets=pin,
-                                          min_z=z_pin),
+                                          min_z=z_pin, fixed_z=fixed_z),
             settings, e)
 
 

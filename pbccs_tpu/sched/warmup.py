@@ -105,11 +105,43 @@ def _warm_one(tasks) -> dict:
             "W": polisher._W}
 
 
+_SYNTH_SEED = 20260729
+
+
+def synth_chunks(n_zmws: int, n_passes: int, tpl_len: int,
+                 min_passes: int = 1):
+    """The bucket's geometry as ZMWs at the front door (`ccs serve
+    --bucket`, which drives them through draft and polish before it is
+    ready): the pass counts go PASSES, the gate's least (--minPasses),
+    PASSES - 1, least + 1, .. and round again, so that even a few ZMWs
+    hold both ends: the most passes set the flush's R, and the draft of
+    a ZMW with few passes is the longest (a 3-pass draft of a 2 kb
+    insert is 2,110-2,234 bases, a 10-pass one about 2,000) and sets its
+    Jmax."""
+    from pbccs_tpu.pipeline import Chunk, Subread
+    from pbccs_tpu.simulate import simulate_zmw
+
+    rng = np.random.default_rng(_SYNTH_SEED)
+    lo = min(max(min_passes, 1), n_passes)
+    counts = list(range(n_passes, lo - 1, -1))
+    order = [c for pair in zip(counts, reversed(counts))
+             for c in pair][:len(counts)]       # hi, lo, hi - 1, lo + 1, ..
+    chunks = []
+    for z in range(n_zmws):
+        _tpl, reads, _strands, snr = simulate_zmw(
+            rng, tpl_len, order[z % len(order)])
+        chunks.append(Chunk(
+            f"warmup/{z}",
+            [Subread(f"warmup/{z}/{k}", r) for k, r in enumerate(reads)],
+            snr))
+    return chunks
+
+
 def _synth_tasks(n_zmws: int, n_passes: int, tpl_len: int):
     from pbccs_tpu.parallel.batch import ZmwTask
     from pbccs_tpu.simulate import simulate_zmw
 
-    rng = np.random.default_rng(20260729)
+    rng = np.random.default_rng(_SYNTH_SEED)
     tasks = []
     for z in range(n_zmws):
         tpl, reads, strands, snr = simulate_zmw(rng, tpl_len, n_passes)

@@ -1,10 +1,12 @@
 """Dynamic batcher: group pending ZMW requests into compiled-shape buckets.
 
 The continuous-batching core of the serving engine.  Each pending item
-carries the (Jmax, Imax) length bucket its ZMW polishes in
-(parallel.batch.length_bucket -- the same shape key the offline
-BatchPolisher derives, so every flush reuses already-compiled polish
-programs) and a flush-by time.  A bucket flushes when
+carries the key the engine computed for it -- the (Imax, Jmax, R) pin of
+its ZMW's length class in the process's shape menu
+(parallel.batch.ShapeMenu, serve.engine._flush_shapes), so ZMWs on both
+sides of a bucket edge wait in one queue and every flush reuses the
+programs the class's first flush loaded -- and a flush-by time.  A bucket
+flushes when
 
   * it FILLS (max_batch items: the device batch is worth dispatching), or
   * the OLDEST item's flush-by expires (max-wait flush: the item's
@@ -90,8 +92,10 @@ class DynamicBatcher:
             _m_bucketed.inc()
             return None
 
-    def due(self, now: float) -> list[Batch]:
-        """Pop every bucket whose OLDEST item's flush-by has expired.
+    def due(self, now: float, hold=()) -> list[Batch]:
+        """Pop every bucket whose OLDEST item's flush-by has expired,
+        but for those whose key is in `hold` (the engine's: classes whose
+        batches keep every executor taken).
 
         The whole bucket ships, not just the expired item: the remaining
         items ride along for free (their polish is one batched program
@@ -100,7 +104,8 @@ class DynamicBatcher:
         out = []
         with self._lock:
             for key in [k for k, items in self._buckets.items()
-                        if min(i.flush_by for i in items) <= now]:
+                        if k not in hold
+                        and min(i.flush_by for i in items) <= now]:
                 batch = Batch(key, self._buckets.pop(key), "deadline")
                 _m_bucketed.dec(len(batch.items))
                 out.append(_record_flush(batch))
@@ -116,12 +121,13 @@ class DynamicBatcher:
             self._buckets.clear()
         return out
 
-    def next_deadline(self) -> float | None:
-        """Earliest flush-by over all pending items (None when empty) --
-        what the engine's batcher thread sleeps until."""
+    def next_deadline(self, hold=()) -> float | None:
+        """Earliest flush-by over the pending items of buckets not in
+        `hold` (None when there is none) -- what the engine's batcher
+        thread sleeps until."""
         with self._lock:
-            deadlines = [i.flush_by for items in self._buckets.values()
-                         for i in items]
+            deadlines = [i.flush_by for k, items in self._buckets.items()
+                         if k not in hold for i in items]
         return min(deadlines) if deadlines else None
 
     def pending_count(self) -> int:

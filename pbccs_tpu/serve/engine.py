@@ -13,8 +13,11 @@ workload up front; the engine does not, so it:
     killer);
   * preps admitted requests (filter -> POA draft -> mapping, the host
     stages) on a small worker pool, then parks them in the dynamic
-    batcher under their (Jmax, Imax) length bucket
-    (parallel.batch.length_bucket);
+    batcher under the pin of their length class in the process's shape
+    menu (parallel.batch.ShapeMenu): ZMWs on both sides of a bucket edge
+    share one queue, and every flush of the class polishes at that pin
+    and at Z = max_batch however many ZMWs it holds -- one family of
+    programs a class, which warm() loads before the server is ready;
   * flushes a bucket to the polish executor when it fills (max_batch)
     or when its oldest request's deadline slack expires
     (min(admit + max_wait, deadline - polish_margin); see
@@ -33,6 +36,7 @@ here live on the prep workers)."""
 from __future__ import annotations
 
 import dataclasses
+import functools
 import queue
 import threading
 import time
@@ -50,6 +54,7 @@ from pbccs_tpu.pipeline import (
     ConsensusSettings,
     Failure,
     PreparedZmw,
+    menu_batch_shapes,
     polish_prepared_batch,
     prepare_chunk,
 )
@@ -95,38 +100,42 @@ _m_slo_violations = _reg.counter(
     "ccs_slo_violations_total",
     "Requests whose admission-to-completion latency exceeded --sloP99Ms "
     "(burn-rate numerator; ccs_slo_requests_total is the denominator)")
+# what a flush held of what it polished at: used over capacity is the
+# share of the device batch that real traffic filled
+_m_flush_slots = {kind: _reg.counter(
+    "ccs_serve_flush_slots_total",
+    "ZMW slots of the flushed batches: the ZMWs they held (used) and "
+    "the Z they polished at (capacity)", kind=kind)
+    for kind in ("used", "capacity")}
 
 
 def _flush_shapes(preps: Sequence[PreparedZmw]) -> tuple[int, int, int]:
-    """The (imax, jmax, r) bucket a flush of these preps polishes in --
-    the ONE derivation shared by the pinned polish call and the
-    capacity-bucket key, so the governor ceiling the pool records is
-    the same key the polish-time admission pre-split looks up."""
-    from pbccs_tpu.parallel.batch import length_bucket
-    from pbccs_tpu.utils import next_pow2
-
-    jmax, imax = length_bucket(
-        max(len(p.css) for p in preps),
-        max((len(m.seq) for p in preps for m in p.mapped), default=8))
-    r = next_pow2(max(len(p.mapped) for p in preps), 4)
-    return imax, jmax, r
+    """The (imax, jmax, r) a flush of these preps polishes at: the pin of
+    their length class in the process's shape menu, joined by whatever
+    fits its lanes (a flush holds one ZMW or max_batch: `fit_lanes`) --
+    the ONE derivation shared by the batcher's key (a ZMW alone), the
+    pinned polish call and the capacity-bucket key, so the governor
+    ceiling the pool records is the same key the polish-time admission
+    pre-split looks up."""
+    return menu_batch_shapes(preps, fit_lanes=True)[0]
 
 
 def _polish_shape_pinned(preps: Sequence[PreparedZmw], settings, *,
+                         min_z: int = 1,
                          raise_device_shaped: bool = False):
-    """polish_prepared_batch with shapes pinned to the flush's length
-    bucket + pow2 Z/R: online flushes vary in size (1..max_batch ZMWs,
-    arbitrary read counts), and letting each draw pick its own shapes
-    would mint a fresh compiled device loop per (Z, R) combination -- the
-    same bounded-program-menu rule the offline straggler/wide-retry
-    sub-batches follow (parallel/batch.py BatchPolisher `buckets`)."""
-    from pbccs_tpu.utils import next_pow2
-
-    imax, jmax, r = _flush_shapes(preps)
-    return polish_prepared_batch(preps, settings,
-                                 buckets=(imax, jmax, r),
-                                 min_z=next_pow2(len(preps), 4),
-                                 raise_device_shaped=raise_device_shaped)
+    """polish_prepared_batch at the class's pin and at Z = `min_z` (the
+    engine's max_batch): online flushes vary in size (1..max_batch ZMWs,
+    3..10 reads each, drafts on both sides of a bucket edge), and a
+    flush that picked its own shapes would trace, lower and load a
+    program family for each (Z, R, bucket) it met, minutes each, inside
+    traffic.  At one pin and one Z every flush of a length class runs
+    the programs the first one loaded, and a ZMW's answer does not
+    depend on its flush-mates (padding changes no arithmetic; the fills
+    and the dense kernel skip slots and lanes that hold nothing)."""
+    with obs_trace.span("polish", zmws=len(preps)):
+        return polish_prepared_batch(
+            preps, settings, buckets=_flush_shapes(preps), min_z=min_z,
+            fixed_z=True, raise_device_shaped=raise_device_shaped)
 
 
 class EngineOverloaded(RuntimeError):
@@ -141,7 +150,8 @@ class EngineClosed(RuntimeError):
 class ServeConfig:
     """Serving knobs (see module docstring for the policy they drive)."""
 
-    max_batch: int = 16            # bucket fill-flush size (ZMWs per batch)
+    max_batch: int = 16            # bucket fill-flush size, and the Z every
+    #                                flush polishes at, full or not
     max_wait_ms: float = 250.0     # max time a request waits to be batched
     max_pending: int = 256         # admitted-but-incomplete request bound
     prep_workers: int = 2          # host draft/mapping threads
@@ -213,6 +223,7 @@ class Request:
     t_dispatch: float = 0.0
     t_polish0: float = 0.0
     t_polish1: float = 0.0
+    flush: int = 0                   # the flush it polished in (0: none)
     done: threading.Event = dataclasses.field(default_factory=threading.Event)
 
     def wait(self, timeout: float | None = None) -> bool:
@@ -235,7 +246,11 @@ class CcsEngine:
         self.settings = settings or ConsensusSettings()
         self.config = config or ServeConfig()
         self._prep_fn = prep_fn or prepare_chunk
-        self._polish_fn = polish_fn or _polish_shape_pinned
+        # the real polish takes raise_device_shaped (a fleet's first
+        # attempt); an injected stub is called with preps and settings
+        self._default_polish = polish_fn is None
+        self._polish_fn = polish_fn or functools.partial(
+            _polish_shape_pinned, min_z=self.config.max_batch)
         self._log = logger or Logger.default()
 
         self._lock = threading.Lock()
@@ -243,6 +258,8 @@ class CcsEngine:
         self._trace_lock = threading.Lock()
         self._capture: obs_trace.Tracer | None = None
         self._seq = 0
+        self._flushes = 0            # flushes dispatched (serve.flush ids)
+        self._warmed: list[dict] = []    # what warm() loaded, for status()
         self._pending = 0            # admitted, not yet completed
         self._admitted = 0
         self._rejected = 0
@@ -250,6 +267,7 @@ class CcsEngine:
         self._errors = 0
         self._in_flight_batches = 0
         self._in_flight_zmws = 0
+        self._in_flight_keys: dict = {}   # batcher key -> batches in flight
         self._prep_queue: queue.Queue[Request | None] = queue.Queue()
         self._batcher = DynamicBatcher(self.config.max_batch)
         self._wake = threading.Condition()
@@ -464,6 +482,67 @@ class CcsEngine:
     def __exit__(self, *exc) -> None:
         self.close()
 
+    # ----------------------------------------------------------------- warm
+
+    def warm(self, buckets: Sequence[str]) -> list[dict]:
+        """Load the programs of the declared deployment before traffic.
+
+        Each entry is a `ccs warmup` bucket, ZxPASSESxLEN
+        (sched/warmup.py: its parser and its synthetic ZMWs): Z ZMWs of
+        LEN bases with up to PASSES passes go through this engine's own
+        draft and polish stages, max_batch at a time, as a flush of real
+        traffic would -- set-up, refine, QV sweep and, the polisher being
+        the first of its shape set, the wide-band retry
+        (BatchPolisher.warm_shape_set) -- so the class's pin and its
+        programs are those the first real flush asks for.  Called by
+        `ccs serve --bucket` before the socket opens; returns (and
+        keeps, for status()) one entry a bucket: its seconds and the
+        shape sets it built."""
+        from pbccs_tpu.parallel import batch as pbatch
+        from pbccs_tpu.sched.warmup import parse_bucket, synth_chunks
+
+        for spec in buckets:
+            z, passes, length = parse_bucket(spec)
+            t0 = time.monotonic()
+            before = pbatch.shape_sets_seen()
+            with obs_trace.span("serve.warm", bucket=spec) as sp:
+                preps = [prep for _failure, prep in (
+                    self._prep_fn(chunk, self.settings)
+                    for chunk in synth_chunks(z, passes, length,
+                                              self.settings.min_passes))
+                    if prep is not None]
+                step = self.config.max_batch
+                for lo in range(0, len(preps), step):
+                    self._warm_flush(preps[lo:lo + step])
+                sets = sorted(pbatch.shape_sets_seen() - before)
+                if sp is not None:
+                    sp.args["shape_sets"] = [list(k) for k in sets]
+            entry = {"bucket": spec,
+                     "seconds": round(time.monotonic() - t0, 3),
+                     "shape_sets": [dict(zip(("imax", "jmax", "r", "z", "w"),
+                                             k)) for k in sets]}
+            with self._lock:
+                self._warmed.append(entry)
+            self._log.info(f"ccs engine warmed {spec}: {len(sets)} shape "
+                           f"set(s) in {entry['seconds']} s: {sets}")
+        with self._lock:
+            return list(self._warmed)
+
+    def _warm_flush(self, preps: list) -> None:
+        """One synthetic flush on the thread real flushes polish on: in
+        fleet mode on every device of the pool (pinned: a flush of the
+        class may be routed to any of them), else on the caller's (the
+        single polish worker is idle until the server accepts)."""
+        if self._pool is None:
+            self._polish_fn(preps, self.settings)
+            return
+        for fut in [self._pool.submit(
+                _flush_shapes(preps),
+                lambda _device: self._polish_fn(preps, self.settings),
+                zmws=len(preps), worker_index=k, pin=True)
+                for k in range(self._pool.n_devices)]:
+            fut.result()
+
     # ------------------------------------------------------------- admission
 
     def submit(self, chunk: Chunk, deadline_ms: float | None = None,
@@ -528,11 +607,9 @@ class CcsEngine:
             if failure is not None:
                 self._complete(req, failure, None)
                 continue
-            from pbccs_tpu.parallel.batch import length_bucket
-
-            key = length_bucket(
-                len(prep.css),
-                max((len(m.seq) for m in prep.mapped), default=8))
+            # the pin of the ZMW's length class (it joins or opens one):
+            # both sides of a bucket edge wait in one queue
+            key = _flush_shapes([prep])
             slack_end = req.deadline_t - self.config.polish_margin_ms / 1e3
             flush_by = min(req.submit_t + self.config.max_wait_ms / 1e3,
                            slack_end)
@@ -548,6 +625,20 @@ class CcsEngine:
     def _flush_loop(self) -> None:
         """Sleep until the earliest flush-by, then ship due buckets.
 
+        A bucket that is due but not full waits while every polish
+        executor is taken AND batches of its own key are in flight
+        (_held_keys): its class is what keeps the device busy, so shipped
+        at once it would sit in the executor's queue behind them and miss
+        the ZMWs drafted meanwhile (a closed loop of 32 on one device
+        flushed 10.6 of 16 slots that way, tiny flushes of stragglers
+        among them).  The wait is bounded by those batches: every
+        dispatch of a key takes its whole bucket, so the bucket leaves
+        with the class's next fill or, when no more come, as soon as the
+        batches in flight have completed (_complete_batch wakes this
+        loop).  A bucket of a class with nothing in flight -- a lone ZMW
+        of another length beside a saturating class -- ships at its
+        flush-by whatever the executors are doing.
+
         Exits only on _stop_flush (set after the prep workers join), so a
         request prepped during a close() drain is still shipped."""
         while True:
@@ -556,7 +647,8 @@ class CcsEngine:
                     return
                 closed = self._closed
             with self._wake:
-                nxt = self._batcher.next_deadline()
+                nxt = self._batcher.next_deadline(
+                    () if closed else self._held_keys())
                 if nxt is None:
                     # closed-but-empty still naps: close() may be waiting
                     # on in-flight polishes and this must not busy-spin
@@ -567,12 +659,48 @@ class CcsEngine:
                         self._wake.wait(timeout=min(delay, 0.2))
             with self._lock:
                 closed = self._closed
-            batches = self._batcher.due(time.monotonic())
+            batches = self._batcher.due(
+                time.monotonic(), () if closed else self._held_keys())
             if closed:
                 # shutting down: ship everything, due or not
                 batches += self._batcher.drain()
             for batch in batches:
                 self._dispatch(batch)
+
+    def _held_keys(self) -> frozenset:
+        """The bucket keys a due flush waits in: none while a batch
+        dispatched now would start polishing now, else those with a
+        batch in flight (running or queued)."""
+        with self._lock:
+            room = (self._pool.n_devices if self._pool is not None
+                    else self._n_polish_workers)
+            if self._in_flight_batches < room:
+                return frozenset()
+            return frozenset(self._in_flight_keys)
+
+    def _note_flush(self, batch: Batch) -> None:
+        """One flush: its id on every request it holds, the slot
+        counters, and a `serve.flush` span from the arrival of its first
+        ZMW in the batcher to now (what it held, what it polishes at)."""
+        reqs = [item.payload[0] for item in batch.items]
+        with self._lock:
+            self._flushes += 1
+            flush = self._flushes
+        for req in reqs:
+            req.flush = flush
+        z = max(self.config.max_batch, len(reqs))
+        _m_flush_slots["used"].inc(len(reqs))
+        _m_flush_slots["capacity"].inc(z)
+        tracer = obs_trace.get_tracer()
+        if tracer is not None:
+            imax, jmax, r = _flush_shapes(
+                [item.payload[1] for item in batch.items])
+            now = time.monotonic()
+            first = min((q.t_prep1 for q in reqs if q.t_prep1 > 0.0),
+                        default=now)
+            tracer.add_span("serve.flush", now - first,
+                            flush=flush, reason=batch.reason,
+                            zmws=len(reqs), z=z, r=r, jmax=jmax, imax=imax)
 
     def _capacity_bucket(self, batch: Batch):
         """The resources.shape_bucket this flush polishes in (the shape
@@ -587,6 +715,7 @@ class CcsEngine:
     def _dispatch(self, batch: Batch) -> None:
         from pbccs_tpu.resilience import resources
 
+        self._note_flush(batch)
         # serve flushes consult the governor's learned ceilings: a
         # bucket that OOMed at some Z dispatches as ceiling-sized
         # sub-batches from the start (fleet-wide minimum -- the target
@@ -622,6 +751,8 @@ class CcsEngine:
         with self._lock:
             self._in_flight_batches += 1
             self._in_flight_zmws += len(batch.items)
+            self._in_flight_keys[batch.key] = (
+                self._in_flight_keys.get(batch.key, 0) + 1)
         _m_inflight_batches.inc()
         _m_inflight_zmws.inc(len(batch.items))
         self._log.debug(
@@ -658,7 +789,7 @@ class CcsEngine:
         executor (pbccs_tpu.sched.executor)."""
         raise_dev = (first_attempt and self._pool is not None
                      and self._pool.n_devices > 1
-                     and self._polish_fn is _polish_shape_pinned)
+                     and self._default_polish)
         preps = [item.payload[1] for item in batch.items]
         reqs = [item.payload[0] for item in batch.items]
         # batch-level span: parents under the FIRST traced request's
@@ -674,7 +805,7 @@ class CcsEngine:
             with obs_trace.span("serve.polish", ctx=ctx,
                                 bucket=str(batch.key),
                                 zmws=len(batch.items),
-                                reason=batch.reason,
+                                reason=batch.reason, flush=reqs[0].flush,
                                 trace_ids=trace_ids), \
                     timing.stage("serve.polish"):
                 outcomes = self._run_polish_inner(preps, raise_dev,
@@ -743,8 +874,13 @@ class CcsEngine:
             with self._lock:
                 self._in_flight_batches -= 1
                 self._in_flight_zmws -= len(batch.items)
+                left = self._in_flight_keys.pop(batch.key, 1) - 1
+                if left > 0:
+                    self._in_flight_keys[batch.key] = left
             _m_inflight_batches.dec()
             _m_inflight_zmws.dec(len(batch.items))
+            with self._wake:
+                self._wake.notify_all()   # a batch left: buckets it held may go
 
     def _pool_done(self, batch: Batch, fut) -> None:
         # runs on a device executor thread: hand off immediately so the
@@ -781,19 +917,41 @@ class CcsEngine:
     # ------------------------------------------------------------ completion
 
     @staticmethod
-    def _observe_stages(req: Request, now: float) -> None:
-        """Per-request stage intervals into the SLO histograms.  Stages a
-        request never reached (early failure, prep-side yield gate) are
-        skipped, not recorded as zero; clock jitter is clamped at 0."""
-        marks = (("admission", req.submit_t, req.t_prep0),
+    def _stage_seconds(req: Request, t_in: float, now: float):
+        """(stage, seconds) of the stages a request reached between
+        `t_in` and `now`.  Stages it never reached (early failure,
+        prep-side yield gate) are skipped, not given as zero; clock
+        jitter is clamped at 0."""
+        marks = (("admission", t_in, req.t_prep0),
                  ("prepare", req.t_prep0, req.t_prep1),
                  ("queue", req.t_prep1, req.t_dispatch),
                  ("dispatch", req.t_dispatch, req.t_polish0),
                  ("polish", req.t_polish0, req.t_polish1),
                  ("emit", req.t_polish1, now))
-        for stage, t0, t1 in marks:
-            if t0 > 0.0 and t1 > 0.0:
-                _m_stages[stage].observe(max(t1 - t0, 0.0))
+        return [(stage, max(t1 - t0, 0.0)) for stage, t0, t1 in marks
+                if t0 > 0.0 and t1 > 0.0]
+
+    @classmethod
+    def _observe_stages(cls, req: Request, now: float) -> None:
+        """Per-request stage intervals into the SLO histograms."""
+        for stage, seconds in cls._stage_seconds(req, req.submit_t, now):
+            _m_stages[stage].observe(seconds)
+
+    @classmethod
+    def trace_request(cls, req: Request, t_recv: float) -> None:
+        """`serve.request`: one span a request, from the arrival of its
+        frame (`t_recv`, monotonic; the session calls this once the
+        reply is on the socket) to now, with the stage intervals the
+        engine keeps for its histograms and the flush it polished in."""
+        tracer = obs_trace.get_tracer()
+        if tracer is None:
+            return
+        now = time.monotonic()
+        tracer.add_span(
+            "serve.request", now - t_recv, ctx=req.trace_ctx,
+            zmw=req.chunk.id, flush=req.flush,
+            **{f"{stage}_ms": round(seconds * 1e3, 3) for stage, seconds
+               in cls._stage_seconds(req, t_recv, now)})
 
     def _finish(self, req: Request) -> None:
         now = time.monotonic()
@@ -895,6 +1053,7 @@ class CcsEngine:
             )
             pool = self._pool   # close() nulls this under the same lock
             ledger = self._ledger
+            warmed = list(self._warmed)
         stage_s = {k: round(v, 4)
                    for k, v in timing.stage_seconds(self._window).items()}
         sched = {"sched": pool.status()} if pool is not None else {}
@@ -914,6 +1073,9 @@ class CcsEngine:
             "max_pending": self.config.max_pending,
             "max_batch": self.config.max_batch,
             "max_wait_ms": self.config.max_wait_ms,
+            # what warm() loaded before the server was ready (`ccs serve
+            # --bucket`): empty on a server that loads with its traffic
+            "warmed": warmed,
             "stage_seconds": stage_s,
             "device_wait_s": round(
                 timing.device_wait_seconds(self._window), 4),

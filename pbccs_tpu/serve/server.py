@@ -32,6 +32,7 @@ import socket
 import ssl
 import sys
 import threading
+import time
 
 from pbccs_tpu.obs.metrics import default_registry
 from pbccs_tpu.runtime.logging import Logger, LogLevel
@@ -322,6 +323,7 @@ class _Session(_FramedSession):
     engine's span capture."""
 
     def _on_submit(self, msg: dict) -> None:
+        t_recv = time.monotonic()
         rid = msg.get("id")
         if not self._try_acquire_slot(rid):
             return
@@ -345,6 +347,9 @@ class _Session(_FramedSession):
                 self.send(protocol.result_to_wire(
                     rid, req.chunk.id, req.failure, req.result,
                     req.latency_ms))
+            # closed once the reply is on the socket, so a capture reads
+            # what the client waited for, its frame's parse included
+            CcsEngine.trace_request(req, t_recv)
 
         try:
             self.server.engine.submit(chunk, deadline_ms=deadline_ms,
@@ -567,7 +572,10 @@ def build_serve_parser() -> argparse.ArgumentParser:
                         f"{defaults.max_batch}")
     p.add_argument("--maxWaitMs", type=float, default=None,
                    help="Max time a request waits to be batched before a "
-                        "deadline flush. Default: the applied "
+                        "deadline flush; a bucket whose own class keeps "
+                        "every polish executor taken waits on, until it "
+                        "fills or that class's batches in flight complete. "
+                        "Default: the applied "
                         "--tuneProfile's serve_max_wait_ms, else "
                         f"{defaults.max_wait_ms}")
     p.add_argument("--maxPending", type=int, default=defaults.max_pending,
@@ -576,6 +584,18 @@ def build_serve_parser() -> argparse.ArgumentParser:
                         "Default = %(default)s")
     p.add_argument("--prepWorkers", type=int, default=defaults.prep_workers,
                    help="Host draft/mapping threads. Default = %(default)s")
+    p.add_argument("--bucket", action="append", default=None,
+                   metavar="ZxPASSESxLEN",
+                   help="The deployment's geometry, as `ccs warmup` takes "
+                        "it: up to PASSES subreads a ZMW, LEN-base "
+                        "inserts.  Z synthetic ZMWs of it go through draft "
+                        "and polish (--maxBatch at a time) BEFORE the "
+                        "socket opens and CCS-SERVE-READY is printed, so "
+                        "the length class's programs are loaded when the "
+                        "first request arrives; the ready line and the "
+                        "status verb name what was loaded.  Repeatable.  "
+                        "Default: none, programs load with the first "
+                        "flush of each length class.")
     p.add_argument("--devices", type=int, default=defaults.devices,
                    help="Polish across a device fleet (pbccs_tpu.sched): "
                         "N>1 uses the first N visible devices, 0 all of "
@@ -676,8 +696,11 @@ def build_serve_parser() -> argparse.ArgumentParser:
     return p
 
 
-def run_serve(argv: list[str] | None = None) -> int:
-    """`ccs serve` entry point (dispatched from pbccs_tpu.cli)."""
+def run_serve(argv: list[str] | None = None,
+              stop: threading.Event | None = None) -> int:
+    """`ccs serve` entry point (dispatched from pbccs_tpu.cli).  An
+    embedding caller (a thread of a test) hands its own `stop` event in
+    place of the signals a thread cannot take."""
     args = build_serve_parser().parse_args(argv)
     if args.devices < 0:
         print(f"option --devices: must be >= 0, got {args.devices}",
@@ -741,19 +764,26 @@ def run_serve(argv: list[str] | None = None) -> int:
         perf_ledger_interval_s=args.perfLedgerInterval)
 
     with CcsEngine(settings, config, logger=log) as engine:
+        # a declared deployment loads its programs before the socket
+        # opens: `ready` means the first request meets no compile
+        warmed = engine.warm(args.bucket) if args.bucket else []
         server = CcsServer(engine, args.host, args.port, logger=log,
                            ssl_context=ssl_ctx, tenants=tenants)
         server.start()
         metrics_http = start_metrics_endpoint(
             args.metricsPort, engine.metrics_text, args.host, log,
             health=engine.accepting, ssl_context=ssl_ctx)
-        # machine-readable ready line for wrappers (serve_bench polls it)
-        print(f"CCS-SERVE-READY {server.host} {server.port}", flush=True)
+        # machine-readable ready line for wrappers (serve_bench polls
+        # it); a warmed server appends what it loaded
+        sets = sum(len(w["shape_sets"]) for w in warmed)
+        print(f"CCS-SERVE-READY {server.host} {server.port}"
+              + (f" warmed={','.join(w['bucket'] for w in warmed)} "
+                 f"shape_sets={sets}" if warmed else ""), flush=True)
 
         # graceful drain: a k8s-style TERM (or ^C) stops admission,
         # finishes what is in flight (bounded by --drainTimeout, falling
         # back to fast abort), and exits 0 -- never a mid-batch kill
-        stop = threading.Event()
+        stop = stop or threading.Event()
 
         def _on_signal(signum, frame):
             # machine-readable line for wrappers (mirrors CCS-SERVE-READY)

@@ -830,8 +830,13 @@ def build_fleet_parser() -> argparse.ArgumentParser:
                         "Default = %(default)s")
     p.add_argument("--readyTimeout", type=float,
                    default=sdefaults.ready_timeout_s,
-                   help="Spawn-to-READY budget per child. "
-                        "Default = %(default)s")
+                   help="Spawn-to-READY budget per child.  A replica "
+                        "that declares its deployment (`ccs serve "
+                        "--bucket`) is ready only once its programs are "
+                        "loaded, so the budget must cover that warm-up: "
+                        "a machine's first process, with nothing in the "
+                        "compile cache, can take longer than the "
+                        "default.  Default = %(default)s")
     p.add_argument("--healthGateTimeout", type=float,
                    default=sdefaults.health_gate_timeout_s,
                    help="Rolling restart: how long a respawned replica "

@@ -473,7 +473,7 @@ def run_serve_leg(cfg: TuneConfig, profile_knobs: dict[str, Any]
                 if not line:
                     return "serve exited before ready"
                 if line.startswith("CCS-SERVE-READY"):
-                    _, host, port = line.split()
+                    _, host, port = line.split()[:3]
                     break
             if host is None:
                 return "serve never printed CCS-SERVE-READY"

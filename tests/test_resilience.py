@@ -850,7 +850,7 @@ class TestOomAdaptiveDispatch:
         sizes = []
 
         def stub_dispatch(preps, settings, *, buckets=None, min_z=1,
-                          prebaked=None):
+                          fixed_z=False, prebaked=None):
             sizes.append(len(preps))
             if len(preps) > 2:
                 raise RuntimeError("RESOURCE_EXHAUSTED: out of memory "

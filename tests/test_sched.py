@@ -627,6 +627,27 @@ def test_engine_pool_mode_completes_and_reports():
     assert eng._pool is None
 
 
+def test_engine_warm_loads_every_device_of_the_pool():
+    """`ccs serve --bucket --devices N`: the synthetic flush runs on each
+    device's own thread, so a flush routed to any of them meets its
+    programs loaded."""
+    from pbccs_tpu.serve.engine import CcsEngine, ServeConfig
+
+    ran = []
+
+    def polish(preps, settings):
+        ran.append((threading.current_thread().name, len(preps)))
+        return _stub_polish_ok(preps, settings)
+
+    cfg = ServeConfig(max_batch=4, devices=3)
+    with CcsEngine(config=cfg, prep_fn=_stub_prep, polish_fn=polish) as eng:
+        (entry,) = eng.warm(["4x3x60"])
+        assert entry["bucket"] == "4x3x60"
+        assert eng.status()["warmed"] == [entry]
+    assert len({name for name, _n in ran}) == 3
+    assert sorted(n for _name, n in ran) == [4, 4, 4]
+
+
 def test_engine_pool_mode_survives_benched_device():
     from pbccs_tpu.serve.engine import CcsEngine, ServeConfig
 

@@ -228,6 +228,91 @@ class TestEngine:
             assert req.wait(10.0)
             assert req.failure == Failure.SUCCESS
 
+    def test_a_due_bucket_waits_while_its_class_keeps_the_executor(self):
+        """While the one polish executor is taken by a batch of the same
+        key, a bucket past its flush-by keeps collecting: what arrived
+        meanwhile leaves as ONE flush when that batch completes, not as
+        a flush a request."""
+        gate, sizes = threading.Event(), []
+
+        def polish(preps, settings):
+            sizes.append(len(preps))
+            if len(sizes) == 1:
+                gate.wait(10.0)
+            return stub_polish(preps, settings)
+
+        with stub_engine(max_batch=8, max_wait_ms=20.0, polish=polish) as eng:
+            first = eng.submit(make_chunk("m/0"))
+            deadline = time.monotonic() + 5.0
+            while not sizes and time.monotonic() < deadline:
+                time.sleep(0.005)      # the lone request left at its flush-by
+            late = []
+            for i in (1, 2, 3):
+                late.append(eng.submit(make_chunk(f"m/{i}")))
+                time.sleep(0.05)       # each past the one before's flush-by
+            assert eng.status()["bucketed"] == 3 and sizes == [1]
+            gate.set()
+            assert first.wait(10.0) and all(r.wait(10.0) for r in late)
+        assert sizes == [1, 3]
+
+    def test_a_saturating_class_does_not_starve_a_lone_zmw(self):
+        """The hold is the class's own.  A closed loop of a majority
+        class keeps the one executor taken with fill flushes (a batch
+        always running or queued); a lone ZMW of another length class
+        still leaves at its flush-by (--maxWaitMs holds for mixed
+        lengths), and a second one, due while the first's batch is in
+        flight, leaves when that batch has completed."""
+        turns, lock, stop = [], threading.Lock(), threading.Event()
+
+        def prep(chunk, settings):
+            L = 512 if chunk.id.startswith("lone") else 64
+            return None, PreparedZmw(chunk, np.zeros(L, np.int8), [],
+                                     len(chunk.reads), 0, 0.0)
+
+        def polish(preps, settings):
+            time.sleep(0.03)
+            with lock:
+                turns.append(time.monotonic())     # a completion
+            return stub_polish(preps, settings)
+
+        def session(eng, k):
+            n = 0
+            while not stop.is_set():
+                req = eng.submit(make_chunk(f"busy{k}/{n}"))
+                assert req.wait(10.0)
+                n += 1
+
+        cfg = ServeConfig(max_batch=4, max_wait_ms=20.0, max_pending=64)
+        with CcsEngine(config=cfg, prep_fn=prep, polish_fn=polish) as eng:
+            loop = [threading.Thread(target=session, args=(eng, k),
+                                     daemon=True) for k in range(8)]
+            for t in loop:
+                t.start()
+            try:
+                deadline = time.monotonic() + 5.0
+                while len(turns) < 4 and time.monotonic() < deadline:
+                    time.sleep(0.005)              # the loop is in its stride
+                lone = eng.submit(make_chunk("lone/0"))
+                while not lone.t_dispatch and time.monotonic() < deadline:
+                    time.sleep(0.002)
+                second = eng.submit(make_chunk("lone/1"))
+                answered = lone.wait(5.0) and second.wait(5.0)
+                still_saturated = eng.status()["in_flight_batches"] >= 1
+            finally:
+                stop.set()
+                for t in loop:
+                    t.join(10.0)
+        assert answered and still_saturated
+        assert lone.failure == second.failure == Failure.SUCCESS
+        due = lone.submit_t + cfg.max_wait_ms / 1e3
+        with lock:
+            waited = [t for t in turns if due < t <= lone.t_dispatch]
+        # it left at its flush-by (a completion may land while the
+        # batcher's thread wakes), not when the load dipped
+        assert len(waited) <= 2, (len(waited), lone.t_dispatch - due)
+        # the second one's bucket was held by the first one's batch alone
+        assert second.t_dispatch <= lone.t_polish1 + 0.5
+
     def test_out_of_order_completion_across_buckets(self):
         """A later-submitted small-bucket request completes while an
         earlier request still waits on its (slower) bucket."""
