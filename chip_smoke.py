@@ -43,10 +43,12 @@ CHILD_TIMEOUT_S = 1000
 # the CLI's default batch of 64 ZMWs; the kernel check is one fill bucket
 # of that configuration (256 reads, Jmax 2112, W 96).
 REAL = dict(n_zmws=256, tpl_len=2000, passes=(3, 10), serve_zmws=32,
-            serve_args=("--bucket", "16x10x2000"), kernel=(256, 2112, 96))
+            serve_args=("--bucket", "16x10x2000"), kernel=(256, 2112, 96),
+            # (Z, R, Jm) of the benchmark's three cells' score grids
+            score_grids=((16, 12, 2304), (32, 12, 2304), (32, 32, 576)))
 TINY = dict(n_zmws=8, tpl_len=120, passes=(3, 4), serve_zmws=4,
             serve_args=("--bucket", "4x4x120", "--maxBatch", "4"),
-            kernel=(8, 192, 64))
+            kernel=(8, 192, 64), score_grids=((2, 12, 192),))
 SERVE_SESSIONS = 4
 SERVE_LEDGER_INTERVAL_S = 5.0
 
@@ -421,11 +423,68 @@ def kernel_check(seed: int, rehearse: bool, dev: dict) -> None:
         f"(tolerance {KERNEL_LL_RTOL}), mean ll {lls[True][0].mean():.1f}")
 
 
+def score_grid_check(seed: int, rehearse: bool, dev: dict) -> None:
+    """The score grid between the dense kernel and the (Z, M) totals, on
+    the device: the slot-major splice and the totals kernel (a reversal
+    and a dynamic rotate along the lanes, the masks, the baselines, the
+    written-out reduction over reads) against the NumPy transcription of
+    the gather formulation with NumPy's float32 adds in the same order,
+    bit for bit, at the cells' shapes.  (A `reverse` of read windows read
+    wrong on the chip only: PR 28.)"""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from pbccs_tpu.ops import dense_score_pallas as dsp
+
+    sys.path.insert(0, os.path.join(HERE, "tests"))
+    try:   # the reference lives with the parity tests
+        from test_dense_score import (_random_windows, _slot_planes,
+                                      splice_reference, totals_reference)
+    finally:
+        sys.path.pop(0)
+
+    @jax.jit
+    def on_device(grid, e6, wl, strand, ts, te, live, base, valid, *planes):
+        return dsp.slot_grid_totals(
+            dsp.slot_major_spliced(grid, e6, wl), strand, ts, te, live, base,
+            jnp.swapaxes(valid, 1, 2), *planes)
+
+    for z, r, jm in sizes_for(rehearse)["score_grids"]:
+        n = z * r
+        rng = np.random.default_rng([seed, n, jm])
+        grid = (rng.normal(size=(n, jm, 9)) * 40).astype(np.float32)
+        e6 = (rng.normal(size=(n, 6, 9)) * 40).astype(np.float32)
+        base = (rng.normal(size=n) * 40).astype(np.float32)
+        strand, ts, te = _random_windows(rng, n, jm, jm)
+        wl = np.clip(te - ts, 0, jm)
+        live = rng.random(n) > 0.2
+        valid = rng.random((z, jm, 9)) > 0.3
+        t0 = time.monotonic()
+        got = np.asarray(on_device(*map(jnp.asarray, (
+            grid, e6, wl, strand, ts, te, live, base, valid)
+            + _slot_planes(jm)))).transpose(0, 2, 1)
+        wall = time.monotonic() - t0
+        want = totals_reference(
+            np.stack([splice_reference(grid[k], e6[k], wl[k])
+                      for k in range(n)]), strand, ts, te, live, base, valid)
+        bad = np.argwhere(got != want)
+        check(bad.size == 0,
+              f"score grid check {z}x{r}x{jm}: {len(bad)} of {want.size} "
+              f"totals differ from the gather reference, the first at "
+              f"(ZMW, position, slot) {bad[:1].tolist()}")
+        say(f"score grid check: {z}x{r}x{jm} on {dev['platform']}: "
+            f"{want.size} totals over {n} reads equal the reference bit "
+            f"for bit ({wall:.1f} s, compile included)")
+
+
 def phase_device(args) -> dict:
-    """One chip: set-up, kernel check, then the batch CLI cold and warm."""
+    """One chip: set-up, the kernel and score grid checks, then the batch
+    CLI cold and warm."""
     sizes = sizes_for(args.rehearse)
     dev = device_facts(args.rehearse)
     kernel_check(args.seed, args.rehearse, dev)
+    score_grid_check(args.seed, args.rehearse, dev)
     cold = run_cli_once(args.workdir, "batch_cold", sizes["n_zmws"], [], dev)
     warm = run_cli_once(args.workdir, "batch_warm", sizes["n_zmws"], [], dev)
     for key in ("bam", "report"):

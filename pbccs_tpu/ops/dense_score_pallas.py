@@ -63,6 +63,7 @@ from pbccs_tpu.ops.fwdbwd import (BAND_LEAD, BandedMatrix, _affine_scan_circ,
                                   band_frame, band_frame_rows, circ_roll,
                                   circ_rows, row_major)
 from pbccs_tpu.ops.fwdbwd_pallas import band_read_windows
+from pbccs_tpu.ops.mutation_score import slot_geometry
 
 _TINY = 1e-30
 _PB = 64          # template positions per kernel sub-block
@@ -874,61 +875,140 @@ def edge_window_scores_batch(reads, rlens, win_tpl, win_trans, wlens,
                          pt_b[:, _OFF0: _OFF0 + 3], alpha_e, pt_e, rem)
 
 
-def splice_edge_rows(grid, e6, J):
-    """Overwrite one read's window-frame grid rows {0,1,2, J-2,J-1,J}
-    with the edge scores (ins at J-2 keeps its interior-kernel value).
+def slot_major_spliced(grid, e6, J):
+    """The dense kernel's (R, Jm, 9) window-frame scores turned slot-major,
+    (R, 9, Jm): positions on the lanes, so the nine slots no longer tile
+    to 128 (a (.., Jm, 9) float32 array is 14x its own bytes in HBM), with
+    every read's window-frame rows {0,1,2, J-2,J-1,J} overwritten by the
+    (R, 6, 9) edge scores `e6` (ins at J-2 keeps its interior-kernel
+    value).  This is the one read of the kernel's output in a score call;
+    what follows (slot_grid_totals: the orientation mapping, the masks,
+    the reduction over reads) runs on slot-major arrays.
 
-    Pure masked selects: the per-read dynamic_update_slices this replaces
-    lowered to vmapped scatters (~3k per round, ~2% of device time)."""
-    Jm = grid.shape[0]
-    pos = jnp.arange(Jm, dtype=jnp.int32)[:, None]                # (Jm, 1)
-    out = jnp.where(pos < 3, jnp.pad(e6[:3], ((0, Jm - 3), (0, 0))), grid)
-    ne_mask = jnp.asarray(_NE_MASK9)
+    The splice is masked selects: per-read dynamic_update_slices lower to
+    vmapped scatters."""
+    Jm = grid.shape[1]
+    g = row_major(jnp.swapaxes(grid, 1, 2))                       # (R, 9, Jm)
+    e = jnp.swapaxes(e6, 1, 2)                                    # (R, 9, 6)
+    pos = jnp.arange(Jm, dtype=jnp.int32)[None, None, :]
+    Jc = J.astype(jnp.int32)[:, None, None]
+    ne_mask = jnp.asarray(_NE_MASK9)                              # (3, 9)
     for i in range(3):
-        row = jnp.broadcast_to(e6[3 + i], (Jm, 9))
-        out = jnp.where((pos == J - 2 + i) & ne_mask[i], row, out)
-    return out
+        g = jnp.where(pos == i, e[:, :, i:i + 1], g)
+    for i in range(3):
+        g = jnp.where((pos == Jc - 2 + i) & ne_mask[i][None, :, None],
+                      e[:, :, 3 + i:4 + i], g)
+    return g
 
 
 # --------------------------------------------------------------------------
 # orientation mapping: window-frame grid -> template-frame slot grid
 # --------------------------------------------------------------------------
 
-# rev-frame slot permutation: sub b <-> sub 3-b, ins b <-> ins 3-b, del
-_REV_PERM = jnp.asarray([3, 2, 1, 0, 7, 6, 5, 4, 8], jnp.int32)
+def _reverse_frame(grid_s):
+    """A slot-major (R, 9, Jm) grid reversed along its positions, with the
+    rev-frame slot permutation sub b <-> sub 3-b, ins b <-> ins 3-b, del:
+    [3, 2, 1, 0, 7, 6, 5, 4, 8] is each base quartet reversed, so it is
+    three static reversals and no gather."""
+    return jnp.concatenate([jnp.flip(grid_s[:, 0:4], (1, 2)),
+                            jnp.flip(grid_s[:, 4:8], (1, 2)),
+                            jnp.flip(grid_s[:, 8:9], 2)], axis=1)
 
 
-def window_grid_to_template(grid, strand, ts, te, Jmax: int):
-    """Map one read's window-frame (Jm, 9) score grid onto the
-    template-frame slot grid (Jmax, 9).
+def _totals_kernel(par_ref, base_ref, x_ref, valid_ref, ms_ref, me_ref,
+                   ins_ref, o_ref, acc_ref, *, R: int, Jm: int):
+    """One ZMW: its reads' oriented window-frame slot grids (R, 9, L)
+    shifted onto the template frame, masked, baselined and summed over the
+    reads into (9, L).  par_ref (5, Z*R) int32 in SMEM: shift, ts, te,
+    strand, live of every read; base_ref (Z*R,) float32 in SMEM."""
+    z = pl.program_id(0)
+    ms, me, ins = ms_ref[...], me_ref[...], ins_ref[...] != 0
+    valid = valid_ref[...] != 0
+    pos = lax.broadcasted_iota(jnp.int32, ms.shape, 1)
+    n = acc_ref.shape[0]
+    for r in range(R):
+        k = z * R + r
+        ts, te, strand = par_ref[1, k], par_ref[2, k], par_ref[3, k]
+        rev_ins = ins & (strand != 0)
+        x = pltpu.roll(x_ref[r], par_ref[0, k], axis=1)
+        # a reverse read's insertion slots sit one row further on
+        x = jnp.where(rev_ins, pltpu.roll(x, 1, axis=1), x)
+        row = jnp.where(strand == 0, pos - ts,
+                        jnp.where(ins, te, te - 1) - pos)
+        mapped = jnp.where((row >= 0) & (row < Jm), x, 0.0)
+        overlap, _, _ = slot_geometry(ts, te, strand, ms, me, ins)
+        acc_ref[r] = jnp.where(valid & overlap & (par_ref[4, k] != 0),
+                               mapped - base_ref[k], 0.0)
+    for r in range(R, n):
+        acc_ref[r] = jnp.zeros(ms.shape, jnp.float32)
+    # the reduction over reads in an order that is the code's own: the
+    # halves of the read axis added elementwise until one read is left
+    # (as fwdbwd_pallas._scale_total sums a read's log-scales)
+    while n > 1:
+        n //= 2
+        acc_ref[0:n] = acc_ref[0:n] + acc_ref[n:2 * n]
+    o_ref[...] = acc_ref[0]
 
-    Forward reads: template position P reads grid[P - ts].  Reverse reads:
-    the window scores live on the reverse-complement template, so slot
-    (P, sub b) reads grid[te-1-P, sub 3-b], (P, ins b) reads
-    grid[te-P, ins 3-b], and (P, del) reads grid[te-1-P, del]
-    (mutations.reverse_complement_arrays frame algebra).  Out-of-window
-    entries return 0 and must be masked by the caller.
 
-    Index-shift gather formulation: under the caller's vmap this is ONE
-    batched gather per frame instead of a dynamic_slice per read -- the
-    per-read dynamic slices lowered to ~16% of all device time
-    (dynamic-update-slice x3072) on the round-3 bench trace."""
-    Jm = grid.shape[0]
-    gpad = jnp.concatenate(
-        [grid, jnp.zeros((1, grid.shape[1]), grid.dtype)], axis=0)
-    sentinel = Jm                                          # zero row
+@jax.jit
+def slot_grid_totals(grid_s, strand, ts, te, live, baselines, valid_s,
+                     start_s, end_s, ins_s):
+    """(Z, 9, Jmax) template-frame totals over each ZMW's reads of the
+    slot-major window-frame score grids `grid_s` (Z*R, 9, Jm), reads
+    ZMW-major: the orientation mapping, the overlap mask, the baselines
+    and the reduction over reads in one pass over the grid.
 
-    def pick(idx):
-        safe = jnp.where((idx >= 0) & (idx < Jm), idx, sentinel)
-        return jnp.take(gpad, safe, axis=0)
+    strand, ts, te, live, baselines are (Z*R,): a read scores a slot where
+    the slot is `valid_s` (Z, 9, Jmax) for its ZMW, overlaps its window
+    [ts, te) (slot_geometry over the slots' (9, Jmax) start_s / end_s /
+    ins_s planes) and the read is `live`; there it adds its mapped score
+    less its baseline.
 
-    P = jnp.arange(Jmax, dtype=jnp.int32)
-    fwd = pick(P - ts)
-    rev_g = gpad[:, _REV_PERM]
-    pick_r = lambda idx: jnp.take(
-        rev_g, jnp.where((idx >= 0) & (idx < Jm), idx, sentinel), axis=0)
-    rev_subdel = pick_r(te - 1 - P)
-    rev_ins = pick_r(te - P)
-    rev = jnp.concatenate([rev_subdel[:, :4], rev_ins[:, 4:8],
-                           rev_subdel[:, 8:]], axis=1)
-    return jnp.where(strand == 0, fwd, rev)
+    Mapping.  Forward reads: template position P reads window row P - ts.
+    Reverse reads: the window scores live on the reverse-complement
+    template, so slot (P, sub b) reads row te-1-P of slot sub 3-b,
+    (P, ins b) row te-P of ins 3-b, and (P, del) row te-1-P of del
+    (mutations.reverse_complement_arrays frame algebra); a row outside
+    the window frame reads 0.  Nothing in it depends on data but two
+    integers a read, so it is data movement with static structure, not a
+    gather (one index a (read, position): the largest device operation
+    of a refine round until PR 34): reverse reads take _reverse_frame,
+    whose position q holds row Jm-1-q, and then every read's rows are
+    its own shifted along the lanes, by ts (forward), by te - Jm (reverse
+    sub and del) or te + 1 - Jm (reverse ins) -- a dynamic lane rotate in
+    the kernel, circular over the lane-padded frame, with what wrapped
+    round masked.
+
+    Reduction.  Pairwise over the read axis in the kernel's own order, so
+    a ZMW's totals are the same bits at any Z, beside any batch-mates and
+    in lanes padded to any R (XLA orders a jnp.sum by its operand's shape
+    and layout; elementwise float adds are not reassociated)."""
+    N, _, Jm = grid_s.shape
+    Z, _, Jmax = valid_s.shape
+    R = N // Z
+    L = -(-max(Jm, Jmax) // 128) * 128        # the rotate wants whole vregs
+    fwd = strand == 0
+    ts, te = ts.astype(jnp.int32), te.astype(jnp.int32)
+    x = jnp.where(fwd[:, None, None], grid_s, _reverse_frame(grid_s))
+    lanes = lambda a: jnp.pad(
+        a, [(0, 0)] * (a.ndim - 1) + [(0, L - a.shape[-1])])
+    par = jnp.stack([jnp.mod(jnp.where(fwd, ts, te - Jm), L), ts, te,
+                     strand.astype(jnp.int32), live.astype(jnp.int32)])
+    plane = pl.BlockSpec((N_SLOTS, L), lambda z: (0, 0))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    n_acc = 1 << (R - 1).bit_length()
+    out = pl.pallas_call(
+        functools.partial(_totals_kernel, R=R, Jm=Jm),
+        grid=(Z,),
+        in_specs=[smem, smem,
+                  pl.BlockSpec((R, N_SLOTS, L), lambda z: (z, 0, 0)),
+                  pl.BlockSpec((None, N_SLOTS, L), lambda z: (z, 0, 0)),
+                  plane, plane, plane],
+        out_specs=pl.BlockSpec((None, N_SLOTS, L), lambda z: (z, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((Z, N_SLOTS, L), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((n_acc, N_SLOTS, L), jnp.float32)],
+        interpret=_interpret(),
+    )(par, baselines.astype(jnp.float32), lanes(x),
+      *(lanes(a.astype(jnp.int32))
+        for a in (valid_s, start_s, end_s, ins_s)))
+    return out[:, :, :Jmax]

@@ -745,3 +745,18 @@ def mutated_windows_per_pair(wt_e, wtr_e, wlens_e, p, mtype,
     bases = jnp.where(valid, bases, 4.0).astype(jnp.int8)
     trans = jnp.where((valid & (idx < new_len[:, None] - 1))[:, :, None], trans, 0.0)
     return bases, trans, new_len
+
+
+def slot_geometry(ts, te, strand, ms, me, is_ins):
+    """Interior-vs-edge classification of mutation slots against read
+    windows (ONE definition, shared by the chunked and dense scoring
+    paths; mirrors the host _dispatch_chunk rules).  All args broadcast;
+    returns (overlap, interior, wlen)."""
+    # and / or, not a select of booleans: the kernels' compiler has none
+    overlap = (is_ins & (ts <= me) & (ms <= te)) | \
+        (~is_ins & (ts < me) & (ms < te))
+    p_w = jnp.where(strand == 0, ms - ts, te - me)
+    e_w = jnp.where(strand == 0, me - ts, te - ms)
+    wlen = te - ts
+    interior = (p_w >= 3) & (e_w <= wlen - 2)
+    return overlap, interior, wlen

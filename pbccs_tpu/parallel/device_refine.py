@@ -46,6 +46,7 @@ from pbccs_tpu.models.arrow.mutations import (_LN10 as _MUT_LN10,
                                               INSERTION, QV_SATURATED,
                                               SUBSTITUTION)
 from pbccs_tpu.ops.fwdbwd import BandedMatrix, row_major
+from pbccs_tpu.ops.mutation_score import slot_geometry
 
 N_SLOTS = 9
 EDGE_BUDGET = 64  # packed edge-mutation slab width per scoring chunk
@@ -362,20 +363,6 @@ def _chunk_count(jmax: int, chunk: int) -> int:
     return (jmax * N_SLOTS + chunk - 1) // chunk
 
 
-def slot_geometry(ts, te, strand, ms, me, is_ins):
-    """Interior-vs-edge classification of mutation slots against read
-    windows (ONE definition, shared by the chunked and dense scoring
-    paths; mirrors the host _dispatch_chunk rules).  All args broadcast;
-    returns (overlap, interior, wlen)."""
-    overlap = jnp.where(is_ins, (ts <= me) & (ms <= te),
-                        (ts < me) & (ms < te))
-    p_w = jnp.where(strand == 0, ms - ts, te - me)
-    e_w = jnp.where(strand == 0, me - ts, te - ms)
-    wlen = te - ts
-    interior = (p_w >= 3) & (e_w <= wlen - 2)
-    return overlap, interior, wlen
-
-
 def _state_layout(reads, rlens, win_tpl, win_trans, wlens, table,
                   alpha: BandedMatrix, beta: BandedMatrix, a_prefix,
                   b_suffix, width: int, windows=None):
@@ -415,21 +402,34 @@ def _score_slot_grid_dense(st: "RefineLoopState", reads, rlens, strands,
     the small window-frame edge program (edge_window_scores_batch) and
     spliced into the kernel grid before the orientation mapping -- the
     whole grid then maps and reduces in one pass, with no packed edge
-    slab, no edge budget, and no template-frame edge machinery."""
+    slab, no edge budget, and no template-frame edge machinery.
+
+    The kernel's (Z*R, Jm, 9) output is read once, by the splice, which
+    writes it slot-major, (Z*R, 9, Jm): positions on the lanes.  One more
+    pass (slot_grid_totals) maps the reads onto the template frame, masks
+    them (slot_geometry over the slots' (9, Jmax) start / end / type
+    planes), takes the baselines off and sums over a ZMW's reads in a
+    written-out order, and only the reduced (Z, 9, Jmax) is turned to the
+    position-major (Z, M) the callers read."""
     from pbccs_tpu.ops.dense_score_pallas import (
         build_dense_layout, dense_interior_scores_batch,
-        edge_window_scores_batch, splice_edge_rows, window_grid_to_template)
+        edge_window_scores_batch, slot_grid_totals, slot_major_spliced)
 
     Z, R = reads.shape[:2]
     jmax = st.tpl.shape[1]
     M = jmax * N_SLOTS
 
+    # the candidates' planes, slot-major: (9, jmax) and (Z, 9, jmax)
+    turn = lambda a: jnp.swapaxes(
+        a.reshape(a.shape[:-1] + (jmax, N_SLOTS)), -1, -2)
+    start_s, end_s, valid_s = turn(start), turn(end), turn(valid)
+    ins_s = turn(mtype) == INSERTION
+
     # geometry classification over the full grid
+    zr = lambda a: a[:, :, None, None]
     overlap, interior, wlen = slot_geometry(
-        st.tstarts[:, :, None], st.tends[:, :, None], strands[:, :, None],
-        start[None, None, :], end[None, None, :],
-        (mtype == INSERTION)[None, None, :])
-    geo = valid[:, None, :] & overlap & real_rows[:, :, None]
+        zr(st.tstarts), zr(st.tends), zr(strands), start_s, end_s, ins_s)
+    geo = valid_s[:, None] & overlap & zr(real_rows)
     # tiny windows (wlen < min_fast_edge) cannot ride the window-frame
     # edge program (its two regimes would overlap); bail to the host loop
     fb = (geo & ~interior & (wlen < min_fast_edge)).any()
@@ -454,7 +454,7 @@ def _score_slot_grid_dense(st: "RefineLoopState", reads, rlens, strands,
     # cover the ins/subdel row offset in the reverse frame).
     from pbccs_tpu.ops.dense_score_pallas import _PB
     NB = -(-jmax // _PB)
-    pos_any = valid.reshape(Z, jmax, N_SLOTS).any(-1)
+    pos_any = valid_s.any(1)
     pref = jnp.concatenate(
         [jnp.zeros((Z, 1), jnp.int32),
          jnp.cumsum(pos_any.astype(jnp.int32), axis=1)], axis=1)
@@ -495,16 +495,11 @@ def _score_slot_grid_dense(st: "RefineLoopState", reads, rlens, strands,
     e6 = edge_window_scores_batch(f_reads, f_rlens, f_wt, f_wtr, f_wl,
                                   tables, alpha_f, beta_f, f_apre, f_bsuf,
                                   W, layout=lay)
-    grid_w = jax.vmap(splice_edge_rows)(grid_w, e6, f_wl.astype(jnp.int32))
-    mapped = jax.vmap(
-        lambda g, s, a, b: window_grid_to_template(g, s, a, b, jmax)
-    )(grid_w, flat(strands), flat(st.tstarts), flat(st.tends))
-    mapped = mapped.reshape(Z, R, M)
-    score_mask = geo & st.active[:, :, None]
-    out = jnp.sum(
-        jnp.where(score_mask, mapped - st.baselines[:, :, None], 0.0),
-        axis=1)
-    return out, fb
+    out_s = slot_grid_totals(
+        slot_major_spliced(grid_w, e6, f_wl), flat(strands),
+        flat(st.tstarts), flat(st.tends), flat(real_rows & st.active),
+        flat(st.baselines), valid_s, start_s, end_s, ins_s)
+    return jnp.swapaxes(out_s, 1, 2).reshape(Z, M), fb
 
 
 def score_slot_grid(st: "RefineLoopState", reads, rlens, strands, table,

@@ -99,6 +99,22 @@ def _dense_case(n, jmax, width):
     return fn, reads + (tables, alpha, beta, apre, bsuf)
 
 
+def _totals_case(z, r, jmax):
+    """slot_grid_totals, the pass after the dense kernel, on a bucket's
+    slot-major score grid."""
+    from pbccs_tpu.ops.dense_score_pallas import slot_grid_totals
+
+    s = jax.ShapeDtypeStruct
+    per_read = lambda dtype: s((z * r,), dtype)
+    plane = lambda dtype: s((9, jmax), dtype)
+    return slot_grid_totals, (
+        s((z * r, 9, jmax), jnp.float32),           # slot-major grid
+        per_read(jnp.int32), per_read(jnp.int32), per_read(jnp.int32),
+        per_read(jnp.bool_), per_read(jnp.float32),  # live, baselines
+        s((z, 9, jmax), jnp.bool_),                  # valid slots
+        plane(jnp.int32), plane(jnp.int32), plane(jnp.bool_))
+
+
 def _bucket_shapes(z, r, jmax):
     from pbccs_tpu.parallel.batch import _imax_bucket
 
@@ -197,6 +213,8 @@ CASES = [
     pytest.param(_dense_case, (256, 576, 64), id="dense-256x576xW64"),
     pytest.param(_dense_case, (256, 2112, 96), id="dense-256x2112xW96"),
     pytest.param(_dense_case, (96, 15104, 96), id="dense-96x15104xW96"),
+    # the 15 kb x 3 bucket's score grid (never run on the chip)
+    pytest.param(_totals_case, (32, 3, 15104), id="totals-32x3x15104"),
     pytest.param(_bucket_case, (32, 10, 2112), id="bucket-32x10x2112"),
     pytest.param(_bucket_case, (128, 8, 576), id="bucket-128x8x576"),
     pytest.param(_refine_loop_case, (32, 10, 2112),
@@ -291,10 +309,32 @@ def band_layout_ops(hlo_text: str, floor: int, pass_floor: int | None = None):
     return found
 
 
+_LOOP_HLO: dict = {}
+
+
+def _loop_hlo(z, r, jmax, one_chip) -> str:
+    """Optimised HLO of run_refine_loop at one shape set, real kernels,
+    for the described chip; compiled once for the tests that read it."""
+    from pbccs_tpu.ops import dense_score_pallas, fwdbwd_pallas
+
+    if (z, r, jmax) not in _LOOP_HLO:
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(fwdbwd_pallas, "_interpret", lambda: False)
+            mp.setattr(dense_score_pallas, "_interpret", lambda: False)
+            jax.clear_caches()
+            try:
+                fn, shapes = _refine_loop_case(z, r, jmax)
+                _LOOP_HLO[z, r, jmax] = jax.jit(fn).lower(
+                    *_on(shapes, one_chip)).compile().as_text()
+            finally:
+                jax.clear_caches()
+    return _LOOP_HLO[z, r, jmax]
+
+
 @pytest.mark.parametrize("z,r,jmax", [(32, 32, 576), (32, 12, 2304)],
                          ids=["500bp-32x32x576", "2kb-32x12x2304"])
 def test_loop_program_rewrites_no_band(z, r, jmax, one_chip,
-                                       no_persistent_cache, monkeypatch):
+                                       no_persistent_cache):
     """In the optimised HLO of run_refine_loop at the cells' shape sets no
     copy, transpose, pad, concatenate or slice outside the Pallas calls
     has an output of a band tensor's element count or more, but those on
@@ -304,17 +344,9 @@ def test_loop_program_rewrites_no_band(z, r, jmax, one_chip,
     import re
 
     from pbccs_tpu.models.arrow import scorer
-    from pbccs_tpu.ops import dense_score_pallas, fwdbwd, fwdbwd_pallas
+    from pbccs_tpu.ops import fwdbwd
 
-    monkeypatch.setattr(fwdbwd_pallas, "_interpret", lambda: False)
-    monkeypatch.setattr(dense_score_pallas, "_interpret", lambda: False)
-    jax.clear_caches()
-    try:
-        fn, shapes = _refine_loop_case(z, r, jmax)
-        text = jax.jit(fn).lower(*_on(shapes, one_chip)).compile().as_text()
-    finally:
-        jax.clear_caches()
-
+    text = _loop_hlo(z, r, jmax, one_chip)
     width, _ = _bucket_statics(jmax)
     a_read = fwdbwd.band_frame_rows(jmax + 1) * width
     seen = collections.Counter(band_layout_ops(
@@ -330,3 +362,98 @@ def test_loop_program_rewrites_no_band(z, r, jmax, one_chip,
             f"{n} band-sized `{opcode}` from {where or 'the program boundary'!r} "
             f"in the loop program at {z}x{r}x{jmax}: not on "
             "ALLOWED_BAND_LAYOUT_OPS (or more than it allows)")
+
+
+# --------------------------------------------------------------------------
+# the score grid is slot-major after the dense kernel, and mapped by no gather
+# --------------------------------------------------------------------------
+
+_INSTR = (r"^\s*(?:ROOT )?%?([\w.\-]+) = [a-z]\w*\[([\d,]*)\]"
+          r"(?:\{([\d,]*)[^}]*\})? ([a-z\-]+)\((.*)")
+
+
+def unfused_instructions(hlo_text: str):
+    """(name, dims, minor dimension's size, opcode, rest of the line) of
+    every array-valued instruction outside the fused computations: what
+    is written to memory.  The minor dimension is the layout's, the one
+    the lanes hold."""
+    import re
+
+    fused = set(re.findall(r"calls=%([\w.\-]+)", hlo_text))
+    comp = None
+    for line in hlo_text.splitlines():
+        m = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if m:
+            comp = m.group(1)
+            continue
+        m = re.match(_INSTR, line)
+        if not m or comp in fused:
+            continue
+        dims = [int(d) for d in m.group(2).split(",") if d]
+        if not dims:
+            continue
+        order = [int(d) for d in m.group(3).split(",")] if m.group(3) \
+            else list(range(len(dims)))[::-1]
+        yield m.group(1), dims, dims[order[0]], m.group(4), m.group(5)
+
+
+def gather_index_counts(hlo_text: str) -> list:
+    """Indices of every gather, fused or not: its output's elements over
+    its slice's."""
+    import re
+
+    counts = []
+    for line in hlo_text.splitlines():
+        m = re.match(_INSTR, line)
+        if not m or m.group(4) != "gather":
+            continue
+        out = int(np.prod([int(d) for d in m.group(2).split(",") if d]))
+        sl = re.search(r"slice_sizes=\{([\d,]*)\}", line).group(1)
+        counts.append(out // int(np.prod([int(d) for d in sl.split(",")])))
+    return counts
+
+
+@pytest.mark.parametrize("z,r,jmax",
+                         [(16, 12, 2304), (32, 12, 2304), (32, 32, 576)],
+                         ids=["serve-16x12x2304", "2kb-32x12x2304",
+                              "500bp-32x32x576"])
+def test_loop_program_keeps_the_score_grid_slot_major(z, r, jmax, one_chip,
+                                                      no_persistent_cache):
+    """In the optimised HLO of run_refine_loop at the three cells' shape
+    sets: no gather of Z*R*Jmax indices or more (the orientation mapping
+    was three a round, nine floats an index); the dense kernel's output is
+    read by one instruction; nothing but the kernel and that instruction
+    writes a grid-sized array with the nine slots on the lanes (it tiles
+    to 128: 14x the bytes); and the grid is turned once, not copied from
+    layout to layout.  This is what keeps the gathers and the 9-lane
+    passes PR 34 took out from coming back."""
+    import re
+
+    text = _loop_hlo(z, r, jmax, one_chip)
+    counts = gather_index_counts(text)
+    assert counts, "the edge program's gathers are in sight"
+    assert max(counts) < z * r * jmax, (
+        f"a gather of {max(counts)} indices in the loop program at "
+        f"{z}x{r}x{jmax}: the score grid's mapping is rolls and selects")
+
+    grid = z * r * jmax * 9
+    instrs = list(unfused_instructions(text))
+    dense = [name for name, dims, _, op, _ in instrs
+             if op == "custom-call" and dims == [z * r, jmax, 9]
+             and name.startswith("dense_interior_scores_batch")]
+    assert len(dense) == 1, "the loop's one dense score call is in sight"
+    readers = [name for name, _, _, _, rest in instrs
+               if re.search(rf"%{re.escape(dense[0])}\b", rest)]
+    assert len(readers) == 1, (
+        f"the dense kernel's output is read by {readers}: it is read once, "
+        "by what turns it slot-major")
+    on_lanes = [name for name, dims, minor, _, _ in instrs
+                if minor == 9 and int(np.prod(dims)) >= grid
+                and name not in dense + readers]
+    assert not on_lanes, (
+        f"{on_lanes} write a grid-sized array with the 9 slots on the lanes")
+    turns = [name for name, dims, _, op, _ in instrs
+             if op == "copy" and 9 in dims and int(np.prod(dims)) >= grid]
+    assert len(turns) <= 1, (
+        f"the score grid is copied between layouts {len(turns)} times: "
+        f"{turns}")

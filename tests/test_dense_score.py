@@ -133,10 +133,36 @@ def _dense_grid(case, r):
         case["reads"], case["rlens"], case["win_tpl"], case["win_trans"],
         case["wlens"], tables, case["alpha"], case["beta"],
         case["apre"], case["bsuf"], W)
-    mapped = dsp.window_grid_to_template(
-        grid_w[r], case["strands"][r], case["ts"][r], case["te"][r],
-        case["Jmax"])
-    return np.asarray(mapped)
+    return _to_template(grid_w, case)[r]
+
+
+def _slot_planes(jmax):
+    """The candidates' (9, jmax) start / end / is-insertion planes."""
+    start = np.broadcast_to(np.arange(jmax, dtype=np.int32), (9, jmax))
+    return (start, start + np.asarray(dr.SLOT_ENDOFF, np.int32)[:, None],
+            np.broadcast_to((np.asarray(dr.SLOT_TYPES) == dr.INSERTION)
+                            [:, None], (9, jmax)))
+
+
+def _mapped_reads(grid_s, strand, ts, te, jmax):
+    """(N, 9, jmax): every read's slot-major window-frame grid on the
+    template frame, through the pass the score call runs
+    (slot_grid_totals with each read a ZMW of its own, every slot valid,
+    no baseline): the mapped score where the slot overlaps the read's
+    window, 0 elsewhere."""
+    n = grid_s.shape[0]
+    return np.asarray(dsp.slot_grid_totals(
+        jnp.asarray(grid_s), jnp.asarray(strand), jnp.asarray(ts),
+        jnp.asarray(te), jnp.ones(n, bool), jnp.zeros(n, jnp.float32),
+        jnp.ones((n, 9, jmax), bool), *map(jnp.asarray, _slot_planes(jmax))))
+
+
+def _to_template(grid_w, case):
+    """(R, Jmax, 9) template-frame scores of (R, Jm, 9) window-frame ones
+    (0 where the slot does not overlap the read's window)."""
+    return _mapped_reads(
+        jnp.swapaxes(jnp.asarray(grid_w), 1, 2), case["strands"],
+        case["ts"], case["te"], case["Jmax"]).transpose(0, 2, 1)
 
 
 @pytest.mark.parametrize("windows", [
@@ -341,12 +367,8 @@ def test_band_read_windows_flat_offset_garbage_lane(rng):
             start, end, mtype, base, valid = dr.slot_candidates(
                 case["tpl_p"].astype(jnp.int8), case["tlen"])
             mask = _interior_mask(case, r, start, end, mtype, valid)
-            m_ref = np.asarray(dsp.window_grid_to_template(
-                jnp.asarray(int_ref[r]), case["strands"][r], case["ts"][r],
-                case["te"][r], case["Jmax"])).reshape(-1)
-            m_v = np.asarray(dsp.window_grid_to_template(
-                jnp.asarray(int_v[r]), case["strands"][r], case["ts"][r],
-                case["te"][r], case["Jmax"])).reshape(-1)
+            m_ref = _to_template(int_ref, case)[r].reshape(-1)
+            m_v = _to_template(int_v, case)[r].reshape(-1)
             np.testing.assert_array_equal(
                 m_v[mask], m_ref[mask],
                 err_msg=f"{variant}: interior scores moved, read {r}")
@@ -792,3 +814,201 @@ def test_edge_program_on_framed_layout_matches_oracle(strand):
                     err_msg=f"read {r} row {row} slot {k}")
                 checked += 1
     assert checked >= 16
+
+
+# --------------------------------------------------------------------------
+# the score grid after the kernel: slot-major, mapped without a gather
+# --------------------------------------------------------------------------
+
+_REV_PERM = np.array([3, 2, 1, 0, 7, 6, 5, 4, 8])
+
+
+def gather_map_reference(grid, strand, ts, te, jmax):
+    """One read's window-frame (Jm, 9) grid on the template frame, (jmax,
+    9): a NumPy transcription of the index-gather formulation the program
+    ran until PR 34 (window_grid_to_template: three takes a read, the slot
+    permutation a fourth), kept here as the mapping's reference."""
+    jm = grid.shape[0]
+    gpad = np.concatenate([grid, np.zeros((1, 9), grid.dtype)])
+
+    def pick(g, idx):
+        return g[np.where((idx >= 0) & (idx < jm), idx, jm)]
+
+    pos = np.arange(jmax)
+    if strand == 0:
+        return pick(gpad, pos - ts)
+    rev_g = gpad[:, _REV_PERM]
+    subdel, ins = pick(rev_g, te - 1 - pos), pick(rev_g, te - pos)
+    return np.concatenate([subdel[:, :4], ins[:, 4:8], subdel[:, 8:]], axis=1)
+
+
+def splice_reference(grid, e6, j):
+    """One read's (Jm, 9) grid with rows {0,1,2, J-2,J-1,J} overwritten by
+    the (6, 9) edge scores, ins at J-2 kept (splice_edge_rows until PR 34:
+    near-begin first, then the near-end rows in order)."""
+    out = grid.copy()
+    out[:3] = e6[:3]
+    for i in range(3):
+        if 0 <= j - 2 + i < grid.shape[0]:
+            keep = dsp._NE_MASK9[i]
+            out[j - 2 + i, keep] = e6[3 + i, keep]
+    return out
+
+
+def _random_windows(rng, n, jm, jmax):
+    """(strand, ts, te) of n reads: random windows, then windows equal to
+    the template, at its begin and at its end, shorter than the edge rows,
+    and past both ends (ts < 0, te - ts over Jm), on both strands."""
+    strand = rng.integers(0, 2, n)
+    ts = rng.integers(0, jmax - 1, n)
+    te = np.minimum(ts + rng.integers(1, jmax + 1, n), jmax)
+    fixed = [(0, jmax), (0, jmax // 3), (jmax - jmax // 3, jmax), (5, 9),
+             (0, 2), (jmax - 1, jmax), (-7, jmax - 11), (4, jm + 9),
+             (-3, jm + 2)]
+    for i, (a, b) in enumerate(fixed):
+        for s in (0, 1):
+            strand[2 * i + s], ts[2 * i + s], te[2 * i + s] = s, a, b
+    return strand, ts, te
+
+
+def totals_reference(spliced, strand, ts, te, live, base, valid):
+    """(Z, jmax, 9) float32 totals of (Z*R, Jm, 9) window-frame grids, as
+    the program reckoned them until PR 34 but for the order of the sum:
+    each read mapped by gather_map_reference, masked where its ZMW's slot
+    is valid (Z, jmax, 9), overlaps its window and the read is live, less
+    its baseline, and summed over the ZMW's reads by halves of the read
+    axis (the written-out order; a jnp.sum's was XLA's to choose)."""
+    n, z, jmax = spliced.shape[0], valid.shape[0], valid.shape[1]
+    r = n // z
+    ms, me, ins = (np.asarray(a).T for a in _slot_planes(jmax))
+    terms = np.zeros((n, jmax, 9), np.float32)
+    for k in range(n):
+        overlap = np.where(ins, (ts[k] <= me) & (ms <= te[k]),
+                           (ts[k] < me) & (ms < te[k]))
+        mapped = gather_map_reference(spliced[k], strand[k], ts[k], te[k],
+                                      jmax)
+        terms[k] = np.where(valid[k // r] & overlap & live[k],
+                            mapped - base[k], np.float32(0))
+    out = []
+    for zmw in terms.reshape(z, r, jmax, 9):
+        m = 1 << (r - 1).bit_length()
+        x = np.concatenate([zmw, np.zeros((m - r, jmax, 9), np.float32)])
+        while m > 1:
+            m //= 2
+            x = x[:m] + x[m:]
+        out.append(x[0])
+    return np.stack(out)
+
+
+@pytest.mark.parametrize("z,r,jm,jmax", [
+    (2, 12, 2304, 2304),         # 2kb-3to10x.serve-c32: 16 x 12 x 2,304
+    (4, 12, 2304, 2304),         # 2kb-3to10x.batch: 32 x 12 x 2,304
+    (2, 32, 576, 576),           # 500bp-30x.batch: 32 x 32 x 576
+    (2, 12, 192, 256),           # a template frame longer than the windows
+    (2, 12, 256, 192),           # and shorter
+], ids=["serve-2x12x2304", "2kb-4x12x2304", "500bp-2x32x576",
+        "jmax-over-jm", "jmax-under-jm"])
+def test_slot_major_totals_match_gather_reference(z, r, jm, jmax):
+    """slot_major_spliced + slot_grid_totals give, bit for bit, what the
+    splice, the gather mapping, the masks and the baselines gave, at the
+    cells' (Z*R, Jm) (Z reduced): first read by read (every entry a
+    read's window overlaps), then as the ZMWs' totals."""
+    n = z * r
+    rng = np.random.default_rng(34 + n + jm)
+    grid = (rng.normal(size=(n, jm, 9)) * 40).astype(np.float32)
+    e6 = (rng.normal(size=(n, 6, 9)) * 40).astype(np.float32)
+    strand, ts, te = _random_windows(rng, n, jm, jmax)
+    wl = np.clip(te - ts, 0, jm)
+
+    spliced = np.stack([splice_reference(grid[k], e6[k], wl[k])
+                        for k in range(n)])
+    grid_s = dsp.slot_major_spliced(jnp.asarray(grid), jnp.asarray(e6),
+                                    jnp.asarray(wl))
+    np.testing.assert_array_equal(np.asarray(grid_s),
+                                  spliced.transpose(0, 2, 1))
+
+    every = np.ones(n, bool)
+    want = totals_reference(spliced, strand, ts, te, every,
+                            np.zeros(n, np.float32),
+                            np.ones((n, jmax, 9), bool))
+    assert (want != 0).mean() > 0.3, "windows cover too little to compare"
+    np.testing.assert_array_equal(
+        _mapped_reads(grid_s, strand, ts, te, jmax),
+        want.transpose(0, 2, 1))
+
+    live = rng.random(n) > 0.2
+    base = (rng.normal(size=n) * 40).astype(np.float32)
+    valid = rng.random((z, jmax, 9)) > 0.3
+    got = dsp.slot_grid_totals(
+        grid_s, *map(jnp.asarray, (strand, ts, te, live, base,
+                                   valid.transpose(0, 2, 1))),
+        *map(jnp.asarray, _slot_planes(jmax)))
+    np.testing.assert_array_equal(
+        np.asarray(got).transpose(0, 2, 1),
+        totals_reference(spliced, strand, ts, te, live, base, valid))
+
+
+def test_mapping_lowers_to_no_gather():
+    """The pass after the kernel is a turn, selects, a reversal and a lane
+    rotate: its jaxpr, the kernel's included, holds no gather,
+    dynamic_slice or scatter."""
+    n, jm = 24, 2304
+    f = lambda g, e, j, s, a, b, v: dsp.slot_grid_totals(
+        dsp.slot_major_spliced(g, e, j), s, a, b, jnp.ones(n, bool),
+        jnp.zeros(n, jnp.float32), v, *map(jnp.asarray, _slot_planes(jm)))
+    i32 = jax.ShapeDtypeStruct((n,), jnp.int32)
+    text = str(jax.make_jaxpr(f)(
+        jax.ShapeDtypeStruct((n, jm, 9), jnp.float32),
+        jax.ShapeDtypeStruct((n, 6, 9), jnp.float32), i32, i32, i32, i32,
+        jax.ShapeDtypeStruct((2, 9, jm), jnp.bool_)))
+    assert "pallas_call" in text and "roll" in text
+    for op in ("gather", "dynamic_slice", "scatter"):
+        assert op not in text, f"{op} in the score grid's mapping"
+
+
+def test_read_reduction_is_the_same_bits_at_any_batch_shape():
+    """A ZMW's totals over its reads are the same bits at Z = 4, 16 and 32,
+    in whichever slot of the batch it sits, and in 12 lanes as in 32 (the
+    lanes past its reads are not live and add zeros): the order is the
+    kernel's own, which no batch shape, Z or layout chooses."""
+    rng = np.random.default_rng(3434)
+    r, jm = 12, 256
+    mine = (rng.normal(size=(r, jm, 9)) * 50).astype(np.float32)
+    strand, ts, te = _random_windows(rng, 18, jm, jm)
+    strand, ts, te = strand[:r], ts[:r], te[:r]
+    live = np.arange(r) < 10             # a 10-pass ZMW in 12 lanes
+    base = (rng.normal(size=r) * 50).astype(np.float32)
+    valid = rng.random((jm, 9)) > 0.2
+    want = totals_reference(mine, strand, ts, te, live, base, valid[None])[0]
+    # the order matters on this input: read after read gives other bits
+    one = lambda k: totals_reference(mine[k:k + 1], strand[k:k + 1],
+                                     ts[k:k + 1], te[k:k + 1], live[k:k + 1],
+                                     base[k:k + 1], valid[None])[0]
+    in_turn = one(0)
+    for k in range(1, r):
+        in_turn = in_turn + one(k)
+    assert (in_turn != want).any()
+
+    planes = tuple(map(jnp.asarray, _slot_planes(jm)))
+    for z, slot, lanes in [(4, 0, 12), (16, 7, 12), (32, 31, 12),
+                           (32, 5, 32)]:
+        # a batch of other ZMWs' reads, with mine in `slot`'s first lanes
+        n = z * lanes
+        g = (rng.normal(size=(n, jm, 9)) * 50).astype(np.float32)
+        b_strand = rng.integers(0, 2, n)
+        b_ts = rng.integers(0, jm // 2, n)
+        b_te = b_ts + 40
+        b_live = rng.random(n) > 0.5
+        b_base = (rng.normal(size=n) * 50).astype(np.float32)
+        at = slot * lanes
+        b_live[at:at + lanes] = False
+        for whole, part in ((g, mine), (b_strand, strand), (b_ts, ts),
+                            (b_te, te), (b_live, live), (b_base, base)):
+            whole[at:at + r] = part
+        got = dsp.slot_grid_totals(
+            *map(jnp.asarray, (g.transpose(0, 2, 1), b_strand, b_ts, b_te,
+                               b_live, b_base,
+                               np.broadcast_to(valid.T, (z, 9, jm)))),
+            *planes)
+        np.testing.assert_array_equal(
+            np.asarray(got)[slot].T, want, err_msg=f"Z={z} R={lanes}")

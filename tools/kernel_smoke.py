@@ -125,7 +125,7 @@ def main() -> int:
                 if slot_mt[k] != 1 and p + 1 > J - 2:
                     continue
                 check(float(grid[r, p, k]), p, k, "interior")
-        # edge rows {0,1,2} x {J-2,J-1,J}, regime rules as splice_edge_rows
+        # edge rows {0,1,2} x {J-2,J-1,J}, regime rules as slot_major_spliced
         for row, p in enumerate([0, 1, 2, J - 2, J - 1, J]):
             for k in range(9):
                 mtype = slot_mt[k]
