@@ -41,7 +41,12 @@ from pbccs_tpu.pipeline import (
     Subread,
 )
 from pbccs_tpu.runtime.chemistry import verify_chemistry
-from pbccs_tpu.runtime.logging import Logger, LogLevel, install_signal_handlers
+from pbccs_tpu.runtime.logging import (
+    Logger,
+    LogLevel,
+    dump_stacks_on_crash,
+    install_signal_handlers,
+)
 from pbccs_tpu.runtime.whitelist import Whitelist
 
 DESCRIPTION = ("Generate circular consensus sequences (ccs) from subreads "
@@ -410,6 +415,7 @@ def run(argv: list[str] | None = None) -> int:
         stream=open(args.logFile, "w") if args.logFile else sys.stderr,
         level=LogLevel.from_string(args.logLevel)))
     install_signal_handlers(log)
+    dump_stacks_on_crash()
 
     from pbccs_tpu.runtime import tuning
 

@@ -587,39 +587,40 @@ def _polish_batch_arrow(preps: Sequence[PreparedZmw],
         refine_results = polisher.refine(settings.refine, skip=skip)
     wide_refine = wide_qvs = wide_gz = None
     if wide_pick:
-        try:  # the whole wide retry is speculative: any failure in its
-            # polish falls back to the narrow batch's completed results
-            # (with the narrow gates) instead of discarding the batch
-            wide_skip = {i for i in range(wide.n_zmws)
-                         if i not in {wi for z, wi in wide_pick.items()
-                                      if z not in gate_failed}}
-            wide_gz = wide.global_zscores()
-            wide_refine = wide.refine(settings.refine, skip=wide_skip)
-            wide_qvs = wide.consensus_qvs(
-                skip=wide_skip | {i for i, r in enumerate(wide_refine)
-                                  if not r.converged})
-        except Exception as e:  # noqa: BLE001 -- revert to narrow batch
-            record_zmw_failure("polish.wide", e,
-                               zmw=f"reband[{len(wide_pick)}]")
-            retry = set(wide_pick)
-            for z in list(wide_pick):
-                gate_info[z] = _read_gates(
-                    preps[z], polisher.statuses[z], settings)
-            wide_pick.clear()
-            gate_failed = {z for z, g in enumerate(gate_info)
-                           if g[0] is not None}
-            skip = gate_failed
-            # refine ONLY the formerly wide-routed ZMWs: the rest of
-            # the narrow batch already refined in the first pass, and
-            # re-running them would hand non-convergent ZMWs a second
-            # full iteration budget and rebuild their refine stats
-            todo = retry - gate_failed
-            if todo:
-                retry_results = polisher.refine(
-                    settings.refine,
-                    skip=set(range(polisher.n_zmws)) - todo)
-                for z in todo:
-                    refine_results[z] = retry_results[z]
+        with obs_trace.span("polish.wide", zmws=len(wide_pick)):
+            try:  # the whole wide retry is speculative: any failure in its
+                # polish falls back to the narrow batch's completed results
+                # (with the narrow gates) instead of discarding the batch
+                wide_skip = {i for i in range(wide.n_zmws)
+                             if i not in {wi for z, wi in wide_pick.items()
+                                          if z not in gate_failed}}
+                wide_gz = wide.global_zscores()
+                wide_refine = wide.refine(settings.refine, skip=wide_skip)
+                wide_qvs = wide.consensus_qvs(
+                    skip=wide_skip | {i for i, r in enumerate(wide_refine)
+                                      if not r.converged})
+            except Exception as e:  # noqa: BLE001 -- revert to narrow batch
+                record_zmw_failure("polish.wide", e,
+                                   zmw=f"reband[{len(wide_pick)}]")
+                retry = set(wide_pick)
+                for z in list(wide_pick):
+                    gate_info[z] = _read_gates(
+                        preps[z], polisher.statuses[z], settings)
+                wide_pick.clear()
+                gate_failed = {z for z, g in enumerate(gate_info)
+                               if g[0] is not None}
+                skip = gate_failed
+                # refine ONLY the formerly wide-routed ZMWs: the rest of
+                # the narrow batch already refined in the first pass, and
+                # re-running them would hand non-convergent ZMWs a second
+                # full iteration budget and rebuild their refine stats
+                todo = retry - gate_failed
+                if todo:
+                    retry_results = polisher.refine(
+                        settings.refine,
+                        skip=set(range(polisher.n_zmws)) - todo)
+                    for z in todo:
+                        refine_results[z] = retry_results[z]
     # non-converged ZMWs are discarded by _finish_zmw; don't pay the QV
     # sweep (the most expensive single pass) for them
     skip = skip | {z for z, r in enumerate(refine_results)

@@ -12,6 +12,7 @@ from __future__ import annotations
 import atexit
 import datetime
 import enum
+import faulthandler
 import queue
 import signal
 import sys
@@ -147,3 +148,17 @@ def install_signal_handlers(logger: Logger | None = None) -> None:
             signal.signal(sig, handler)
         except (ValueError, OSError):  # non-main thread / unsupported
             pass
+
+
+def dump_stacks_on_crash() -> None:
+    """Arm `faulthandler` on the process's own stderr: a SIGSEGV, SIGBUS,
+    SIGILL, SIGFPE or SIGABRT (native code, XLA, the TPU runtime) leaves
+    every thread's Python stack there before the process dies, so an
+    exit 139 says where it was.  Call it AFTER install_signal_handlers:
+    `signal.signal` takes a handler faulthandler had armed off again,
+    and faulthandler hands on to the handler it found."""
+    try:
+        faulthandler.disable()
+        faulthandler.enable(file=sys.__stderr__, all_threads=True)
+    except (AttributeError, OSError, RuntimeError, ValueError):
+        pass   # no stderr to write to (a detached embedding process)

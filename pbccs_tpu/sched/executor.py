@@ -284,8 +284,9 @@ class ScheduledPipeline:
                                 start_unix=submit_unix, zmws=len(preps),
                                 batch=idx, cpu_ms=0.0)
                     try:
-                        with obs_trace.span("polish", zmws=len(preps),
-                                            batch=idx):
+                        with obs_trace.span(
+                                "polish", zmws=len(preps), batch=idx,
+                                device=resources.current_device()):
                             return pipeline.polish_prepared_batch(
                                 preps, settings, buckets=pin,
                                 on_error=on_error,

@@ -66,6 +66,7 @@ import time
 import traceback
 from typing import Any, Callable, Hashable, Sequence
 
+from pbccs_tpu.obs import trace as obs_trace
 from pbccs_tpu.obs.metrics import default_registry
 from pbccs_tpu.runtime.logging import Logger
 from pbccs_tpu.sched.health import StickyMap
@@ -187,6 +188,20 @@ class _Task:
     capacity_requeues: int = 0
 
 
+def starved_counter(device: str):
+    """Seconds the thread that owns `device` sat with nothing to polish.
+    Near zero: work was always queued when the device came free (the
+    device sets the pace); large: the host's prepare does.  Booked where
+    that thread waits: DevicePool._worker_loop, and on `ccs serve`'s
+    one-device path, which has no pool, CcsEngine._polish_worker (from
+    the first flush it took).  Each wait is also a `device.starved`
+    span."""
+    return _reg.counter(
+        "ccs_sched_device_starved_seconds_total",
+        "Seconds a device's executor sat with an empty queue between "
+        "the pool's first submit and its close", device=device)
+
+
 class _Worker:
     """Bookkeeping for one device executor (state guarded by pool lock)."""
 
@@ -210,12 +225,7 @@ class _Worker:
         self.m_depth = _reg.gauge("ccs_sched_queue_depth",
                                   "Queued + running tasks per device",
                                   device=self.name)
-        # near zero: work was always queued when the device came free
-        # (the device sets the pace); large: the host's prepare does
-        self.m_starved = _reg.counter(
-            "ccs_sched_device_starved_seconds_total",
-            "Seconds a device's executor sat with an empty queue between "
-            "the pool's first submit and its close", device=self.name)
+        self.m_starved = starved_counter(self.name)
 
     def depth(self) -> int:
         return len(self.pending) + (1 if self.busy else 0)
@@ -353,7 +363,11 @@ class DevicePool:
             with self._cv:
                 while not w.pending and not self._closed and not w.benched:
                     t_idle = time.monotonic()
-                    self._cv.wait()
+                    # `head`: a wait that began before the first submit
+                    # (the counter leaves that part out, the span does not)
+                    with obs_trace.span("device.starved", device=w.name,
+                                        head=self._first_submit is None):
+                        self._cv.wait()
                     if self._first_submit is not None:
                         w.m_starved.inc(max(0.0, time.monotonic() - max(
                             t_idle, self._first_submit)))

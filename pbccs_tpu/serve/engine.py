@@ -132,10 +132,23 @@ def _polish_shape_pinned(preps: Sequence[PreparedZmw], settings, *,
     the programs the first one loaded, and a ZMW's answer does not
     depend on its flush-mates (padding changes no arithmetic; the fills
     and the dense kernel skip slots and lanes that hold nothing)."""
-    with obs_trace.span("polish", zmws=len(preps)):
+    with obs_trace.span("polish", zmws=len(preps), device=_device_name()):
         return polish_prepared_batch(
             preps, settings, buckets=_flush_shapes(preps), min_z=min_z,
             fixed_z=True, raise_device_shaped=raise_device_shaped)
+
+
+def _device_name() -> str:
+    """The device the calling thread polishes on, as a DevicePool names
+    its workers (`tpu:0`): the pool's `jax.default_device` scope on a
+    fleet (the polish watchdog carries it to its thread), else the
+    process's first device, where `--devices 1` polishes."""
+    import jax
+
+    device = jax.config.jax_default_device
+    if not hasattr(device, "platform"):
+        device = jax.devices()[0]
+    return f"{device.platform}:{device.id}"
 
 
 class EngineOverloaded(RuntimeError):
@@ -882,6 +895,15 @@ class CcsEngine:
             with self._wake:
                 self._wake.notify_all()   # a batch left: buckets it held may go
 
+    def _complete_traced(self, batch: Batch, outcomes: list | None,
+                         error: BaseException | None) -> None:
+        """_complete_batch under `serve.complete`: the name says what is
+        done, the thread whether a device waited for it (the polish
+        executor's at one device, the completion thread's with a pool)."""
+        with obs_trace.span("serve.complete", zmws=len(batch.items),
+                            flush=batch.items[0].payload[0].flush):
+            self._complete_batch(batch, outcomes, error=error)
+
     def _pool_done(self, batch: Batch, fut) -> None:
         # runs on a device executor thread: hand off immediately so the
         # device goes back to polishing while replies hit client sockets
@@ -896,23 +918,38 @@ class CcsEngine:
                 return
             batch, outcomes, error = item
             try:
-                self._complete_batch(batch, outcomes, error=error)
+                self._complete_traced(batch, outcomes, error)
             except Exception as e:  # noqa: BLE001 -- the completer must
                 # outlive any one batch (accounting already ran in
                 # _complete_batch's finally)
                 self._log.warn(f"batch completion failed: {e!r}")
 
     def _polish_worker(self) -> None:
+        """The one-device path's polish executor: the thread that owns
+        the device.  It books its waits on an empty queue as a DevicePool
+        worker does (`device.starved`, and from the first flush it took
+        ccs_sched_device_starved_seconds_total), and completes a flush's
+        requests itself before it takes the next one."""
+        from pbccs_tpu.sched.pool import starved_counter
+
+        device = _device_name()
+        m_starved = starved_counter(device)
+        head = True
         while True:
-            batch = self._polish_queue.get()
+            t_idle = time.monotonic()
+            with obs_trace.span("device.starved", device=device, head=head):
+                batch = self._polish_queue.get()
+            if not head:
+                m_starved.inc(time.monotonic() - t_idle)
             if batch is None:
                 return
+            head = False
+            outcomes = error = None
             try:
                 outcomes = self._run_polish(batch)
             except Exception as e:  # noqa: BLE001 -- fail THIS batch only
-                self._complete_batch(batch, error=e)
-            else:
-                self._complete_batch(batch, outcomes)
+                error = e
+            self._complete_traced(batch, outcomes, error)
 
     # ------------------------------------------------------------ completion
 

@@ -35,7 +35,7 @@ import threading
 import time
 
 from pbccs_tpu.obs.metrics import default_registry
-from pbccs_tpu.runtime.logging import Logger, LogLevel
+from pbccs_tpu.runtime.logging import Logger, LogLevel, dump_stacks_on_crash
 from pbccs_tpu.serve import protocol, tenancy
 from pbccs_tpu.serve.engine import (
     CcsEngine,
@@ -701,6 +701,7 @@ def run_serve(argv: list[str] | None = None,
     """`ccs serve` entry point (dispatched from pbccs_tpu.cli).  An
     embedding caller (a thread of a test) hands its own `stop` event in
     place of the signals a thread cannot take."""
+    dump_stacks_on_crash()
     args = build_serve_parser().parse_args(argv)
     if args.devices < 0:
         print(f"option --devices: must be >= 0, got {args.devices}",

@@ -255,6 +255,254 @@ def test_scheduled_turn_wait_runs_from_submit_to_the_polish_opening(
             >= polishes[0]["ts"] + polishes[0]["dur"] - 20_000)
 
 
+# --------------------------------------------- the thread that owns the chip
+
+STARVED = "ccs_sched_device_starved_seconds_total"
+
+
+def starved_seconds(device: str) -> float:
+    return default_registry().counter(STARVED, device=device).value
+
+
+def assert_spans_match_the_counter(starved: list[dict], moved: float) -> None:
+    """The non-`head` waits are what the counter counts: 1 % + 5 ms."""
+    counted = sum(e["dur"] for e in starved if not e["args"]["head"]) / 1e6
+    assert abs(counted - moved) <= 0.01 * moved + 0.005, (counted, moved)
+
+
+def test_device_starved_spans_the_pool_workers_waits_as_the_counter_counts(
+        tracer):
+    """One device: a wait before the first submit (`head`: the counter
+    leaves it out), one between two tasks, one up to the close.  Each is
+    a `device.starved` span on the worker's thread that carries the
+    worker's name, and the non-`head` ones sum to the counter's movement."""
+    from pbccs_tpu.sched import DevicePool
+
+    device = jax.devices()[0]
+    name = f"{device.platform}:{device.id}"
+    before = starved_seconds(name)
+    ran_on = []
+
+    def task(_device):
+        ran_on.append(threading.get_ident() & 0xFFFFFFFF)
+        time.sleep(0.05)
+
+    with DevicePool([device]) as pool:
+        time.sleep(0.15)
+        pool.submit("k", task).result(10.0)
+        time.sleep(0.25)
+        pool.submit("k", task).result(10.0)
+        time.sleep(0.1)
+    moved = starved_seconds(name) - before
+    starved = events_by_name(tracer.to_chrome())["device.starved"]
+    assert all(e["args"]["device"] == name for e in starved)
+    assert {e["tid"] for e in starved} == set(ran_on)     # the owner thread
+    assert not any(e["args"].get("open") for e in starved)
+    assert [e["args"]["head"] for e in starved][0] is True
+    heads = [e for e in starved if e["args"]["head"]]
+    assert len(heads) == 1 and heads[0]["dur"] >= 140_000
+    assert sum(e["dur"] for e in starved if not e["args"]["head"]) >= 340_000
+    assert_spans_match_the_counter(starved, moved)
+    for e in starved:
+        assert e["args"]["cpu_ms"] < 20.0              # waiting is not work
+
+
+class _ServedStub:
+    """A CcsEngine whose drafts and polishes are stubs: the engine's own
+    threads, queues and spans, no device work."""
+
+    def __init__(self, **cfg):
+        import numpy as np
+
+        from pbccs_tpu.serve.engine import CcsEngine, ServeConfig
+
+        self.polished_on = []
+
+        def prep(chunk, settings):
+            return None, pipeline.PreparedZmw(
+                chunk, np.zeros(64, np.int8), [], len(chunk.reads), 0, 0.0)
+
+        def polish(preps, settings):
+            self.polished_on.append(threading.get_ident() & 0xFFFFFFFF)
+            time.sleep(0.05)
+            return [(pipeline.Failure.OTHER, None) for _ in preps]
+
+        self.engine = CcsEngine(
+            config=ServeConfig(max_batch=2, max_wait_ms=10.0, **cfg),
+            prep_fn=prep, polish_fn=polish)
+
+    def serve(self, waves: int = 2, gap_s: float = 0.2) -> None:
+        import numpy as np
+
+        def chunk(k):
+            seq = np.arange(20, dtype=np.int8) % 4
+            return pipeline.Chunk(f"m/{k}", [pipeline.Subread(f"m/{k}/0", seq)],
+                                  np.full(4, 8.0))
+
+        with self.engine as eng:
+            for wave in range(waves):
+                reqs = [eng.submit(chunk(2 * wave + i)) for i in range(2)]
+                assert all(r.wait(10.0) for r in reqs)
+                time.sleep(gap_s)
+
+
+def test_the_served_path_books_its_waits_at_one_device(tracer):
+    """`--devices 1`: the polish executor's waits on its queue are
+    `device.starved` spans (the one before its first flush `head`), the
+    counter of the pool's name moves by the non-`head` ones, and a
+    flush's requests are completed under `serve.complete` on the thread
+    that polished them, between two waits."""
+    device = jax.devices()[0]
+    name = f"{device.platform}:{device.id}"
+    before = starved_seconds(name)
+    stub = _ServedStub()
+    stub.serve()
+    moved = starved_seconds(name) - before
+    by_name = events_by_name(tracer.to_chrome())
+    starved, completes = by_name["device.starved"], by_name["serve.complete"]
+    assert len(stub.polished_on) == 2 and len(set(stub.polished_on)) == 1
+    assert {e["tid"] for e in starved + completes} == set(stub.polished_on)
+    assert all(e["args"]["device"] == name for e in starved)
+    assert [e["args"]["head"] for e in starved] == [True, False, False]
+    assert moved >= 0.15                               # the gap after a wave
+    assert_spans_match_the_counter(starved, moved)
+    assert [(e["args"]["zmws"], e["args"]["flush"]) for e in completes] \
+        == [(2, 1), (2, 2)]
+    # wait, polish, complete, wait: no hole on the owner thread's timeline
+    for done, wait in zip(completes, starved[1:]):
+        assert 0 <= wait["ts"] - (done["ts"] + done["dur"]) < 20_000
+    assert "parent" not in completes[0]["args"]
+
+
+def test_the_served_path_with_a_pool_leaves_the_waits_to_the_pool(tracer):
+    """`--devices 2`: no polish executor of the engine's own, so every
+    `device.starved` span and every counted second is the pool's (one
+    site a path), and `serve.complete` runs on the completion thread,
+    where no device waits for it."""
+    devices = jax.devices()[:2]
+    names = [f"{d.platform}:{d.id}" for d in devices]
+    before = [starved_seconds(n) for n in names]
+    stub = _ServedStub(devices=2)
+    stub.serve()
+    moved = sum(starved_seconds(n) - b for n, b in zip(names, before))
+    by_name = events_by_name(tracer.to_chrome())
+    starved, completes = by_name["device.starved"], by_name["serve.complete"]
+    assert {e["args"]["device"] for e in starved} <= set(names)
+    assert_spans_match_the_counter(starved, moved)
+    pool_threads = {e["tid"] for e in starved}
+    assert set(stub.polished_on) <= pool_threads
+    assert len(completes) == 2
+    assert not {e["tid"] for e in completes} & pool_threads
+
+
+def test_polish_names_the_device_it_runs_on(tracer, monkeypatch):
+    """Both `polish` sites carry `device=` as the pool names it: the
+    scheduled driver's from the pool's scope, the engine's pinned polish
+    from the process's first device."""
+    from pbccs_tpu.sched import DevicePool
+    from pbccs_tpu.sched.executor import ScheduledPipeline
+    from pbccs_tpu.serve import engine as serve_engine
+
+    def stub_polish(preps, settings, **kw):
+        return [(pipeline.Failure.OTHER, None) for _ in preps]
+
+    monkeypatch.setattr(pipeline, "prepare_batch",
+                        lambda chunks, settings, **kw:
+                        (pipeline.ResultTally(), list(chunks)))
+    monkeypatch.setattr(pipeline, "polish_prepared_batch", stub_polish)
+    monkeypatch.setattr(serve_engine, "polish_prepared_batch", stub_polish)
+    monkeypatch.setattr(pipeline, "menu_batch_shapes",
+                        lambda preps, **kw: ((8, 8, 4), 4))
+    monkeypatch.setattr(serve_engine, "menu_batch_shapes",
+                        lambda preps, **kw: ((8, 8, 4), 4))
+    monkeypatch.setattr(pipeline, "prebake_polish", lambda preps, **kw: None)
+    device = jax.devices()[1]
+    with DevicePool([device]) as pool:
+        pipe = ScheduledPipeline(pool, pipeline.ConsensusSettings(),
+                                 prepare_workers=1)
+        assert [idx for idx, _t in pipe.run([(0, ["a"], None)])] == [0]
+    serve_engine._polish_shape_pinned(["a", "b"], pipeline.ConsensusSettings())
+    scheduled, served = events_by_name(tracer.to_chrome())["polish"]
+    assert scheduled["args"]["device"] == f"{device.platform}:{device.id}"
+    assert (scheduled["args"]["zmws"], scheduled["args"]["batch"]) == (1, 0)
+    first = jax.devices()[0]
+    assert served["args"]["device"] == f"{first.platform}:{first.id}"
+    assert served["args"]["zmws"] == 2
+
+
+def test_the_owner_threads_sites_make_nothing_without_a_tracer(monkeypatch):
+    """Tracing off: a pool wait, a served wait and completion, and a
+    polish each get the one shared no-op back (one global read a site)."""
+    from pbccs_tpu.sched import DevicePool
+
+    handed = []
+    real = obs_trace.span
+
+    def spy(name, ctx=None, **args):
+        got = real(name, ctx=ctx, **args)
+        handed.append((name, got))
+        return got
+
+    prev = obs_trace.set_tracer(None)
+    monkeypatch.setattr(obs_trace, "span", spy)
+    try:
+        with DevicePool(jax.devices()[:1]) as pool:
+            pool.submit("k", lambda _device: None).result(10.0)
+        _ServedStub().serve(waves=1, gap_s=0.0)
+    finally:
+        obs_trace.set_tracer(prev)
+    assert {"device.starved", "serve.complete"} <= {n for n, _ in handed}
+    assert all(got is obs_trace._NO_SPAN for _n, got in handed)
+
+
+def test_polish_wide_covers_the_wide_band_retry_under_polish(
+        tracer, rng, monkeypatch):
+    """A batch in which one ZMW's read fails the alpha/beta mating at the
+    narrow band and mates at twice the band: the retry's z-scores, refine
+    and QV sweep run under `polish.wide`, a child of `polish` between
+    `polish.refine` and `polish.qv`, and polish's parts then cover it
+    (a batch without a retry has no such span: the CLI test below)."""
+    import pbccs_tpu.parallel.batch as batchmod
+    from pbccs_tpu.models.arrow.scorer import ADD_ALPHABETAMISMATCH
+    from pbccs_tpu.simulate import simulate_zmw
+
+    chunks = []
+    for z in range(2):
+        _tpl, reads, _strands, snr = simulate_zmw(rng, 60, 4)
+        chunks.append(pipeline.Chunk(
+            f"rb/{z}", [pipeline.Subread(f"rb/{z}/{i}", r)
+                        for i, r in enumerate(reads)], snr))
+    built = []
+
+    class DropAtTheNarrowBand(batchmod.BatchPolisher):
+        def __init__(self, tasks, **kw):
+            super().__init__(tasks, **kw)
+            built.append(self._W)
+            if len(built) == 1:
+                for z, t in enumerate(tasks):
+                    if t.id == "rb/1":
+                        self.statuses[z, len(t.reads) - 1] = \
+                            ADD_ALPHABETAMISMATCH
+                        self.active[z, len(t.reads) - 1] = False
+
+    monkeypatch.setattr(batchmod, "BatchPolisher", DropAtTheNarrowBand)
+    tally = pipeline.process_chunks(chunks)
+    assert tally.counts[pipeline.Failure.SUCCESS] == 2
+    assert len(built) == 2 and built[1] == 2 * built[0]
+
+    chrome = tracer.to_chrome()
+    by_name = events_by_name(chrome)
+    (polish,), (wide,) = by_name["polish"], by_name["polish.wide"]
+    assert wide["args"]["parent"] == polish["id"]
+    assert wide["args"]["zmws"] == 1
+    parts = [e for e in chrome["traceEvents"]
+             if e["args"].get("parent") == polish["id"]]
+    assert [e["name"] for e in parts] == [
+        "polish.setup", "polish.gates", "polish.refine", "polish.wide",
+        "polish.qv", "polish.finish"]
+    assert sum(e["dur"] for e in parts) / polish["dur"] > 0.98
+
+
 # --------------------------------------------------------------- the batch CLI
 
 
@@ -303,8 +551,13 @@ def test_trace_out_of_a_batch_cli_run_has_the_spans_of_its_path(
     reached = {"run", "read", "prepare", "filter", "draft",
                "draft.poa", "draft.map", "dispatch.turn_wait", "polish",
                "polish.setup", "polish.gates", "polish.refine", "polish.qv",
-               "polish.finish", "emit"}
+               "polish.finish", "emit", "device.starved"}
     assert reached <= set(by_name), reached - set(by_name)
+    # the pool's thread waits and polishes under one name for its device
+    assert ({e["args"]["device"] for e in by_name["device.starved"]}
+            == {e["args"]["device"] for e in by_name["polish"]})
+    assert ({e["tid"] for e in by_name["device.starved"]}
+            == {e["tid"] for e in by_name["polish"]})
 
     (run_ev,) = by_name["run"]
     assert run_ev["args"]["zmws"] == 4 and run_ev["args"]["threads"] == 2
@@ -347,6 +600,53 @@ def test_trace_out_of_a_batch_cli_run_has_the_spans_of_its_path(
         assert ev["args"]["fun"] and "parent" in ev["args"]
 
 
+# ------------------------------------------------------ a crash names itself
+
+CRASHING_CHILD = """
+import os, signal, sys, threading, time
+
+def crash(*args, **kwargs):
+    threading.Thread(target=time.sleep, args=(30,), daemon=True,
+                     name="bystander").start()
+    time.sleep(0.2)
+    os.kill(os.getpid(), signal.SIGSEGV)
+    time.sleep(30)
+
+if sys.argv[1] == "batch":
+    from pbccs_tpu import cli
+    cli._run_pipeline = crash              # past the flags, where work starts
+    sys.exit(cli.run([sys.argv[2] + "/out.bam", sys.argv[0],
+                      "--reportFile", sys.argv[2] + "/report.csv"]))
+from pbccs_tpu.serve import server
+server.load_edge_config = crash            # the first thing after the flags
+sys.exit(server.run_serve(["--port", "0"]))
+"""
+
+
+@pytest.mark.parametrize("entry", ["batch", "serve"])
+def test_a_crash_leaves_every_threads_stack_on_stderr(entry, tmp_path):
+    """`ccs` and `ccs serve` arm faulthandler where they start: a child
+    that takes a SIGSEGV dies of it (exit 139 to a shell) and says first
+    where each of its threads was."""
+    import os
+    import signal
+    import subprocess
+    import sys
+
+    script = tmp_path / "crashing_child.py"
+    script.write_text(CRASHING_CHILD)
+    root = os.path.join(os.path.dirname(__file__), os.pardir)
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.abspath(root))
+    done = subprocess.run([sys.executable, str(script), entry, str(tmp_path)],
+                          env=env, capture_output=True, text=True, timeout=300)
+    assert done.returncode == -signal.SIGSEGV, done.stderr[-2000:]
+    assert "Fatal Python error: Segmentation fault" in done.stderr
+    assert "Current thread" in done.stderr and " in crash" in done.stderr
+    # the bystander's stack is there too: all threads, not the one that died
+    assert done.stderr.count("most recent call first") >= 2
+
+
 # ------------------------------------------------------- tools/trace_cover.py
 
 
@@ -360,12 +660,12 @@ def test_trace_cover_reads_coverage_and_pairs_annotations_by_duration():
     cover = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(cover)
 
-    def ev(i, name, ts_s, dur_s, parent=None, batch=None):
+    def ev(i, name, ts_s, dur_s, parent=None, batch=None, tid=1):
         args = {} if parent is None else {"parent": parent}
         if batch is not None:
             args["batch"] = batch
         return {"id": i, "name": name, "ts": ts_s * 1e6, "dur": dur_s * 1e6,
-                "args": args}
+                "tid": tid, "args": args}
 
     # batch 0: two overlapping prepare slices, half a second of joining
     # before the submit, the wait, the polish; batch 1 never polished
@@ -389,3 +689,16 @@ def test_trace_cover_reads_coverage_and_pairs_annotations_by_duration():
              ("prepare", 1000.0 + 0.9, 4.0)]
     skews = cover.annotation_skews(events, 1000.0, notes)
     assert sorted(round(s * 1e6) for s in skews) == [20, 40]
+    # the wide-band retry is one of polish's parts
+    events += [ev(10, "polish.wide", 9.5, 0.25, 3)]
+    assert cover.coverage(events, "polish", cover.POLISH_PARTS) == [0.9, 0.0]
+    # the owner thread, from its first polish (7.5) to its last closing (22):
+    # two polishes (4.5 s), a wait of 8 s clipped at neither end, a completion
+    # of 1 s that overlaps the wait by half; a head wait before the first
+    # polish and another thread's wait count for nothing
+    events += [ev(11, "device.starved", 10.0, 8.0),
+               ev(12, "serve.complete", 17.5, 1.0),
+               ev(13, "device.starved", 0.0, 7.5),
+               ev(14, "device.starved", 18.5, 1.5, tid=2)]
+    (share,) = cover.owner_coverage(events)
+    assert share == pytest.approx((4.5 + 8.0 + 0.5) / 14.5)

@@ -8,13 +8,16 @@ slices on the prepare pool's threads, its `dispatch.turn_wait`, its `polish`
 on the device's thread): the share of the time from its first `prepare`
 opening to its `polish` closing that the union of those spans covers; for
 every `polish` span: the share `polish.setup`, `polish.gates`,
-`polish.refine`, `polish.qv`, `polish.finish` and (in a shape set's first
-polish) `polish.warm` cover (the children are sequential on one thread, so a
-share is a sum).  The least covered and the
-median of each are printed; what runs in the rest has no span (in a batch:
-joining the slices, the pinned shapes, the budget gate and the prebake
-between the last `prepare` and the submit; in `polish`: the wide-band retry
-between `polish.refine` and `polish.qv`, where a batch had mating failures).
+`polish.refine`, `polish.wide` (where a batch had mating failures),
+`polish.qv`, `polish.finish` and (in a shape set's first polish)
+`polish.warm` cover (the children are sequential on one thread, so a share
+is a sum); for every thread that owns a device (one that ran a `polish`): the
+share of the time from its first `polish` opening to its last one closing
+that `polish`, `device.starved` and `serve.complete` cover on that thread.
+The least covered and the median of each are printed; what runs in the rest
+has no span (in a batch: joining the slices, the pinned shapes, the budget
+gate and the prebake between the last `prepare` and the submit; on the owner
+thread: the pool's own bookkeeping between a task and the next wait).
 
 With `--xplane` (a jax.profiler capture taken while the same run was traced:
 `--profile-dir`, or the benchmark's `--trace 1`): the skew between each
@@ -34,8 +37,9 @@ import statistics
 import sys
 
 BATCH_PARTS = ("prepare", "dispatch.turn_wait", "polish")
-POLISH_PARTS = ("polish.setup", "polish.gates", "polish.refine", "polish.qv",
-                "polish.finish", "polish.warm")
+POLISH_PARTS = ("polish.setup", "polish.gates", "polish.refine", "polish.wide",
+                "polish.qv", "polish.finish", "polish.warm")
+OWNER_PARTS = ("polish", "device.starved", "serve.complete")
 
 
 def coverage(events: list[dict], parent: str, parts: tuple) -> list[float]:
@@ -65,14 +69,41 @@ def batch_coverage(events: list[dict]) -> list[float]:
             polished.add(idx)
     shares = []
     for idx in sorted(polished):
-        spans = sorted(by_batch[idx])
-        begin = reach = spans[0][0]
-        covered = 0.0
-        for a, b in spans:
-            if b > reach:
-                covered, reach = covered + b - max(a, reach), b
+        begin = min(a for a, _b in by_batch[idx])
+        reach = max(b for _a, b in by_batch[idx])
         if reach > begin:
-            shares.append(covered / (reach - begin))
+            shares.append(_union(by_batch[idx]) / (reach - begin))
+    return shares
+
+
+def _union(spans: list[tuple[float, float]]) -> float:
+    covered, reach = 0.0, float("-inf")
+    for a, b in sorted(spans):
+        if b > reach:
+            covered, reach = covered + b - max(a, reach), b
+    return covered
+
+
+def owner_coverage(events: list[dict]) -> list[float]:
+    """For each thread that ran a `polish` (it owns a device): the union of
+    its `OWNER_PARTS` spans over the time from its first `polish` opening to
+    its last one closing."""
+    by_thread: dict[int, list[dict]] = {}
+    for e in events:
+        if e["name"] in OWNER_PARTS:
+            by_thread.setdefault(e["tid"], []).append(e)
+    shares = []
+    for spans in by_thread.values():
+        polishes = [e for e in spans if e["name"] == "polish"]
+        if not polishes:
+            continue
+        begin = min(e["ts"] for e in polishes)
+        end = max(e["ts"] + e["dur"] for e in polishes)
+        if end > begin:
+            shares.append(_union(
+                [(max(e["ts"], begin), min(e["ts"] + e["dur"], end))
+                 for e in spans if e["ts"] < end and e["ts"] + e["dur"] > begin]
+            ) / (end - begin))
     return shares
 
 
@@ -140,7 +171,8 @@ def main(argv=None) -> int:
         for what, parts, shares in (
                 ("batches", BATCH_PARTS, batch_coverage(events)),
                 ("polish spans", POLISH_PARTS,
-                 coverage(events, "polish", POLISH_PARTS))):
+                 coverage(events, "polish", POLISH_PARTS)),
+                ("owner threads", OWNER_PARTS, owner_coverage(events))):
             if shares:
                 print(f"{path}: {len(shares)} {what}, "
                       f"{' + '.join(parts)} cover {min(shares):.4f} of the "
