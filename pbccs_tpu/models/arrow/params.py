@@ -46,10 +46,16 @@ def encode_bases(seq: str) -> np.ndarray:
     return _BASE_LUT[np.frombuffer(seq.encode("ascii"), dtype=np.uint8)]
 
 
+_BASE_LETTERS = np.frombuffer(BASES.encode("ascii"), dtype="S1")
+
+
 def decode_bases(codes: np.ndarray) -> str:
-    """int8 codes -> ASCII sequence. Pad codes (>=4) are dropped."""
+    """int8 codes -> ASCII sequence. Pad codes (>=4) are dropped.  One
+    array pass: a result's sequence is decoded on the thread that owns
+    the device (`polish.finish`), 0.3 ms a 2 kb template as a loop."""
     codes = np.asarray(codes)
-    return "".join(BASES[c] for c in codes if 0 <= c < 4)
+    kept = codes[(codes >= 0) & (codes < 4)].astype(np.intp)
+    return _BASE_LETTERS[kept].tobytes().decode("ascii")
 
 
 _COMPLEMENT = np.array([3, 2, 1, 0, 4], dtype=np.int8)

@@ -31,7 +31,10 @@ workload up front; the engine does not, so it:
 The device itself is single-owner: polish batches run on a dedicated
 executor (default 1 worker -- one lockstep batch on device at a time,
 matching the offline driver; overlap applies to host stages, which
-here live on the prep workers)."""
+here live on the prep workers), and that executor only polishes: a
+finished flush's requests are completed -- histograms, callbacks, the
+reply on the client's socket -- on one completion thread, at one device
+as with a pool, while the executor takes the next flush."""
 
 from __future__ import annotations
 
@@ -290,8 +293,10 @@ class CcsEngine:
         self._start_t = 0.0
         self._threads: list[threading.Thread] = []
         self._pool = None   # DevicePool when config.devices != 1
-        self._complete_queue = None   # fleet-mode completion hand-off
-        self._complete_thread = None
+        # the completion hand-off: finished flushes, from whichever thread
+        # owns a device to the one thread that completes their requests
+        self._complete_queue = None
+        self._complete_thread = None   # None: no completer to hand to
         self._n_polish_workers = 0   # set by start(); close() must not
         # depend on attributes a failed start() never assigned
         # performance ledger (obs.ledger): periodic snapshot records
@@ -314,6 +319,7 @@ class CcsEngine:
         # in the process does not clobber engine counters
         self._window = timing.window()
         n_polish = self.config.polish_workers
+        pool = None
         if self.config.devices != 1:
             # device-fleet mode: the DevicePool's per-device executor
             # threads replace the single polish executor; flushed buckets
@@ -329,21 +335,22 @@ class CcsEngine:
                 devs, DevicePoolConfig(policy=self.config.sched_policy),
                 logger=self._log)
             n_polish = 0
-            # batch completions run arbitrary caller code (replies on a
-            # possibly-slow client socket, bounded only by the session's
-            # idle timeout): hand them to a dedicated thread so a stalled
-            # send blocks this thread, never a device executor
-            complete_queue = queue.Queue()
-            complete_thread = threading.Thread(
-                target=self._completion_worker, daemon=True,
-                name="ccs-serve-complete")
-            # publish under the lock: status() and close() read these
-            # attributes from other threads (ccs-analyze CONC001)
-            with self._lock:
-                self._pool = pool
-                self._complete_queue = complete_queue
-                self._complete_thread = complete_thread
-            complete_thread.start()
+        # batch completions run arbitrary caller code (replies on a
+        # possibly-slow client socket, bounded only by the session's
+        # idle timeout): at every device count they run on a thread of
+        # their own, so a stalled send blocks that thread and never one
+        # that owns a device (the polish executor, a pool's worker)
+        complete_queue = queue.Queue()
+        complete_thread = threading.Thread(
+            target=self._completion_worker, args=(complete_queue,),
+            daemon=True, name="ccs-serve-complete")
+        # publish under the lock: status() and close() read these
+        # attributes from other threads (ccs-analyze CONC001)
+        with self._lock:
+            self._pool = pool
+            self._complete_queue = complete_queue
+            self._complete_thread = complete_thread
+        complete_thread.start()
         self._threads = [
             threading.Thread(target=self._prep_worker, daemon=True,
                              name=f"ccs-serve-prep-{i}")
@@ -433,6 +440,11 @@ class CcsEngine:
             self._stop_flush = True
         with self._wake:
             self._wake.notify_all()
+        for t in self._threads:
+            if t.name == "ccs-serve-batcher":
+                t.join(timeout=10.0)
+        # the batcher has shipped its last flush: the polish workers'
+        # sentinels land behind it, so none is left in their queue
         for _ in range(self._n_polish_workers):
             self._polish_queue.put(None)
         for t in self._threads:
@@ -453,12 +465,16 @@ class CcsEngine:
             with self._lock:
                 self._pool = None
         if complete_thread is not None:
-            # after pool.close() every settled future has enqueued its
-            # completion; the sentinel lands behind them all
-            complete_queue.put(None)
-            complete_thread.join(timeout=10.0)
+            # the polish workers are joined and, after pool.close(), every
+            # settled future has handed its flush off: the sentinel lands
+            # behind them all, and the join waits for their callbacks.  A
+            # flush that ends later still (a join above timed out on a
+            # hung device program) finds no completer and is completed
+            # where it ended (_hand_off takes the same lock)
             with self._lock:
                 self._complete_thread = None
+                complete_queue.put(None)
+            complete_thread.join(timeout=10.0)
         if aborted:
             # fail whatever is still parked anywhere
             leftovers = [i.payload[0] for b in self._batcher.drain()
@@ -895,41 +911,59 @@ class CcsEngine:
             with self._wake:
                 self._wake.notify_all()   # a batch left: buckets it held may go
 
-    def _complete_traced(self, batch: Batch, outcomes: list | None,
-                         error: BaseException | None) -> None:
-        """_complete_batch under `serve.complete`: the name says what is
-        done, the thread whether a device waited for it (the polish
-        executor's at one device, the completion thread's with a pool)."""
-        with obs_trace.span("serve.complete", zmws=len(batch.items),
-                            flush=batch.items[0].payload[0].flush):
+    def _hand_off(self, batch: Batch, outcomes: list | None,
+                  error: BaseException | None) -> None:
+        """A finished flush leaves the thread that owns a device (the
+        polish executor, a pool's worker) for the completion thread, so
+        the device goes back to polishing while replies hit client
+        sockets.  After close() has sent the completer its sentinel there
+        is nobody to hand to (a hung device program outlived close()'s
+        joins): the flush is completed here, once, under no span."""
+        with self._lock:
+            handed = self._complete_thread is not None
+            if handed:
+                self._complete_queue.put(
+                    (batch, outcomes, error, time.monotonic()))
+        if not handed:
             self._complete_batch(batch, outcomes, error=error)
 
     def _pool_done(self, batch: Batch, fut) -> None:
-        # runs on a device executor thread: hand off immediately so the
-        # device goes back to polishing while replies hit client sockets
         exc = fut.exception()
-        self._complete_queue.put(
-            (batch, None if exc is not None else fut.result(), exc))
+        self._hand_off(batch, None if exc is not None else fut.result(), exc)
 
-    def _completion_worker(self) -> None:
+    def _completion_worker(self, handed: queue.Queue) -> None:
+        """The one thread that completes flushes, at every device count."""
         while True:
-            item = self._complete_queue.get()
+            item = handed.get()
             if item is None:
                 return
-            batch, outcomes, error = item
             try:
-                self._complete_traced(batch, outcomes, error)
+                self._complete_traced(*item)
             except Exception as e:  # noqa: BLE001 -- the completer must
                 # outlive any one batch (accounting already ran in
                 # _complete_batch's finally)
                 self._log.warn(f"batch completion failed: {e!r}")
 
+    def _complete_traced(self, batch: Batch, outcomes: list | None,
+                         error: BaseException | None, t_handed: float
+                         ) -> None:
+        """_complete_batch under `serve.complete`, on the completion
+        thread: no device waits for it.  `queued_ms` is the hand-off's
+        wait, from the end of the flush's polish to here."""
+        queued_ms = round((time.monotonic() - t_handed) * 1e3, 3)
+        with obs_trace.span("serve.complete", zmws=len(batch.items),
+                            flush=batch.items[0].payload[0].flush,
+                            queued_ms=queued_ms):
+            self._complete_batch(batch, outcomes, error=error)
+
     def _polish_worker(self) -> None:
         """The one-device path's polish executor: the thread that owns
-        the device.  It books its waits on an empty queue as a DevicePool
-        worker does (`device.starved`, and from the first flush it took
-        ccs_sched_device_starved_seconds_total), and completes a flush's
-        requests itself before it takes the next one."""
+        the device, and all it does is polish.  It books its waits on an
+        empty queue as a DevicePool worker does (`device.starved`, and
+        from the first flush it took
+        ccs_sched_device_starved_seconds_total), hands a finished flush
+        to the completion thread as a pool's worker does (_hand_off) and
+        takes the next one at once."""
         from pbccs_tpu.sched.pool import starved_counter
 
         device = _device_name()
@@ -949,7 +983,7 @@ class CcsEngine:
                 outcomes = self._run_polish(batch)
             except Exception as e:  # noqa: BLE001 -- fail THIS batch only
                 error = e
-            self._complete_traced(batch, outcomes, error)
+            self._hand_off(batch, outcomes, error)
 
     # ------------------------------------------------------------ completion
 

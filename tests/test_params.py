@@ -3,6 +3,7 @@ reference ContextParameterProvider.cpp:69-113 semantics)."""
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from pbccs_tpu.models.arrow.params import (
     CONTEXT_COEFF,
@@ -63,3 +64,18 @@ def test_encode_decode_revcomp():
     e = encode_bases(s)
     assert decode_bases(e) == s
     assert decode_bases(revcomp(e)) == "TGCAACGT"
+
+
+@pytest.mark.parametrize("codes", [
+    np.array([0, 1, 2, 3, 4, 4, 3, 2], dtype=np.int8),   # pads dropped
+    np.array([-1, 0, 5, 127, 3], dtype=np.int8),         # anything outside 0..3
+    np.array([3, 3, 0, 4], dtype=np.int32),
+    np.zeros(0, dtype=np.int8),
+    [],                                                  # an empty list
+    [2, 4, 1],
+], ids=["pads", "outside", "int32", "empty", "empty-list", "list"])
+def test_decode_bases_is_the_per_base_loop_in_one_pass(codes):
+    """`decode_bases` is one array pass; the per-base loop it replaced
+    stays here as what it must equal."""
+    assert decode_bases(codes) == "".join(
+        "ACGT"[c] for c in np.asarray(codes) if 0 <= c < 4)

@@ -14,6 +14,7 @@ from pbccs_tpu.pipeline import (
     ADAPTER_AFTER,
     ADAPTER_BEFORE,
     Chunk,
+    ConsensusResult,
     ConsensusSettings,
     Failure,
     Subread,
@@ -64,6 +65,27 @@ def test_pipeline_recovers_template(rng):
     assert len(result.qualities) == len(result.sequence)
     assert np.isfinite(result.global_zscore)
     assert np.isfinite(result.avg_zscore)
+
+
+@pytest.mark.parametrize("qvs", [
+    np.arange(-5, 130, dtype=np.int32),            # both clamps
+    np.arange(0, 94, dtype=np.int64),
+    np.array([0, 93, 94, 255], dtype=np.uint8),
+    np.array([-0.5, 0.4, 41.9, 92.7, 93.5, 1e9], dtype=np.float32),
+    np.zeros(0, dtype=np.int32),
+    [3, 40, 93, 200],                              # a list, as a test builds one
+], ids=["int32", "int64", "uint8", "float32", "empty", "list"])
+def test_qualities_is_the_per_base_clamp_in_one_pass(qvs):
+    """`qualities` is one array pass; the reference's per-base loop
+    (QVsToASCII: clamp to [0, 93], Phred+33) stays here as what it must
+    equal, character for character."""
+    result = ConsensusResult(
+        id="m/1", sequence="A" * len(qvs), qvs=qvs, num_passes=3,
+        predicted_accuracy=0.99, global_zscore=0.0, avg_zscore=0.0,
+        zscores=np.zeros(0), status_counts=[0] * 5, mutations_tested=0,
+        mutations_applied=0, snr=np.full(4, 8.0), elapsed_ms=0.0)
+    assert result.qualities == "".join(
+        chr(min(max(0, int(q)), 93) + 33) for q in qvs)
 
 
 def test_pipeline_too_few_passes(rng):

@@ -455,6 +455,15 @@ class TestEngine:
         with pytest.raises(EngineClosed):
             eng.submit(make_chunk("m/1"))
 
+    @pytest.mark.parametrize("drain", [True, False])
+    def test_close_after_a_start_that_failed(self, drain):
+        """start() refuses a negative device count before it has made a
+        thread: close() must still come back."""
+        eng = stub_engine(devices=-1)
+        with pytest.raises(ValueError, match="ServeConfig.devices"):
+            eng.start()
+        assert eng.close(drain=drain) is True
+
     def test_close_drains_pending(self):
         with stub_engine(max_batch=1000, max_wait_ms=60_000.0) as eng:
             # neither fill nor max-wait can flush this before close();
@@ -462,6 +471,191 @@ class TestEngine:
             req = eng.submit(make_chunk("m/1"))
         assert req.done.is_set()
         assert req.failure == Failure.SUCCESS
+
+    @pytest.mark.parametrize("devices", [1, 2])
+    def test_a_drain_returns_only_after_every_callback_ran(self, devices):
+        """Replies leave on the completion thread, at one device as with
+        a pool: close(drain=True) still returns only once every admitted
+        request's callback has run, the slow ones too."""
+        ran, names = [], set()
+
+        def on_done(req):
+            time.sleep(0.1)                 # a reply on a slow socket
+            names.add(threading.current_thread().name)
+            ran.append(req.chunk.id)
+
+        eng = stub_engine(max_batch=2, max_wait_ms=60_000.0,
+                          devices=devices).start()
+        ids = [f"m/{i}" for i in range(5)]  # two fills and a parked one
+        for zmw in ids:
+            eng.submit(make_chunk(zmw), callback=on_done)
+        assert eng.close(drain=True) is True
+        assert sorted(ran) == ids           # at the return, not later
+        assert names == {"ccs-serve-complete"}
+        st = eng.status()
+        assert st["pending"] == st["in_flight_batches"] == 0
+        assert st["completed"] == 5
+
+    @pytest.mark.parametrize("devices", [1, 2])
+    def test_an_abort_with_a_flush_mid_polish_finishes_each_request_once(
+            self, devices):
+        """close(drain=False) with one flush on the device, one queued
+        behind it and a ZMW parked in the batcher: every callback runs
+        exactly once, and the flush that was polishing is handed off with
+        its results, not left behind the completer's sentinel."""
+        started, gate = threading.Event(), threading.Event()
+        lock, calls = threading.Lock(), {}
+
+        def polish(preps, settings):
+            started.set()
+            gate.wait(10.0)
+            return stub_polish(preps, settings)
+
+        def on_done(req):
+            with lock:
+                calls[req.chunk.id] = calls.get(req.chunk.id, 0) + 1
+
+        eng = stub_engine(max_batch=2, max_wait_ms=60_000.0, polish=polish,
+                          devices=devices).start()
+        mid = [eng.submit(make_chunk(f"mid/{i}"), callback=on_done)
+               for i in range(2)]
+        assert started.wait(5.0)
+        rest = [eng.submit(make_chunk(f"queued/{i}"), callback=on_done)
+                for i in range(2)]
+        rest.append(eng.submit(make_chunk("parked/0"), callback=on_done))
+        deadline = time.monotonic() + 5.0
+        while eng.status()["bucketed"] != 1 and time.monotonic() < deadline:
+            time.sleep(0.005)
+        closer = threading.Thread(target=eng.close,
+                                  kwargs={"drain": False}, daemon=True)
+        closer.start()
+        time.sleep(0.1)                     # the abort is under way
+        gate.set()
+        closer.join(30.0)
+        assert not closer.is_alive()
+        assert all(r.done.is_set() for r in mid + rest)
+        assert calls == {r.chunk.id: 1 for r in mid + rest}
+        assert all(r.failure == Failure.SUCCESS and r.error is None
+                   for r in mid)
+        assert all((r.error is None) == (r.failure == Failure.SUCCESS)
+                   for r in rest)
+        st = eng.status()
+        assert st["pending"] == st["in_flight_batches"] == 0
+        assert st["completed"] == 5
+
+    @pytest.mark.parametrize("devices", [1, 2])
+    def test_a_raising_callback_does_not_stop_the_completer(self, devices):
+        seen = []
+
+        def boom(req):
+            raise RuntimeError("the client went away")
+
+        with stub_engine(max_batch=1, max_wait_ms=60_000.0,
+                         devices=devices) as eng:
+            bad = eng.submit(make_chunk("m/1"), callback=boom)
+            assert bad.wait(10.0) and bad.failure == Failure.SUCCESS
+            ok = eng.submit(make_chunk("m/2"),
+                            callback=lambda r: seen.append(r.chunk.id))
+            assert ok.wait(10.0)
+        assert seen == ["m/2"]
+        assert eng.status()["completed"] == 2
+
+    def test_a_flush_that_outlives_close_is_completed_where_it_ended(
+            self, monkeypatch):
+        """A device program that hangs past close()'s bounded joins ends
+        after the completer has gone: its requests still finish, once,
+        on the thread the flush ended on."""
+        started, gate = threading.Event(), threading.Event()
+        calls = []
+
+        def polish(preps, settings):
+            started.set()
+            gate.wait(10.0)
+            return stub_polish(preps, settings)
+
+        join = threading.Thread.join
+        monkeypatch.setattr(          # close() gives a hung polish 10 s
+            threading.Thread, "join",
+            lambda self, timeout=None: join(
+                self, 0.05 if timeout == 10.0 else timeout))
+        eng = stub_engine(max_batch=1, max_wait_ms=60_000.0,
+                          polish=polish).start()
+        req = eng.submit(make_chunk("m/1"), callback=lambda r: calls.append(
+            threading.current_thread().name))
+        assert started.wait(5.0)
+        assert eng.close(drain=False) is False
+        assert not req.done.is_set()
+        gate.set()
+        assert req.wait(10.0) and req.failure == Failure.SUCCESS
+        deadline = time.monotonic() + 5.0
+        while not calls and time.monotonic() < deadline:
+            time.sleep(0.005)
+        assert calls == ["ccs-serve-polish-0"]
+        assert eng.status()["pending"] == 0
+
+    @pytest.mark.parametrize("devices", [1, 2])
+    def test_an_abort_racing_the_hand_off_finishes_every_request_once(
+            self, devices):
+        """More submitters than cores, a short switch interval, and
+        close(drain=False) in the middle of it: whichever stage an
+        admitted request had reached (undrafted, parked, queued for the
+        executor, polishing, handed off), its callback
+        runs exactly once and the accounts close."""
+        import sys
+
+        lock, calls, admitted = threading.Lock(), {}, []
+        stop = threading.Event()
+
+        def polish(preps, settings):
+            time.sleep(0.002)
+            return stub_polish(preps, settings)
+
+        def on_done(req):
+            with lock:
+                calls[req.chunk.id] = calls.get(req.chunk.id, 0) + 1
+
+        def submitter(eng, k):
+            n = 0
+            while not stop.is_set():
+                try:
+                    req = eng.submit(make_chunk(f"s{k}/{n}"), callback=on_done)
+                except EngineClosed:
+                    return
+                except EngineOverloaded:
+                    time.sleep(0.001)
+                    continue
+                with lock:
+                    admitted.append(req)
+                n += 1
+
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            eng = stub_engine(max_batch=3, max_wait_ms=5.0, max_pending=48,
+                              polish=polish, devices=devices).start()
+            threads = [threading.Thread(target=submitter, args=(eng, k),
+                                        daemon=True) for k in range(16)]
+            for t in threads:
+                t.start()
+            deadline = time.monotonic() + 10.0
+            while (eng.status()["completed"] < 96
+                   and time.monotonic() < deadline):
+                time.sleep(0.01)            # the loop is in its stride
+            eng.close(drain=False)
+            stop.set()
+            for t in threads:
+                t.join(10.0)
+            assert not any(t.is_alive() for t in threads)
+        finally:
+            sys.setswitchinterval(interval)
+        assert len(admitted) >= 96          # the loop went round
+        assert all(r.wait(5.0) for r in admitted)
+        time.sleep(0.05)                    # `done` is set before the callback
+        with lock:
+            assert calls == {r.chunk.id: 1 for r in admitted}
+        st = eng.status()
+        assert st["pending"] == st["in_flight_batches"] == 0
+        assert st["completed"] == len(admitted)
 
     def test_status_shape(self):
         with stub_engine() as eng:

@@ -331,17 +331,18 @@ class _ServedStub:
             config=ServeConfig(max_batch=2, max_wait_ms=10.0, **cfg),
             prep_fn=prep, polish_fn=polish)
 
-    def serve(self, waves: int = 2, gap_s: float = 0.2) -> None:
+    @staticmethod
+    def chunk(k: int):
         import numpy as np
 
-        def chunk(k):
-            seq = np.arange(20, dtype=np.int8) % 4
-            return pipeline.Chunk(f"m/{k}", [pipeline.Subread(f"m/{k}/0", seq)],
-                                  np.full(4, 8.0))
+        seq = np.arange(20, dtype=np.int8) % 4
+        return pipeline.Chunk(f"m/{k}", [pipeline.Subread(f"m/{k}/0", seq)],
+                              np.full(4, 8.0))
 
+    def serve(self, waves: int = 2, gap_s: float = 0.2) -> None:
         with self.engine as eng:
             for wave in range(waves):
-                reqs = [eng.submit(chunk(2 * wave + i)) for i in range(2)]
+                reqs = [eng.submit(self.chunk(2 * wave + i)) for i in range(2)]
                 assert all(r.wait(10.0) for r in reqs)
                 time.sleep(gap_s)
 
@@ -349,9 +350,10 @@ class _ServedStub:
 def test_the_served_path_books_its_waits_at_one_device(tracer):
     """`--devices 1`: the polish executor's waits on its queue are
     `device.starved` spans (the one before its first flush `head`), the
-    counter of the pool's name moves by the non-`head` ones, and a
-    flush's requests are completed under `serve.complete` on the thread
-    that polished them, between two waits."""
+    counter of the pool's name moves by the non-`head` ones, and the
+    executor only polishes: a flush's requests are completed under
+    `serve.complete` on the completion thread, which says how long the
+    hand-off waited (`queued_ms`)."""
     device = jax.devices()[0]
     name = f"{device.platform}:{device.id}"
     before = starved_seconds(name)
@@ -361,15 +363,19 @@ def test_the_served_path_books_its_waits_at_one_device(tracer):
     by_name = events_by_name(tracer.to_chrome())
     starved, completes = by_name["device.starved"], by_name["serve.complete"]
     assert len(stub.polished_on) == 2 and len(set(stub.polished_on)) == 1
-    assert {e["tid"] for e in starved + completes} == set(stub.polished_on)
+    assert {e["tid"] for e in starved} == set(stub.polished_on)
     assert all(e["args"]["device"] == name for e in starved)
     assert [e["args"]["head"] for e in starved] == [True, False, False]
     assert moved >= 0.15                               # the gap after a wave
     assert_spans_match_the_counter(starved, moved)
     assert [(e["args"]["zmws"], e["args"]["flush"]) for e in completes] \
         == [(2, 1), (2, 2)]
-    # wait, polish, complete, wait: no hole on the owner thread's timeline
-    for done, wait in zip(completes, starved[1:]):
+    # one thread completes, and it is not the one that polished
+    assert len({e["tid"] for e in completes}) == 1
+    assert not {e["tid"] for e in completes} & set(stub.polished_on)
+    assert all(0.0 <= e["args"]["queued_ms"] < 50.0 for e in completes)
+    # polish, wait: nothing between them on the owner thread's timeline
+    for done, wait in zip(by_name["serve.polish"], starved[1:]):
         assert 0 <= wait["ts"] - (done["ts"] + done["dur"]) < 20_000
     assert "parent" not in completes[0]["args"]
 
@@ -393,6 +399,30 @@ def test_the_served_path_with_a_pool_leaves_the_waits_to_the_pool(tracer):
     assert set(stub.polished_on) <= pool_threads
     assert len(completes) == 2
     assert not {e["tid"] for e in completes} & pool_threads
+
+
+@pytest.mark.parametrize("devices", [1, 2])
+def test_the_next_flush_polishes_while_the_last_one_completes(tracer, devices):
+    """Two flushes queued, replies that take 0.2 s each: the thread that
+    owns the device hands the first flush off and opens the second one's
+    polish while the first one's `serve.complete` is still open, at one
+    device as with a pool."""
+    stub = _ServedStub(devices=devices)
+    with stub.engine as eng:
+        reqs = [eng.submit(stub.chunk(k), callback=lambda _r: time.sleep(0.2))
+                for k in range(4)]
+        assert all(r.wait(10.0) for r in reqs)
+    by_name = events_by_name(tracer.to_chrome())
+    polish = {e["args"]["flush"]: e for e in by_name["serve.polish"]}
+    first, second = sorted(by_name["serve.complete"], key=lambda e: e["ts"])
+    assert {first["args"]["flush"], second["args"]["flush"]} == set(polish) \
+        == {1, 2}
+    assert first["dur"] >= 380_000                     # two replies of 0.2 s
+    late = polish[second["args"]["flush"]]
+    assert late["ts"] < first["ts"] + first["dur"]
+    assert late["tid"] != first["tid"] == second["tid"]
+    # that flush ended under the first one's replies, and its hand-off waited
+    assert second["args"]["queued_ms"] >= 100.0 > first["args"]["queued_ms"]
 
 
 def test_polish_names_the_device_it_runs_on(tracer, monkeypatch):
@@ -693,12 +723,12 @@ def test_trace_cover_reads_coverage_and_pairs_annotations_by_duration():
     events += [ev(10, "polish.wide", 9.5, 0.25, 3)]
     assert cover.coverage(events, "polish", cover.POLISH_PARTS) == [0.9, 0.0]
     # the owner thread, from its first polish (7.5) to its last closing (22):
-    # two polishes (4.5 s), a wait of 8 s clipped at neither end, a completion
-    # of 1 s that overlaps the wait by half; a head wait before the first
-    # polish and another thread's wait count for nothing
+    # two polishes (4.5 s), a wait of 8 s clipped at neither end; a
+    # completion (the completion thread's, whatever its tid says), a head
+    # wait before the first polish and another thread's wait count for nothing
     events += [ev(11, "device.starved", 10.0, 8.0),
                ev(12, "serve.complete", 17.5, 1.0),
                ev(13, "device.starved", 0.0, 7.5),
                ev(14, "device.starved", 18.5, 1.5, tid=2)]
     (share,) = cover.owner_coverage(events)
-    assert share == pytest.approx((4.5 + 8.0 + 0.5) / 14.5)
+    assert share == pytest.approx((4.5 + 8.0) / 14.5)

@@ -13,11 +13,13 @@ every `polish` span: the share `polish.setup`, `polish.gates`,
 `polish.warm` cover (the children are sequential on one thread, so a share
 is a sum); for every thread that owns a device (one that ran a `polish`): the
 share of the time from its first `polish` opening to its last one closing
-that `polish`, `device.starved` and `serve.complete` cover on that thread.
-The least covered and the median of each are printed; what runs in the rest
-has no span (in a batch: joining the slices, the pinned shapes, the budget
-gate and the prebake between the last `prepare` and the submit; on the owner
-thread: the pool's own bookkeeping between a task and the next wait).
+that `polish` and `device.starved` cover on that thread (a served flush's
+`serve.complete` runs on the completion thread at every device count, and
+is no part of an owner's time).  The least covered and the median of each
+are printed; what runs in the rest has no span (in a batch: joining the
+slices, the pinned shapes, the budget gate and the prebake between the last
+`prepare` and the submit; on the owner thread: the pool's own bookkeeping,
+or the served executor's hand-off, between a task and the next wait).
 
 With `--xplane` (a jax.profiler capture taken while the same run was traced:
 `--profile-dir`, or the benchmark's `--trace 1`): the skew between each
@@ -39,7 +41,7 @@ import sys
 BATCH_PARTS = ("prepare", "dispatch.turn_wait", "polish")
 POLISH_PARTS = ("polish.setup", "polish.gates", "polish.refine", "polish.wide",
                 "polish.qv", "polish.finish", "polish.warm")
-OWNER_PARTS = ("polish", "device.starved", "serve.complete")
+OWNER_PARTS = ("polish", "device.starved")
 
 
 def coverage(events: list[dict], parent: str, parts: tuple) -> list[float]:
