@@ -33,6 +33,7 @@ from pbccs_tpu.io.bam import (
 from pbccs_tpu.io.fasta import flatten_fofn, read_fasta
 from pbccs_tpu.io.report import write_report_file as write_results_report_file
 from pbccs_tpu.models.arrow.params import encode_bases
+from pbccs_tpu.obs.metrics import default_registry
 from pbccs_tpu.pipeline import (
     Chunk,
     ConsensusSettings,
@@ -234,6 +235,16 @@ def build_parser() -> argparse.ArgumentParser:
     return p
 
 
+# ZMWs the reader turns away before any draft, by the gate that did it
+# (they are tallied in the yield report like every other outcome)
+_reg = default_registry()
+_m_gated = {gate: _reg.counter(
+    "ccs_reader_gated_zmws_total",
+    "ZMWs the batch CLI's reader gates turned away before drafting "
+    "(--minSnr, --minReadScore, --minPasses)", gate=gate)
+    for gate in ("snr", "read_score", "passes")}
+
+
 def _iter_fasta_chunks(path: str, log: Logger):
     """Group FASTA records named movie/zmw[/s_e] into per-ZMW chunks."""
     current: Chunk | None = None
@@ -352,12 +363,18 @@ def _chunks_from_files(files, whitelist: Whitelist, args, log,
             if float(np.min(chunk.snr)) < args.minSnr:
                 log.debug(f"Skipping ZMW {chunk.id}, fails SNR threshold")
                 tally.tally(Failure.POOR_SNR)
+                _m_gated["snr"].inc()
                 continue
+            n_reads = len(chunk.reads)
             chunk.reads = [r for r in chunk.reads
                            if r.read_accuracy >= args.minReadScore]
             if len(chunk.reads) < args.minPasses:
                 log.debug(f"Skipping ZMW {chunk.id}, insufficient passes")
                 tally.tally(Failure.TOO_FEW_PASSES)
+                # the read-score gate's where it took the reads that
+                # --minPasses then missed
+                _m_gated["passes" if n_reads < args.minPasses
+                         else "read_score"].inc()
                 continue
             batch.append(chunk)
             if len(batch) >= args.chunkSize:
@@ -510,8 +527,6 @@ def run(argv: list[str] | None = None) -> int:
             obs_trace.clear_tracer(tracer)
             tracer.write_json(args.trace_out)
             log.info(f"trace spans written to {args.trace_out}")
-
-    from pbccs_tpu.obs.metrics import default_registry
 
     summary = default_registry().summary_table(run_window)
     log.info("run metrics:\n" + summary)
@@ -674,7 +689,8 @@ def _run_pipeline(args, files, whitelist, settings, log) -> ResultTally:
     pipe = ScheduledPipeline(pool, settings,
                              prepare_workers=_prepare_workers(args),
                              on_error=args.batchFallback,
-                             budget=budget, logger=log)
+                             budget=budget, logger=log,
+                             chunk_zmws=args.chunkSize)
 
     # journal-restored chunks ride through the scheduler as precomputed
     # tallies so they merge at their index slot

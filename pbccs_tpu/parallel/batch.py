@@ -95,6 +95,19 @@ _m_shape_sets = _reg.counter(
     "built a BatchPolisher at for the first time")
 _shape_sets_seen: set[tuple] = set()
 _shape_sets_lock = threading.Lock()
+_m_cycle_stops = {kind: _reg.counter(
+    "ccs_refine_cycle_stops_total",
+    "ZMWs the device loop stopped, not converged, because their rounds "
+    "had become periodic (zmws), and the rounds of the budget they did "
+    "not run (rounds_spared)", kind=kind)
+    for kind in ("zmws", "rounds_spared")}
+# a pin the menu opens or grows is a family of programs to load: after a
+# file's first batch neither should move
+_m_menu_pins = {kind: _reg.counter(
+    "ccs_menu_pins_total",
+    "Pins of the process's shape menu: opened (new) and widened in "
+    "Imax, Jmax or, up to 12, lanes to hold a batch (grown)", kind=kind)
+    for kind in ("new", "grown")}
 
 
 def shape_sets_seen() -> set[tuple]:
@@ -112,6 +125,8 @@ EDGE_SLAB = 64
 # windows shorter than this score boundary mutations by full refill: the
 # extend-from-begin and extend-to-end regimes would overlap
 MIN_FAST_EDGE_WLEN = 8
+# ZMW axis of a batch driver's wide-band retry (BatchPolisher.wide_band_subs)
+WIDE_BAND_Z = 4
 
 
 def _jmax_bucket(max_len: int) -> int:
@@ -147,6 +162,24 @@ def _imax_step(n: int) -> int:
     return max(64, 1 << max(n - 1, 1).bit_length() - 3)
 
 
+# The read-lane axis steps on this ladder and on nothing between: 4, 8 and
+# 12 lanes, then 32 and its doublings.  Every step a file straddles is a
+# family of programs to trace, lower and load (a minute or more at 2 kb),
+# so past the 3-10-pass libraries' 12 lanes the ladder only doubles: a
+# chunk of a cell's file, whose most passes wander over 13-30, lands on
+# 32 whichever ZMWs it holds.
+_LANE_STEPS = (4, 8, 12)
+
+
+def lane_step(n_reads: int) -> int:
+    """The fewest lanes of the ladder (4, 8, 12, 32, 64, ..) that hold
+    `n_reads` reads."""
+    for step in _LANE_STEPS:
+        if n_reads <= step:
+            return step
+    return next_pow2(n_reads, 32)
+
+
 def length_bucket(tpl_len: int, max_read_len: int) -> tuple[int, int]:
     """The (Jmax, Imax) compiled-shape bucket a ZMW of this geometry
     would polish in alone -- the router's sticky-routing key
@@ -169,7 +202,7 @@ def effective_shapes(n_zmws: int, max_reads: int, max_read_len: int,
     programs and (W being a function of Jmax) reproduces surviving ZMWs
     byte-identically."""
     Z = pad_to(max(n_zmws, min_z), zq)
-    R = pad_to(max_reads, max(4, rq))
+    R = pad_to(lane_step(max_reads), rq)
     Imax = _imax_bucket(max_read_len + 8)
     Jmax = _jmax_bucket(max_tpl_len)
     if buckets is not None:
@@ -198,20 +231,33 @@ def _length_class_statics(jmax: int) -> tuple:
 
 class ShapeMenu:
     """The (Imax, Jmax, R) a process polishes at, one pin for each length
-    class it has met, so that the programs a file needs are a closed set.
+    class and lane step it has met, so that the programs a file needs are
+    a closed set.
 
     Left to itself every batch picks its own bucket, and a file's batches
     straddle bucket edges (the longest read, the longest draft and the
     most passes of 64 ZMWs move from batch to batch): each new bucket is a
     family of programs, traced, lowered and loaded when it first appears,
-    minutes into a run.  The scheduled driver asks here instead: a batch
-    adopts the pin of its class (element-wise at least its own bucket;
-    the pin grows if a batch does not fit), which is what the quarantine
-    and split paths already do with a parent's buckets.  A class is a
-    neighbourhood, not the whole menu: a pin and a bucket at most one
-    step of their grids apart in Imax and in Jmax and a factor two in R,
-    with the same band width and guided passes, so the bytes are those of
-    the batch's own bucket and a 500 bp batch never pads to a 15 kb
+    minutes into a run.  The scheduled driver and `ccs serve` ask here
+    instead.  A batch joins a pin of its length class whose lanes hold
+    its reads, however few of them it fills (a chunk of 3-pass ZMWs
+    polishes in the 32 lanes its file's first chunk opened: the fills
+    skip a lane without a read): of several, the one with the fewest
+    lanes, and of those one it fits as it stands before one it would
+    widen.  Past the ladder's 12 a pin's lanes never grow: a batch with
+    more reads than any pin of its class holds opens a new pin at its
+    own step of the lane ladder (`lane_step`), at the class's widest
+    lengths.  (Up to 12 lanes a pin still grows to a batch of at most
+    twice its lanes, as before the ladder: the flushes of a 3-10-pass
+    library wander over 8 and 12, and one pin and one batcher key serve
+    both.)  A pin widens in Imax or Jmax, by the one bucket step a class
+    spans, for a batch whose reads or drafts do not fit it
+    (`ccs_menu_pins_total` counts every growth: after a file's first
+    batch none should move).  A
+    class is a neighbourhood, not the whole menu: a pin and a bucket at
+    most one step of their grids apart in Imax and in Jmax, with the
+    same band width and guided passes, so the bytes are those of the
+    batch's own bucket and a 500 bp batch never pads to a 15 kb
     neighbour.  The pins live as long as the process, as its loaded
     programs do."""
 
@@ -223,37 +269,38 @@ class ShapeMenu:
     def _same_class(pin, own) -> bool:
         return (abs(pin[0] - own[0]) <= _imax_step(max(pin[0], own[0]))
                 and abs(pin[1] - own[1]) <= _jmax_step(max(pin[1], own[1]))
-                and 2 * min(pin[2], own[2]) >= max(pin[2], own[2])
                 and _length_class_statics(pin[1])
                 == _length_class_statics(own[1]))
 
     def shapes(self, n_zmws: int, max_reads: int, max_read_len: int,
-               max_tpl_len: int, *, fit_lanes: bool = False
-               ) -> tuple[int, int, int, int]:
-        """effective_shapes of these inputs under their class's pin.
-
-        `fit_lanes` is `ccs serve`'s, whose flushes hold one ZMW or
-        sixteen: the batch also joins a pin of its lengths whose lanes
-        hold its reads however few of them it fills (a lone 3-pass ZMW
-        polishes in the 12 lanes its class was warmed at: the fills skip
-        a lane without a read), and a pin it fits as it stands comes
-        before one it would grow.  A batch driver's chunks do not ask for
-        it: a chunk of few passes keeps lanes of its own."""
-        own = effective_shapes(n_zmws, max_reads, max_read_len, max_tpl_len)
+               max_tpl_len: int) -> tuple[int, int, int, int]:
+        """effective_shapes of these inputs under their class's pin."""
+        extents = (n_zmws, max_reads, max_read_len, max_tpl_len)
+        own = effective_shapes(*extents)
         with self._lock:
-            ks = [k for k, pin in enumerate(self._pins)
-                  if self._same_class(pin, own[:3])
-                  or (fit_lanes and own[2] <= pin[2]
-                      and self._same_class(pin, (*own[:2], pin[2])))]
-            if fit_lanes:
-                ks.sort(key=lambda k: any(
-                    o > p for o, p in zip(own[:3], self._pins[k])))
-            if not ks:
-                self._pins.append(own[:3])
-                return own
-            got = effective_shapes(n_zmws, max_reads, max_read_len,
-                                   max_tpl_len, buckets=self._pins[ks[0]])
-            self._pins[ks[0]] = got[:3]
+            mates = [k for k, pin in enumerate(self._pins)
+                     if self._same_class(pin, own)]
+            fits = [k for k in mates if own[2] <= self._pins[k][2]]
+            if not fits:
+                # up to the ladder's 12 lanes a pin grows to a batch of at
+                # most twice its lanes, as it did before the ladder
+                fits = [k for k in mates
+                        if own[2] <= min(2 * self._pins[k][2],
+                                         _LANE_STEPS[-1])]
+            if not fits:
+                pin = (max([own[0]] + [self._pins[k][0] for k in mates]),
+                       max([own[1]] + [self._pins[k][1] for k in mates]),
+                       own[2])
+                self._pins.append(pin)
+                _m_menu_pins["new"].inc()
+                return effective_shapes(*extents, buckets=pin)
+            k = min(fits, key=lambda k: (
+                self._pins[k][2],
+                own[0] > self._pins[k][0] or own[1] > self._pins[k][1]))
+            got = effective_shapes(*extents, buckets=self._pins[k])
+            if got[:3] != self._pins[k]:
+                self._pins[k] = got[:3]
+                _m_menu_pins["grown"].inc()
             return got
 
     def reset_for_tests(self) -> None:
@@ -701,9 +748,11 @@ class BatchPolisher:
 
         `fixed_z`: the caller polishes every batch of this bucket at this
         one Z however many ZMWs it holds (`ccs serve`: `min_z` is its
-        --maxBatch), so the wide-band retry keeps that Z too and the
-        shape set's first polish loads it whatever Z is
-        (wide_band_sub, warm_shape_set).
+        --maxBatch; the scheduled driver under a governor's ceiling, the
+        parts of a split and `ccs warmup`: theirs), so this polisher
+        stands for all that follow and the shape set's first polish
+        loads the wide-band retry's program whatever Z is
+        (warm_shape_set).
 
         `prebaked`: a PrebakedBatch marshalled ahead of time on a prepare
         worker (pipeline.prebake_polish); adopted when its shapes match
@@ -1323,7 +1372,13 @@ class BatchPolisher:
             history=jnp.zeros((Z, H), jnp.uint32),
             hist_n=jnp.zeros(Z, jnp.int32),
             overflow=jnp.asarray(False),
-            dlayout=dlayout, fill_reads=jnp.zeros(2, jnp.int32))
+            dlayout=dlayout, fill_reads=jnp.zeros(2, jnp.int32),
+            # under Z = 32 the loop has no straggler exit, so a ZMW that
+            # ping-pongs to the budget would hold the dispatch: it stops
+            # as soon as its rounds are shown to repeat
+            cycle=dr.new_cycle_watch(Z, H)
+            if self.mesh is None and not dr.straggler_exit_zmws(Z)
+            else None)
 
     def refine_device(self, opts: RefineOptions | None = None,
                       skip=None, budget: int | None = None
@@ -1399,7 +1454,9 @@ class BatchPolisher:
                                         (Z,)),
                        jnp.broadcast_to(qv_fb.astype(jnp.int32), (Z,)),
                        jnp.broadcast_to(out.fill_reads[0], (Z,)),
-                       jnp.broadcast_to(out.fill_reads[1], (Z,))],
+                       jnp.broadcast_to(out.fill_reads[1], (Z,)),
+                       jnp.zeros(Z, jnp.int32) if out.cycle is None
+                       else out.cycle.cycled.astype(jnp.int32)],
                       axis=1),
             out.tpl.astype(jnp.int32),
             out.tstarts.astype(jnp.int32),
@@ -1412,7 +1469,8 @@ class BatchPolisher:
         obs_flight.record_fill_reads(h[0, 7], h[0, 8])
         if overflow_h[0]:
             return None  # host loop re-runs from the polisher's last state
-        planes = h[:, 9:]   # template, window starts, window ends, QVs
+        cycled_h = h[:, 9]
+        planes = h[:, 10:]  # template, window starts, window ends, QVs
         if not h[0, 6]:  # no tiny-window fallback in the QV sweep
             self._cont.qv_cache = (frozenset(skip or ()),
                                    planes[:, Jmax + 2 * R:].astype(np.int32))
@@ -1447,6 +1505,11 @@ class BatchPolisher:
                                 n_applied=int(applied_h[z]),
                                 iterations=int(iters_h[z]))
                    for z in range(self.n_zmws)]
+        # ZMWs the loop stopped as periodic: final, not converged
+        cycled = {z for z in range(self.n_zmws) if cycled_h[z]}
+        for z in cycled:
+            _m_cycle_stops["zmws"].inc()
+            _m_cycle_stops["rounds_spared"].inc(budget - results[z].iterations)
 
         # flight recorder: the device-resident loop is one jitted program
         # (per-round host callbacks would reintroduce the fetch-per-round
@@ -1468,7 +1531,8 @@ class BatchPolisher:
         skipset = set(skip or ())
         stragglers = [z for z in range(self.n_zmws)
                       if z not in skipset and not results[z].converged
-                      and results[z].iterations < budget]
+                      and results[z].iterations < budget
+                      and z not in cycled]
         # stragglers share one iteration count by construction: the device
         # loop is lockstep, a ZMW leaves it only by converging (which
         # excludes it from `stragglers`), so every straggler ran every
@@ -1547,26 +1611,27 @@ class BatchPolisher:
                              buckets=(self._Imax, self._Jmax, self._R),
                              min_z=self.straggler_shape_min_z())
 
-    def wide_band_sub(self, tasks: Sequence[ZmwTask]) -> "BatchPolisher":
-        """The 2x-band sub-batch of the pipeline's mating retry -- ONE
+    def wide_band_subs(self, tasks: Sequence[ZmwTask]
+                       ) -> list["BatchPolisher"]:
+        """The 2x-band sub-batches of the pipeline's mating retry -- ONE
         shape recipe shared by the live retry (pipeline.
         _polish_batch_arrow) and warm_shape_set.  Shapes pin to the
-        parent's buckets + a pow2 Z (the parent's own under `fixed_z`:
-        one program however many ZMWs reband) so the data-dependent
-        reband count doesn't mint fresh compiles; 2x the EFFECTIVE width
-        (the W(L)
-        schedule may have shrunk the parent below the configured width);
-        a non-default width passes through the schedule."""
+        parent's buckets and to one Z, four, whatever the parent's:
+        however many ZMWs reband, they polish four at a time in the
+        program the shape set's first polish loaded, where a Z padded to
+        their count minted a fresh family for every count past four.  2x
+        the EFFECTIVE width (the W(L) schedule may have shrunk the parent
+        below the configured width); a non-default width passes through
+        the schedule."""
         wcfg = dataclasses.replace(
             self.config,
             banding=dataclasses.replace(self.config.banding,
                                         band_width=2 * self._W))
-        return BatchPolisher(tasks, config=wcfg,
-                             min_zscore=self.min_zscore,
-                             buckets=(self._Imax, self._Jmax, self._R),
-                             min_z=self._Z if self.fixed_z
-                             else next_pow2(len(tasks), 4),
-                             fixed_z=self.fixed_z)
+        return [BatchPolisher(tasks[i: i + WIDE_BAND_Z], config=wcfg,
+                              min_zscore=self.min_zscore,
+                              buckets=(self._Imax, self._Jmax, self._R),
+                              min_z=WIDE_BAND_Z)
+                for i in range(0, len(tasks), WIDE_BAND_Z)]
 
     def warm_straggler_shapes(self, opts: RefineOptions | None = None
                               ) -> None:
@@ -1594,20 +1659,20 @@ class BatchPolisher:
         (sched/warmup.py) and of the batch path, which calls it from
         the first polish of each shape set
         (pipeline._polish_batch_arrow), a flush of `ccs serve` among
-        them.  A batch driver's Z has a straggler exit or, below 32,
-        nothing to load here; a `fixed_z` polisher (every flush of
-        `ccs serve` at its --maxBatch) has no continuation and loads its
-        one wide-band program, at its own Z.  Not covered: a batch
-        driver's wide-band sub-batch of more than four ZMWs."""
+        them.  A `fixed_z` polisher (a part of the governor's split,
+        every dispatch of the scheduled driver under a ceiling, every
+        flush of `ccs serve`) stands for all that follow at its Z and
+        loads the retry's program whatever Z is; one at a Z of its own
+        under 32 has no continuation, and what follows it may polish at
+        another Z: nothing is loaded for it."""
         if self._Z // 32 < 1 and not self.fixed_z:
             return
         with obs_trace.span("polish.warm", imax=self._Imax,
                             jmax=self._Jmax, r=self._R,
-                            z=self._Z if self.fixed_z
-                            else self.straggler_shape_min_z()):
+                            z=self.straggler_shape_min_z()):
             self.warm_straggler_shapes(opts)
             # built, gated and polished as the live retry does it
-            wide = self.wide_band_sub(self._row_tasks([0], "warm"))
+            wide, = self.wide_band_subs(self._row_tasks([0], "warm"))
             wide.statuses
             wide.refine(opts)
             wide.consensus_qvs()

@@ -57,6 +57,8 @@ SLOT_TYPES = _SLOT_TYPES
 SLOT_ENDOFF = _SLOT_ENDOFF
 
 _HASH_MULT = np.uint32(2654435761)  # Knuth multiplicative constant
+# the two multipliers of state_fingerprint (xxHash's 32-bit primes 2, 3)
+_FP_MULTS = (np.uint32(2246822519), np.uint32(3266489917))
 
 
 def slot_candidates(tpl: jax.Array, tlen: jax.Array,
@@ -306,6 +308,61 @@ def template_hash(tpl: jax.Array, tlen: jax.Array) -> jax.Array:
     return (vals * powers).sum(dtype=jnp.uint32) ^ tlen.astype(jnp.uint32)
 
 
+def state_fingerprint(tpl, tlen, tstarts, tends, active, allowed):
+    """(2,) uint32: 64 bits over everything of one ZMW that a round of
+    run_refine_loop reads and writes but its template-hash history: the
+    live template, the read windows, the active reads and the candidate
+    filter (fills, baselines and scores are functions of these).  Two
+    rounds of a ZMW that start at equal fingerprints start at the same
+    state."""
+    j = jnp.arange(tpl.shape[0], dtype=jnp.uint32)
+    per_pos = (jnp.where(j < tlen.astype(jnp.uint32),
+                         tpl.astype(jnp.uint32) + 2, 0)
+               + 7 * allowed.astype(jnp.uint32))
+    r = jnp.arange(tstarts.shape[0], dtype=jnp.uint32)
+    per_read = (tstarts.astype(jnp.uint32) * 31
+                + tends.astype(jnp.uint32) * 17
+                + active.astype(jnp.uint32))
+
+    def fold(mult):
+        return ((per_pos * jnp.power(mult, j + 1)).sum(dtype=jnp.uint32)
+                ^ (per_read * jnp.power(mult, r + 1)).sum(dtype=jnp.uint32)
+                * _HASH_MULT ^ tlen.astype(jnp.uint32))
+
+    return jnp.stack([fold(m) for m in _FP_MULTS])
+
+
+def straggler_exit_zmws(z: int) -> int:
+    """run_refine_loop at Z = `z` returns once this many ZMWs or fewer are
+    live (0: it has no early exit) and leaves them to the caller's
+    continuation (batch.BatchPolisher.refine_device)."""
+    return z // 32
+
+
+class CycleWatch(NamedTuple):
+    """What run_refine_loop carries to stop a ZMW whose rounds repeat.
+
+    A ZMW that ping-pongs between templates runs to the round budget and
+    ends not converged; in a loop with no straggler exit it holds every
+    other slot of its dispatch until then.  Its rounds are a function of
+    its state and of its template-hash history, and the history only
+    grows and only ever turns a multi-mutation round into its single best
+    mutation.  So once a round starts at the fingerprint an earlier round
+    started at, and no round since that one was a multi-mutation round
+    the history had not yet trimmed (`settled_from`), every later round
+    repeats one already run: the ZMW cannot converge, and stops at once
+    with the outcome the budget would have given it."""
+
+    ring: jax.Array          # (Z, H, 2) uint32 fingerprints, by round run
+    settled_from: jax.Array  # (Z,) int32 first round of the settled run
+    cycled: jax.Array        # (Z,) bool stopped as periodic
+
+
+def new_cycle_watch(z: int, depth: int) -> CycleWatch:
+    return CycleWatch(jnp.zeros((z, depth, 2), jnp.uint32),
+                      jnp.zeros(z, jnp.int32), jnp.zeros(z, bool))
+
+
 class RefineLoopState(NamedTuple):
     """Carry of the device-resident refinement while_loop.
 
@@ -350,6 +407,9 @@ class RefineLoopState(NamedTuple):
     # fetched with the outcome, ccs_refine_fill_reads_total.  A state
     # built without one starts at zero.
     fill_reads: typing.Any = None
+    # CycleWatch where the loop stops periodic ZMWs (a loop with no
+    # straggler exit, off a mesh: batch._loop_state); else None
+    cycle: typing.Any = None
 
 
 def reads_to_refill(applied, real_rows):
@@ -751,7 +811,12 @@ def run_refine_loop(state: "RefineLoopState", reads, rlens, strands, table,
     reduce over the WHOLE mesh so every device runs the same number of
     iterations (divergent conds would deadlock the in-body collectives).
     The straggler early exit is disabled under a mesh -- the continuation
-    sub-batch is a host-side construct that would break the sharding."""
+    sub-batch is a host-side construct that would break the sharding.
+
+    A state that carries a CycleWatch (`state.cycle`: batch._loop_state
+    gives one to a loop with no straggler exit, off a mesh) stops a ZMW
+    whose rounds have become periodic, not converged, where it would
+    have run to the budget with every other slot waiting."""
     from pbccs_tpu.models.arrow.params import (revcomp_padded,
                                                template_transition_params)
     from pbccs_tpu.models.arrow.scorer import oriented_window
@@ -847,13 +912,28 @@ def run_refine_loop(state: "RefineLoopState", reads, rlens, strands, table,
     def body(st: RefineLoopState) -> RefineLoopState:
         jmax = st.tpl.shape[1]
 
+        # 0. a ZMW whose round starts where a settled earlier round of its
+        # own started is periodic (CycleWatch): it stops before the round
+        done0, watch = st.done, st.cycle
+        if watch is not None:
+            fp = jax.vmap(state_fingerprint)(
+                st.tpl, st.tlens, st.tstarts, st.tends, st.active,
+                st.allowed)
+            depth = watch.ring.shape[1]
+            rounds = jnp.arange(depth)[None, :]
+            repeats = ((watch.ring == fp[:, None, :]).all(axis=2)
+                       & (rounds >= watch.settled_from[:, None])
+                       & (rounds < st.hist_n[:, None])).any(axis=1)
+            cycled_now = ~st.done & repeats & (st.hist_n <= depth)
+            done0 = st.done | cycled_now
+
         # 1. candidates (slot geometry is ZMW-independent; validity is not)
         start, end, mtype, base, _ = slot_candidates(
             st.tpl[0], st.tlens[0])
         valid = jax.vmap(
             lambda t, L, al: slot_candidates(t, L, al)[4]
         )(st.tpl, st.tlens, st.allowed)
-        valid &= ~st.done[:, None]
+        valid &= ~done0[:, None]
 
         # 2. scores
         totals, fb_any = score_all(st, start, end, mtype, base, valid)
@@ -871,13 +951,13 @@ def run_refine_loop(state: "RefineLoopState", reads, rlens, strands, table,
         favorable = valid & (scores > eps_z[:, None])
         fav_any = favorable.any(axis=1)
 
-        iterations = st.iterations + (~st.done).astype(jnp.int32)
-        n_tested = st.n_tested + jnp.where(st.done, 0,
+        iterations = st.iterations + (~done0).astype(jnp.int32)
+        n_tested = st.n_tested + jnp.where(done0, 0,
                                            valid.sum(axis=1, dtype=jnp.int32))
 
-        newly_converged = (~st.done) & (~fav_any)
+        newly_converged = (~done0) & (~fav_any)
         converged = st.converged | newly_converged
-        done_now = st.done | newly_converged
+        done_now = done0 | newly_converged
 
         # 3. greedy selection + cycle trim (position-major fast form:
         # slot_candidates' start is m // N_SLOTS by construction)
@@ -904,13 +984,24 @@ def run_refine_loop(state: "RefineLoopState", reads, rlens, strands, table,
 
         # 4. history push (current template, pre-apply) where a round ran
         cur_hash = jax.vmap(template_hash)(st.tpl, st.tlens)
-        pushing = (~st.done) & fav_any
+        pushing = (~done0) & fav_any
         slot = st.hist_n % st.history.shape[1]
         history = jnp.where(
             pushing[:, None],
             st.history.at[jnp.arange(Z), slot].set(cur_hash),
             st.history)
         hist_n = st.hist_n + pushing.astype(jnp.int32)
+        if watch is not None:
+            # a multi-mutation round the history has not trimmed yet may
+            # select otherwise once it has: the settled run starts after it
+            watch = CycleWatch(
+                ring=jnp.where(
+                    pushing[:, None, None],
+                    watch.ring.at[jnp.arange(Z), slot % depth].set(fp),
+                    watch.ring),
+                settled_from=jnp.where(pushing & multi & ~seen, hist_n,
+                                       watch.settled_from),
+                cycled=watch.cycled | cycled_now)
 
         # 5. apply
         apply_mask = pushing
@@ -969,7 +1060,8 @@ def run_refine_loop(state: "RefineLoopState", reads, rlens, strands, table,
             it=st.it + 1, done=done_now, converged=converged,
             iterations=iterations, n_tested=n_tested, n_applied=n_applied,
             allowed=allowed, history=history, hist_n=hist_n,
-            overflow=overflow, dlayout=dlayout, fill_reads=fill_reads)
+            overflow=overflow, dlayout=dlayout, fill_reads=fill_reads,
+            cycle=watch)
 
     # Straggler early exit: each lockstep round costs full (Z, ...) compute
     # whatever the active count, so once only a handful of ZMWs remain
@@ -978,7 +1070,8 @@ def run_refine_loop(state: "RefineLoopState", reads, rlens, strands, table,
     # paying Z-wide rounds (batch.BatchPolisher.refine).  Z <= 32 has no
     # early exit (threshold 0); mesh runs have none (the continuation is a
     # host-side construct) and count live ZMWs across all zmw shards.
-    straggler_exit = 0 if axis is not None else reads.shape[0] // 32
+    straggler_exit = 0 if axis is not None else straggler_exit_zmws(
+        reads.shape[0])
 
     def cond(st: RefineLoopState):
         live = (~st.done).sum()

@@ -56,6 +56,14 @@ _polish_turn = threading.Lock()
 _m_polish_dispatches = _reg.counter(
     "ccs_polish_dispatches_total",
     "polish_prepared_batch dispatches (incl. sub-dispatch re-entries)")
+# how much the wide-band retry holds: a retry polishes four ZMWs at a time
+# (BatchPolisher.wide_band_subs), so more than four a batch run in turn
+_m_band_retry = {kind: _reg.counter(
+    "ccs_band_retry_total",
+    "The 2x-band mating retry: batches that built one (batches), the ZMWs "
+    "it held (zmws), the sub-batches it built for them (sub_batches), and "
+    "the ZMWs that adopted the wide band (adopted)", kind=kind)
+    for kind in ("batches", "zmws", "sub_batches", "adopted")}
 
 
 def record_zmw_failure(stage: str, exc: BaseException,
@@ -553,28 +561,35 @@ def _polish_batch_arrow(preps: Sequence[PreparedZmw],
         reband = sorted(z for z, p in enumerate(preps)
                         if (polisher.statuses[z, : len(p.mapped)]
                             == ADD_ALPHABETAMISMATCH).any())
-        wide = None
-        wide_pick: dict[int, int] = {}
+        # the wide sub-batches hold the rebanding ZMWs a few at a time, at
+        # one Z: wide_pick maps a ZMW that adopts the wide band to its
+        # (sub-batch, row)
+        wide_pick: dict[int, tuple] = {}
+        wides: list = []
         if reband:
             try:  # speculative build: any failure keeps the narrow batch
-                wide = polisher.wide_band_sub([tasks[z] for z in reband])
+                wides = polisher.wide_band_subs([tasks[z] for z in reband])
             except Exception as e:  # noqa: BLE001 -- keep the narrow batch
                 record_zmw_failure("polish.wide_build", e,
                                    zmw=f"reband[{len(reband)}]")
-                wide = None
-            if wide is not None:
-                for i, z in enumerate(reband):
-                    nr = len(preps[z].mapped)
-                    n_narrow = int((polisher.statuses[z, :nr]
-                                    != ADD_ALPHABETAMISMATCH).sum())
-                    n_wide = int((wide.statuses[i, :nr]
-                                  != ADD_ALPHABETAMISMATCH).sum())
-                    if n_wide > n_narrow:
-                        wide_pick[z] = i
-                        gate_info[z] = _read_gates(
-                            preps[z], wide.statuses[i], settings)
+            for k, z in enumerate(reband if wides else ()):
+                sub_k, i = divmod(k, wides[0]._Z)
+                wide = wides[sub_k]
+                nr = len(preps[z].mapped)
+                n_narrow = int((polisher.statuses[z, :nr]
+                                != ADD_ALPHABETAMISMATCH).sum())
+                n_wide = int((wide.statuses[i, :nr]
+                              != ADD_ALPHABETAMISMATCH).sum())
+                if n_wide > n_narrow:
+                    wide_pick[z] = (wide, i)
+                    gate_info[z] = _read_gates(
+                        preps[z], wide.statuses[i], settings)
             # banding observability: retry outcomes per batch (the
             # reference's NumFlipFlops analogue at batch granularity)
+            for kind, n in (("batches", 1), ("zmws", len(reband)),
+                            ("sub_batches", len(wides)),
+                            ("adopted", len(wide_pick))):
+                _m_band_retry[kind].inc(n)
             Logger.default().debug(
                 f"band retry: {len(reband)} ZMW(s) had mating failures at "
                 f"W={polisher._W}; "
@@ -589,20 +604,34 @@ def _polish_batch_arrow(preps: Sequence[PreparedZmw],
         global_zs = polisher.global_zscores()
     with obs_trace.span("polish.refine", zmws=len(preps) - len(skip)):
         refine_results = polisher.refine(settings.refine, skip=skip)
-    wide_refine = wide_qvs = wide_gz = None
+    # z -> (template, QVs, refine result, z-scores, global z-score) of the
+    # ZMWs that polished at the wide band
+    wide_out: dict[int, tuple] = {}
     if wide_pick:
         with obs_trace.span("polish.wide", zmws=len(wide_pick)):
             try:  # the whole wide retry is speculative: any failure in its
                 # polish falls back to the narrow batch's completed results
                 # (with the narrow gates) instead of discarding the batch
-                wide_skip = {i for i in range(wide.n_zmws)
-                             if i not in {wi for z, wi in wide_pick.items()
-                                          if z not in gate_failed}}
-                wide_gz = wide.global_zscores()
-                wide_refine = wide.refine(settings.refine, skip=wide_skip)
-                wide_qvs = wide.consensus_qvs(
-                    skip=wide_skip | {i for i, r in enumerate(wide_refine)
-                                      if not r.converged})
+                for wide in wides:
+                    rows = {i: z for z, (w, i) in wide_pick.items()
+                            if w is wide}
+                    if not rows:
+                        continue
+                    wide_skip = {i for i in range(wide.n_zmws)
+                                 if rows.get(i, -1) in gate_failed
+                                 or i not in rows}
+                    wide_gz = wide.global_zscores()
+                    wide_refine = wide.refine(settings.refine,
+                                              skip=wide_skip)
+                    wide_qvs = wide.consensus_qvs(
+                        skip=wide_skip | {i for i, r in
+                                          enumerate(wide_refine)
+                                          if not r.converged})
+                    for i, z in rows.items():
+                        nr = len(preps[z].mapped)
+                        wide_out[z] = (wide.tpls[i], wide_qvs[i],
+                                       wide_refine[i],
+                                       wide.zscores[i, :nr], wide_gz[i])
             except Exception as e:  # noqa: BLE001 -- revert to narrow batch
                 record_zmw_failure("polish.wide", e,
                                    zmw=f"reband[{len(wide_pick)}]")
@@ -611,6 +640,7 @@ def _polish_batch_arrow(preps: Sequence[PreparedZmw],
                     gate_info[z] = _read_gates(
                         preps[z], polisher.statuses[z], settings)
                 wide_pick.clear()
+                wide_out.clear()
                 gate_failed = {z for z, g in enumerate(gate_info)
                                if g[0] is not None}
                 skip = gate_failed
@@ -646,11 +676,10 @@ def _polish_batch_arrow(preps: Sequence[PreparedZmw],
                 continue
             nr = len(p.mapped)
             if z in wide_pick:
-                i = wide_pick[z]
+                w_tpl, w_qvs, w_refine, w_zscores, w_gz = wide_out[z]
                 failure, result = _finish_zmw(
-                    p, settings, wide.tpls[i], wide_qvs[i], wide_refine[i],
-                    wide.zscores[i, :nr], wide_gz[i], status_counts,
-                    n_passes, p.prep_ms + polish_ms)
+                    p, settings, w_tpl, w_qvs, w_refine, w_zscores, w_gz,
+                    status_counts, n_passes, p.prep_ms + polish_ms)
             else:
                 failure, result = _finish_zmw(
                     p, settings, polisher.tpls[z], qvs[z],
@@ -679,19 +708,35 @@ def _batch_extents(preps: Sequence[PreparedZmw]) -> tuple[int, int, int, int]:
             max(len(p.css) for p in preps))
 
 
-def menu_batch_shapes(preps: Sequence[PreparedZmw], *,
-                      fit_lanes: bool = False
-                      ) -> tuple[tuple[int, int, int], int]:
-    """The (Imax, Jmax, R)/Z a batch of the scheduled driver (or, with
-    `fit_lanes`, a flush of `ccs serve`) polishes at: its length class's
-    pin in the process's shape menu (parallel.batch.ShapeMenu), so a
-    file's batches share one family of programs.  Pass the pin on as
-    `buckets` to prebake_polish and polish_prepared_batch."""
+def menu_pin(preps: Sequence[PreparedZmw]) -> tuple[int, int, int]:
+    """The (Imax, Jmax, R) a batch of the scheduled driver or a flush of
+    `ccs serve` polishes at: its length class's pin in the process's
+    shape menu (parallel.batch.ShapeMenu), so a file's batches share one
+    family of programs.  Pass the pin on as `buckets` to prebake_polish
+    and polish_prepared_batch."""
     from pbccs_tpu.parallel.batch import shape_menu
 
-    imax, jmax, r, z = shape_menu.shapes(*_batch_extents(preps),
-                                         fit_lanes=fit_lanes)
-    return (imax, jmax, r), z
+    return shape_menu.shapes(*_batch_extents(preps))[:3]
+
+
+def menu_batch_shapes(preps: Sequence[PreparedZmw], full_zmws: int
+                      ) -> tuple[tuple[int, int, int], int | None]:
+    """A batch's pin (menu_pin) and the one Z every dispatch of the
+    scheduled driver at that pin runs at, to pass on as `min_z` with
+    `fixed_z`: the governor's ceiling for the pin, or the power of two of
+    a whole work item (`full_zmws`: the driver's --chunkSize) where that
+    is less.  A chunk over it is split into parts of Z ZMWs, and the
+    last part of a chunk and the last chunk of a file, however few ZMWs
+    they hold, polish at that Z too: a Z of their own would be a family
+    of programs of their own, loaded when they come.  None where the
+    governor gives the pin no ceiling (a backend that reports no
+    memory): a batch then keeps the Z of its own size."""
+    from pbccs_tpu.resilience import resources
+    from pbccs_tpu.utils import next_pow2
+
+    pin = menu_pin(preps)
+    cap = resources.default_governor().cap(resources.shape_bucket(*pin))
+    return pin, None if cap is None else min(cap, next_pow2(full_zmws, 1))
 
 
 def _pinned_batch_shapes(preps: Sequence[PreparedZmw],
@@ -832,7 +877,7 @@ def polish_prepared_batch(preps: Sequence[PreparedZmw],
         start = 0
         for size in resources.split_sizes(len(preps), cap):
             out.extend(_polish_split_part(
-                preps[start:start + size], settings, pin,
+                preps[start:start + size], settings, pin, cap,
                 on_error=on_error,
                 raise_device_shaped=raise_device_shaped))
             start += size
@@ -845,18 +890,18 @@ def polish_prepared_batch(preps: Sequence[PreparedZmw],
 
 
 def _polish_split_part(preps: Sequence[PreparedZmw],
-                       settings: ConsensusSettings, pin, *,
+                       settings: ConsensusSettings, pin, z: int, *,
                        on_error: str, raise_device_shaped: bool
                        ) -> list[tuple[Failure, ConsensusResult | None]]:
-    """One OOM-split part: pinned to the parent's (Imax, Jmax, R)
-    bucket (byte-identity) with its OWN pow2 Z (the smaller Z IS the
-    memory relief), full recovery semantics (further capacity splits,
-    quarantine, serial rescue) intact."""
-    from pbccs_tpu.utils import next_pow2
-
-    z = next_pow2(len(preps), 1)
+    """One part of a split batch: pinned to the parent's (Imax, Jmax, R)
+    bucket (byte-identity) and to the split's Z (the ceiling: the smaller
+    Z IS the memory relief), the remainder of few ZMWs too -- it polishes
+    in the program the whole parts loaded, not at a Z of its own.  Full
+    recovery semantics (further capacity splits, quarantine, serial
+    rescue) intact."""
     return _polish_guarded(preps, settings, buckets=pin, min_z=z,
-                           pin=pin, z_pin=z, on_error=on_error,
+                           fixed_z=True, pin=pin, z_pin=z,
+                           on_error=on_error,
                            raise_device_shaped=raise_device_shaped,
                            prebaked=None)
 
@@ -881,12 +926,14 @@ def _capacity_split(preps: Sequence[PreparedZmw],
     if len(preps) == 1:
         return [quarantine.serial_rescue(preps[0], settings, exc)]
     resources.note_oom_split()
+    from pbccs_tpu.utils import next_pow2
+
     mid = len(preps) // 2
     out: list[tuple[Failure, ConsensusResult | None]] = []
     for sub in (preps[:mid], preps[mid:]):
         out.extend(_polish_split_part(
-            sub, settings, pin, on_error=on_error,
-            raise_device_shaped=raise_device_shaped))
+            sub, settings, pin, next_pow2(len(preps) - mid, 1),
+            on_error=on_error, raise_device_shaped=raise_device_shaped))
     return out
 
 

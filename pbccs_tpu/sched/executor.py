@@ -102,12 +102,17 @@ class ScheduledPipeline:
     DevicePool, yielding (index, ResultTally) in submission order."""
 
     def __init__(self, pool: DevicePool,
-                 settings: "pipeline.ConsensusSettings",
+                 settings: "pipeline.ConsensusSettings", *,
+                 chunk_zmws: int,
                  prepare_workers: int = 2, on_error: str = "bisect",
                  max_inflight: int | None = None,
                  budget=None,
                  logger: Logger | None = None):
         self.pool = pool
+        # the most ZMWs a work item holds (the CLI's --chunkSize): a pin's
+        # dispatches run at one Z reckoned from it
+        # (pipeline.menu_batch_shapes)
+        self.chunk_zmws = chunk_zmws
         self.settings = settings
         self.prepare_workers = max(1, prepare_workers)
         self.on_error = on_error
@@ -207,11 +212,15 @@ class ScheduledPipeline:
                 if not preps:
                     finish(seq, (idx, tally))
                     return
-                # the class's pin, not the batch's own bucket: one
-                # family of programs a file, loaded with its first batch
-                pin, z = pipeline.menu_batch_shapes(preps)
+                # the class's pin, not the batch's own bucket, and under
+                # a governor's ceiling one Z for every dispatch at it:
+                # one family of programs a file, loaded with its first
+                # batch
+                pin, z = pipeline.menu_batch_shapes(preps, self.chunk_zmws)
                 imax, jmax, r = pin
-                key = (jmax, imax, r, z)
+                z_own = z or len(preps)
+                parts = -(-len(preps) // z_own)
+                key = (jmax, imax, r, z_own)
                 # host-budget gate (--memBudget): charge this batch's
                 # marshalled-bytes estimate BEFORE building the prebake;
                 # blocks (a visible resource.throttle, not a crash)
@@ -221,7 +230,7 @@ class ScheduledPipeline:
                     from pbccs_tpu.parallel.batch import premarshal_nbytes
 
                     lease = self.budget.admit(
-                        premarshal_nbytes((imax, jmax, r, z)),
+                        premarshal_nbytes((imax, jmax, r, z_own)),
                         site="sched.prepare", abort=stop.is_set)
                     if stop.is_set():
                         if lease is not None:
@@ -239,13 +248,11 @@ class ScheduledPipeline:
                 # ceiling is pre-split into parts that marshal their own
                 # subsets; any prebake failure falls back to inline
                 # marshalling (accounted, never fatal).
-                cap = resources.default_governor().cap(bucket)
                 prebaked = None
-                if self.settings.model != "quiver" and (
-                        cap is None or len(preps) <= cap):
+                if self.settings.model != "quiver" and parts == 1:
                     try:
-                        prebaked = pipeline.prebake_polish(preps,
-                                                           buckets=pin)
+                        prebaked = pipeline.prebake_polish(
+                            preps, buckets=pin, min_z=z or 1)
                     except Exception as e:  # noqa: BLE001 -- inline fallback
                         pipeline.record_zmw_failure(
                             "prepare.prebake", e,
@@ -286,9 +293,11 @@ class ScheduledPipeline:
                     try:
                         with obs_trace.span(
                                 "polish", zmws=len(preps), batch=idx,
-                                device=resources.current_device()):
+                                device=resources.current_device(),
+                                lanes=r, parent_z=z_own, parts=parts):
                             return pipeline.polish_prepared_batch(
                                 preps, settings, buckets=pin,
+                                min_z=z or 1, fixed_z=z is not None,
                                 on_error=on_error,
                                 raise_device_shaped=fleet
                                 and attempts[0] == 1,

@@ -96,7 +96,10 @@ def _warm_one(tasks) -> dict:
     from pbccs_tpu.parallel.batch import BatchPolisher
 
     opts = RefineOptions()
-    polisher = BatchPolisher(tasks)
+    # the bucket names its Z, as the drivers polish at theirs:
+    # warm_shape_set then loads the wide-band retry's program under
+    # Z = 32 too
+    polisher = BatchPolisher(tasks, fixed_z=True)
     polisher.refine(opts)
     polisher.consensus_qvs()
     polisher.warm_shape_set(opts)

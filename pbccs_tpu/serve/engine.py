@@ -57,7 +57,7 @@ from pbccs_tpu.pipeline import (
     ConsensusSettings,
     Failure,
     PreparedZmw,
-    menu_batch_shapes,
+    menu_pin,
     polish_prepared_batch,
     prepare_chunk,
 )
@@ -115,12 +115,12 @@ _m_flush_slots = {kind: _reg.counter(
 def _flush_shapes(preps: Sequence[PreparedZmw]) -> tuple[int, int, int]:
     """The (imax, jmax, r) a flush of these preps polishes at: the pin of
     their length class in the process's shape menu, joined by whatever
-    fits its lanes (a flush holds one ZMW or max_batch: `fit_lanes`) --
+    fits its lanes (a flush holds one ZMW or max_batch) --
     the ONE derivation shared by the batcher's key (a ZMW alone), the
     pinned polish call and the capacity-bucket key, so the governor
     ceiling the pool records is the same key the polish-time admission
     pre-split looks up."""
-    return menu_batch_shapes(preps, fit_lanes=True)[0]
+    return menu_pin(preps)
 
 
 def _polish_shape_pinned(preps: Sequence[PreparedZmw], settings, *,

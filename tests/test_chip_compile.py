@@ -186,7 +186,11 @@ def _refine_loop_case(z, r, jmax):
             hist_n=jnp.zeros(z, jnp.int32), overflow=jnp.asarray(False),
             dlayout=dr.state_layout(reads, rlens, win_tpl, win_trans, wlens,
                                     tables, alpha, beta, apre, bsuf,
-                                    width=width))
+                                    width=width),
+            # as batch._loop_state: a loop with no straggler exit watches
+            # for ZMWs whose rounds repeat
+            cycle=None if dr.straggler_exit_zmws(z)
+            else dr.new_cycle_watch(z, 48))
 
     state = jax.eval_shape(loop_state, tpl, tlens, tables, reads, rlens,
                            strands, tstarts, tends)
@@ -219,6 +223,10 @@ CASES = [
     pytest.param(_bucket_case, (128, 8, 576), id="bucket-128x8x576"),
     pytest.param(_refine_loop_case, (32, 10, 2112),
                  id="refine_loop-32x10x2112"),
+    # a part of the ragged cell slice's chunk: 32 lanes, the governor's
+    # ceiling Z = 8, no straggler exit, so with the cycle watch
+    pytest.param(_refine_loop_case, (8, 32, 2304),
+                 id="refine_loop-8x32x2304-cycle_watch"),
 ]
 
 

@@ -189,9 +189,9 @@ def test_a_files_batches_share_the_first_batchs_shape_set(
     menu_shapes = pipeline.menu_batch_shapes
     polish = pipeline.polish_prepared_batch
 
-    def noting_shapes(preps):
+    def noting_shapes(preps, full_zmws):
         own_buckets.append(pipeline._pinned_batch_shapes(preps, None, 1)[0])
-        return menu_shapes(preps)
+        return menu_shapes(preps, full_zmws)
 
     def noting_polish(preps, settings, **kw):
         try:
@@ -215,7 +215,8 @@ def test_a_files_batches_share_the_first_batchs_shape_set(
     # the same file with every batch at its own bucket
     monkeypatch.setattr(
         pipeline, "menu_batch_shapes",
-        lambda preps: pipeline._pinned_batch_shapes(preps, None, 1))
+        lambda preps, full_zmws: (
+            pipeline._pinned_batch_shapes(preps, None, 1)[0], None))
     del after_polish[:]
     own = run_cli(tmp_path, "own", in_bam, *flags)
     assert [p[0] for p in after_polish] == [(192, 192, 4), (128, 128, 4)]
@@ -233,9 +234,13 @@ def test_a_files_batches_share_the_first_batchs_shape_set(
      (2560, 2304, 12)),
     # 500 bp and 600 bp differ in band width: no shared pin
     ((640, 576, 32), (64, 30, 640, 600), (768, 640, 32, 64), None),
-    # two steps apart, or three times the reads: another class
+    # two steps apart: another class; more reads than the pin's lanes
+    # hold: a pin of its own, at its step of the lane ladder
     ((2560, 2304, 12), (64, 10, 100, 100), (128, 128, 12, 64), None),
     ((2560, 2304, 12), (64, 30, 2150, 2190), (2560, 2304, 32, 64), None),
+    ((2560, 2304, 12), (64, 14, 2150, 2190), (2560, 2304, 32, 64), None),
+    # fewer reads than the pin's lanes hold: it joins, and the lanes stay
+    ((2560, 2304, 32), (2, 3, 2150, 2190), (2560, 2304, 32, 2), None),
 ])
 def test_shape_menu_pins_a_length_class(pin, own, want, pin_after):
     menu = pbatch.ShapeMenu()

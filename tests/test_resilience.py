@@ -1017,7 +1017,7 @@ class TestBudgetedPipeline:
         items = [(i, [make_chunk(f"m/{2 * i + k}") for k in range(2)],
                   None) for i in range(8)]
         with DevicePool() as pool:
-            pipe = ScheduledPipeline(pool, ConsensusSettings(),
+            pipe = ScheduledPipeline(pool, ConsensusSettings(), chunk_zmws=64,
                                      prepare_workers=2, budget=budget)
             got = {}
             done = threading.Event()

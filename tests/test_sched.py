@@ -339,7 +339,7 @@ def test_scheduled_pipeline_matches_process_chunks():
             want_counts[f] += c
 
     with make_pool(4) as pool:
-        pipe = ScheduledPipeline(pool, settings, prepare_workers=2)
+        pipe = ScheduledPipeline(pool, settings, chunk_zmws=64, prepare_workers=2)
         got, got_counts = {}, {f: 0 for f in Failure}
         order = []
         for idx, tally in pipe.run(
@@ -368,7 +368,7 @@ def test_scheduled_pipeline_precomputed_and_chaos():
     scope = reg.scope()
     with make_pool(3, bench_after=1) as pool:
         bad = worker_name(pool, 0)
-        pipe = ScheduledPipeline(pool, settings, prepare_workers=1)
+        pipe = ScheduledPipeline(pool, settings, chunk_zmws=64, prepare_workers=1)
         with faults.active(f"sched.dispatch:error~{bad}"):
             items = [(0, None, base[0]),      # precomputed (restored)
                      (1, list(batches[1]), None)]
@@ -403,8 +403,8 @@ def test_executor_first_attempt_device_failure_reaches_pool(monkeypatch):
     flags = []
 
     def fake_polish(preps, settings, *, buckets=None, min_z=1,
-                    on_error="bisect", raise_device_shaped=False,
-                    prebaked=None):
+                    fixed_z=False, on_error="bisect",
+                    raise_device_shaped=False, prebaked=None):
         flags.append(raise_device_shaped)
         if len(flags) == 1:
             raise FakeXla("device fell over")
@@ -413,11 +413,11 @@ def test_executor_first_attempt_device_failure_reaches_pool(monkeypatch):
     monkeypatch.setattr(pl, "prepare_batch", stub_prepare)
     monkeypatch.setattr(pl, "polish_prepared_batch", fake_polish)
     monkeypatch.setattr(pl, "menu_batch_shapes",
-                        lambda preps: ((8, 8, 4), 4))
+                        lambda preps, full_zmws: ((8, 8, 4), None))
 
     scope = reg.scope()
     with make_pool(3) as pool:
-        pipe = ScheduledPipeline(pool, ConsensusSettings(),
+        pipe = ScheduledPipeline(pool, ConsensusSettings(), chunk_zmws=64,
                                  prepare_workers=1)
         emitted = dict(pipe.run([(0, chunks, None)]))
         assert any(w.strikes == 1 for w in pool._workers)
@@ -452,7 +452,7 @@ def _stub_host_and_device(monkeypatch, prep_seconds, polished=None,
     monkeypatch.setattr(pl, "prepare_chunk", stub_prepare_chunk)
     monkeypatch.setattr(pl, "polish_prepared_batch", stub_polish)
     monkeypatch.setattr(pl, "menu_batch_shapes",
-                        lambda preps: ((8, 8, 4), 4))
+                        lambda preps, full_zmws: ((8, 8, 4), None))
     monkeypatch.setattr(pl, "prebake_polish", lambda preps, **kw: None)
 
 
@@ -481,7 +481,7 @@ def test_first_polish_opens_after_its_own_slices_alone(monkeypatch):
     prev = obs_trace.set_tracer(tracer)
     try:
         with make_pool(1) as pool:
-            pipe = ScheduledPipeline(pool, ConsensusSettings(),
+            pipe = ScheduledPipeline(pool, ConsensusSettings(), chunk_zmws=64,
                                      prepare_workers=2)
             order = [idx for idx, _t in pipe.run(read_batches())]
     finally:
@@ -521,7 +521,7 @@ def test_slices_out_of_order_keep_chunk_and_emission_order(monkeypatch):
         polished,
         lambda preps: 0.5 if preps[0].chunk.id == batches[0][0].id else 0.01)
     with make_pool(2) as pool:
-        pipe = ScheduledPipeline(pool, ConsensusSettings(),
+        pipe = ScheduledPipeline(pool, ConsensusSettings(), chunk_zmws=64,
                                  prepare_workers=3)
         emitted = list(pipe.run(
             (i, b, None) for i, b in enumerate(batches)))
@@ -539,7 +539,7 @@ def test_a_chunk_whose_prepare_raises_tallies_other_alone(monkeypatch):
         lambda chunk: ValueError("boom") if chunk.id == bad else 0.0,
         polished)
     with make_pool(1) as pool:
-        pipe = ScheduledPipeline(pool, ConsensusSettings(),
+        pipe = ScheduledPipeline(pool, ConsensusSettings(), chunk_zmws=64,
                                  prepare_workers=2)
         emitted = dict(pipe.run(
             (i, b, None) for i, b in enumerate(batches)))
@@ -567,7 +567,7 @@ def test_reader_stalls_at_max_inflight_until_results_are_taken(
             yield i, batch, None
 
     with make_pool(1) as pool:
-        pipe = ScheduledPipeline(pool, ConsensusSettings(),
+        pipe = ScheduledPipeline(pool, ConsensusSettings(), chunk_zmws=64,
                                  prepare_workers=2,
                                  max_inflight=max_inflight)
         out = pipe.run(read_batches())

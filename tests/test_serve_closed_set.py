@@ -92,12 +92,13 @@ def flushes():
 @pytest.mark.parametrize("n", FLUSH_SIZES)
 def test_every_flush_size_polishes_in_the_first_ones_shape_set(flushes, n):
     """The flush of one ZMW builds (Imax, Jmax, R, Z = 16) and its
-    wide-band retry at the same Z; the flushes of 3 and of 16 build
-    nothing, though their own buckets differ in R and in Z."""
+    wide-band retry's set at the retry's own one Z; the flushes of 3 and
+    of 16 build nothing, though their own buckets differ in R and in Z."""
     first = flushes[FLUSH_SIZES[0]]
     assert first["sets_moved"] == 2
-    narrow, wide = sorted(first["built"])
-    assert narrow[:4] == wide[:4] and wide[4] == 2 * narrow[4]
+    wide, narrow = sorted(first["built"], key=lambda key: key[3])
+    assert narrow[:3] == wide[:3] and wide[4] == 2 * narrow[4]
+    assert wide[3] == pbatch.WIDE_BAND_Z
     assert narrow[2] == 12 and narrow[3] == MAX_BATCH
     if n != FLUSH_SIZES[0]:
         assert flushes[n]["sets_moved"] == 0 and not flushes[n]["built"]
@@ -143,7 +144,7 @@ def stub_prep(css_len: int, n_reads: int) -> PreparedZmw:
 
 @pytest.mark.parametrize("first, then", [
     # a long 5-pass draft pins Jmax 2,304; a 10-pass ZMW of 2,010 joins it
-    # (R grows to 12: a class spans a factor two in lanes)
+    # (R grows to 12: up to the ladder's 12 a pin grows by a factor two)
     ((2150, 5), (2010, 10)),
     # the other way round the pin grows once (a draft of 2,200 does not fit
     # 2,176 columns), and both sides then share it
@@ -164,16 +165,22 @@ def test_both_sides_of_a_jmax_edge_share_a_batcher_key(first, then):
     pbatch.shape_menu.reset_for_tests()
 
 
-@pytest.mark.parametrize("fit_lanes, r, pins", [
-    # a flush of `ccs serve`: it fits the pin's lanes and joins it
-    (True, 12, [(2560, 2304, 12)]),
-    # a chunk of the batch driver: a quarter of the lanes is a class of its own
-    (False, 4, [(2560, 2304, 12), (2560, 2304, 4)]),
+@pytest.mark.parametrize("n_zmws, n_reads, r, pins", [
+    # a flush of `ccs serve`, one 3-pass ZMW: it fits the pin's lanes and
+    # joins it
+    (1, 3, 12, [(2560, 2304, 12)]),
+    # a chunk of the batch driver does so too (since PR 46: a file's last
+    # chunk of few passes opens no pin of its own)
+    (64, 3, 12, [(2560, 2304, 12)]),
+    # more reads than the pin's lanes hold: a pin of its own, the first
+    # one's lanes as they were
+    (1, 13, 32, [(2560, 2304, 12), (2560, 2304, 32)]),
 ])
-def test_only_a_served_flush_joins_lanes_it_leaves_empty(fit_lanes, r, pins):
+def test_a_batch_joins_lanes_it_leaves_empty_and_grows_none(
+        n_zmws, n_reads, r, pins):
     menu = pbatch.ShapeMenu()
     menu._pins = [(2560, 2304, 12)]
-    assert menu.shapes(1, 3, 2180, 2200, fit_lanes=fit_lanes)[:3] == (2560, 2304, r)
+    assert menu.shapes(n_zmws, n_reads, 2180, 2200)[:3] == (2560, 2304, r)
     assert menu._pins == pins
 
 
@@ -183,7 +190,7 @@ def test_an_undeclared_servers_lanes_do_not_open_a_class_twice():
     after joins the pin it fits as it stands -- the 4-lane pin is not
     grown into a second 12-lane family."""
     menu = pbatch.ShapeMenu()
-    got = [menu.shapes(1, n, 2180, 2200, fit_lanes=True)[2] for n in (3, 10, 7, 10, 3)]
+    got = [menu.shapes(1, n, 2180, 2200)[2] for n in (3, 10, 7, 10, 3)]
     assert got == [4, 12, 12, 12, 4]
     assert sorted(pin[2] for pin in menu._pins) == [4, 12]
 

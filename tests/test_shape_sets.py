@@ -58,7 +58,7 @@ def two_batches_of_32(tmp_path_factory):
                 sub = self._straggler_sub([0])
                 refine(sub, opts or RefineOptions())
                 sub.consensus_qvs()
-                wide = self.wide_band_sub(self._row_tasks([1, 2], "wide"))
+                wide, = self.wide_band_subs(self._row_tasks([1, 2], "wide"))
                 wide.statuses
                 refine(wide, opts or RefineOptions())
                 wide.consensus_qvs()

@@ -230,10 +230,10 @@ def test_scheduled_turn_wait_runs_from_submit_to_the_polish_opening(
     monkeypatch.setattr(pipeline, "prepare_batch", stub_prepare)
     monkeypatch.setattr(pipeline, "polish_prepared_batch", stub_polish)
     monkeypatch.setattr(pipeline, "menu_batch_shapes",
-                        lambda preps: ((8, 8, 4), 4))
+                        lambda preps, full_zmws: ((8, 8, 4), None))
     monkeypatch.setattr(pipeline, "prebake_polish", lambda preps, **kw: None)
     with DevicePool(jax.devices()[:1]) as pool:
-        pipe = ScheduledPipeline(pool, pipeline.ConsensusSettings(),
+        pipe = ScheduledPipeline(pool, pipeline.ConsensusSettings(), chunk_zmws=64,
                                  prepare_workers=2)
         emitted = list(pipe.run([(0, ["a"], None), (1, ["b", "c"], None)]))
     assert [idx for idx, _tally in emitted] == [0, 1]
@@ -442,13 +442,12 @@ def test_polish_names_the_device_it_runs_on(tracer, monkeypatch):
     monkeypatch.setattr(pipeline, "polish_prepared_batch", stub_polish)
     monkeypatch.setattr(serve_engine, "polish_prepared_batch", stub_polish)
     monkeypatch.setattr(pipeline, "menu_batch_shapes",
-                        lambda preps, **kw: ((8, 8, 4), 4))
-    monkeypatch.setattr(serve_engine, "menu_batch_shapes",
-                        lambda preps, **kw: ((8, 8, 4), 4))
+                        lambda preps, full_zmws: ((8, 8, 4), None))
+    monkeypatch.setattr(serve_engine, "menu_pin", lambda preps: (8, 8, 4))
     monkeypatch.setattr(pipeline, "prebake_polish", lambda preps, **kw: None)
     device = jax.devices()[1]
     with DevicePool([device]) as pool:
-        pipe = ScheduledPipeline(pool, pipeline.ConsensusSettings(),
+        pipe = ScheduledPipeline(pool, pipeline.ConsensusSettings(), chunk_zmws=64,
                                  prepare_workers=1)
         assert [idx for idx, _t in pipe.run([(0, ["a"], None)])] == [0]
     serve_engine._polish_shape_pinned(["a", "b"], pipeline.ConsensusSettings())

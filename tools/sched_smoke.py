@@ -93,7 +93,7 @@ def check(name: str, ok: bool, detail: str = "") -> None:
 
 
 def run_scheduled(batches, settings, pool) -> list:
-    pipe = ScheduledPipeline(pool, settings, prepare_workers=2)
+    pipe = ScheduledPipeline(pool, settings, chunk_zmws=64, prepare_workers=2)
     emitted = list(pipe.run(
         (i, list(b), None) for i, b in enumerate(batches)))
     check("emission order == submission order",
